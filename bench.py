@@ -46,17 +46,31 @@ import json
 import os
 import sys
 import time
+import traceback
 
 BASELINE_MNIST_S_PER_ITER = 38.2  # BASELINE.md row 1, low end
 
-# TPU v5e (the bench chip) peak: 197 TFLOPS bf16. The MFU column divides
-# by this number, so it is the BF16-peak utilization; the sim computes in
-# f32, whose MXU peak is lower, making the printed MFU conservative
-# either way. Expectation check (VERDICT r3 #8): Biscotti's models are
-# 8k-164k params — thousands of times below the size where one chip
-# saturates — so the device round is dispatch/latency-bound and MFU is
-# honestly tiny; the number exists to say so with data, not to impress.
-PEAK_FLOPS_BF16 = 1.97e14
+# Peak bf16 FLOP/s of one chip, keyed by the `device_kind` JAX reports.
+# Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16). The
+# MFU column divides by this number, so it is the BF16-peak utilization;
+# the sim computes in f32, whose MXU peak is lower, making the printed MFU
+# conservative either way. Biscotti's models are 8k-164k params —
+# thousands of times below the size where one chip saturates — so the
+# device round is dispatch/latency-bound and MFU is honestly tiny; the
+# number exists to say so with data. A device that is not in the table is
+# an error, not a default: a utilization against another chip's peak is
+# no measurement.
+PEAK_FLOPS_BF16 = {"TPU v5 lite": 1.97e14}
+
+
+def peak_flops(device_kind):
+    try:
+        return PEAK_FLOPS_BF16[device_kind]
+    except KeyError:
+        raise SystemExit(
+            f"bench: no peak FLOP/s on record for device_kind "
+            f"{device_kind!r} — the bench measures the chip; add the kind "
+            f"to PEAK_FLOPS_BF16 with its source") from None
 
 
 def _timeit(fn, warm=1, iters=3):
@@ -190,7 +204,7 @@ def cross_host_round_bytes(cfg, w64, accepted, codec="raw64", hosts=2,
     return int(cross)
 
 
-def bench_config(name, cfg, device_iters=10, metrics=None):
+def bench_config(name, cfg, peak, device_iters=10, metrics=None):
     import jax
     import numpy as np
 
@@ -236,9 +250,9 @@ def bench_config(name, cfg, device_iters=10, metrics=None):
         "noising": cfg.noising, "poison": cfg.poison_fraction,
         "device_round_s": round(device_s, 6),
         "device_gflops_est": round(flops / 1e9, 3),
-        # fraction of one v5e's bf16 peak the device round achieves —
+        # fraction of this chip's bf16 peak the device round achieves —
         # see PEAK_FLOPS_BF16 note for why this is honestly tiny
-        "mfu": round(flops / max(device_s, 1e-9) / PEAK_FLOPS_BF16, 8),
+        "mfu": round(flops / max(device_s, 1e-9) / peak, 8),
         "accepted_per_round": accepted,
         "final_error": round(float(err), 4),
     }
@@ -315,15 +329,17 @@ def bench_config(name, cfg, device_iters=10, metrics=None):
         # the SAME mint-time settle with the kernel plane armed
         # (miner_crypto_device_s), plus the device MSM throughput at
         # this config's grid width (msm_points_per_s). Gated by
-        # availability and dimensionality: on this bench box the XLA
-        # *CPU* backend emulates the limb kernels, so CNN-sized grids
-        # are priced out by default — raise BISCOTTI_BENCH_DEVICE_MAX_D
-        # on a real accelerator, where the kernels are the point.
+        # availability (the backend's compiler must accept the kernels;
+        # where it refuses, the row says why) and dimensionality
+        # (BISCOTTI_BENCH_DEVICE_MAX_D prices out CNN-sized grids).
         from biscotti_tpu.crypto import kernels as dk
 
         device_cap = int(os.environ.get("BISCOTTI_BENCH_DEVICE_MAX_D",
                                         "2048"))
-        if dk.available() and c_chunks * k <= device_cap:
+        if not dk.available():
+            row["miner_crypto_device"] = (
+                f"skipped: {dk.availability_reason()}")
+        elif c_chunks * k <= device_cap:
             dk.set_enabled(True)
             try:
                 acc_dev = fold_intake()
@@ -331,11 +347,9 @@ def bench_config(name, cfg, device_iters=10, metrics=None):
                 if acc_dev._acc_dev is None:
                     # a device fault failed the batch over to CPU
                     # (VssIntakeBatch._device_failover): recording the
-                    # CPU settle as a device number would be a lie, and
-                    # dk.msm(·, None) would sink the bench — skip the
-                    # device keys for this config, loudly
-                    _progress(f"{name}: device settle failed over to "
-                              f"CPU — device keys skipped")
+                    # CPU settle as a device number would be a lie
+                    row["miner_crypto_device"] = {
+                        "error": "device settle failed over to the CPU"}
                 else:
                     dev_s = _timeit(lambda: acc_dev.verify(xs_all[sl]),
                                     warm=0, iters=reps)
@@ -457,8 +471,11 @@ def bench_peer_density(sizes=(100, 400, 1000), iterations=2,
     simulator row. Reports s/iter, peak RSS per co-hosted peer, and the
     chain-equality verdict, so BENCH_r*.json tracks the density frontier
     alongside the flagship round time. Each size runs as a subprocess
-    (its RSS peak must be its own, not the bench driver's); a failed or
-    timed-out size yields an error row, never a sunk bench.
+    (its RSS peak must be its own, not the bench driver's), on whatever
+    device JAX gives it — so this section runs BEFORE the parent first
+    touches JAX (a chip belongs to one process), one child at a time, and
+    each row carries the device its child reported. A failed or timed-out
+    size yields an error row, and the bench exits non-zero.
 
     Set BISCOTTI_BENCH_DENSITY=0 to skip (e.g. memory-constrained CI)."""
     import subprocess
@@ -479,11 +496,10 @@ def bench_peer_density(sizes=(100, 400, 1000), iterations=2,
                "-t", str(n), "-d", "mnist",
                "--iterations", str(iterations),
                "-sa", "0", "-np", "0", "-vp", "1", "--seed", "3"]
-        env = dict(os.environ, JAX_PLATFORMS="cpu")
         try:
             proc = subprocess.run(
                 cmd, cwd=os.path.dirname(os.path.abspath(__file__)),
-                env=env, capture_output=True, text=True, timeout=budget)
+                capture_output=True, text=True, timeout=budget)
             # one parser for the hive summary format (pod_launch is the
             # other consumer — shared so the two can't drift)
             from biscotti_tpu.tools.pod_launch import hive_summary
@@ -507,6 +523,10 @@ def bench_peer_density(sizes=(100, 400, 1000), iterations=2,
                 "rss_per_peer_mb": round(
                     s["rss_per_peer_bytes"] / 2**20, 2),
                 "loop_lag_s": s["loop_lag_s"],
+                "platform": s["platform"],
+                "device_kind": s["device_kind"],
+                "device_count": s["device_count"],
+                "devices_used": s["devices_used"],
             }
             _progress(f"peer_density: N={n} {s['s_per_iter']}s/iter, "
                       f"{out[name]['rss_per_peer_mb']}MB/peer, "
@@ -535,8 +555,7 @@ def bench_crypto_kernel(widths=(8, 35, 100)):
     from biscotti_tpu.crypto import kernels as dk
 
     if not dk.available():
-        return {"skipped": f"device kernels unavailable "
-                           f"({dk.availability_reason()})"}
+        return {"skipped": dk.availability_reason()}
     _progress(f"crypto_kernel: CPU vs device MSM at widths {widths}")
     key = cm.CommitKey.generate(max(widths), label=b"bench-msm")
     out = {}
@@ -832,12 +851,40 @@ def bench_migration(n=100, iterations=2, budget_s=600.0):
     return out
 
 
+def _errors(node, path=""):
+    """Paths of every `error` key in a result tree — any section that
+    caught a failure into its row."""
+    if not isinstance(node, dict):
+        return []
+    out = [path or "."] if "error" in node else []
+    for k, v in node.items():
+        out += _errors(v, f"{path}/{k}" if path else str(k))
+    return out
+
+
 def main():
+    # a pure-Python crypto fallback would time the wrong system ~30x slow
+    from biscotti_tpu.crypto import _native
+
+    if _native.load_error():
+        raise SystemExit(f"bench: native crypto library unavailable: "
+                         f"{_native.load_error()}")
+
+    # scale frontier: live hive-hosted peer density (one box, real
+    # rounds) — the number the hive runtime exists to move. FIRST: each
+    # child process needs the chip, which this process holds from its
+    # first JAX call on.
+    density = bench_peer_density()
+
     import jax
 
     from biscotti_tpu.config import BiscottiConfig, Defense
+    from biscotti_tpu.utils import jaxenv
 
     jax.config.update("jax_enable_x64", True)
+    jaxenv.configure_compile_cache()
+    device = jaxenv.device_info()
+    peak = peak_flops(device["device_kind"])
 
     base = dict(batch_size=10, epsilon=1.0, sample_percent=0.70,
                 num_verifiers=3, num_miners=3, num_noisers=2, seed=0)
@@ -886,9 +933,13 @@ def main():
     for name, cfg in configs:
         iters = 4 if cfg.model_name else 10  # CNN/svm rows: fewer reps
         try:
-            name, row, total = bench_config(name, cfg, device_iters=iters,
+            name, row, total = bench_config(name, cfg, peak,
+                                            device_iters=iters,
                                             metrics=registry)
-        except Exception as e:  # a config must never sink the whole bench
+        except Exception as e:
+            # the other configs still run and report; the error row
+            # makes the whole bench exit non-zero (see _errors)
+            traceback.print_exc()
             rows[name] = {"error": f"{type(e).__name__}: {e}"}
             continue
         # only the mnist SOFTMAX rows compare against the reference's 38.2
@@ -904,10 +955,6 @@ def main():
             # runtime this PR ships); the serial composition stays in the
             # row as round_total_s for the r02–r05 trajectory
             headline_total = row["round_total_pipelined_s"]
-
-    # scale frontier: live hive-hosted peer density (one box, real
-    # rounds) — the number the hive runtime exists to move
-    density = bench_peer_density()
 
     # straggler-degradation curve (ISSUE 10): live mnist round time at
     # 0/10/20% slowed peers, fixed vs adaptive deadlines
@@ -939,7 +986,7 @@ def main():
                               path="device")
 
     detail = {
-        "device": str(jax.devices()[0]),
+        **device,
         "data_note": ("synthetic Gaussian shards at reference dimensions "
                       "(zero-egress env): timings comparable, error columns "
                       "not"),
@@ -971,7 +1018,11 @@ def main():
         _progress(f"could not write detail file: {e}")
     print(json.dumps(detail), file=sys.stderr, flush=True)
     serial_total = rows.get("mnist_100_dp_eps1", {}).get("round_total_s")
+    errors = _errors(detail)
     out = {
+        **device,
+        # sections whose row holds an `error`: non-empty => exit code 1
+        "errors": errors,
         "metric": ("crypto-inclusive s/iter, 100-peer MNIST softmax + Krum "
                    "+ DP eps=1.0 + secure-agg, pipelined round engine "
                    "(ref fleet: 38.2 s/iter)"),
@@ -1010,7 +1061,7 @@ def main():
         "crypto_kernel": crypto_kernel,
     }
     print(json.dumps(out))
-    return 0
+    return 1 if errors else 0
 
 
 if __name__ == "__main__":

@@ -47,21 +47,26 @@ def _degrade(reason: str) -> None:
           f"target with `make -C native` to restore it.", file=sys.stderr)
 
 
-def _build() -> None:
-    """Build the shared object from source if absent (the .so is not
-    committed: its provenance could not be audited against the source).
-    Disable with BISCOTTI_NO_NATIVE_BUILD=1."""
+def _build() -> str:
+    """Run `make -C native`; returns "" or why the build failed. The .so
+    is never committed and never trusted from another machine: the
+    Makefile keys it to (sources, this host's CPU flags), so make is a
+    no-op when the binary was built here from these sources and a
+    rebuild otherwise. Disable with BISCOTTI_NO_NATIVE_BUILD=1."""
     if os.environ.get("BISCOTTI_NO_NATIVE_BUILD"):
-        return
+        return ""
     import subprocess
 
     native_dir = os.path.abspath(
         os.path.join(os.path.dirname(__file__), "..", "..", "native"))
     try:
         subprocess.run(["make", "-C", native_dir], check=True,
-                       capture_output=True, timeout=120)
-    except Exception:
-        pass  # pure-Python fallback covers everything
+                       capture_output=True, text=True, timeout=300)
+    except subprocess.CalledProcessError as e:
+        return f"`make -C native` failed: {e.stderr.strip()[-400:]}"
+    except (OSError, subprocess.TimeoutExpired) as e:
+        return f"`make -C native` did not run: {e}"
+    return ""
 
 
 def _selfcheck(lib: ctypes.CDLL) -> bool:
@@ -172,10 +177,9 @@ def _load() -> Optional[ctypes.CDLL]:
     if _load_attempted:
         return _lib
     _load_attempted = True
-    # always let make run: it is a no-op when the .so is current, and it
-    # refreshes a stale binary whose exported symbols predate the sources
-    # (which would otherwise silently drop all native acceleration)
-    _build()
+    # always let make run: it is a no-op when the .so was built on this
+    # host from these sources, and rebuilds a stale or foreign binary
+    build_error = _build()
     reason = ""
     found = False
     for path in _LIB_PATHS:
@@ -185,15 +189,16 @@ def _load() -> Optional[ctypes.CDLL]:
         found = True
         lib, reason = _try_load(full)
         if lib is None:
-            _build()  # one retry in case the first build raced/failed
+            # one retry in case the first build raced/failed
+            build_error = _build()
             lib, reason = _try_load(full)
         if lib is not None:
             _lib = lib
             break
     if _lib is None:
-        _degrade(reason if found else
+        _degrade(build_error or (reason if found else
                  "native/libbiscotti_native.so not found (never built, "
-                 "or BISCOTTI_NO_NATIVE_BUILD=1 suppressed the build)")
+                 "or BISCOTTI_NO_NATIVE_BUILD=1 suppressed the build)"))
     return _lib
 
 

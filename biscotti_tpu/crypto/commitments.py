@@ -364,12 +364,15 @@ def batch_verify_commitments(items: Sequence[Tuple[bytes, np.ndarray]],
         # exact, so the computed group elements — and the verdict — are
         # identical to the CPU backends'; a failed batch still bisects
         # through the CPU recompute (find_bad_commitments), so rejection
-        # evidence never comes from this path. Any device fault falls
-        # back to the CPU verdict below.
+        # evidence never comes from this path. A device FAULT falls
+        # back to the CPU verdict below; a compiler refusal is not a
+        # fault and propagates.
         try:
             lhs = dev.msm(gam, c_pts)
             rhs = dev.msm(scalars, key.device_buf(d))
             return ed.point_equal(lhs, rhs)
+        except dev.CompileError:
+            raise
         except Exception:
             pass
     lhs = msm(gam, c_pts)
@@ -525,6 +528,8 @@ def batch_schnorr_verify(items: Sequence[Tuple[bytes, bytes, bytes]]) -> bool:
             lhs = dev.fixed_base_mult([s_tot % _Q])[0]
             rhs = dev.msm(scalars, points)
             return ed.point_equal(lhs, rhs)
+        except dev.CompileError:
+            raise
         except Exception:
             pass
     lhs = base_mult_fast(s_tot % _Q)
@@ -1348,8 +1353,9 @@ class VssIntakeBatch:
         self._members.pop(sid, None)
 
     def _device_failover(self) -> List[int]:
-        """A device kernel FAULTED mid-batch (backend OOM, compile
-        failure — never a verdict): retire the device accumulator for
+        """A device kernel FAULTED mid-batch (backend OOM, a lost device
+        — never a verdict, and never a compiler refusal, which
+        propagates as kernels.CompileError): retire the device accumulator for
         this batch's lifetime and rebuild the CPU accumulator by
         re-folding every retained member grid (earlier waves live only
         in the device accumulator, and the grids are all retained in
@@ -1395,6 +1401,8 @@ class VssIntakeBatch:
                                      else dev.ext_add(self._acc_dev,
                                                       summed))
                 return rejected
+            except dev.CompileError:
+                raise
             except Exception:
                 wave = self._device_failover()
                 rejected = []
@@ -1480,6 +1488,8 @@ class VssIntakeBatch:
                 lhs = dev.pedersen_commit_point((8 * self._s_tot) % _Q,
                                                 (8 * self._t_tot) % _Q)
                 return ed.point_equal(lhs, rhs)
+            except dev.CompileError:
+                raise
             except Exception:
                 # re-fold every retained grid through the CPU path, then
                 # settle below exactly as an all-CPU batch would

@@ -10,8 +10,11 @@ behind one process-wide arming switch:
     kernels.set_enabled(True)          # what --device-crypto does
     kernels.active()                   # armed AND runnable here
 
-**Default OFF.** Disarmed (or unavailable: no jax, x64 mode off), every
-caller takes today's CPU path bit-identically. Armed, the seams PR 6
+**Default OFF.** Disarmed, every caller takes the CPU path
+bit-identically. Arming a plane the backend cannot run (no jax, x64 mode
+off, or a compiler that refuses the kernels — `available()` compiles the
+smallest one to find out) is an error naming the reason, never a silent
+CPU run. Armed, the seams PR 6
 created — `cm.batch_verify_commitments`, `VssIntakeBatch` wave folds,
 `cm.batch_schnorr_verify`, `ss.recover_coeffs` — compute their batch
 verdicts on device; the CPU path stays the exact-verdict oracle, and
@@ -27,59 +30,61 @@ Importing this package is cheap (numpy only): jax loads lazily on first
 
 from __future__ import annotations
 
-import sys
 from typing import Optional
 
 from biscotti_tpu.crypto.kernels.instrument import (  # noqa: F401
     device_calls, device_seconds, release_hooks, reset_counters,
     set_metrics_registry, set_span_hook)
 from biscotti_tpu.crypto.kernels.primitives import (  # noqa: F401
-    ext_add, fixed_base_mult, grid_validate_sum, msm, pedersen_commit_point,
-    point_neg_limbs, prewarm, shamir_recover)
+    CompileError, ext_add, fixed_base_mult, grid_validate_sum, msm,
+    pedersen_commit_point, point_neg_limbs, prewarm, shamir_recover)
 
 _enabled = False
 _avail: Optional[bool] = None
 _avail_reason = ""
-_warned = False
 
 
 def set_enabled(on: bool) -> None:
     """Arm/disarm the device-crypto plane process-wide (the
-    --device-crypto switch). Arming while unavailable degrades loudly —
-    one stderr note naming why — but gracefully: every seam keeps its
-    CPU path."""
-    global _enabled, _warned
+    --device-crypto switch). Arming while unavailable raises with the
+    reason: a run that asked for device crypto must not quietly do the
+    work on the CPU and report otherwise."""
+    global _enabled
+    if on and not available():
+        _enabled = False
+        raise RuntimeError(
+            f"--device-crypto requested but the device plane is "
+            f"unavailable here: {_avail_reason}")
     _enabled = bool(on)
-    if _enabled and not available() and not _warned:
-        _warned = True
-        print(f"[crypto/kernels] --device-crypto requested but the device "
-              f"plane is unavailable ({_avail_reason}); all crypto stays "
-              f"on the CPU path", file=sys.stderr)
-
-
-def enabled() -> bool:
-    return _enabled
 
 
 def available() -> bool:
-    """True when the kernel plane can run here: jax imports and x64 mode
-    is on (the limb accumulators are int64; enable via JAX_ENABLE_X64=1
-    or jax.config.update('jax_enable_x64', True) before first use)."""
+    """True when the kernel plane can run here: jax imports, x64 mode is
+    on (the limb accumulators are int64), and the default backend's
+    compiler accepts the smallest kernel (one point addition — every
+    kernel is built from its field multiply). Probed once per process;
+    the compiler's message is kept for `availability_reason()`."""
     global _avail, _avail_reason
     if _avail is None:
         try:
             import jax
-
-            if not jax.config.jax_enable_x64:
-                _avail = False
-                _avail_reason = ("jax x64 mode disabled — int64 limb "
-                                 "accumulators need JAX_ENABLE_X64=1")
-            else:
-                jax.devices()
-                _avail = True
-        except Exception as e:  # pragma: no cover - env-dependent
+        except ImportError as e:  # pragma: no cover - env-dependent
+            _avail, _avail_reason = False, f"jax unavailable: {e}"
+            return False
+        if not jax.config.jax_enable_x64:
             _avail = False
-            _avail_reason = f"jax unavailable: {type(e).__name__}: {e}"
+            _avail_reason = ("jax x64 mode disabled — int64 limb "
+                             "accumulators need JAX_ENABLE_X64=1")
+            return False
+        from biscotti_tpu.crypto.kernels import group, instrument
+
+        ident = group.IDENTITY_LIMBS[None]
+        try:
+            with instrument.suppressed():
+                ext_add(ident, ident)
+            _avail = True
+        except CompileError as e:
+            _avail, _avail_reason = False, str(e)
     return bool(_avail)
 
 
@@ -89,9 +94,9 @@ def availability_reason() -> str:
 
 
 def active() -> bool:
-    """Armed AND runnable — the one predicate every CPU/device dispatch
-    seam consults."""
-    return _enabled and available()
+    """Armed — the one predicate every CPU/device dispatch seam
+    consults. Arming already proved the plane runnable (set_enabled)."""
+    return _enabled
 
 
 def active_module():
@@ -101,11 +106,3 @@ def active_module():
     import biscotti_tpu.crypto.kernels as _k
 
     return _k if active() else None
-
-
-def _reset_probe_for_tests() -> None:
-    """Forget the cached availability probe (tests flip x64/jax state)."""
-    global _avail, _avail_reason, _warned
-    _avail = None
-    _avail_reason = ""
-    _warned = False

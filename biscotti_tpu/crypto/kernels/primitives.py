@@ -106,10 +106,28 @@ def _fixed_table(which: str) -> np.ndarray:
 # ------------------------------------------------------------- compiled
 
 
-def _get(key, builder):
+class CompileError(RuntimeError):
+    """The backend's compiler refused a kernel. Not a device fault: the
+    same program will be refused every time, so no caller fails over to
+    the CPU on it — it propagates, carrying the compiler's message."""
+
+
+def _get(key, builder, *args):
+    """The executable for `key`, compiled for `args`' shapes on first
+    use. Compilation is explicit (ahead of the call, not inside it) so a
+    compiler refusal surfaces as CompileError, apart from whatever the
+    device does at run time."""
     fn = _fn_cache.get(key)
     if fn is None:
-        fn = _fn_cache[key] = builder()
+        import jax
+
+        try:
+            fn = builder().lower(*args).compile()
+        except jax.errors.JaxRuntimeError as e:
+            raise CompileError(
+                f"device-crypto kernel {key} does not compile on "
+                f"{jax.default_backend()}: {e}") from e
+        _fn_cache[key] = fn
     return fn
 
 
@@ -243,8 +261,9 @@ def msm(scalars: Sequence[int], points) -> ed.Point:
             pts = np.concatenate(
                 [pts, np.broadcast_to(gp.IDENTITY_LIMBS,
                                       (m - n, 4, fe.LIMBS))])
-        fn = _get(("msm", m), lambda: _build_msm(m))
-        out = np.asarray(fn(bits.astype(np.int32), pts))
+        bits = bits.astype(np.int32)
+        fn = _get(("msm", m), lambda: _build_msm(m), bits, pts)
+        out = np.asarray(fn(bits, pts))
     return gp.limbs_to_point(out)
 
 
@@ -262,8 +281,10 @@ def fixed_base_mult(scalars: Sequence[int], which: str = "B") -> List[ed.Point]:
         if m != n:
             bits = np.concatenate(
                 [bits, np.zeros((m - n, 256), bits.dtype)])
-        fn = _get(("fixed", m), lambda: _build_fixed(m))
-        out = np.asarray(fn(bits.astype(np.int32), _fixed_table(which)))
+        bits = bits.astype(np.int32)
+        table = _fixed_table(which)
+        fn = _get(("fixed", m), lambda: _build_fixed(m), bits, table)
+        out = np.asarray(fn(bits, table))
     return [gp.limbs_to_point(out[i]) for i in range(n)]
 
 
@@ -274,10 +295,10 @@ def pedersen_commit_point(a: int, b: int) -> ed.Point:
         bits = np.concatenate([
             fe.scalars_to_bits([int(a) % fe.Q], msb_first=False),
             fe.scalars_to_bits([int(b) % fe.Q], msb_first=False),
-        ], axis=1)  # [1, 512]
+        ], axis=1).astype(np.int32)  # [1, 512]
         table = np.concatenate([_fixed_table("B"), _fixed_table("H")])
-        fn = _get(("fixed", 1), lambda: _build_fixed(1))
-        out = np.asarray(fn(bits.astype(np.int32), table))
+        fn = _get(("pedersen",), lambda: _build_fixed(1), bits, table)
+        out = np.asarray(fn(bits, table))
     return gp.limbs_to_point(out[0])
 
 
@@ -307,52 +328,23 @@ def grid_validate_sum(grids: Sequence) -> Tuple[np.ndarray,
             pad = np.zeros((wp - w, n, 2, fe.LIMBS), dtype=np.int64)
             pad[..., 1, 0] = 1  # affine identity (0, 1): valid, sums away
             xy = np.concatenate([xy, pad])
-        fn = _get(("grid", wp, n), lambda: _build_grid(wp, n))
+        fn = _get(("grid", wp, n), lambda: _build_grid(wp, n), xy)
         grid_ok, summed = fn(xy)
         mask = np.asarray(grid_ok)[:w]
-        if _use_pallas():
-            # experimental Pallas validation path: the on-curve mask from
-            # the Mosaic kernel must agree with the XLA verdict (the sum
-            # stays on the XLA path either way); a disagreement is a
-            # kernel bug and fails loudly rather than splitting verdicts
-            from biscotti_tpu.crypto.kernels import pallas_validate as pv
-
-            pm = pv.oncurve_mask(xy.reshape(wp * n, 2, fe.LIMBS))
-            pm = pm.reshape(wp, n)[:w]
-            xla_cell = _cell_canonical_mask(xy[:w])
-            if not np.array_equal(pm & xla_cell[0], xla_cell[1]):
-                raise RuntimeError(
-                    "pallas on-curve mask disagrees with the XLA verdict")
         if not mask.any():
             return mask, None
         summed_np = np.asarray(summed)
     return mask, summed_np
 
 
-def _cell_canonical_mask(xy: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
-    """Host-side per-cell (canonicity, canonicity AND on-curve) masks —
-    the cross-check oracle for the experimental Pallas path."""
-    w, n = xy.shape[0], xy.shape[1]
-    canon = np.zeros((w, n), dtype=bool)
-    full = np.zeros((w, n), dtype=bool)
-    for i in range(w):
-        for j in range(n):
-            x = fe.limbs_to_int(xy[i, j, 0])
-            y = fe.limbs_to_int(xy[i, j, 1])
-            c = x < fe.P and y < fe.P
-            canon[i, j] = c
-            full[i, j] = c and (
-                (y * y - x * x - 1 - ed.D * x * x * y * y) % fe.P == 0)
-    return canon, full
-
-
 def ext_add(acc: np.ndarray, other: np.ndarray) -> np.ndarray:
     """Pointwise acc[i] += other[i] over two [n, 4, 16] limb batches —
     the accumulator fold of the incremental VSS intake."""
     with timed("ext_add"):
-        fn = _get(("ext_add",), _build_ext_add)
-        return np.asarray(fn(np.asarray(acc, np.int64),
-                             np.asarray(other, np.int64)))
+        acc = np.asarray(acc, np.int64)
+        other = np.asarray(other, np.int64)
+        fn = _get(("ext_add", acc.shape[0]), _build_ext_add, acc, other)
+        return np.asarray(fn(acc, other))
 
 
 def shamir_recover(pinv: np.ndarray, agg: np.ndarray) -> np.ndarray:
@@ -360,9 +352,11 @@ def shamir_recover(pinv: np.ndarray, agg: np.ndarray) -> np.ndarray:
     device, rounded → [C, k] int64 chunk coefficients (the
     `ss.recover_coeffs` tail)."""
     with timed("shamir_recover"):
-        fn = _get(("recover",), _build_recover)
-        sol = np.asarray(fn(np.asarray(pinv, np.float64),
-                            np.asarray(agg, np.int64)))
+        pinv = np.asarray(pinv, np.float64)
+        agg = np.asarray(agg, np.int64)
+        fn = _get(("recover", pinv.shape, agg.shape), _build_recover,
+                  pinv, agg)
+        sol = np.asarray(fn(pinv, agg))
     return np.ascontiguousarray(sol.T)
 
 
@@ -370,37 +364,25 @@ def prewarm(grid_points: int = 0) -> None:
     """Compile the ladder kernels at the bucket shapes a cluster of this
     dimensionality will hit (`grid_points` = C·k, the commitment-grid
     width), so XLA compile time is paid ONCE at peer startup instead of
-    inside a round deadline. No-op when the plane is disarmed; any
-    compile failure is swallowed — the seams fall back to CPU exactly as
-    they would mid-round."""
+    inside a round deadline. No-op when the plane is disarmed. This IS
+    the start-up compile: a kernel the backend refuses raises
+    CompileError here, before the first round, not mid-round."""
     from biscotti_tpu.crypto import kernels
     from biscotti_tpu.crypto.kernels import instrument
 
     if not kernels.active():
         return
-    try:
-        # suppressed: warm-up wall-clock must not pollute the round-work
-        # instrumentation (seconds accumulators, histogram, spans)
-        with instrument.suppressed():
-            fixed_base_mult([1])
-            pedersen_commit_point(1, 1)
-            n = max(1, int(grid_points))
-            msm([1] * n, [ed.BASE] * n)
-            if grid_points:
-                ident = np.zeros((n, 64), np.uint8)
-                ident[:, 32] = 1  # affine identity (0, 1): on-curve
-                grid_validate_sum([ident])
-    except Exception:
-        pass
-
-
-def _use_pallas() -> bool:
-    """Pallas grid-validation dispatch: off by default (the XLA path's
-    conv-matmul already lowers to MXU-shaped ops); BISCOTTI_PALLAS_CRYPTO=1
-    opts in (interpret mode off-TPU — exercised by the kernel tests)."""
-    import os
-
-    return os.environ.get("BISCOTTI_PALLAS_CRYPTO", "") == "1"
+    # suppressed: warm-up wall-clock must not pollute the round-work
+    # instrumentation (seconds accumulators, histogram, spans)
+    with instrument.suppressed():
+        fixed_base_mult([1])
+        pedersen_commit_point(1, 1)
+        n = max(1, int(grid_points))
+        msm([1] * n, [ed.BASE] * n)
+        if grid_points:
+            ident = np.zeros((n, 64), np.uint8)
+            ident[:, 32] = 1  # affine identity (0, 1): on-curve
+            grid_validate_sum([ident])
 
 
 __all__ = [

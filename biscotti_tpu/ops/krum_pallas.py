@@ -119,50 +119,68 @@ def krum_scores_pallas(deltas: jax.Array, num_adversaries: int) -> jax.Array:
     kd_steps = d_pad // d_t
 
     kernel = functools.partial(_krum_kernel, n=n, k=k, kd_steps=kd_steps)
-    scores = pl.pallas_call(
-        kernel,
-        grid=(n_pad // TILE_M, kd_steps),
-        in_specs=[
-            pl.BlockSpec((TILE_M, d_t), lambda i, kd: (i, kd),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((n_pad, d_t), lambda i, kd: (0, kd),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((TILE_M, 1), lambda i, kd: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, n_pad), lambda i, kd: (0, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((TILE_M, 1), lambda i, kd: (i, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((n_pad, 1), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((TILE_M, n_pad), jnp.float32)],
-        interpret=jax.default_backend() != "tpu",
-    )(x, x, sq[:, None], sq[None, :])
+
+    def call(interpret, x, sq_col, sq_row):
+        # Mosaic has no 64-bit types: under jax_enable_x64 the kernel's
+        # weak Python ints (loop indices, index maps, shifts) would trace
+        # as int64 and fail to lower, so the call is traced with x64 off.
+        # Every operand is already float32, so no value changes.
+        with jax.enable_x64(False):
+            return pl.pallas_call(
+                kernel,
+                grid=(n_pad // TILE_M, kd_steps),
+                in_specs=[
+                    pl.BlockSpec((TILE_M, d_t), lambda i, kd: (i, kd),
+                                 memory_space=pltpu.VMEM),
+                    pl.BlockSpec((n_pad, d_t), lambda i, kd: (0, kd),
+                                 memory_space=pltpu.VMEM),
+                    pl.BlockSpec((TILE_M, 1), lambda i, kd: (i, 0),
+                                 memory_space=pltpu.VMEM),
+                    pl.BlockSpec((1, n_pad), lambda i, kd: (0, 0),
+                                 memory_space=pltpu.VMEM),
+                ],
+                out_specs=pl.BlockSpec((TILE_M, 1), lambda i, kd: (i, 0),
+                                       memory_space=pltpu.VMEM),
+                out_shape=jax.ShapeDtypeStruct((n_pad, 1), jnp.float32),
+                scratch_shapes=[pltpu.VMEM((TILE_M, n_pad), jnp.float32)],
+                interpret=interpret,
+            )(x, x, sq_col, sq_row)
+
+    # the platform being LOWERED FOR picks the branch (not the process's
+    # default backend), so an ahead-of-time compile for a TPU topology
+    # from a CPU host lowers through Mosaic like the chip does; interpret
+    # mode exists only for the JAX_PLATFORMS=cpu tests
+    scores = jax.lax.platform_dependent(
+        x, sq[:, None], sq[None, :],
+        tpu=functools.partial(call, False),
+        default=functools.partial(call, True))
     return scores[:n, 0]
 
 
-# committees below this stay on the XLA matmul+top_k path (faster at
-# small n: one fused HLO, no grid/padding overhead). Device-trace
-# measurements inside the window at d=7850 on v5e (eval/eval_krum_kernel):
-# 1.17x at n=512, 1.48x at 1024, 0.96x at 2048 (break-even: XLA's sort
-# happens to tile well there), 1.48x at 4096 — the window is kept
-# contiguous rather than carving out the one ~4% break-even size.
+# committees below this stay on the XLA matmul+top_k path (one fused HLO,
+# no grid/padding overhead). The window's speed-up is unmeasured on the
+# current machine (eval/eval_krum_kernel.py regenerates it from a device
+# trace); what the v5e run of PR 21 established is correctness across it.
 PALLAS_MIN_N = 512
 # above this the kernel's VMEM working set (double-buffered (n_pad, d_t)
-# operand stripe + (TILE_M, n_pad) gram scratch) no longer compiles on
-# v5e (verified: n=8192 fails Mosaic VMEM allocation) — fall back to XLA
+# operand stripe + (TILE_M, n_pad) gram scratch) outgrows VMEM — fall back
+# to XLA. The ceiling itself was re-checked on the v5e with libtpu 0.0.34
+# (chip_smoke.py, PR 21): n = 4096 at d = 164,266 compiles and runs.
 PALLAS_MAX_N = 4096
 
 
 def krum_scores_auto(deltas: jax.Array, num_adversaries: int) -> jax.Array:
     """Dispatch Krum scoring: XLA path for small committees (and for
     n beyond the kernel's VMEM ceiling), the fused Pallas kernel for
-    large ones on TPU.
+    large ones on TPU. "On TPU" is the platform the enclosing program is
+    being lowered for, so an ahead-of-time compile for the chip makes the
+    chip's choice wherever it runs.
 
     Deployment constraint (ADVICE r3): inside the [PALLAS_MIN_N,
     PALLAS_MAX_N] window the accept set is backend-dependent — Pallas and
-    XLA scores agree only to ~1e-4 rtol, so tie-boundary accept sets can
-    differ between a TPU verifier and a CPU verifier. All verifiers of one
+    XLA scores agree to float-sum reassociation (2.3e-7 relative seen on
+    the v5e, both 4.1e-6 off a float64 oracle), so tie-boundary accept
+    sets can differ between a TPU verifier and a CPU verifier. All verifiers of one
     cluster must therefore share a backend (see docs/RUNTIME.md,
     "Verifier backend homogeneity"). The live protocol's committees
     (3-70 verifiers) sit below PALLAS_MIN_N, where every backend takes
@@ -170,7 +188,9 @@ def krum_scores_auto(deltas: jax.Array, num_adversaries: int) -> jax.Array:
     sizes >= 512."""
     from biscotti_tpu.ops.krum import krum_scores
 
-    n = deltas.shape[0]
-    if PALLAS_MIN_N <= n <= PALLAS_MAX_N and jax.default_backend() == "tpu":
-        return krum_scores_pallas(deltas, num_adversaries)
+    if PALLAS_MIN_N <= deltas.shape[0] <= PALLAS_MAX_N:
+        return jax.lax.platform_dependent(
+            deltas,
+            tpu=lambda x: krum_scores_pallas(x, num_adversaries),
+            default=lambda x: krum_scores(x, num_adversaries))
     return krum_scores(deltas, num_adversaries)

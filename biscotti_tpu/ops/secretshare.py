@@ -246,8 +246,10 @@ def recover_coeffs(agg_shares: jax.Array, xs: jax.Array,
     if dev is not None:
         try:
             return dev.shamir_recover(pinv, agg)
+        except dev.CompileError:
+            raise
         except Exception:
-            pass  # exact host matmul below
+            pass  # device fault: exact host matmul below
     sol = pinv @ agg.astype(np.float64)  # [k, C]
     return np.round(sol.T).astype(np.int64)  # [C, k]
 
@@ -426,7 +428,6 @@ def make_sharded_share_fns(mesh, axis: str = "chunks",
     C must divide over the mesh axis size. Runs wherever the mesh lives —
     the 8-device virtual CPU mesh in tests; on TPU pods this axis rides
     hosts (int64 — see module docstring on device placement)."""
-    from biscotti_tpu.utils.compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     _require_x64("make_sharded_share_fns")
@@ -442,13 +443,13 @@ def make_sharded_share_fns(mesh, axis: str = "chunks",
         return _recover_kernel(agg, vandermonde(xs, poly_size)
                                .astype(jnp.float64))
 
-    make_sh = jax.jit(shard_map(
+    make_sh = jax.jit(jax.shard_map(
         _make, mesh=mesh, in_specs=(P(axis, None),),
         out_specs=P(None, axis), check_vma=False))
-    agg_sh = jax.jit(shard_map(
+    agg_sh = jax.jit(jax.shard_map(
         _agg, mesh=mesh, in_specs=(P(None, None, axis),),
         out_specs=P(None, axis), check_vma=False))
-    recover_sh = jax.jit(shard_map(
+    recover_sh = jax.jit(jax.shard_map(
         _recover, mesh=mesh, in_specs=(P(None, axis), P()),
         out_specs=P(axis, None), check_vma=False))
     return make_sh, agg_sh, recover_sh

@@ -156,9 +156,10 @@ class Simulator:
         self._noise_scale = dp_noise.sigma_for(self._noise_eps, cfg.delta)
         self._dp_mechanism = cfg.dp_mechanism
         self._noise_alpha = alpha if self.mode == "sgd" else 1.0
-        self._round_step_raw = self._build_round_step()
+        self._round_step_raw, noised_raw = self._build_round_step()
         self._round_step_jit = jax.jit(self._round_step_raw,
                                        donate_argnums=(0, 1))
+        self._noised_jit = jax.jit(noised_raw)
 
         def round_step(w, stake, it):
             return self._round_step_jit(w, stake, it,
@@ -229,19 +230,20 @@ class Simulator:
         # data tensors are ARGUMENTS, not closure captures: a captured jnp
         # array is baked into the HLO as a constant, which at CNN sizes
         # makes the program itself hundreds of MB (the [N, rows, d] peer
-        # stack) — slow to compile and over upload limits on remote-compile
-        # setups. As arguments they stay device-resident buffers. The SEED
+        # stack) and slow to compile. As arguments they stay
+        # device-resident buffers. The SEED
         # is an argument for the same reason: a baked-in PRNGKey constant
         # would force a fresh trace+compile per seed, making multi-seed
         # sweeps (eval_poison --seeds) pay the compile N times.
         seed_base = jax.random.PRNGKey(0)  # same constant for every sim
 
-        def round_step(w, stake, it, seed, x, y, x_val, y_val):
+        def noised_updates(w, it, seed, x, y):
+            """Round `it`'s contributor ids with their raw and noised
+            deltas — everything the round does before the defence."""
             rkey = jax.random.fold_in(jax.random.fold_in(seed_base, seed),
                                       it)
             ckey, bkey, nkey = jax.random.split(rkey, 3)
             cidx = self._contributors(ckey)
-            s = cidx.shape[0]
 
             bkeys = jax.vmap(lambda i: jax.random.fold_in(bkey, i))(cidx)
             deltas = jax.vmap(one_delta, in_axes=(None, 0, 0, 0))(
@@ -253,8 +255,11 @@ class Simulator:
                 noise = jax.vmap(self._peer_noise)(nkeys)
             else:
                 noise = jnp.zeros_like(deltas)
-            noised = deltas + noise
+            return cidx, deltas, deltas + noise
 
+        def round_step(w, stake, it, seed, x, y, x_val, y_val):
+            cidx, deltas, noised = noised_updates(w, it, seed, x, y)
+            s = cidx.shape[0]
             mask = defense_mask(defense, model, w, noised, x_val,
                                 y_val, cfg.roni_threshold,
                                 default_num_adversaries(s))
@@ -273,7 +278,7 @@ class Simulator:
             err = model.error_flat(w_next, x_val, y_val)
             return w_next, stake_next, mask, err
 
-        return round_step
+        return round_step, noised_updates
 
     # ------------------------------------------------------------------ run
 
@@ -353,6 +358,13 @@ class Simulator:
 
     # ------------------------------------------------------------------ metrics
 
+    def noised_updates(self, w, it: int) -> jax.Array:
+        """The [S, d] noised deltas round `it` hands the verifier
+        committee at weights `w` — the defence's actual input, for
+        checking a scoring kernel against an oracle on it."""
+        return self._noised_jit(w, it, jnp.asarray(self.cfg.seed, jnp.int32),
+                                self.x, self.y)[2]
+
     def test_error(self, w) -> float:
         return float(self.model.error_flat(jnp.asarray(w), self.x_val, self.y_val))
 
@@ -394,7 +406,28 @@ def make_sharded_round_step(sim: Simulator, mesh: jax.sharding.Mesh,
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from biscotti_tpu.utils.compat import shard_map
+    step = sharded_round_step_fn(sim, mesh, axis)
+    sharding = NamedSharding(mesh, P(axis))
+    x_sh = jax.device_put(sim.x, sharding)
+    y_sh = jax.device_put(sim.y, sharding)
+
+    def run_step(w, it, seed: Optional[int] = None):
+        s = sim.cfg.seed if seed is None else seed
+        return step(w, x_sh, y_sh, jnp.asarray(it),
+                    jnp.asarray(s, jnp.int32))
+
+    run_step.x = x_sh  # the sharded peer stack: lets a caller check placement
+    return run_step
+
+
+def sharded_round_step_fn(sim: Simulator, mesh: jax.sharding.Mesh,
+                          axis: str = "peers"):
+    """The jitted program behind make_sharded_round_step:
+    `(w, x, y, it, seed) -> (w', mask, err)` with (x, y) sharded over
+    `axis`. No data is placed, so it can also be lowered ahead of time for
+    a mesh of devices this host does not have
+    (tests/test_tpu_lowering.py)."""
+    from jax.sharding import PartitionSpec as P
 
     cfg = sim.cfg
     model = sim.model
@@ -456,24 +489,13 @@ def make_sharded_round_step(sim: Simulator, mesh: jax.sharding.Mesh,
         err = model.error_flat(w_next, sim.x_val, sim.y_val)
         return w_next, mask, err
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         sharded_step, mesh=mesh,
         in_specs=(P(), P(axis), P(axis), P(), P()),
         out_specs=(P(), P(), P()),
         check_vma=False,
     )
-    step = jax.jit(mapped)
-
-    sharding = NamedSharding(mesh, P(axis))
-    x_sh = jax.device_put(sim.x, sharding)
-    y_sh = jax.device_put(sim.y, sharding)
-
-    def run_step(w, it, seed: Optional[int] = None):
-        s = sim.cfg.seed if seed is None else seed
-        return step(w, x_sh, y_sh, jnp.asarray(it),
-                    jnp.asarray(s, jnp.int32))
-
-    return run_step
+    return jax.jit(mapped)
 
 
 # ------------------------------------------------------------------- CLI
@@ -506,6 +528,9 @@ def main(argv=None) -> int:
         ap.error("--metrics-out requires a non-scan run (run_scan compiles "
                  "the whole training into one XLA program; there are no "
                  "per-round host observations to export)")
+    from biscotti_tpu.utils import jaxenv
+
+    jaxenv.configure_compile_cache()
     cfg = BiscottiConfig.from_args(ns)
     registry = None
     if ns.metrics_out:
@@ -532,6 +557,7 @@ def main(argv=None) -> int:
         "final_error": logs[-1].error if logs else float("nan"),
         "test_error": sim.test_error(w),
         "attack_rate": sim.attack_rate(w),
+        **jaxenv.device_info(),
     }
     print(_json.dumps(summary))
     return 0

@@ -418,8 +418,11 @@ class PeerAgent:
         # critical path into crypto_cpu vs crypto_device. Any
         # non-qualifying construction CLEARS the hooks so a torn-down
         # cluster's telemetry never keeps receiving kernel events.
+        # raises, naming the compiler's reason, when the plane is asked
+        # for on a backend that cannot run it — a start-up error, never
+        # a silent CPU run
         devkern.set_enabled(cfg.device_crypto)
-        self.device_crypto = cfg.device_crypto and devkern.available()
+        self.device_crypto = cfg.device_crypto
         self._devkern_span_hook = None
         self._devkern_registry = None
         if cfg.device_crypto and cfg.telemetry:
@@ -4990,7 +4993,10 @@ def main(argv=None) -> int:
     # embedders must do this themselves — secretshare fails loudly if not)
     import jax
 
+    from biscotti_tpu.utils import jaxenv
+
     jax.config.update("jax_enable_x64", True)
+    jaxenv.configure_compile_cache()
     cfg = BiscottiConfig.from_args(ns)
     cfg = cfg.replace(timeouts=cfg.timeouts.scaled(
         cfg.num_nodes, cfg.num_verifiers, cfg.num_miners,

@@ -365,7 +365,10 @@ def main(argv=None) -> int:
 
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    # the platform is inherited, never forced: one process owns a chip, so
+    # SEVERAL launches on one host (every localhost layout here) cannot
+    # share it — set JAX_PLATFORMS=cpu for those yourself; one launch per
+    # remote host gets that host's accelerator
 
     def launch(key, h, cmd):
         if h == "localhost":
@@ -377,7 +380,7 @@ def main(argv=None) -> int:
                 stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
                 text=True)))
         else:
-            remote = (f"cd {shlex.quote(REPO)} && JAX_PLATFORMS=cpu "
+            remote = (f"cd {shlex.quote(REPO)} && "
                       f"{' '.join(map(shlex.quote, cmd))}")
             ssh = [*shlex.split(args.ssh_cmd), h, remote]
             if args.dry_run:

@@ -192,10 +192,12 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     if args.chrome_out:
         args.trace = 1
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import jax
 
+    from biscotti_tpu.utils import jaxenv
+
     jax.config.update("jax_enable_x64", True)
+    jaxenv.configure_compile_cache()
 
     from biscotti_tpu.config import BiscottiConfig, Defense, Timeouts
     from biscotti_tpu.runtime.peer import PeerAgent

@@ -182,7 +182,10 @@ def main(argv=None) -> int:
 
     import jax
 
+    from biscotti_tpu.utils import jaxenv
+
     jax.config.update("jax_enable_x64", True)
+    jaxenv.configure_compile_cache()
 
     from biscotti_tpu.config import BiscottiConfig, Defense, Timeouts
     from biscotti_tpu.runtime import adversary, faults, hive

@@ -5,8 +5,8 @@ wall-clock deltas between log lines, parsed after the fact by
 eval_performance/parseLogs.py):
 
 * `device_trace(log_dir)` — context manager around `jax.profiler` so any
-  run (bench, sim, peer) can capture a real XLA device trace viewable in
-  TensorBoard/Perfetto.
+  run (bench, sim, peer) can capture a real XLA device trace;
+  `device_program_ms(log_dir)` reduces it to per-program device times.
 * `PhaseClock` — cheap cumulative wall-clock accounting by phase name
   (sgd / noise / crypto_commit / share_gen / verify_wait / miner_verify /
   recovery / transport). The peer agent carries one and returns the totals
@@ -19,28 +19,57 @@ from __future__ import annotations
 
 import contextlib
 import time
-from typing import Dict
+from typing import Dict, List
 
 
 @contextlib.contextmanager
 def device_trace(log_dir: str):
-    """Capture a jax.profiler device trace into `log_dir` (TensorBoard /
-    Perfetto format). No-op context if profiling is unavailable."""
+    """Capture a jax.profiler device trace into `log_dir` (an
+    `.xplane.pb` under `plugins/profile/<time>/`; read it back with
+    `device_program_ms`). A profiler that will not start or stop raises:
+    a caller that asked for a trace must not get an untraced run."""
     import jax
 
-    try:
-        jax.profiler.start_trace(log_dir)
-        started = True
-    except Exception:
-        started = False
+    jax.profiler.start_trace(log_dir)
     try:
         yield
     finally:
-        if started:
-            try:
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
+        jax.profiler.stop_trace()
+
+
+def device_program_ms(trace_dir: str) -> Dict[str, List[float]]:
+    """Device durations (ms) of every XLA program in the newest trace
+    under `trace_dir`, keyed by program name (`jit_<fn>`), one entry per
+    execution — read from the `.xplane.pb`, the profiler's own record,
+    with nothing but JAX. Layout as seen on the v5e (PR 21): device
+    planes are `/device:TPU:n`; their "XLA Modules" line carries one
+    event per program run, named `jit_<fn>(<fingerprint>)` ("XLA Ops"
+    holds the per-op events). Raises when the trace holds no device
+    plane: a CPU trace has no device time to report."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    paths = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    if not paths:
+        raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
+    data = ProfileData.from_file(paths[-1])
+    planes = [p for p in data.planes if p.name.startswith("/device:TPU")]
+    if not planes:
+        raise RuntimeError(
+            f"{paths[-1]} has no /device:TPU plane (planes: "
+            f"{[p.name for p in data.planes]}) — device time comes only "
+            "from a trace taken on the chip")
+    out: Dict[str, List[float]] = {}
+    for line in planes[0].lines:
+        if line.name != "XLA Modules":
+            continue
+        for ev in line.events:
+            out.setdefault(ev.name.split("(")[0], []).append(
+                ev.duration_ns / 1e6)
+    return out
 
 
 class PhaseClock:

@@ -263,6 +263,9 @@ def main(argv=None) -> int:
     import jax
 
     jax.config.update("jax_enable_x64", True)
+    from biscotti_tpu.utils import jaxenv
+
+    jaxenv.configure_compile_cache()
 
     from biscotti_tpu.config import Defense
     from biscotti_tpu.tools.verdicts import separates

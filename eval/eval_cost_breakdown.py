@@ -43,6 +43,9 @@ def main(argv=None) -> int:
 
     jax.config.update("jax_platforms", args.platform)
     jax.config.update("jax_enable_x64", True)
+    from biscotti_tpu.utils import jaxenv
+
+    jaxenv.configure_compile_cache()
 
     from biscotti_tpu.config import BiscottiConfig, Defense, Timeouts
     from biscotti_tpu.runtime.peer import PeerAgent

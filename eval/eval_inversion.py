@@ -41,6 +41,9 @@ def main(argv=None) -> int:
 
     if args.platform:
         jax.config.update("jax_platforms", args.platform)
+    from biscotti_tpu.utils import jaxenv
+
+    jaxenv.configure_compile_cache()
     import jax.numpy as jnp
     import numpy as np
 

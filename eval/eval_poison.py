@@ -103,9 +103,9 @@ def main(argv=None) -> int:
         jax.config.update("jax_platforms", args.platform)
     # persistent compile cache: cells with the same defense share one HLO
     # (data + seed are arguments), so the sweep compiles once per defense
-    jax.config.update("jax_compilation_cache_dir",
-                      os.path.join(REPO, ".jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    from biscotti_tpu.utils import jaxenv
+
+    jaxenv.configure_compile_cache()
 
     from biscotti_tpu.config import BiscottiConfig, Defense
     from biscotti_tpu.parallel.sim import Simulator

@@ -39,6 +39,9 @@ def main(argv=None) -> int:
 
     if args.platform:
         jax.config.update("jax_platforms", args.platform)
+    from biscotti_tpu.utils import jaxenv
+
+    jaxenv.configure_compile_cache()
 
     from biscotti_tpu.config import BiscottiConfig, Defense
     from biscotti_tpu.parallel.sim import Simulator

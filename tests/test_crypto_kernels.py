@@ -257,17 +257,6 @@ def test_grid_validate_matches_cpu_loader():
     assert mask4.tolist() == [False] and summed4 is None
 
 
-def test_pallas_validation_agrees_with_xla(monkeypatch):
-    g1 = _good_grid(seed=3)
-    bad = g1.copy()
-    bad[2, 33] ^= 4
-    base = kernels.grid_validate_sum([g1, bad])[0].tolist()
-    monkeypatch.setenv("BISCOTTI_PALLAS_CRYPTO", "1")
-    # the pallas path cross-checks itself against the XLA verdict and
-    # raises on disagreement — same mask coming back IS the assertion
-    assert kernels.grid_validate_sum([g1, bad])[0].tolist() == base
-
-
 @prop(max_examples=4)
 @given(seed=st.integers(0, 2**31))
 def test_shamir_recover_matches_cpu(seed):
@@ -422,6 +411,30 @@ def test_vss_device_fault_fails_over_to_cpu(armed, monkeypatch):
                             RuntimeError("backend fault")))
     assert acc2.verify(xs) is True
     assert acc2._dev_failed
+
+
+def test_compiler_refusal_is_not_a_device_fault(armed, monkeypatch):
+    """A kernel the backend's compiler refuses must not fail over to the
+    CPU and report a device run: CompileError propagates from every
+    seam, and arming a plane whose probe was refused is an error."""
+    comms, rows, br, xs, ent, dims = _vss_instance(seed=22)
+    acc = cm.VssIntakeBatch(*dims, entropy=ent)
+    assert acc.add(1, comms, rows, br)
+
+    def refuse(*a, **kw):
+        raise kernels.CompileError("UNIMPLEMENTED: s64 dot")
+
+    monkeypatch.setattr(kernels, "grid_validate_sum", refuse)
+    with pytest.raises(kernels.CompileError):
+        acc.fold()
+    assert not acc._dev_failed
+
+    kernels.set_enabled(False)
+    monkeypatch.setattr(kernels, "_avail", False)
+    monkeypatch.setattr(kernels, "_avail_reason", "UNIMPLEMENTED: s64 dot")
+    with pytest.raises(RuntimeError, match="UNIMPLEMENTED: s64 dot"):
+        kernels.set_enabled(True)
+    assert not kernels.active()
 
 
 def test_recover_coeffs_parity(armed):
