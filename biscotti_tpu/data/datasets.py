@@ -44,6 +44,14 @@ from typing import Dict, Tuple
 
 import numpy as np
 
+from biscotti_tpu.utils.profiling import PhaseClock
+
+# `shard_draw`: the seconds `_draw` took, summed over every thread that
+# drew (busy-seconds, not wall clock: callers fill `load_shard`'s cache
+# from several threads); also the span `biscotti:shard_draw` in a profiler
+# trace
+CLOCK = PhaseClock()
+
 
 @dataclass(frozen=True)
 class DatasetSpec:
@@ -190,6 +198,12 @@ def disjoint_shard_capacity(dataset: str) -> "int | None":
 
 
 def _draw(dataset: str, tag: str, n: int) -> Tuple[np.ndarray, np.ndarray]:
+    with CLOCK.phase("shard_draw"):
+        return _draw_rows(dataset, tag, n)
+
+
+def _draw_rows(dataset: str, tag: str, n: int
+                ) -> Tuple[np.ndarray, np.ndarray]:
     s = _spec(dataset)
     if s.real:
         x, y = _real_corpus(dataset)

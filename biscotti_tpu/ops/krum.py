@@ -34,10 +34,12 @@ def pairwise_sq_dists(x: jax.Array) -> jax.Array:
     """D[i,j] = ‖x_i − x_j‖², computed as one MXU matmul (ref:
     client_obj.py:131-134). float32 accumulation keeps scores stable for
     bfloat16 inputs."""
-    x = x.astype(jnp.float32)
-    sq = jnp.sum(x * x, axis=-1)
-    d = sq[:, None] + sq[None, :] - 2.0 * (x @ x.T)
-    return jnp.maximum(d, 0.0)  # clamp fp cancellation noise
+    with jax.named_scope("krum_prepare"):  # parallel/sim.py STAGES
+        x = x.astype(jnp.float32)
+        sq = jnp.sum(x * x, axis=-1)
+    with jax.named_scope("krum_scores"):
+        d = sq[:, None] + sq[None, :] - 2.0 * (x @ x.T)
+        return jnp.maximum(d, 0.0)  # clamp fp cancellation noise
 
 
 @partial(jax.jit, static_argnames=("num_adversaries",))
@@ -48,12 +50,13 @@ def krum_scores(deltas: jax.Array, num_adversaries: int) -> jax.Array:
     groupsize = n - num_adversaries
     k = max(groupsize - 2, 0)
     d = pairwise_sq_dists(deltas)
-    # exclude self-distance exactly (the reference's sorted[0] drop)
-    d = d + jnp.diag(jnp.full((n,), jnp.inf, jnp.float32))
-    if k == 0:
-        return jnp.zeros((n,), jnp.float32)
-    neg_nearest, _ = jax.lax.top_k(-d, k)
-    return -jnp.sum(neg_nearest, axis=-1)
+    with jax.named_scope("krum_scores"):
+        # exclude self-distance exactly (the reference's sorted[0] drop)
+        d = d + jnp.diag(jnp.full((n,), jnp.inf, jnp.float32))
+        if k == 0:
+            return jnp.zeros((n,), jnp.float32)
+        neg_nearest, _ = jax.lax.top_k(-d, k)
+        return -jnp.sum(neg_nearest, axis=-1)
 
 
 @partial(jax.jit, static_argnames=("num_adversaries",))
@@ -66,8 +69,9 @@ def krum_accept_mask(deltas: jax.Array, num_adversaries: int) -> jax.Array:
     n = deltas.shape[0]
     keep = n - num_adversaries
     scores = krum_scores_auto(deltas, num_adversaries)
-    _, idx = jax.lax.top_k(-scores, keep)
-    return jnp.zeros((n,), jnp.bool_).at[idx].set(True)
+    with jax.named_scope("krum_select"):
+        _, idx = jax.lax.top_k(-scores, keep)
+        return jnp.zeros((n,), jnp.bool_).at[idx].set(True)
 
 
 def krum_select(deltas: jax.Array, num_adversaries: int) -> jax.Array:

@@ -109,13 +109,14 @@ def krum_scores_pallas(deltas: jax.Array, num_adversaries: int) -> jax.Array:
     if k == 0:
         return jnp.zeros((n,), jnp.float32)
 
-    x = deltas.astype(jnp.float32)
     n_pad = -(-n // TILE_M) * TILE_M
     # feature tile: bounded VMEM for the (n_pad, d_t) operand stripe
     d_t = 256 if n_pad <= 4096 else 128
     d_pad = -(-d // d_t) * d_t
-    x = jnp.pad(x, ((0, n_pad - n), (0, d_pad - d)))
-    sq = jnp.sum(x * x, axis=-1)  # zero padding leaves norms exact
+    with jax.named_scope("krum_prepare"):  # parallel/sim.py STAGES
+        x = deltas.astype(jnp.float32)
+        x = jnp.pad(x, ((0, n_pad - n), (0, d_pad - d)))
+        sq = jnp.sum(x * x, axis=-1)  # zero padding leaves norms exact
     kd_steps = d_pad // d_t
 
     kernel = functools.partial(_krum_kernel, n=n, k=k, kd_steps=kd_steps)
@@ -150,11 +151,12 @@ def krum_scores_pallas(deltas: jax.Array, num_adversaries: int) -> jax.Array:
     # default backend), so an ahead-of-time compile for a TPU topology
     # from a CPU host lowers through Mosaic like the chip does; interpret
     # mode exists only for the JAX_PLATFORMS=cpu tests
-    scores = jax.lax.platform_dependent(
-        x, sq[:, None], sq[None, :],
-        tpu=functools.partial(call, False),
-        default=functools.partial(call, True))
-    return scores[:n, 0]
+    with jax.named_scope("krum_scores"):
+        scores = jax.lax.platform_dependent(
+            x, sq[:, None], sq[None, :],
+            tpu=functools.partial(call, False),
+            default=functools.partial(call, True))
+        return scores[:n, 0]
 
 
 # committees below this stay on the XLA matmul+top_k path (one fused HLO,

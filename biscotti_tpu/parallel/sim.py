@@ -44,6 +44,28 @@ from biscotti_tpu.models.zoo import model_for_dataset
 from biscotti_tpu.ops import dp_noise
 from biscotti_tpu.ops.krum import default_num_adversaries, krum_accept_mask
 from biscotti_tpu.ops.roni import roni_accept_mask
+from biscotti_tpu.utils.profiling import PhaseClock
+
+# The round's stages, as `jax.named_scope`s in the round program: THE
+# vocabulary a device trace is read by (docs/OBSERVABILITY.md, "Device
+# trace"). A scope is compile-time metadata: it lands in the `op_name` of
+# every instruction traced inside it and changes no instruction. The
+# one-chip step and `sharded_round_step_fn` open them in the helpers they
+# share, so both programs carry the same names. The three `krum_*` scopes
+# are opened in ops/krum.py and ops/krum_pallas.py. Each name is matched as
+# a whole token, and none is a JAX primitive's or function's name.
+STAGES = (
+    "round_sample",     # contributor choice, key folding, minibatch indices
+    "round_gather",     # x[cidx], y[cidx], then each peer's xi[idx], yi[idx]
+    "round_grad",       # loss gradient and clip, vmapped over the peers
+    "round_noise",      # noise keys, the normal draw, deltas + noise
+    "krum_prepare",     # cast, pad, squared norms
+    "krum_scores",      # Pallas kernel, or Gram matmul + distances + top_k
+    "krum_select",      # top_k of the scores, the accept-mask scatter
+    "round_aggregate",  # masked sum of the accepted deltas, w + agg
+    "round_ledger",     # stake scatter, the fault plane's drop mask
+    "round_eval",       # test error of the next weights
+)
 
 
 @dataclass
@@ -128,44 +150,61 @@ class Simulator:
         self.num_params = self.model.num_params
         n = cfg.num_nodes
 
+        # set-up and per-round host phases, also `biscotti:<name>` spans in
+        # a profiler trace (sim.shards / sim.stack / sim.to_device /
+        # sim.build; sim.round.args / sim.round.dispatch)
+        self.phases = PhaseClock()
+
         poisoned = _poisoned_ids(n, cfg.poison_fraction)
         xs, ys = [], []
-        for i in range(n):
-            shard = ds.load_shard(cfg.dataset,
-                                  ds.shard_name(cfg.dataset, i, i in poisoned))
-            xs.append(shard["x_train"])
-            ys.append(shard["y_train"])
+        with self.phases.phase("sim.shards"):
+            for i in range(n):
+                shard = ds.load_shard(
+                    cfg.dataset, ds.shard_name(cfg.dataset, i, i in poisoned))
+                xs.append(shard["x_train"])
+                ys.append(shard["y_train"])
+            test = ds.load_shard(cfg.dataset, f"{cfg.dataset}_test")
+            attack = ds.load_shard(cfg.dataset, f"{cfg.dataset}_digit1")
         rows = min(len(x) for x in xs)
-        self.x = jnp.asarray(np.stack([x[:rows] for x in xs]))  # [N, rows, d]
-        self.y = jnp.asarray(np.stack([y[:rows] for y in ys]))  # [N, rows]
+        with self.phases.phase("sim.stack"):
+            x_host = np.stack([x[:rows] for x in xs])  # [N, rows, d]
+            y_host = np.stack([y[:rows] for y in ys])  # [N, rows]
+        # the hand-over only: the copy itself runs on the runtime's threads
+        # after jnp.asarray returns, beside whatever the host does next
+        # (tracing and fetching the first round). Waiting for it here was
+        # tried (PERF.md, PR 24): 22 s at 3,383 peers, and 5-6 s more of
+        # set-up than not waiting
+        with self.phases.phase("sim.to_device"):
+            self.x = jnp.asarray(x_host)
+            self.y = jnp.asarray(y_host)
+            self.x_val = jnp.asarray(test["x_test"])
+            self.y_val = jnp.asarray(test["y_test"])
+            self.x_attack = jnp.asarray(attack["x_test"])
+            self.y_attack = jnp.asarray(attack["y_test"])
         self.rows = rows
 
-        test = ds.load_shard(cfg.dataset, f"{cfg.dataset}_test")
-        self.x_val = jnp.asarray(test["x_test"])
-        self.y_val = jnp.asarray(test["y_test"])
-        attack = ds.load_shard(cfg.dataset, f"{cfg.dataset}_digit1")
-        self.x_attack = jnp.asarray(attack["x_test"])
-        self.y_attack = jnp.asarray(attack["y_test"])
-
-        self.root_key = jax.random.PRNGKey(cfg.seed)
-        alpha = cfg.logreg_alpha
-        self._step = local_step_fn(self.model, self.mode, clip=cfg.grad_clip,
-                                   alpha=alpha)
-        self._noise_eps = (cfg.epsilon
-                           if cfg.noising or cfg.dp_in_model else 0.0)
-        self._noise_scale = dp_noise.sigma_for(self._noise_eps, cfg.delta)
-        self._dp_mechanism = cfg.dp_mechanism
-        self._noise_alpha = alpha if self.mode == "sgd" else 1.0
-        self._round_step_raw, noised_raw = self._build_round_step()
-        self._round_step_jit = jax.jit(self._round_step_raw,
-                                       donate_argnums=(0, 1))
-        self._noised_jit = jax.jit(noised_raw)
+        with self.phases.phase("sim.build"):
+            self.root_key = jax.random.PRNGKey(cfg.seed)
+            alpha = cfg.logreg_alpha
+            self._step = local_step_fn(self.model, self.mode,
+                                       clip=cfg.grad_clip, alpha=alpha)
+            self._use_noise = cfg.noising or cfg.dp_in_model
+            self._noise_eps = cfg.epsilon if self._use_noise else 0.0
+            self._noise_scale = dp_noise.sigma_for(self._noise_eps, cfg.delta)
+            self._dp_mechanism = cfg.dp_mechanism
+            self._noise_alpha = alpha if self.mode == "sgd" else 1.0
+            self._round_step_raw, noised_raw = self._build_round_step()
+            self._round_step_jit = jax.jit(self._round_step_raw,
+                                           donate_argnums=(0, 1))
+            self._noised_jit = jax.jit(noised_raw)
 
         def round_step(w, stake, it):
-            return self._round_step_jit(w, stake, it,
-                                        jnp.asarray(self.cfg.seed, jnp.int32),
-                                        self.x, self.y,
-                                        self.x_val, self.y_val)
+            with self.phases.phase("sim.round.args"):
+                seed = jnp.asarray(self.cfg.seed, jnp.int32)
+            with self.phases.phase("sim.round.dispatch"):
+                return self._round_step_jit(w, stake, it, seed,
+                                            self.x, self.y,
+                                            self.x_val, self.y_val)
 
         self.round_step = round_step
 
@@ -199,11 +238,43 @@ class Simulator:
             )
         return (-self._noise_alpha / b) * draw
 
+    def _one_delta(self, w: jax.Array, key: jax.Array, xi: jax.Array,
+                   yi: jax.Array) -> jax.Array:
+        """One peer's raw delta: minibatch indices, the rows, the step.
+        Vmapped over the peers by both round programs."""
+        with jax.named_scope("round_sample"):
+            idx = sample_batch(key, self.rows, self.cfg.batch_size)
+        with jax.named_scope("round_gather"):
+            xb, yb = xi[idx], yi[idx]
+        with jax.named_scope("round_grad"):
+            return self._step(w, xb, yb)
+
+    def _peer_updates(self, w: jax.Array, bkey: jax.Array, nkey: jax.Array,
+                      ids: jax.Array, x: jax.Array, y: jax.Array,
+                      gather: bool = False):
+        """Raw and noised [S, d] deltas of the peers `ids` — shared by the
+        one-chip step and the sharded one, so the two draw the same
+        streams under the same scopes. The rows of (x, y) are those peers'
+        shards, or with `gather` the whole stack that `ids` picks them
+        from (the one-chip step's sampled contributors)."""
+        with jax.named_scope("round_sample"):
+            bkeys = jax.vmap(lambda i: jax.random.fold_in(bkey, i))(ids)
+        if gather:
+            with jax.named_scope("round_gather"):
+                x, y = x[ids], y[ids]
+        deltas = jax.vmap(self._one_delta, in_axes=(None, 0, 0, 0))(
+            w, bkeys, x, y)  # [S, d]
+        with jax.named_scope("round_noise"):
+            if self._use_noise:
+                nkeys = jax.vmap(lambda i: jax.random.fold_in(nkey, i))(ids)
+                noise = jax.vmap(self._peer_noise)(nkeys)
+            else:
+                noise = jnp.zeros_like(deltas)
+            return deltas, deltas + noise
+
     def _build_round_step(self):
         cfg = self.cfg
         model = self.model
-        batch = cfg.batch_size
-        use_noise = cfg.noising or cfg.dp_in_model
         defense = cfg.defense if cfg.verification else Defense.NONE
         # cheap mirror of the live fault plane (cfg.fault_plan, runtime/
         # faults.py): with drop probability p, each contributor's round
@@ -223,10 +294,6 @@ class Simulator:
                 "mask to carry the drops (run the live runtime for that)")
         fault_base = jax.random.PRNGKey(cfg.fault_plan.seed)
 
-        def one_delta(w, key, xi, yi):
-            idx = sample_batch(key, self.rows, batch)
-            return self._step(w, xi[idx], yi[idx])
-
         # data tensors are ARGUMENTS, not closure captures: a captured jnp
         # array is baked into the HLO as a constant, which at CNN sizes
         # makes the program itself hundreds of MB (the [N, rows, d] peer
@@ -240,22 +307,14 @@ class Simulator:
         def noised_updates(w, it, seed, x, y):
             """Round `it`'s contributor ids with their raw and noised
             deltas — everything the round does before the defence."""
-            rkey = jax.random.fold_in(jax.random.fold_in(seed_base, seed),
-                                      it)
-            ckey, bkey, nkey = jax.random.split(rkey, 3)
-            cidx = self._contributors(ckey)
-
-            bkeys = jax.vmap(lambda i: jax.random.fold_in(bkey, i))(cidx)
-            deltas = jax.vmap(one_delta, in_axes=(None, 0, 0, 0))(
-                w, bkeys, x[cidx], y[cidx]
-            )  # [S, d]
-
-            if use_noise:
-                nkeys = jax.vmap(lambda i: jax.random.fold_in(nkey, i))(cidx)
-                noise = jax.vmap(self._peer_noise)(nkeys)
-            else:
-                noise = jnp.zeros_like(deltas)
-            return cidx, deltas, deltas + noise
+            with jax.named_scope("round_sample"):
+                rkey = jax.random.fold_in(
+                    jax.random.fold_in(seed_base, seed), it)
+                ckey, bkey, nkey = jax.random.split(rkey, 3)
+                cidx = self._contributors(ckey)
+            deltas, noised = self._peer_updates(w, bkey, nkey, cidx, x, y,
+                                                gather=True)
+            return cidx, deltas, noised
 
         def round_step(w, stake, it, seed, x, y, x_val, y_val):
             cidx, deltas, noised = noised_updates(w, it, seed, x, y)
@@ -263,22 +322,63 @@ class Simulator:
             mask = defense_mask(defense, model, w, noised, x_val,
                                 y_val, cfg.roni_threshold,
                                 default_num_adversaries(s))
-            delta_stake = jnp.where(mask, cfg.stake_unit, -cfg.stake_unit)
-            if drop_p > 0.0:
-                dkey = jax.random.fold_in(fault_base, it)
-                keep = jax.random.uniform(dkey, (s,)) >= drop_p
-                mask = mask & keep  # lost frames join no aggregate …
-                delta_stake = jnp.where(keep, delta_stake, 0)  # … or ledger
-            w_next = w + masked_aggregate(mask, deltas, noised,
-                                          cfg.dp_in_model, defense,
-                                          cfg.trim_fraction)
-
-            stake_next = stake.at[cidx].add(delta_stake)
-
-            err = model.error_flat(w_next, x_val, y_val)
+            with jax.named_scope("round_ledger"):
+                delta_stake = jnp.where(mask, cfg.stake_unit,
+                                        -cfg.stake_unit)
+                if drop_p > 0.0:
+                    dkey = jax.random.fold_in(fault_base, it)
+                    keep = jax.random.uniform(dkey, (s,)) >= drop_p
+                    mask = mask & keep  # lost frames join no aggregate …
+                    delta_stake = jnp.where(keep, delta_stake, 0)  # … or ledger
+            with jax.named_scope("round_aggregate"):
+                w_next = w + masked_aggregate(mask, deltas, noised,
+                                              cfg.dp_in_model, defense,
+                                              cfg.trim_fraction)
+            with jax.named_scope("round_ledger"):
+                stake_next = stake.at[cidx].add(delta_stake)
+            with jax.named_scope("round_eval"):
+                err = model.error_flat(w_next, x_val, y_val)
             return w_next, stake_next, mask, err
 
         return round_step, noised_updates
+
+    def round_hlo(self) -> str:
+        """The optimized HLO text of the round program at this simulator's
+        own shapes, every instruction carrying its `op_name` with the
+        STAGES scopes: what a device trace's instruction names (`fusion.3`)
+        are joined against (docs/OBSERVABILITY.md, "Device trace").
+
+        Lowered from shapes (no buffer is touched, nothing is donated) with
+        `it` as the weakly typed Python int that run() passes, and compiled
+        OUTSIDE the persistent compile cache: its key ignores scope
+        metadata (`jax_compilation_cache_include_metadata_in_key` is off),
+        so a cache filled by an older tree would hand back that tree's
+        executable, and its text that tree's names. For the same reason it
+        is traced anew, through a wrapper of its own: JAX keeps the
+        executable it fetched on the memoized lowering of
+        `_round_step_jit`, and would hand that back uncompiled. A compile
+        costs seconds: call it after a timed window, never in one."""
+        from jax.experimental.compilation_cache import compilation_cache
+
+        def round_step(*args):  # the name the program and its scopes carry
+            return self._round_step_raw(*args)
+
+        w, stake = jax.eval_shape(self.init_state)
+        it = jax.ShapeDtypeStruct((), jax.dtypes.canonicalize_dtype(int),
+                                  weak_type=True)
+        seed = jax.ShapeDtypeStruct((), jnp.int32)
+        data = [jax.ShapeDtypeStruct(a.shape, a.dtype)
+                for a in (self.x, self.y, self.x_val, self.y_val)]
+        lowered = jax.jit(round_step, donate_argnums=(0, 1)).lower(
+            w, stake, it, seed, *data)
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()  # the switch is read once a process
+        try:
+            return lowered.compile().as_text()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was)
+            compilation_cache.reset_cache()
 
     # ------------------------------------------------------------------ run
 
@@ -432,7 +532,6 @@ def sharded_round_step_fn(sim: Simulator, mesh: jax.sharding.Mesh,
     cfg = sim.cfg
     model = sim.model
     n = cfg.num_nodes
-    use_noise = cfg.noising or cfg.dp_in_model
     defense = cfg.defense if cfg.verification else Defense.NONE
     f = default_num_adversaries(n)
     seed_base = jax.random.PRNGKey(0)  # same constant as _build_round_step
@@ -440,23 +539,14 @@ def sharded_round_step_fn(sim: Simulator, mesh: jax.sharding.Mesh,
     fault_base = jax.random.PRNGKey(cfg.fault_plan.seed)
 
     def local_deltas(w, x_loc, y_loc, it, seed):
-        def one(key, xi, yi):
-            idx = sample_batch(key, sim.rows, cfg.batch_size)
-            return sim._step(w, xi[idx], yi[idx])
-
-        pid = jax.lax.axis_index(axis)
-        n_loc = x_loc.shape[0]
-        gids = pid * n_loc + jnp.arange(n_loc)
-        rkey = jax.random.fold_in(jax.random.fold_in(seed_base, seed), it)
-        bkey, nkey = jax.random.split(rkey)
-        bkeys = jax.vmap(lambda i: jax.random.fold_in(bkey, i))(gids)
-        deltas = jax.vmap(one)(bkeys, x_loc, y_loc)
-        if use_noise:
-            nkeys = jax.vmap(lambda i: jax.random.fold_in(nkey, i))(gids)
-            noise = jax.vmap(sim._peer_noise)(nkeys)
-        else:
-            noise = jnp.zeros_like(deltas)
-        return deltas, deltas + noise
+        with jax.named_scope("round_sample"):
+            pid = jax.lax.axis_index(axis)
+            n_loc = x_loc.shape[0]
+            gids = pid * n_loc + jnp.arange(n_loc)
+            rkey = jax.random.fold_in(jax.random.fold_in(seed_base, seed),
+                                      it)
+            bkey, nkey = jax.random.split(rkey)
+        return sim._peer_updates(w, bkey, nkey, gids, x_loc, y_loc)
 
     def sharded_step(w, x_loc, y_loc, it, seed):
         deltas, noised = local_deltas(w, x_loc, y_loc, it, seed)
@@ -467,26 +557,30 @@ def sharded_round_step_fn(sim: Simulator, mesh: jax.sharding.Mesh,
             # mirror of the live fault plane's frame drops: the accepted
             # update whose miner-bound frame is lost contributes nothing
             # (see _build_round_step for the exact shared semantics)
-            dkey = jax.random.fold_in(fault_base, it)
-            mask = mask & (jax.random.uniform(dkey, (n,)) >= drop_p)
-        pid = jax.lax.axis_index(axis)
-        n_loc = deltas.shape[0]
-        if defense == Defense.TRIMMED_MEAN:
-            # order statistics need the FULL peer set: one more all_gather
-            # (of the raw deltas) and the trimmed aggregate is computed
-            # replicated — same collective budget class as Krum's gather
-            src = all_noised if cfg.dp_in_model else jax.lax.all_gather(
-                deltas, axis, tiled=True)
-            agg = masked_aggregate(mask, src, src, cfg.dp_in_model,
-                                   defense, cfg.trim_fraction)
-        else:
-            local_mask = jax.lax.dynamic_slice_in_dim(mask, pid * n_loc,
-                                                      n_loc)
-            local_agg = masked_aggregate(local_mask, deltas, noised,
-                                         cfg.dp_in_model)
-            agg = jax.lax.psum(local_agg, axis)
-        w_next = w + agg
-        err = model.error_flat(w_next, sim.x_val, sim.y_val)
+            with jax.named_scope("round_ledger"):
+                dkey = jax.random.fold_in(fault_base, it)
+                mask = mask & (jax.random.uniform(dkey, (n,)) >= drop_p)
+        with jax.named_scope("round_aggregate"):
+            pid = jax.lax.axis_index(axis)
+            n_loc = deltas.shape[0]
+            if defense == Defense.TRIMMED_MEAN:
+                # order statistics need the FULL peer set: one more
+                # all_gather (of the raw deltas) and the trimmed aggregate
+                # is computed replicated — same collective budget class as
+                # Krum's gather
+                src = all_noised if cfg.dp_in_model else jax.lax.all_gather(
+                    deltas, axis, tiled=True)
+                agg = masked_aggregate(mask, src, src, cfg.dp_in_model,
+                                       defense, cfg.trim_fraction)
+            else:
+                local_mask = jax.lax.dynamic_slice_in_dim(mask, pid * n_loc,
+                                                          n_loc)
+                local_agg = masked_aggregate(local_mask, deltas, noised,
+                                             cfg.dp_in_model)
+                agg = jax.lax.psum(local_agg, axis)
+            w_next = w + agg
+        with jax.named_scope("round_eval"):
+            err = model.error_flat(w_next, sim.x_val, sim.y_val)
         return w_next, mask, err
 
     mapped = jax.shard_map(

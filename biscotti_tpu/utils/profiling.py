@@ -13,13 +13,36 @@ eval_performance/parseLogs.py):
   with its result, which eval/eval_cost_breakdown.py turns into the
   per-phase cost table (the reference's eval_cost_breakdown.pdf
   equivalent, ref: usenix-eval/).
+
+The two meet in `annotation(name)`: every `PhaseClock.phase` (and so every
+`Telemetry.span`, which times through it) also opens a
+`jax.profiler.TraceAnnotation` named `biscotti:<name>`, so a device trace
+shows the program's own spans on the profiler's clock, beside the device's
+operations (docs/OBSERVABILITY.md, "Device trace"). With the profiler off
+that is one flag check. This module imports nothing but the stdlib:
+`telemetry` sits on the config/tooling import path.
 """
 
 from __future__ import annotations
 
 import contextlib
+import sys
+import threading
 import time
 from typing import Dict, List
+
+TRACE_PREFIX = "biscotti:"  # the program's spans in a profiler trace
+
+
+def annotation(name: str):
+    """`jax.profiler.TraceAnnotation("biscotti:<name>")` where this process
+    has imported jax already, a `nullcontext` where it has not (a process
+    without jax has no profiler to write to, and importing it here would
+    tax every CLI start)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation(TRACE_PREFIX + name)
 
 
 @contextlib.contextmanager
@@ -27,10 +50,14 @@ def device_trace(log_dir: str):
     """Capture a jax.profiler device trace into `log_dir` (an
     `.xplane.pb` under `plugins/profile/<time>/`; read it back with
     `device_program_ms`). A profiler that will not start or stop raises:
-    a caller that asked for a trace must not get an untraced run."""
+    a caller that asked for a trace must not get an untraced run. Python's
+    own tracer stays off: it slows the host it is meant to observe, and the
+    spans worth reading are the `biscotti:` annotations."""
     import jax
 
-    jax.profiler.start_trace(log_dir)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(log_dir, profiler_options=options)
     try:
         yield
     finally:
@@ -72,26 +99,45 @@ def device_program_ms(trace_dir: str) -> Dict[str, List[float]]:
     return out
 
 
+class _Timing:
+    """What `PhaseClock.phase` yields: `seconds` is set as the phase ends."""
+
+    __slots__ = ("seconds",)
+
+    def __init__(self):
+        self.seconds = 0.0
+
+
 class PhaseClock:
     """Cumulative per-phase wall-clock accounting."""
 
     def __init__(self):
         self.totals: Dict[str, float] = {}
         self.counts: Dict[str, int] = {}
+        self._lock = threading.Lock()
 
     def add(self, name: str, dt: float) -> None:
-        """Charge `dt` seconds to `name` — the ONE accounting invariant,
-        shared by phase() and telemetry spans (telemetry/core.py)."""
-        self.totals[name] = self.totals.get(name, 0.0) + dt
-        self.counts[name] = self.counts.get(name, 0) + 1
+        """Charge `dt` seconds to `name` — the ONE accounting invariant.
+        Locked: shards are drawn from several threads (data/datasets.py),
+        and a read-modify-write of two dicts loses calls without it."""
+        with self._lock:
+            self.totals[name] = self.totals.get(name, 0.0) + dt
+            self.counts[name] = self.counts.get(name, 0) + 1
 
     @contextlib.contextmanager
     def phase(self, name: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.add(name, time.perf_counter() - t0)
+        """Time the body, charge it to `name`, and show it in a profiler
+        trace as `biscotti:<name>`. THE one place a span is timed:
+        `Telemetry.span` runs its body through here and reads the yielded
+        timing's `seconds` afterwards."""
+        timing = _Timing()
+        with annotation(name):
+            t0 = time.perf_counter()
+            try:
+                yield timing
+            finally:
+                timing.seconds = time.perf_counter() - t0
+                self.add(name, timing.seconds)
 
     def summary(self) -> Dict[str, Dict[str, float]]:
         return {
