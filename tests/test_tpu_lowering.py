@@ -14,9 +14,14 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
+from jax.experimental.layout import Format
+
 from biscotti_tpu.config import BiscottiConfig, Defense
+from biscotti_tpu.models.trainer import sample_batch
 from biscotti_tpu.ops.krum_pallas import krum_scores_pallas
-from biscotti_tpu.parallel.sim import Simulator, sharded_round_step_fn
+from biscotti_tpu.parallel.sim import (Simulator, sharded_round_step_fn,
+                                       stack_layout,
+                                       whole_stack_instructions)
 
 
 @pytest.fixture(scope="module")
@@ -36,24 +41,34 @@ def v5e():
     return topo.devices
 
 
-def _abstract(arrays, sharding):
-    return [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+def _abstract(arrays, sharding, stack=False):
+    """Shapes on `sharding`; with `stack`, in the format `put_stack` gives a
+    peer stack on the chip (a described device can hold no array to ask)."""
+
+    def where(a):
+        layout = stack_layout(a.shape, a.dtype.itemsize) if stack else None
+        return sharding if layout is None else Format(layout, sharding)
+
+    return [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=where(a))
             for a in arrays]
 
 
-def _compile_round_step(sim, device):
+def _compile_round_step(sim, device, stack=True, step=None):
     w, stake = sim.init_state()
-    args = _abstract(
-        [w, stake, jnp.asarray(0), jnp.asarray(sim.cfg.seed, jnp.int32),
-         sim.x, sim.y, sim.x_val, sim.y_val], SingleDeviceSharding(device))
-    return jax.jit(sim._round_step_raw).lower(*args).compile()
+    one = SingleDeviceSharding(device)
+    args = (_abstract([w, stake, jnp.asarray(0),
+                       jnp.asarray(sim.cfg.seed, jnp.int32)], one)
+            + _abstract([sim.x, sim.y], one, stack=stack)
+            + _abstract([sim.x_val, sim.y_val], one))
+    return jax.jit(step or sim._round_step_raw).lower(*args).compile()
 
 
-def _compile_sharded_step(sim, devices):
+def _compile_sharded_step(sim, devices, stack=True):
     mesh = jax.sharding.Mesh(np.array(devices), ("peers",))
     rep, peers = NamedSharding(mesh, P()), NamedSharding(mesh, P("peers"))
     w = jnp.zeros((sim.num_params,), jnp.float32)
-    args = (_abstract([w], rep) + _abstract([sim.x, sim.y], peers)
+    args = (_abstract([w], rep)
+            + _abstract([sim.x, sim.y], peers, stack=stack)
             + _abstract([jnp.asarray(0),
                          jnp.asarray(sim.cfg.seed, jnp.int32)], rep))
     return sharded_round_step_fn(sim, mesh).lower(*args).compile()
@@ -104,3 +119,96 @@ def test_pallas_round_at_1024_peers_compiles_for_v5e(v5e):
     sim = Simulator(_cfg(dataset="mnist", num_nodes=1024,
                          sample_percent=0.70))
     assert "tpu_custom_call" in _compile_round_step(sim, v5e[0]).as_text()
+
+
+# ------------------------------------- the round reads no whole stack (PR 25)
+
+
+@pytest.fixture(scope="module")
+def sim_1024():
+    """1,024 peers of mnist (1.5 GB of shards): large enough for the
+    compiler to decide as it does at the benchmark's 3,383."""
+    return Simulator(_cfg(dataset="mnist", num_nodes=1024,
+                          sample_percent=0.70))
+
+
+def _x_entry_layout(hlo):
+    line = next(l for l in hlo.splitlines()
+                if "entry_computation_layout" in l)
+    return line[line.index("480,784]") + len("480,784]"):][:20]
+
+
+def test_round_at_1024_peers_reads_no_whole_stack_on_one_chip(v5e, sim_1024):
+    hlo = _compile_round_step(sim_1024, v5e[0]).as_text()
+    assert "tpu_custom_call" in hlo
+    assert _x_entry_layout(hlo).startswith("{2,1,0:T(8,128)}")
+    assert whole_stack_instructions(hlo, 1024, sim_1024.rows) == []
+
+
+def test_sharded_round_at_1024_peers_reads_no_whole_stack_per_device(
+        v5e, sim_1024):
+    hlo = _compile_sharded_step(sim_1024, v5e).as_text()
+    assert "all-gather" in hlo and "all-reduce" in hlo
+    assert "f32[256,480,784]{2,1,0:T(8,128)}" in hlo  # one device's share
+    assert whole_stack_instructions(hlo, 256, sim_1024.rows) == []
+
+
+def _two_step_round(sim):
+    """The round's gather as it was before PR 25: the sampled peers' whole
+    shards first, then each peer's minibatch rows."""
+
+    def step(w, stake, it, seed, x, y, x_val, y_val):
+        rkey = jax.random.fold_in(
+            jax.random.fold_in(jax.random.PRNGKey(0), seed), it)
+        ckey, bkey, _ = jax.random.split(rkey, 3)
+        cidx = sim._contributors(ckey)
+        bkeys = jax.vmap(lambda i: jax.random.fold_in(bkey, i))(cidx)
+
+        def one(key, xi, yi):
+            idx = sample_batch(key, sim.rows, sim.cfg.batch_size)
+            return sim._step(w, xi[idx], yi[idx])
+
+        return jax.vmap(one)(bkeys, x[cidx], y[cidx])
+
+    return step
+
+
+@pytest.mark.parametrize("form", ["two_step_default_layout",
+                                  "two_step_row_major",
+                                  "composed_default_layout"])
+def test_either_half_alone_still_passes_over_the_whole_stack(v5e, sim_1024,
+                                                             form):
+    """So the assertion above cannot pass vacuously: the witness finds the
+    parent's whole-stack casts and copies, and finds what is left of them
+    when only the index is composed or only the layout is held."""
+    step = None if form.startswith("composed") else _two_step_round(sim_1024)
+    hlo = _compile_round_step(sim_1024, v5e[0], step=step,
+                              stack=form.endswith("row_major")).as_text()
+    expect = "{2,1,0" if form.endswith("row_major") else "{0,2,1"
+    assert _x_entry_layout(hlo).startswith(expect)
+    found = whole_stack_instructions(hlo, 1024, sim_1024.rows)
+    assert found, "no whole-stack instruction in a form known to have them"
+    assert all("[1024,480," in f or "[491520," in f for f in found)
+
+
+def test_round_hlo_is_the_program_of_the_stacks_own_format(v5e, sim_1024,
+                                                           monkeypatch):
+    """`round_hlo()` asks each data argument for its format and compiles
+    for it: with the stack where `put_stack` leaves it on the chip, its text
+    is the row-major program, the one a trace's names are joined against."""
+    one = SingleDeviceSharding(v5e[0])
+
+    class OnChip:  # what round_hlo() asks of an array, on a described chip
+        def __init__(self, a, stack=False):
+            self.shape, self.dtype = a.shape, a.dtype
+            self.format = _abstract([a], one, stack=stack)[0].format
+
+    for name in ("x", "y"):
+        monkeypatch.setattr(sim_1024, name,
+                            OnChip(getattr(sim_1024, name), stack=True))
+    for name in ("x_val", "y_val"):
+        monkeypatch.setattr(sim_1024, name, OnChip(getattr(sim_1024, name)))
+    hlo = sim_1024.round_hlo()
+    assert "tpu_custom_call" in hlo and "round_gather" in hlo
+    assert _x_entry_layout(hlo).startswith("{2,1,0:T(8,128)}")
+    assert sim_1024.whole_stack_instructions(hlo) == []
