@@ -1,0 +1,13 @@
+"""Device time (ms) one execution of the round's program spends on the
+final norm, the head's product over the held vocabulary and the
+cross-entropy (scope `lm_head_loss`), forward and backward.
+Median over the traced executions of the self time of that scope's
+instructions: the device trace's "XLA Ops", joined to the program's scopes
+through its compiled HLO (`benchmark/stages.py`) with the model's own
+vocabulary (`benchmark/lm_stages.py`)."""
+
+from benchmark.lm_stages import scope_total
+
+
+def read(record):
+    return scope_total(record, "lm_head_loss")

@@ -1,0 +1,14 @@
+"""Device time (ms) one execution of the round's program spends routing
+(scope `lm_router`: the router's product over all the model's experts, the
+softmax, the top-k and the coefficients), forward, recomputation and
+backward.
+Median over the traced executions of the self time of that scope's
+instructions: the device trace's "XLA Ops", joined to the program's scopes
+through its compiled HLO (`benchmark/stages.py`) with the model's own
+vocabulary (`benchmark/lm_stages.py`)."""
+
+from benchmark.lm_stages import scope_total
+
+
+def read(record):
+    return scope_total(record, "lm_router")

@@ -23,6 +23,18 @@ Two REAL datasets ship alongside them, loaded from scikit-learn's bundled
 Real shards are disjoint slices of a deterministic dataset-keyed shuffle, so
 they are bit-identical across peer processes exactly like the synthetic ones.
 
+Token shards (`lm_tokens`, and `lm_tokens_tiny` at the tests' size) feed the
+language model of models/laguna.py: a row is a WINDOW of `d_in` token ids
+(int32) and its label row is the same window one position on, so `y` holds
+a label a position. Ids are drawn from the slice of the vocabulary held
+here, `[0, n_classes)`, by shard name: half from one Zipf unigram law that
+every peer shares, half from the same law started at a per-peer offset (its
+topic), so shards are not identically distributed and a sparse-expert
+layer's held experts are loaded unevenly, peer by peer. There is no class
+to flip: a `_bad` shard keeps the peer's own windows and labels EVERY
+position with the attack target id (the analogue of an all-target shard),
+and the attack split is the held-out pool as it is.
+
 Poisoned shards follow the reference's generate_poisoned exactly
 (ref: ML/Pytorch/data/mnist/parse_mnist.py:295-301): ALL-source-class
 data relabeled as the target (1 → 7 for mnist) — every row carries the
@@ -64,6 +76,7 @@ class DatasetSpec:
     attack_target: int = 7
     cluster_scale: float = 1.0  # intra-class spread
     real: bool = False  # backed by a bundled real dataset (see module doc)
+    tokens: bool = False  # rows are windows of d_in token ids (module doc)
 
 
 DATASETS: Dict[str, DatasetSpec] = {
@@ -77,7 +90,16 @@ DATASETS: Dict[str, DatasetSpec] = {
     "digits": DatasetSpec("digits", 64, 10, 140, 397, real=True),
     "cancer": DatasetSpec("cancer", 30, 2, 40, 169,
                           attack_source=0, attack_target=1, real=True),
+    # token windows: 80 (10) windows a peer, 64 (8) of them the train cut,
+    # a multiple of the 8-row tile; ids from the held quarter of
+    # Laguna-S-2.1's vocabulary (a toy one)
+    "lm_tokens": DatasetSpec("lm_tokens", 1024, 25088, 80, 2, tokens=True),
+    "lm_tokens_tiny": DatasetSpec("lm_tokens_tiny", 16, 64, 10, 2,
+                                  tokens=True),
 }
+
+ZIPF_EXPONENT = 1.1  # the unigram law of token shards: p(rank) ∝ rank^-1.1
+TOPIC_SHARE = 0.5    # of a peer's tokens drawn from its own topic
 
 
 def base_name(dataset: str) -> str:
@@ -202,9 +224,30 @@ def _draw(dataset: str, tag: str, n: int) -> Tuple[np.ndarray, np.ndarray]:
         return _draw_rows(dataset, tag, n)
 
 
+@lru_cache(maxsize=None)
+def _zipf_cdf(vocab: int) -> np.ndarray:
+    p = np.arange(1, vocab + 1, dtype=np.float64) ** -ZIPF_EXPONENT
+    return np.cumsum(p / p.sum())
+
+
+def _draw_windows(s: DatasetSpec, dataset: str, tag: str, n: int
+                  ) -> Tuple[np.ndarray, np.ndarray]:
+    """`n` windows of d_in + 1 ids; x the first d_in, y the last d_in."""
+    rng = _rng(dataset, tag)
+    shape = (n, s.d_in + 1)
+    ranks = np.searchsorted(_zipf_cdf(s.n_classes), rng.random(shape))
+    if tag.startswith("shard"):  # the held-out pool has no topic
+        topical = rng.random(shape) < TOPIC_SHARE
+        ranks = np.where(topical, ranks + rng.integers(s.n_classes), ranks)
+    ids = (ranks % s.n_classes).astype(np.int32)
+    return np.ascontiguousarray(ids[:, :-1]), np.ascontiguousarray(ids[:, 1:])
+
+
 def _draw_rows(dataset: str, tag: str, n: int
                 ) -> Tuple[np.ndarray, np.ndarray]:
     s = _spec(dataset)
+    if s.tokens:
+        return _draw_windows(s, dataset, tag, n)
     if s.real:
         x, y = _real_corpus(dataset)
         if tag in ("test", "attack"):
@@ -254,7 +297,8 @@ def load_shard(dataset: str, shard: str) -> Dict[str, np.ndarray]:
         return {"x_train": x, "y_train": y, "x_test": x, "y_test": y}
     if shard == f"{dataset}_digit1":
         x, y = _draw(dataset, "attack", s.test_size)
-        keep = y == s.attack_source
+        keep = (np.ones(len(y), bool) if s.tokens  # no class to keep
+                else y == s.attack_source)
         return {"x_train": x[keep], "y_train": y[keep],
                 "x_test": x[keep], "y_test": y[keep]}
 
@@ -277,7 +321,9 @@ def load_shard(dataset: str, shard: str) -> Dict[str, np.ndarray]:
         # own deterministic stream but condition every row on the source
         # class, then relabel. (Round 1-3 flipped ~10% of an honest
         # shard — a 10× weaker attack than the reference's.)
-        if s.real:
+        if s.tokens:
+            pass  # the peer's own windows; every label is set below
+        elif s.real:
             cx, cy = _real_corpus(dataset)
             # TRAIN slice only: the corpus tail is the held-out test/
             # attack split — letting poisoned peers train on the exact

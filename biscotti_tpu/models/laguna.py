@@ -1,0 +1,405 @@
+"""Laguna-S-2.1 (poolside, `model_type` "laguna") as a Biscotti model: a
+frozen share of its sparse-expert, window/full-attention decoder, with
+rank-r adapters on q, k, v and o whose `B` factors are what the peers
+train, commit and aggregate (the FFA-LoRA form, "Improving LoRA in
+Privacy-preserving Federated Learning", ICLR 2024: `A` frozen and shared,
+so that the sum of the peers' updates IS the update of the sum).
+
+Source: https://huggingface.co/poolside/Laguna-S-2.1/blob/main/config.json.
+What that file does not state (marked † in benchmark/configs/
+laguna_s_2.1_fedlora.json, `assumed`): pre-norm blocks, rotate-half
+rotary, no q/k norm, a sigmoid per-head output gate from the normed input,
+softmax routing before the top-k, no gate on the shared expert, no
+auxiliary load loss.
+
+    h0 = E[tokens];  per layer:
+      x = RMSNorm(h);  q, k, v = x Wq, x Wk, x Wv  (+ adapters)
+      rotary on the first rho*head_dim dimensions (sliding: rho 1, theta 1e4;
+        full: rho 0.5, theta 5e5, YaRN frequencies, cos/sin x attention_factor)
+      o = softmax(q k^T / sqrt(head_dim) + mask) v;  o *= sigmoid(x Wgate) a head
+      h += concat(o) Wo (+ adapter)
+      x = RMSNorm(h);  layer 0: h += SwiGLU_dense(x);  else
+      h += Shared(x) + sum over the top-k experts HELD HERE of
+           scale * p_e / sum_topk p * Expert_e(x)           (ops/moe.py)
+    logits = RMSNorm(h) W_head over the held rows of the vocabulary
+
+The trainable tree is {"layers": [{"k", "o", "q", "v"}: B [r, out]]}; the
+frozen tree holds everything else, in `dtype` (bfloat16 at the published
+size), and every product runs in that dtype with float32 accumulation.
+
+The peer axis meets the expert dispatch ONCE a block: all the peers of a
+block share the round's weights, so their tokens go through the frozen
+stack as one batch (one router, one sort, one grouped product a layer),
+and only the adapters' `B` carry a peer axis: the gradient of the SUM of
+the peers' losses with respect to `B[P, r, out]` is each peer's own
+gradient, because no operation mixes two windows.
+"""
+
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass, field
+from functools import partial
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from biscotti_tpu.models.base import make_model
+from biscotti_tpu.ops import moe
+
+# scopes inside `round_grad` a device trace is read by (a second
+# vocabulary beside parallel/sim.STAGES; docs/OBSERVABILITY.md)
+SCOPES = ("lm_embed", "lm_attention", "lm_router", "lm_experts", "lm_dense",
+          "lm_head_loss", "peer_clip")
+
+
+@dataclass(frozen=True)
+class LagunaConfig:
+    hidden: int
+    head_dim: int
+    kv_heads: int
+    heads: Tuple[int, ...]          # query heads, layer by layer
+    layer_types: Tuple[str, ...]    # "full" | "sliding"
+    dense_layers: Tuple[int, ...]   # layers whose MLP is the dense SwiGLU
+    window: int
+    dense_width: int
+    expert_width: int
+    shared_width: int
+    num_experts: int                # the router's width (published)
+    experts_held: int               # experts first_expert .. + held, here
+    top_k: int
+    routed_scale: float
+    vocab: int                      # rows of the vocabulary held here
+    rope_full: dict = field(hash=False, compare=False, default=None)
+    rope_sliding: dict = field(hash=False, compare=False, default=None)
+    first_expert: int = 0
+    eps: float = 1e-6
+    rank: int = 16
+    alpha: float = 32.0
+    dtype: str = "bfloat16"
+
+    @property
+    def layers(self) -> int:
+        return len(self.layer_types)
+
+
+ROPE_FULL = {"rope_theta": 500000.0, "factor": 128.0,
+             "original_max_position_embeddings": 8192, "beta_fast": 32.0,
+             "beta_slow": 1.0, "attention_factor": 1.4852030263919618,
+             "partial_rotary_factor": 0.5}
+ROPE_SLIDING = {"rope_theta": 10000.0, "partial_rotary_factor": 1.0}
+
+PRESETS = {
+    # the published widths; layers 0-4 (the leading dense one and one whole
+    # period), 64 of the 256 experts and a quarter of the vocabulary: one
+    # chip's share when four chips share each layer
+    "laguna_s_fedlora": LagunaConfig(
+        hidden=3072, head_dim=128, kv_heads=8, heads=(48, 72, 72, 72, 48),
+        layer_types=("full", "sliding", "sliding", "sliding", "full"),
+        dense_layers=(0,), window=512, dense_width=12288, expert_width=1024,
+        shared_width=1024, num_experts=256, experts_held=64, top_k=10,
+        routed_scale=2.5, vocab=25088, rope_full=ROPE_FULL,
+        rope_sliding=ROPE_SLIDING),
+    # the same mechanism at the CPU tests' size: every kind of layer, the
+    # 48/72-style head split, 4 of 16 experts held, float32 throughout
+    "laguna_tiny": LagunaConfig(
+        hidden=32, head_dim=8, kv_heads=2, heads=(4, 6, 4),
+        layer_types=("full", "sliding", "full"), dense_layers=(0,),
+        window=4, dense_width=48, expert_width=8, shared_width=8,
+        num_experts=16, experts_held=4, top_k=3, routed_scale=2.5, vocab=64,
+        rope_full=dict(ROPE_FULL, original_max_position_embeddings=8,
+                       factor=4.0),
+        rope_sliding=ROPE_SLIDING, rank=2, alpha=4.0, dtype="float32"),
+}
+
+
+# ------------------------------------------------------------------ rotary
+
+
+def rotary_tables(cfg: LagunaConfig, kind: str, length: int):
+    """(cos, sin) float32[T, rot / 2] and the rotated width `rot`."""
+    rope = cfg.rope_full if kind == "full" else cfg.rope_sliding
+    rot = int(cfg.head_dim * rope["partial_rotary_factor"])
+    base = float(rope["rope_theta"])
+    inv = 1.0 / base ** (np.arange(0, rot, 2, dtype=np.float64) / rot)
+    factor = 1.0
+    if "factor" in rope:  # YaRN
+        original = rope["original_max_position_embeddings"]
+
+        def correction_dim(rotations):
+            return (rot * math.log(original / (rotations * 2 * math.pi))
+                    / (2 * math.log(base)))
+
+        low = max(math.floor(correction_dim(rope["beta_fast"])), 0)
+        high = min(math.ceil(correction_dim(rope["beta_slow"])), rot - 1)
+        ramp = np.clip((np.arange(rot // 2, dtype=np.float64) - low)
+                       / max(high - low, 1e-3), 0.0, 1.0)
+        inv = inv / rope["factor"] * ramp + inv * (1.0 - ramp)
+        factor = float(rope["attention_factor"])
+    angles = np.arange(length, dtype=np.float64)[:, None] * inv[None, :]
+    return ((np.cos(angles) * factor).astype(np.float32),
+            (np.sin(angles) * factor).astype(np.float32), rot)
+
+
+def _rotate(x, cos, sin, rot):
+    """Rotate-half rotary on the first `rot` of the last axis; x [..., T,
+    head_dim], cos/sin [T, rot / 2]."""
+    turned, rest = x[..., :rot], x[..., rot:]
+    a, b = turned[..., :rot // 2], turned[..., rot // 2:]
+    turned = jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+    return jnp.concatenate([turned, rest], axis=-1)
+
+
+# ----------------------------------------------------------------- forward
+
+
+def _rms(x, weight, eps):
+    x = x.astype(jnp.float32)
+    scale = jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+    return x * scale * weight.astype(jnp.float32)
+
+
+def _mm(a, w):
+    """a @ w in w's dtype, accumulated in float32."""
+    return jnp.dot(a.astype(w.dtype), w, preferred_element_type=jnp.float32)
+
+
+def _swiglu(x, w):
+    hidden = jax.nn.silu(_mm(x, w["w_gate"])) * _mm(x, w["w_up"])
+    return _mm(hidden, w["w_down"])
+
+
+def _adapted(cfg, x, w, a, b):
+    """x W + (alpha / r) (x A) B, B with a peer axis: x [P, b, T, in],
+    B [P, r, out]."""
+    low = jnp.einsum("pbtr,pro->pbto", _mm(x, a).astype(a.dtype),
+                     b.astype(a.dtype), preferred_element_type=jnp.float32)
+    return _mm(x, w) + (cfg.alpha / cfg.rank) * low
+
+
+def _attention(cfg, at, h, frozen, adapters):
+    """The attention block of layer `at` on h [P, b, T, H]."""
+    kind, n = cfg.layer_types[at], cfg.heads[at]
+    p, b, t, _ = h.shape
+    dh, kv = cfg.head_dim, cfg.kv_heads
+    x = _rms(h, frozen["attn_norm"], cfg.eps)
+    lora = frozen["lora_a"]
+
+    def heads(name, count):
+        y = _adapted(cfg, x, frozen["w" + name], lora[name], adapters[name])
+        return y.reshape(p * b, t, count, dh).transpose(0, 2, 1, 3)
+
+    q, k, v = heads("q", n), heads("k", kv), heads("v", kv)
+    cos, sin, rot = rotary_tables(cfg, kind, t)
+    q, k = _rotate(q, cos, sin, rot), _rotate(k, cos, sin, rot)
+    dtype = frozen["wq"].dtype
+    q = q.reshape(p * b, kv, n // kv, t, dh).astype(dtype)
+    scores = jnp.einsum("wgqtd,wgsd->wgqts", q, k.astype(dtype),
+                        preferred_element_type=jnp.float32) / math.sqrt(dh)
+    i, j = np.arange(t)[:, None], np.arange(t)[None, :]
+    seen = (j <= i) if kind == "full" else ((j <= i) & (i - j < cfg.window))
+    probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
+    out = jnp.einsum("wgqts,wgsd->wgqtd", probs.astype(dtype),
+                     v.astype(dtype), preferred_element_type=jnp.float32)
+    gate = jax.nn.sigmoid(_mm(x, frozen["wgate"]))          # [P, b, T, n]
+    out = out.reshape(p * b, n, t, dh).transpose(0, 2, 1, 3)  # [W, T, n, dh]
+    out = out * gate.reshape(p * b, t, n)[..., None]
+    out = out.reshape(p, b, t, n * dh)
+    return _adapted(cfg, out, frozen["wo"], lora["o"], adapters["o"])
+
+
+def _mlp(cfg, at, h, frozen):
+    """The MLP block of layer `at` on h [N, H]: (result, the dispatch's
+    counts, the router's (experts, probabilities)); the last two None on a
+    dense layer."""
+    x = _rms(h, frozen["mlp_norm"], cfg.eps)
+    if at in cfg.dense_layers:
+        with jax.named_scope("lm_dense"):
+            return _swiglu(x, frozen["dense"]), None, None
+    with jax.named_scope("lm_router"):
+        experts, coef, probs = moe.route(x, frozen["router"], cfg.top_k,
+                                         cfg.routed_scale)
+    with jax.named_scope("lm_dense"):
+        shared = _swiglu(x, frozen["shared"])
+    with jax.named_scope("lm_experts"):
+        routed, counts = moe.held_experts(x, experts, coef,
+                                          frozen["experts"],
+                                          cfg.first_expert, cfg.num_experts)
+    return shared + routed, counts, (experts, probs)
+
+
+def _layer(cfg, at, h, frozen, adapters):
+    with jax.named_scope("lm_attention"):
+        h = h + _attention(cfg, at, h, frozen, adapters)
+    out, counts, picks = _mlp(cfg, at, h.reshape(-1, h.shape[-1]), frozen)
+    return h + out.reshape(h.shape), counts, picks
+
+
+def _stacked(found):
+    found = [f for f in found if f is not None]
+    return jax.tree.map(lambda *a: jnp.stack(a), *found) if found else {}
+
+
+def hidden_states(cfg, params, tokens, frozen, remat=True):
+    """(final hidden states [P, b, T, H], the dispatch's counts, the
+    router's picks) of `tokens` int32[P, b, T] under adapters with a peer
+    axis (every leaf of `params` [P, r, out]); counts and picks stacked
+    over the sparse layers, in layer order."""
+    with jax.named_scope("lm_embed"):
+        h = frozen["embed"][tokens].astype(jnp.float32)
+    counted, picked = [], []
+    for at in range(cfg.layers):
+        def step(h, layer, adapters, at=at):
+            return _layer(cfg, at, h, layer, adapters)
+
+        if remat:
+            step = jax.checkpoint(step)
+        h, counts, picks = step(h, frozen["layers"][at],
+                                params["layers"][at])
+        counted.append(counts)
+        picked.append(picks)
+    return h, _stacked(counted), _stacked(picked)
+
+
+def _logits(cfg, h, frozen):
+    return _mm(_rms(h, frozen["final_norm"], cfg.eps), frozen["head"])
+
+
+def peer_losses(cfg, params, tokens, labels, frozen):
+    """Each peer's mean next-token cross-entropy over its own windows,
+    float32[P], and the dispatch's counts: `params` leaves [P, r, out],
+    tokens/labels int32[P, b, T]."""
+    h, counts, _ = hidden_states(cfg, params, tokens, frozen)
+    with jax.named_scope("lm_head_loss"):
+        logits = _logits(cfg, h, frozen)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        picked = jnp.sum(jnp.where(
+            jnp.arange(logp.shape[-1], dtype=jnp.int32)
+            == labels[..., None].astype(jnp.int32), logp, 0.0), axis=-1)
+        return -jnp.mean(picked, axis=(1, 2)), counts
+
+
+def _one_peer(tree):
+    return jax.tree.map(lambda a: a[None], tree)
+
+
+def routing(cfg, params, tokens, frozen):
+    """The router's choices for `tokens` int32[b, T] under adapters
+    `params` (no peer axis): experts int32[L, b*T, k] and probabilities
+    float32[L, b*T, E_all], one row a sparse layer, in layer order."""
+    return hidden_states(cfg, _one_peer(params), tokens[None], frozen,
+                         remat=False)[2]
+
+
+# ------------------------------------------------------------------- model
+
+
+def _shapes(cfg: LagunaConfig):
+    """({path: (shape, fan_in)} of the frozen leaves, layer by layer,
+    [{name: shape}] of the trained ones)."""
+    hdim, dh, r = cfg.hidden, cfg.head_dim, cfg.rank
+    frozen = {"embed": ((cfg.vocab, hdim), 1),
+              "head": ((hdim, cfg.vocab), hdim),
+              "final_norm": ((hdim,), 0), "layers": []}
+    trained = []
+    for at in range(cfg.layers):
+        n, kv = cfg.heads[at] * dh, cfg.kv_heads * dh
+        layer = {"attn_norm": ((hdim,), 0), "mlp_norm": ((hdim,), 0),
+                 "wq": ((hdim, n), hdim), "wk": ((hdim, kv), hdim),
+                 "wv": ((hdim, kv), hdim), "wo": ((n, hdim), n),
+                 "wgate": ((hdim, cfg.heads[at]), hdim),
+                 "lora_a": {"q": ((hdim, r), hdim), "k": ((hdim, r), hdim),
+                            "v": ((hdim, r), hdim), "o": ((n, r), n)}}
+
+        def swiglu(width, lead=()):
+            return {"w_gate": (lead + (hdim, width), hdim),
+                    "w_up": (lead + (hdim, width), hdim),
+                    "w_down": (lead + (width, hdim), width)}
+
+        if at in cfg.dense_layers:
+            layer["dense"] = swiglu(cfg.dense_width)
+        else:
+            layer["router"] = ((hdim, cfg.num_experts), hdim)
+            layer["shared"] = swiglu(cfg.shared_width)
+            layer["experts"] = swiglu(cfg.expert_width,
+                                      (cfg.experts_held,))
+        frozen["layers"].append(layer)
+        trained.append({"q": (r, n), "k": (r, kv), "v": (r, kv),
+                        "o": (r, hdim)})
+    return frozen, trained
+
+
+def _is_leaf(node):
+    return isinstance(node, tuple) and isinstance(node[0], tuple)
+
+
+@partial(jax.jit, static_argnames=("shape", "fan_in", "dtype"))
+def _draw(key, shape, fan_in, dtype):
+    """One frozen leaf, drawn where it will live: norm weights around 1,
+    the rest fan-in scaled normal."""
+    noise = jax.random.normal(key, shape, jnp.float32)
+    if fan_in == 0:
+        return (1.0 + 0.1 * noise).astype(dtype)
+    return (noise / math.sqrt(fan_in)).astype(dtype)
+
+
+def laguna_model(name: str, cfg: LagunaConfig, length: int):
+    """The Biscotti `Model` of `cfg` on windows of `length` tokens."""
+    frozen_shapes, trained_shapes = _shapes(cfg)
+    dtype = jnp.dtype(cfg.dtype)
+
+    def init(key):
+        """Seeded NON-zero adapters (a round's start is zeros, as LoRA's
+        `B` starts; tests and the benchmark's checked round draw these)."""
+        leaves, treedef = jax.tree.flatten(
+            {"layers": trained_shapes}, is_leaf=lambda n: isinstance(n, tuple))
+        keys = jax.random.split(key, len(leaves))
+        return jax.tree.unflatten(treedef, [
+            0.02 * jax.random.normal(k, shape, jnp.float32)
+            for k, shape in zip(keys, leaves)])
+
+    def init_frozen(key):
+        """Leaf by leaf, each drawn on the device: never the whole base
+        on the host."""
+        leaves, treedef = jax.tree.flatten(frozen_shapes, is_leaf=_is_leaf)
+        return jax.tree.unflatten(treedef, [
+            _draw(jax.random.fold_in(key, i), shape, fan_in, dtype)
+            for i, (shape, fan_in) in enumerate(leaves)])
+
+    def losses(params, x, y, frozen):
+        return peer_losses(cfg, params, x, y, frozen)
+
+    def apply(params, x, frozen):
+        h = hidden_states(cfg, _one_peer(params), x[None], frozen,
+                          remat=False)[0]
+        return _logits(cfg, h[0], frozen)
+
+    def loss(params, x, y, frozen):
+        return losses(_one_peer(params), x[None], y[None], frozen)[0][0]
+
+    def step_bytes(batch):
+        """Bytes one peer's step adds to what a block holds live at its
+        peak, the widest layer's recomputation and backward: the
+        attention's scores and their cotangents in float32 (2 arrays of
+        [heads, T, T]), the sorted expert rows (in `dtype`) and what the
+        grouped products make of them (float32), the logits and their
+        cotangents, a dozen hidden states. Within a fifth of what the
+        compiled round's memory analysis reads a peer at the published
+        size (0.9 GB; PERF.md section 6, PR 27)."""
+        t = batch * length
+        return (2 * 4 * max(cfg.heads) * batch * length * length
+                + t * cfg.top_k * cfg.hidden * (4 + dtype.itemsize)
+                + 2 * 4 * t * cfg.vocab + 12 * 4 * t * cfg.hidden)
+
+    return make_model(name, length, cfg.vocab, init, apply, loss,
+                      step_rule="clipped_sgd", token_input=True,
+                      init_frozen=init_frozen, peer_losses=losses,
+                      step_bytes=step_bytes, info={"config": cfg})
+
+
+def frozen_count(model) -> int:
+    """Parameters in the model's frozen tree, from shapes alone."""
+    tree = jax.eval_shape(model.init_frozen, jax.random.PRNGKey(0))
+    return sum(math.prod(leaf.shape) for leaf in jax.tree.leaves(tree))

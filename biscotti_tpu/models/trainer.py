@@ -2,26 +2,33 @@
 embedded-Python bridge API (init / privateFun / getNoise / roni / getTestErr /
 get17AttackRate; ref: ML/Pytorch/client_obj.py, DistSys/honest.go:204-324).
 
-Two step rules, matching the two reference stacks:
+Three step rules: the two reference stacks', and one for models that a
+unit-rate step would send to infinity. A model DECLARES its own
+(`Model.step_rule`, models/base.py) and `step_rule(model, cfg)` reads it:
 
   * torch-parity ("grad"): delta = −clip₁₀₀(∇CE(w; minibatch))
     (ref: client.py:38-65 — backward + clip_grad_norm(100), no optimizer.step,
     privateFun returns −grad, client_obj.py:73-77)
   * logreg-parity ("sgd"): delta = −α·∇f(w; minibatch), α=1e-2, f the
     L2-regularized logistic loss (ref: logistic_model.py:113-140)
+  * "clipped_sgd": delta = −η·clip_C(∇f(w; minibatch)), η = cfg.learning_rate,
+    C = cfg.grad_clip; the DP noise is scaled by the same η, as "sgd"
+    scales it by α
 
 Everything below `Trainer.__init__` is jitted XLA; the minibatch draw is a
 threefry `random.choice` folded from (seed, iteration) so peers are
 deterministic given their id — required by the chain-equality oracle.
 
-`local_step_fn` is exposed standalone (pure) so parallel/sim.py can vmap the
-identical computation over a stacked peer axis.
+`local_step_fn` is exposed standalone (pure); `block_step_fn` is the same
+rule over a block of peers, which parallel/sim.py, the hive's stepper and
+the device cluster run: vmapped, or, where the model says how its peers
+go through it as one batch (`Model.peer_losses`), as that one batch.
 """
 
 from __future__ import annotations
 
 import zlib
-from typing import Callable
+from typing import Callable, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -41,26 +48,72 @@ def clip_by_global_norm(g: jax.Array, max_norm: float) -> jax.Array:
     return g * jnp.minimum(1.0, max_norm / jnp.maximum(n, 1e-12))
 
 
+def step_rule(model: Model, cfg) -> Tuple[str, float]:
+    """(mode, rate) of the local step `model` declares, under `cfg`:
+    `mode` is `local_step_fn`'s, `rate` scales the step (its `alpha`) AND
+    the DP noise (1.0 where the rule has no rate)."""
+    mode = model.step_rule
+    return mode, {"grad": 1.0, "sgd": cfg.logreg_alpha,
+                  "clipped_sgd": cfg.learning_rate}[mode]
+
+
+def _delta_rule(mode: str, clip: float, alpha: float) -> Callable:
+    """gradient -> delta of one peer."""
+    if mode == "grad":
+        return lambda g: -clip_by_global_norm(g, clip)
+    if mode == "sgd":
+        # model.loss is already (1/B)Σ data + λ/2‖w‖², whose gradient is
+        # the reference's (1/B)·Xᵀres + λw (ref: logistic_model.py:100-106)
+        return lambda g: -alpha * g
+    if mode == "clipped_sgd":
+        return lambda g: -alpha * clip_by_global_norm(g, clip)
+    raise ValueError(f"unknown step mode {mode!r}")
+
+
 def local_step_fn(model: Model, mode: str = "grad", clip: float = GRAD_CLIP,
                   alpha: float = LOGREG_ALPHA) -> Callable:
-    """Pure per-peer update rule: (flat_w, x_batch, y_batch) -> flat_delta."""
-    if mode == "grad":
+    """Pure per-peer update rule:
+    (flat_w, x_batch, y_batch, frozen=None) -> flat_delta."""
+    rule = _delta_rule(mode, clip, alpha)
 
-        def step(flat_w, x, y):
-            g = jax.grad(model.loss_flat)(flat_w, x, y)
-            return -clip_by_global_norm(g, clip)
+    def step(flat_w, x, y, frozen=None):
+        return rule(jax.grad(model.loss_flat)(flat_w, x, y, frozen))
 
-    elif mode == "sgd":
-
-        def step(flat_w, x, y):
-            # model.loss is already (1/B)Σ data + λ/2‖w‖², whose gradient is
-            # the reference's (1/B)·Xᵀres + λw (ref: logistic_model.py:100-106)
-            g = jax.grad(model.loss_flat)(flat_w, x, y)
-            return -alpha * g
-
-    else:
-        raise ValueError(f"unknown step mode {mode!r}")
     return step
+
+
+def block_step_fn(model: Model, mode: str = "grad", clip: float = GRAD_CLIP,
+                  alpha: float = LOGREG_ALPHA) -> Callable:
+    """The same rule over a block of peers that share the weights:
+    (flat_w, x [P, B, ...], y [P, B, ...], frozen) -> (deltas [P, d],
+    counts). A classifier's block is its step vmapped (counts `{}`). A
+    model with `peer_losses` takes the block's rows as ONE batch: the
+    weights get a peer axis, and the gradient of the SUM of the peers'
+    losses with respect to them is each peer's own, since no peer's loss
+    reads another's rows."""
+    if model.peer_losses is None:
+        step = local_step_fn(model, mode, clip, alpha)
+
+        def block(flat_w, xb, yb, frozen=None):
+            return jax.vmap(step, in_axes=(None, 0, 0, None))(
+                flat_w, xb, yb, frozen), {}
+
+        return block
+
+    rule = _delta_rule(mode, clip, alpha)
+
+    def block(flat_w, xb, yb, frozen=None):
+        def total(stacked):
+            losses, counts = model.peer_losses(
+                jax.vmap(model.unravel)(stacked), xb, yb, frozen)
+            return jnp.sum(losses), counts
+
+        stacked = jnp.broadcast_to(flat_w, (xb.shape[0],) + flat_w.shape)
+        grads, counts = jax.grad(total, has_aux=True)(stacked)
+        with jax.named_scope("peer_clip"):
+            return jax.vmap(rule)(grads), counts
+
+    return block
 
 
 def sample_batch(key: jax.Array, n: int, batch_size: int) -> jax.Array:
@@ -88,21 +141,22 @@ def _compiled_fns(model: Model, mode: str, clip: float, alpha: float,
     from functools import partial
 
     @partial(jax.jit, static_argnames=("batch_size",))
-    def _private(flat_w, it, x_train, y_train, batch_key, batch_size):
+    def _private(flat_w, it, x_train, y_train, batch_key, frozen,
+                 batch_size):
         k = jax.random.fold_in(batch_key, it)
         idx = sample_batch(k, x_train.shape[0], batch_size)
-        return step(flat_w, x_train[idx], y_train[idx])
+        return step(flat_w, x_train[idx], y_train[idx], frozen)
 
     @jax.jit
-    def _err(flat_w, x, y):
-        return model.error_flat(flat_w, x, y)
+    def _err(flat_w, x, y, frozen):
+        return model.error_flat(flat_w, x, y, frozen)
 
     @jax.jit
-    def _roni(flat_w, delta, x, y):
+    def _roni(flat_w, delta, x, y, frozen):
         # score = err(w+δ) − err(w) on the local train split
         # (ref: client_obj.py:100-112; rejected if > 0.02, main.go:203-231)
-        before = model.error_flat(flat_w, x, y)
-        after = model.error_flat(flat_w + delta, x, y)
+        before = model.error_flat(flat_w, x, y, frozen)
+        after = model.error_flat(flat_w + delta, x, y, frozen)
         return after - before
 
     fns = (_private, _err, _roni)
@@ -117,6 +171,23 @@ def _compiled_fns(model: Model, mode: str, clip: float, alpha: float,
 # co-hosted clusters paid N copies of the same 6 MB test split. Keyed on
 # the dataset name; jax arrays are immutable, so sharing is safe.
 _EVAL_CACHE: dict = {}
+
+
+# A model's frozen tree is the same for every peer of a run (drawn from the
+# run's seed, models/base.py): co-hosted Trainers share ONE copy.
+_FROZEN_CACHE: dict = {}
+
+
+def shared_frozen(model: Model, seed: int):
+    """`model`'s frozen tree for the run seeded `seed`: the empty tree for
+    a classifier, else drawn once a process from `PRNGKey(seed)` (what
+    parallel/sim.py draws too)."""
+    if model.init_frozen is None:
+        return {}
+    key = (model.name, model.num_params, int(seed))
+    if key not in _FROZEN_CACHE:
+        _FROZEN_CACHE[key] = model.frozen(jax.random.PRNGKey(seed))
+    return _FROZEN_CACHE[key]
 
 
 def _shared_eval_arrays(dataset: str):
@@ -150,7 +221,8 @@ class Trainer:
         self.dataset = dataset
         self.model = model or model_for_dataset(
             dataset, getattr(self.cfg, "model_name", ""))
-        self.mode = "sgd" if self.model.name == "logreg" else "grad"
+        self.mode, self._rate = step_rule(self.model, self.cfg)
+        self.frozen = shared_frozen(self.model, self.cfg.seed)
         self.batch_size = self.cfg.batch_size
         # Every stream is keyed on (config seed, shard identity) so peers
         # built with default args still get independent DP noise and batch
@@ -196,7 +268,7 @@ class Trainer:
                 self.cfg.noise_presample_iters, self.num_params,
             )
 
-        alpha = self.cfg.logreg_alpha
+        alpha = self._rate
         self._batch_key = batch_key
         # share compiled functions across peers of the same (zoo model,
         # step-rule) family; a caller-supplied custom model skips the cache
@@ -229,7 +301,7 @@ class Trainer:
         return np.asarray(
             self._private(jnp.asarray(flat_w, jnp.float32), iteration,
                           self.x_train, self.y_train, self._batch_key,
-                          batch_size=min(self.batch_size,
+                          self.frozen, batch_size=min(self.batch_size,
                                          int(self.x_train.shape[0]))),
             dtype=np.float64,
         )
@@ -239,20 +311,20 @@ class Trainer:
         if self.metrics is not None:
             self.metrics.counter("biscotti_noise_draws_total",
                                  "DP noise vectors served/consumed").inc()
-        alpha = self.cfg.logreg_alpha if self.mode == "sgd" else 1.0
         return np.asarray(
-            dp_noise.noise_at(self.noise_samples, iteration, self.batch_size, alpha),
+            dp_noise.noise_at(self.noise_samples, iteration, self.batch_size,
+                              self._rate),
             dtype=np.float64,
         )
 
     def train_error(self, flat_w: np.ndarray) -> float:
         self._require_full("train shard")
         return float(self._err_fn(jnp.asarray(flat_w, jnp.float32),
-                                  self.x_train, self.y_train))
+                                  self.x_train, self.y_train, self.frozen))
 
     def test_error(self, flat_w: np.ndarray) -> float:
         return float(self._err_fn(jnp.asarray(flat_w, jnp.float32),
-                                  self.x_test, self.y_test))
+                                  self.x_test, self.y_test, self.frozen))
 
     def attack_rate(self, flat_w: np.ndarray) -> float:
         """Reference-faithful metric: 1 − accuracy on the attack-source split
@@ -260,7 +332,7 @@ class Trainer:
         1 − accuracy_score on the digit-1 loader). Counts *any*
         misclassification of source-class samples."""
         return float(self._err_fn(jnp.asarray(flat_w, jnp.float32),
-                                  self.x_attack, self.y_attack))
+                                  self.x_attack, self.y_attack, self.frozen))
 
     def attack_success_rate(self, flat_w: np.ndarray) -> float:
         """Stricter 1→7 metric: fraction of attack-source samples predicted
@@ -269,7 +341,7 @@ class Trainer:
 
         target = ds.spec(self.dataset).attack_target
         logits = self.model.apply_flat(jnp.asarray(flat_w, jnp.float32),
-                                       self.x_attack)
+                                       self.x_attack, self.frozen)
         pred = jnp.argmax(logits, axis=-1)
         return float(jnp.mean((pred == target).astype(jnp.float32)))
 
@@ -277,4 +349,4 @@ class Trainer:
         self._require_full("train shard")
         return float(self._roni_fn(jnp.asarray(flat_w, jnp.float32),
                                    jnp.asarray(delta, jnp.float32),
-                                   self.x_train, self.y_train))
+                                   self.x_train, self.y_train, self.frozen))

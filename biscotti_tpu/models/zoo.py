@@ -6,6 +6,10 @@
   cifar_cnn  LeNet-5 shape                 (ref: cifar_cnn_model.py; BASELINE.md "CIFAR LeNet")
   lfw_cnn    small conv net over 62×47×3   (ref: lfw_cnn_model.py)
   svm        linear + multiclass hinge     (ref: svm_model.py)
+  laguna_s_fedlora  Laguna-S-2.1's sparse-expert, window/full-attention decoder
+             (models/laguna.py): a frozen 3.0 B-parameter share at the
+             published widths, rank-16 adapters trained, d = 1,048,576
+  laguna_tiny  the same mechanism at the CPU tests' size
 
 Inits are MXU-friendly (fan-in scaled normal) and every model is expressed in
 channels-last NHWC, the layout XLA prefers on TPU.
@@ -20,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from biscotti_tpu.data.datasets import base_name, spec as dspec
+from biscotti_tpu.models import laguna
 from biscotti_tpu.models.base import Model, cross_entropy, make_model, multiclass_hinge
 
 
@@ -83,7 +88,7 @@ def logreg_model(d_in: int, lammy: float = 0.01) -> Model:
         z = _with_bias(x) @ p["w"]
         return jnp.mean(jnp.logaddexp(0.0, -ypm * z)) + 0.5 * lammy * jnp.dot(p["w"], p["w"])
 
-    return make_model("logreg", d_in, 2, init, apply, loss)
+    return make_model("logreg", d_in, 2, init, apply, loss, step_rule="sgd")
 
 
 def _conv_init(key, shape):  # HWIO
@@ -200,15 +205,28 @@ MODELS: Dict[str, callable] = {
     "mnist_cnn": lambda ds: mnist_cnn_model(),
     "cifar_cnn": lambda ds: cifar_cnn_model(),
     "lfw_cnn": lambda ds: lfw_cnn_model(),
+    **{name: (lambda ds, name=name: _laguna(name, ds))
+       for name in laguna.PRESETS},
 }
+
+# what a dataset trains where no model is named (softmax otherwise)
+DEFAULTS = {"creditcard": "logreg", "lm_tokens": "laguna_s_fedlora",
+            "lm_tokens_tiny": "laguna_tiny"}
+
+
+def _laguna(name: str, dataset: str) -> Model:
+    spec, cfg = dspec(dataset), laguna.PRESETS[name]
+    if not spec.tokens or spec.n_classes != cfg.vocab:
+        raise ValueError(
+            f"model {name!r} reads windows of token ids below {cfg.vocab}; "
+            f"dataset {dataset!r} has {spec.n_classes} classes"
+            + ("" if spec.tokens else " and no tokens"))
+    return laguna.laguna_model(name, cfg, spec.d_in)
 
 
 def model_for_dataset(dataset: str, model: str = "") -> Model:
     """Default model per dataset, mirroring the reference pairings
     (softmax for mnist/cifar/lfw via client_obj.init; logreg for creditcard
     via ML/code/logistic_model.py)."""
-    if model:
-        return MODELS[model](dataset)
-    if base_name(dataset) == "creditcard":
-        return logreg_model(dspec(dataset).d_in)
-    return softmax_model(dspec(dataset).d_in, dspec(dataset).n_classes)
+    return MODELS[model or DEFAULTS.get(base_name(dataset), "softmax")](
+        dataset)

@@ -1,0 +1,158 @@
+"""A sparse-expert layer that is told which experts it holds.
+
+The router keeps its published width (it scores ALL the model's experts)
+and its experts a token; this device holds the contiguous slice
+`[first, first + E)` of them (the `E` leading rows of the stacked expert
+weights) and computes ITS experts' part of the layer's result for the
+tokens routed to them. What the absent experts would add is left out, as
+on one chip of an expert-parallel deployment before the exchange; no code
+here stands in for the other chips (tests/test_laguna.py: the shares add
+up to the uncut layer).
+
+No token is dropped and there is no capacity factor: the token-expert
+assignments are sorted by expert (those of experts held elsewhere last) and
+ONE grouped product a weight runs over the held rows (`jax.lax.ragged_dot`).
+The sorted buffer is cut to CAPACITY times the rows a uniform router would
+send here (the grouped product's time follows the rows it is given, PERF.md
+section 6, PR 27), and a round whose held rows pass that runs the same code
+on the uncut buffer instead (`lax.cond`): slower, never lossy.
+
+Rows go to their sorted places and come back by gathers in both directions
+(`_dispatch`, `_combine`, each the other's transpose): the transpose XLA
+would derive for a gather is a scatter-add.
+"""
+
+from __future__ import annotations
+
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+
+
+def route(x: jax.Array, router_w: jax.Array, top_k: int, scale: float):
+    """softmax over ALL experts, the `top_k` largest, their probabilities
+    renormalised to one and scaled: (experts int32[N, k], coefficients
+    float32[N, k], probabilities float32[N, E_all])."""
+    logits = jnp.dot(x.astype(router_w.dtype), router_w,
+                     preferred_element_type=jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_i = jax.lax.top_k(probs, top_k)
+    coef = scale * top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    return top_i.astype(jnp.int32), coef, probs
+
+
+CAPACITY = 2.0  # x the uniform router's expectation, before the uncut path
+
+
+def _gather_sum(rows, where, weight):
+    """out[n] = sum_j weight[n, j] * rows[where[n, j]]; `where` == len(rows)
+    reads a zero row. rows [C, H]; where, weight [N, k]."""
+    padded = jnp.concatenate([rows, jnp.zeros_like(rows[:1])])
+    return jnp.einsum("nk,nkh->nh", weight.astype(rows.dtype), padded[where],
+                      preferred_element_type=rows.dtype)
+
+
+@jax.custom_vjp
+def _dispatch(x, token, where, valid):
+    """x[token]: the sorted rows' hidden states, [C, H]. `where` [N, k] is
+    each assignment's sorted row (C where it has none here) and `valid`
+    marks those that have one: the transpose's map."""
+    return x[token]
+
+
+def _dispatch_fwd(x, token, where, valid):
+    return x[token], (where, valid)
+
+
+def _dispatch_bwd(res, g):
+    where, valid = res
+    return _gather_sum(g, where, valid), None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(rows, weight, token, slot, where):
+    """out[n] = sum over n's assignments j of weight[n, j] * rows[where[n,
+    j]], float32[N, H]: the sorted rows back at their tokens, weighed.
+    `token`, `slot` [C]: the assignment each sorted row is."""
+    return _gather_sum(rows, where, weight)
+
+
+def _combine_fwd(rows, weight, token, slot, where):
+    return _gather_sum(rows, where, weight), (rows, weight, token, slot,
+                                              where)
+
+
+def _combine_bwd(res, g):
+    rows, weight, token, slot, where = res
+    back = g[token]                                   # [C, H]
+    d_rows = weight[token, slot][:, None] * back
+    per_row = jnp.concatenate([jnp.sum(rows * back, axis=-1),
+                               jnp.zeros((1,), rows.dtype)])
+    return d_rows, per_row[where], None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def held_experts(x: jax.Array, experts: jax.Array, coef: jax.Array,
+                 weights: dict, first: int = 0, total: int = 0):
+    """Σ over the token's assignments that land on a held expert of
+    coefficient · SwiGLU_e(x): float32[N, H], and what the dispatch
+    counted.
+
+    x [N, H]; experts, coef [N, k] as `route` gives them (ids over all
+    `total` experts of the model; 0: the held ones are all there are);
+    weights: `w_gate`, `w_up` [E, H, F], `w_down` [E, F, H], the E experts
+    `first .. first + E - 1`. The products run in the weights' dtype with
+    float32 accumulation.
+
+    counts: `load` int32[E] assignments a held expert; `dropped` int32:
+    held assignments that reached no row of the sorted buffer (0 by
+    construction: the uncut buffer has a row for every assignment)."""
+    n, k = experts.shape
+    e = weights["w_gate"].shape[0]
+    dtype = weights["w_gate"].dtype
+    local = experts - first
+    held = (local >= 0) & (local < e)
+    group = jnp.where(held, local, e).reshape(n * k)  # e: held elsewhere
+    # int32 throughout, whatever jax_enable_x64 says: the sort by expert
+    # (stable), its inverse, and the groups' sizes
+    rank = jnp.arange(n * k, dtype=jnp.int32)
+    _, order = jax.lax.sort((group, rank), num_keys=1, is_stable=True)
+    _, inverse = jax.lax.sort((order, rank), num_keys=1)
+    load = jnp.sum(group[:, None] == jnp.arange(e, dtype=jnp.int32)[None],
+                   axis=0, dtype=jnp.int32)
+    rows = jnp.sum(load, dtype=jnp.int32)
+    x = x.astype(dtype)
+
+    def part(capacity):
+        """The held rows' result through a sorted buffer of `capacity`
+        rows (static), all of them in it."""
+        token, slot = order[:capacity] // k, order[:capacity] % k
+        valid = held & (inverse.reshape(n, k) < capacity)
+        where = jnp.where(valid, inverse.reshape(n, k), capacity)
+        # the rows past the held ones ride in the last group: computed,
+        # and read by nothing (`where` and the weights leave them out)
+        sizes = load.at[e - 1].add(capacity - rows)
+        dot = partial(jax.lax.ragged_dot, group_sizes=sizes,
+                      preferred_element_type=jnp.float32)
+        xs = _dispatch(x, token, where, valid)
+        hidden = jax.nn.silu(dot(xs, weights["w_gate"])) \
+            * dot(xs, weights["w_up"])
+        ys = dot(hidden.astype(dtype), weights["w_down"])
+        return _combine(ys, jnp.where(valid, coef, 0.0), token, slot, where)
+
+    expected = CAPACITY * n * k * e / max(total, e)
+    capacity = min(n * k, -(-int(expected) // 8) * 8)
+    if capacity == n * k:
+        out = part(n * k)
+    else:
+        out = jax.lax.cond(rows <= capacity, lambda: part(capacity),
+                           lambda: part(n * k))
+    counts = {"load": load,
+              "dropped": jnp.sum(held, dtype=jnp.int32) - rows}
+    return out, counts

@@ -20,19 +20,22 @@ RONI_THRESHOLD = 0.02  # ref: DistSys/main.go:203-231
 
 
 def roni_scores(model: Model, flat_w: jax.Array, deltas: jax.Array,
-                x_val: jax.Array, y_val: jax.Array) -> jax.Array:
+                x_val: jax.Array, y_val: jax.Array, frozen=None) -> jax.Array:
     """scores[i] = err(w + δ_i) − err(w) on the validation split."""
-    base = model.error_flat(flat_w, x_val, y_val)
-    per = jax.vmap(lambda d: model.error_flat(flat_w + d, x_val, y_val))(deltas)
+    base = model.error_flat(flat_w, x_val, y_val, frozen)
+    per = jax.vmap(lambda d: model.error_flat(flat_w + d, x_val, y_val,
+                                              frozen))(deltas)
     return per - base
 
 
 def roni_accept_mask(model: Model, flat_w: jax.Array, deltas: jax.Array,
                      x_val: jax.Array, y_val: jax.Array,
-                     threshold: float = RONI_THRESHOLD) -> jax.Array:
+                     threshold: float = RONI_THRESHOLD,
+                     frozen=None) -> jax.Array:
     """accept iff the update does not worsen validation error by more than
     the threshold (ref: main.go:203-231)."""
-    return roni_scores(model, flat_w, deltas, x_val, y_val) <= threshold
+    return roni_scores(model, flat_w, deltas, x_val, y_val,
+                       frozen) <= threshold
 
 
 def make_roni_kernel(model: Model, threshold: float = RONI_THRESHOLD):

@@ -8,6 +8,9 @@ ML/Pytorch/ml_main_mnist.py:24-60). Here one jitted XLA program executes the
 whole round for all peers at once:
 
     deltas   = vmap(local_step)     — S contributors' SGD steps, batched matmuls
+               (in blocks of the peer axis where one step's activations
+               are large, `peer_block`; a model's frozen base is an
+               ARGUMENT of the program, held once for all peers)
     noise    = vmap(threefry draw)  — DP noising committee equivalent
     mask     = Krum | RONI kernel   — verifier committee equivalent
     w'       = w + Σ maskᵢ·deltaᵢ   — miner aggregation (sum, ref honest.go:360-375)
@@ -42,7 +45,8 @@ from jax.experimental.layout import Format, Layout
 from biscotti_tpu.config import BiscottiConfig, Defense
 from biscotti_tpu.data import datasets as ds
 from biscotti_tpu.models.base import Model
-from biscotti_tpu.models.trainer import local_step_fn, sample_batch
+from biscotti_tpu.models.trainer import (block_step_fn, local_step_fn,
+                                         sample_batch, step_rule)
 from biscotti_tpu.models.zoo import model_for_dataset
 from biscotti_tpu.ops import dp_noise
 from biscotti_tpu.ops.krum import default_num_adversaries, krum_accept_mask
@@ -83,6 +87,28 @@ STAGES = (
 # 3,072 and 8,742 at most 1.01x; creditcard's 24 would cost 5.3x, and such
 # stacks (megabytes) keep the runtime's default.
 STACK_PAD_LIMIT = 1.25
+
+# What one chip of the fleet this simulator is written for holds (TPU v5e),
+# where the backend does not say (`memory_stats()` is None on the CPU), and
+# the share of what the round's standing arrays leave free that a block of
+# peers' activations may take: the rest is the compiler's own temporaries
+# and fragmentation (PERF.md section 6, PR 27: read from the compiled
+# program's memory analysis at the published size).
+DEVICE_BYTES = 16 * 2**30
+BLOCK_SHARE = 0.5
+
+
+def peer_block(samples: int, step_bytes: Optional[int], free: int) -> int:
+    """How many of a round's `samples` peers step together: all of them
+    where the model states no activation size (every classifier), else the
+    largest divisor of `samples` whose block of `step_bytes` a peer fits
+    BLOCK_SHARE of the `free` bytes (at least one peer). A divisor, so that
+    every block is the same program."""
+    if not step_bytes:
+        return samples
+    fit = max(1, int(BLOCK_SHARE * free) // step_bytes)
+    return max(b for b in range(1, samples + 1)
+               if samples % b == 0 and b <= fit)
 
 
 def stack_layout(shape, itemsize: int = 4) -> Optional[Layout]:
@@ -144,6 +170,12 @@ def put_stack(a, sharding=None) -> jax.Array:
         return jax.device_put(a, Format(layout, a.sharding))
 
 
+def _device_bytes() -> int:
+    """The first device's memory, as the runtime states it."""
+    stats = jax.devices()[0].memory_stats() or {}
+    return int(stats.get("bytes_limit", DEVICE_BYTES))
+
+
 def _array_dims(result_type: str):
     """The dimensions of every array in an HLO result type (a tuple type
     holds several): `bf16[3383,480,512]{2,1,0:T(8,128)(2,1)}` -> (3383,
@@ -199,7 +231,8 @@ class RoundLog:
 
 def defense_mask(defense: Defense, model: Model, w: jax.Array,
                  noised: jax.Array, x_val: jax.Array, y_val: jax.Array,
-                 roni_threshold: float, num_adversaries: int) -> jax.Array:
+                 roni_threshold: float, num_adversaries: int,
+                 frozen=None) -> jax.Array:
     """Verifier-committee accept mask over the round's noised updates —
     shared by the single-chip (vmap) and sharded (shard_map) round steps so
     the two paths cannot drift. TRIMMED_MEAN has no per-update reject (it
@@ -217,7 +250,8 @@ def defense_mask(defense: Defense, model: Model, w: jax.Array,
 
         return foolsgold_accept_mask(noised)
     if defense == Defense.RONI:
-        return roni_accept_mask(model, w, noised, x_val, y_val, roni_threshold)
+        return roni_accept_mask(model, w, noised, x_val, y_val,
+                                roni_threshold, frozen)
     return jnp.ones((n,), jnp.bool_)
 
 
@@ -261,7 +295,7 @@ class Simulator:
         self.metrics = metrics
         self.model = model or model_for_dataset(
             cfg.dataset, getattr(cfg, "model_name", ""))
-        self.mode = "sgd" if self.model.name == "logreg" else "grad"
+        self.mode, rate = step_rule(self.model, cfg)
         self.num_params = self.model.num_params
         n = cfg.num_nodes
 
@@ -288,14 +322,29 @@ class Simulator:
 
         with self.phases.phase("sim.build"):
             self.root_key = jax.random.PRNGKey(cfg.seed)
-            alpha = cfg.logreg_alpha
+            # one peer's step, and the same over a block of the peer axis
             self._step = local_step_fn(self.model, self.mode,
-                                       clip=cfg.grad_clip, alpha=alpha)
+                                       clip=cfg.grad_clip, alpha=rate)
+            self._block_step = block_step_fn(self.model, self.mode,
+                                             clip=cfg.grad_clip, alpha=rate)
             self._use_noise = cfg.noising or cfg.dp_in_model
             self._noise_eps = cfg.epsilon if self._use_noise else 0.0
             self._noise_scale = dp_noise.sigma_for(self._noise_eps, cfg.delta)
             self._dp_mechanism = cfg.dp_mechanism
-            self._noise_alpha = alpha if self.mode == "sgd" else 1.0
+            self._noise_alpha = rate  # the step's rate scales its noise
+            # the frozen base, drawn on the device leaf by leaf from the
+            # seed and held ONCE for all peers; `{}` for every classifier
+            with self.phases.phase("sim.frozen"):
+                self.frozen = self.model.frozen(self.root_key)
+            standing = (self.frozen_bytes() + x_host.nbytes + y_host.nbytes
+                        + 4 * (3 * cfg.num_samples + 2) * self.num_params)
+            self.peer_block = peer_block(
+                cfg.num_samples,
+                self.model.step_bytes
+                and self.model.step_bytes(min(cfg.batch_size, rows)),
+                _device_bytes() - standing)
+            self.last_counts = {}  # what the last round's dispatch counted
+            self._round_hlo_text = None
             self._round_step_raw, noised_raw = self._build_round_step()
             self._round_step_jit = jax.jit(self._round_step_raw,
                                            donate_argnums=(0, 1))
@@ -323,9 +372,10 @@ class Simulator:
                 seed = jnp.asarray(self.cfg.seed, jnp.int32)
                 w, stake = self._at_home(w, stake)
             with self.phases.phase("sim.round.dispatch"):
-                return self._round_step_jit(w, stake, it, seed,
-                                            self.x, self.y,
-                                            self.x_val, self.y_val)
+                *out, self.last_counts = self._round_step_jit(
+                    w, stake, it, seed, self.x, self.y, self.x_val,
+                    self.y_val, self.frozen)
+                return tuple(out)
 
         self.round_step = round_step
 
@@ -381,25 +431,42 @@ class Simulator:
             return (x.reshape(n * rows, *x.shape[2:])[flat],
                     y.reshape(n * rows, *y.shape[2:])[flat])
 
+    def _walk(self, w: jax.Array, xb: jax.Array, yb: jax.Array, frozen):
+        """The [S, d] deltas of the minibatches [S, B, ...] and what the
+        model's dispatch counted: the peer axis in blocks of
+        `self.peer_block` (one block where that is all of them: every
+        classifier), each block the same program, one after the other."""
+        s, block = xb.shape[0], min(self.peer_block, xb.shape[0])
+        if s % block:  # a device's share of the peers (the sharded step)
+            block = math.gcd(s, block)
+        if block == s:
+            return self._block_step(w, xb, yb, frozen)
+        blocks = [a.reshape(s // block, block, *a.shape[1:])
+                  for a in (xb, yb)]
+        deltas, counts = jax.lax.map(
+            lambda b: self._block_step(w, b[0], b[1], frozen), blocks)
+        return (deltas.reshape(s, -1),
+                jax.tree.map(lambda c: jnp.sum(c, axis=0), counts))
+
     def _peer_updates(self, w: jax.Array, bkey: jax.Array, nkey: jax.Array,
                       ids: jax.Array, at: jax.Array, x: jax.Array,
-                      y: jax.Array):
-        """Raw and noised [S, d] deltas of the peers `ids` — shared by the
+                      y: jax.Array, frozen=None):
+        """Raw and noised [S, d] deltas of the peers `ids`, and the model's
+        counts — shared by the
         one-chip step and the sharded one, so the two draw the same
         streams under the same scopes. `at` says where in the stack (x, y)
         each of those peers' shards sits: the sampled ids themselves on one
         chip, `arange(n_loc)` on a device that holds only its own peers."""
         xb, yb = self._minibatches(bkey, ids, at, x, y)
         with jax.named_scope("round_grad"):
-            deltas = jax.vmap(self._step, in_axes=(None, 0, 0))(
-                w, xb, yb)  # [S, d]
+            deltas, counts = self._walk(w, xb, yb, frozen)  # [S, d]
         with jax.named_scope("round_noise"):
             if self._use_noise:
                 nkeys = jax.vmap(lambda i: jax.random.fold_in(nkey, i))(ids)
                 noise = jax.vmap(self._peer_noise)(nkeys)
             else:
                 noise = jnp.zeros_like(deltas)
-            return deltas, deltas + noise
+            return deltas, deltas + noise, counts
 
     def _build_round_step(self):
         cfg = self.cfg
@@ -433,24 +500,28 @@ class Simulator:
         # sweeps (eval_poison --seeds) pay the compile N times.
         seed_base = jax.random.PRNGKey(0)  # same constant for every sim
 
-        def noised_updates(w, it, seed, x, y):
+        def updates(w, it, seed, x, y, frozen):
             """Round `it`'s contributor ids with their raw and noised
-            deltas — everything the round does before the defence."""
+            deltas — everything the round does before the defence — and
+            what the model's dispatch counted."""
             with jax.named_scope("round_sample"):
                 rkey = jax.random.fold_in(
                     jax.random.fold_in(seed_base, seed), it)
                 ckey, bkey, nkey = jax.random.split(rkey, 3)
                 cidx = self._contributors(ckey)
-            deltas, noised = self._peer_updates(w, bkey, nkey, cidx, cidx,
-                                                x, y)
-            return cidx, deltas, noised
+            deltas, noised, counts = self._peer_updates(
+                w, bkey, nkey, cidx, cidx, x, y, frozen)
+            return cidx, deltas, noised, counts
 
-        def round_step(w, stake, it, seed, x, y, x_val, y_val):
-            cidx, deltas, noised = noised_updates(w, it, seed, x, y)
+        def noised_updates(w, it, seed, x, y, frozen=None):
+            return updates(w, it, seed, x, y, frozen)[:3]
+
+        def round_step(w, stake, it, seed, x, y, x_val, y_val, frozen=None):
+            cidx, deltas, noised, counts = updates(w, it, seed, x, y, frozen)
             s = cidx.shape[0]
             mask = defense_mask(defense, model, w, noised, x_val,
                                 y_val, cfg.roni_threshold,
-                                default_num_adversaries(s))
+                                default_num_adversaries(s), frozen)
             with jax.named_scope("round_ledger"):
                 delta_stake = jnp.where(mask, cfg.stake_unit,
                                         -cfg.stake_unit)
@@ -466,8 +537,8 @@ class Simulator:
             with jax.named_scope("round_ledger"):
                 stake_next = stake.at[cidx].add(delta_stake)
             with jax.named_scope("round_eval"):
-                err = model.error_flat(w_next, x_val, y_val)
-            return w_next, stake_next, mask, err
+                err = model.error_flat(w_next, x_val, y_val, frozen)
+            return w_next, stake_next, mask, err, counts
 
         return round_step, noised_updates
 
@@ -489,7 +560,12 @@ class Simulator:
         is traced anew, through a wrapper of its own: JAX keeps the
         executable it fetched on the memoized lowering of
         `_round_step_jit`, and would hand that back uncompiled. A compile
-        costs seconds: call it after a timed window, never in one."""
+        costs seconds: call it after a timed window, never in one. The
+        text is kept: a second reader of the same run does not compile
+        again."""
+        if self._round_hlo_text is not None:
+            return self._round_hlo_text
+
         def round_step(*args):  # the name the program and its scopes carry
             return self._round_step_raw(*args)
 
@@ -498,14 +574,22 @@ class Simulator:
         it = jax.ShapeDtypeStruct((), jax.dtypes.canonicalize_dtype(int),
                                   weak_type=True)
         seed = jax.ShapeDtypeStruct((), jnp.int32)
-        data = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.format)
-                for a in (self.x, self.y, self.x_val, self.y_val)]
+        data = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=a.format),
+            (self.x, self.y, self.x_val, self.y_val, self.frozen))
         lowered = jax.jit(round_step, donate_argnums=(0, 1)).lower(
             w, stake, it, seed, *data)
         with outside_compile_cache():
-            return lowered.compile().as_text()
+            self._round_hlo_text = lowered.compile().as_text()
+        return self._round_hlo_text
 
     # ------------------------------------------------- the gather's witness
+
+    def frozen_bytes(self) -> int:
+        """Bytes of the model's frozen tree (0: it has none)."""
+        return sum(a.size * a.dtype.itemsize
+                   for a in jax.tree.leaves(self.frozen))
 
     def stack_info(self) -> dict:
         """Where the peer stack sits: its device layout as the runtime
@@ -577,6 +661,14 @@ class Simulator:
                     "peer stack on the device, bytes under its device "
                     "layout (minor-to-major; 2,1,0 is row-major)").set(
                 info["device_bytes"], layout=info["layout"])
+            m.gauge("biscotti_sim_frozen_bytes",
+                    "the model's frozen tree on the device, held once for "
+                    "all peers (0: the model has none)").set(
+                self.frozen_bytes())
+            m.gauge("biscotti_sim_peer_block",
+                    "sampled peers whose local steps the round computes "
+                    "together (num_samples: all of them at once)").set(
+                self.peer_block)
         for it in range(num_rounds):
             t0 = time.perf_counter()
             w, stake, mask, err = self.round_step(w, stake, it)
@@ -587,6 +679,20 @@ class Simulator:
                     time.perf_counter() - t0)
                 m.gauge("biscotti_sim_round_height",
                         "simulator rounds completed").set(it + 1)
+                moe = self.dispatch_stats()
+                if moe:
+                    m.gauge("biscotti_moe_assignments_held",
+                            "token-expert assignments of the last round "
+                            "that landed on experts held here").set(
+                        moe["assignments_held"])
+                    m.gauge("biscotti_moe_load_max_over_mean",
+                            "fullest held expert's assignments over the "
+                            "held experts' mean, worst sparse layer").set(
+                        moe["load_max_over_mean"])
+                    m.gauge("biscotti_moe_tokens_dropped",
+                            "held assignments of the last round that "
+                            "reached no expert (must read 0)").set(
+                        moe["tokens_dropped"])
             if it % log_every == 0 or it == num_rounds - 1:
                 e = float(err)
                 logs.append(RoundLog(it, e, time.time(), int(mask.sum())))
@@ -617,11 +723,11 @@ class Simulator:
         if full is None:
 
             @jax.jit
-            def full(w, stake, seed, x, y, x_val, y_val):
+            def full(w, stake, seed, x, y, x_val, y_val, frozen):
                 def body(carry, it):
                     w, stake = carry
-                    w, stake, mask, err = step(w, stake, it, seed, x, y,
-                                               x_val, y_val)
+                    w, stake, mask, err, _ = step(w, stake, it, seed, x, y,
+                                                  x_val, y_val, frozen)
                     return (w, stake), (err, jnp.sum(mask))
 
                 return jax.lax.scan(body, (w, stake),
@@ -633,7 +739,7 @@ class Simulator:
         s = self.cfg.seed if seed is None else seed
         (w, stake), (errs, accepted) = full(
             w, stake, jnp.asarray(s, jnp.int32), self.x, self.y,
-            self.x_val, self.y_val)
+            self.x_val, self.y_val, self.frozen)
         return w, stake, np.asarray(errs), np.asarray(accepted)
 
     # ------------------------------------------------------------------ metrics
@@ -644,14 +750,35 @@ class Simulator:
         checking a scoring kernel against an oracle on it."""
         return self._noised_jit(*self._at_home(w), it,
                                 jnp.asarray(self.cfg.seed, jnp.int32),
-                                self.x, self.y)[2]
+                                self.x, self.y, self.frozen)[2]
+
+    def dispatch_stats(self, counts=None) -> dict:
+        """What a round's expert dispatch counted (`counts`: the last
+        round's), `{}` for a model that has none: `assignments_held`,
+        token-expert assignments that landed on experts held here, all
+        sparse layers; `load_max_over_mean`, the fullest held expert's over
+        the held experts' mean, worst sparse layer; `tokens_dropped`, held
+        assignments that reached no expert (must read 0). From `load`
+        int32[layers, held experts] and `dropped`, which the round
+        returns; reads them back: call it outside a timed round."""
+        counts = self.last_counts if counts is None else counts
+        if "load" not in counts:
+            return {}
+        load = np.asarray(counts["load"], np.float64)
+        return {
+            "assignments_held": float(load.sum()),
+            "load_max_over_mean": float(np.max(load.max(axis=1)
+                                               / load.mean(axis=1))),
+            "tokens_dropped": float(np.asarray(counts["dropped"]).sum()),
+        }
 
     def test_error(self, w) -> float:
-        return float(self.model.error_flat(jnp.asarray(w), self.x_val, self.y_val))
+        return float(self.model.error_flat(jnp.asarray(w), self.x_val,
+                                           self.y_val, self.frozen))
 
     def attack_rate(self, w) -> float:
         return float(self.model.error_flat(jnp.asarray(w), self.x_attack,
-                                           self.y_attack))
+                                           self.y_attack, self.frozen))
 
     def attack_success_rate(self, w) -> float:
         """Stricter source→target metric: fraction of attack-source samples
@@ -659,7 +786,8 @@ class Simulator:
         trainer.attack_success_rate analogue — not inflated by benign
         confusion the way attack_rate's 1−accuracy is)."""
         target = ds.spec(self.cfg.dataset).attack_target
-        logits = self.model.apply_flat(jnp.asarray(w), self.x_attack)
+        logits = self.model.apply_flat(jnp.asarray(w), self.x_attack,
+                                       self.frozen)
         pred = jnp.argmax(logits, axis=-1)
         return float(jnp.mean((pred == target).astype(jnp.float32)))
 
@@ -695,7 +823,7 @@ def make_sharded_round_step(sim: Simulator, mesh: jax.sharding.Mesh,
     def run_step(w, it, seed: Optional[int] = None):
         s = sim.cfg.seed if seed is None else seed
         return step(w, x_sh, y_sh, jnp.asarray(it),
-                    jnp.asarray(s, jnp.int32))
+                    jnp.asarray(s, jnp.int32), sim.frozen)
 
     run_step.x = x_sh  # the sharded peer stack: lets a caller check placement
     return run_step
@@ -704,8 +832,9 @@ def make_sharded_round_step(sim: Simulator, mesh: jax.sharding.Mesh,
 def sharded_round_step_fn(sim: Simulator, mesh: jax.sharding.Mesh,
                           axis: str = "peers"):
     """The jitted program behind make_sharded_round_step:
-    `(w, x, y, it, seed) -> (w', mask, err)` with (x, y) sharded over
-    `axis`. No data is placed, so it can also be lowered ahead of time for
+    `(w, x, y, it, seed, frozen) -> (w', mask, err)` with (x, y) sharded
+    over `axis` and the model's frozen tree on every device whole (experts
+    over a second mesh axis: ROADMAP B2's remainder). No data is placed, so it can also be lowered ahead of time for
     a mesh of devices this host does not have
     (tests/test_tpu_lowering.py)."""
     from jax.sharding import PartitionSpec as P
@@ -719,7 +848,7 @@ def sharded_round_step_fn(sim: Simulator, mesh: jax.sharding.Mesh,
     drop_p = cfg.fault_plan.drop if cfg.fault_plan.enabled else 0.0
     fault_base = jax.random.PRNGKey(cfg.fault_plan.seed)
 
-    def local_deltas(w, x_loc, y_loc, it, seed):
+    def local_deltas(w, x_loc, y_loc, it, seed, frozen):
         with jax.named_scope("round_sample"):
             pid = jax.lax.axis_index(axis)
             local = jnp.arange(x_loc.shape[0])
@@ -727,13 +856,14 @@ def sharded_round_step_fn(sim: Simulator, mesh: jax.sharding.Mesh,
             rkey = jax.random.fold_in(jax.random.fold_in(seed_base, seed),
                                       it)
             bkey, nkey = jax.random.split(rkey)
-        return sim._peer_updates(w, bkey, nkey, gids, local, x_loc, y_loc)
+        return sim._peer_updates(w, bkey, nkey, gids, local, x_loc, y_loc,
+                                 frozen)[:2]
 
-    def sharded_step(w, x_loc, y_loc, it, seed):
-        deltas, noised = local_deltas(w, x_loc, y_loc, it, seed)
+    def sharded_step(w, x_loc, y_loc, it, seed, frozen):
+        deltas, noised = local_deltas(w, x_loc, y_loc, it, seed, frozen)
         all_noised = jax.lax.all_gather(noised, axis, tiled=True)  # [N, d]
         mask = defense_mask(defense, model, w, all_noised, sim.x_val,
-                            sim.y_val, cfg.roni_threshold, f)
+                            sim.y_val, cfg.roni_threshold, f, frozen)
         if drop_p > 0.0:
             # mirror of the live fault plane's frame drops: the accepted
             # update whose miner-bound frame is lost contributes nothing
@@ -761,16 +891,20 @@ def sharded_round_step_fn(sim: Simulator, mesh: jax.sharding.Mesh,
                 agg = jax.lax.psum(local_agg, axis)
             w_next = w + agg
         with jax.named_scope("round_eval"):
-            err = model.error_flat(w_next, sim.x_val, sim.y_val)
+            err = model.error_flat(w_next, sim.x_val, sim.y_val, frozen)
         return w_next, mask, err
 
     mapped = jax.shard_map(
         sharded_step, mesh=mesh,
-        in_specs=(P(), P(axis), P(axis), P(), P()),
+        in_specs=(P(), P(axis), P(axis), P(), P(), P()),
         out_specs=(P(), P(), P()),
         check_vma=False,
     )
-    return jax.jit(mapped)
+
+    def sharded_step(w, x, y, it, seed, frozen=None):  # noqa: F811
+        return mapped(w, x, y, it, seed, {} if frozen is None else frozen)
+
+    return jax.jit(sharded_step)
 
 
 # ------------------------------------------------------------------- CLI

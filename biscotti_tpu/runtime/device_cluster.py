@@ -74,7 +74,8 @@ class BatchStepper:
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from biscotti_tpu.data import datasets as ds
-        from biscotti_tpu.models.trainer import local_step_fn, sample_batch
+        from biscotti_tpu.models.trainer import (local_step_fn,
+                                                 sample_batch, step_rule)
         from biscotti_tpu.models.zoo import model_for_dataset
         from biscotti_tpu.parallel.sim import _poisoned_ids
 
@@ -89,9 +90,14 @@ class BatchStepper:
         model = model_for_dataset(cfg.dataset,
                                   getattr(cfg, "model_name", ""))
         self.num_params = model.num_params
-        mode = "sgd" if model.name == "logreg" else "grad"
-        step = local_step_fn(model, mode, clip=cfg.grad_clip,
-                             alpha=cfg.logreg_alpha)
+        if model.init_frozen is not None:
+            # ROADMAP B0's remainder: the frozen base on the live path
+            raise NotImplementedError(
+                f"model {model.name!r} holds a frozen tree; the batched "
+                "live plane steps classifiers only (the simulator and the "
+                "per-peer Trainer take it)")
+        mode, rate = step_rule(model, cfg)
+        step = local_step_fn(model, mode, clip=cfg.grad_clip, alpha=rate)
 
         poisoned = _poisoned_ids(n, cfg.poison_fraction)
         xs, ys = [], []

@@ -388,7 +388,8 @@ class HiveStepper:
         import jax.numpy as jnp
 
         from biscotti_tpu.data import datasets as ds
-        from biscotti_tpu.models.trainer import local_step_fn, sample_batch
+        from biscotti_tpu.models.trainer import (local_step_fn,
+                                                 sample_batch, step_rule)
         from biscotti_tpu.models.zoo import model_for_dataset
         from biscotti_tpu.ops import dp_noise
         from biscotti_tpu.parallel.sim import _poisoned_ids
@@ -401,9 +402,14 @@ class HiveStepper:
         model = model_for_dataset(cfg.dataset,
                                   getattr(cfg, "model_name", ""))
         self.num_params = model.num_params
-        mode = "sgd" if model.name == "logreg" else "grad"
-        step = local_step_fn(model, mode, clip=cfg.grad_clip,
-                             alpha=cfg.logreg_alpha)
+        if model.init_frozen is not None:
+            # ROADMAP B0's remainder: the frozen base on the live path
+            raise NotImplementedError(
+                f"model {model.name!r} holds a frozen tree; the batched "
+                "live plane steps classifiers only (the simulator and the "
+                "per-peer Trainer take it)")
+        mode, rate = step_rule(model, cfg)
+        step = local_step_fn(model, mode, clip=cfg.grad_clip, alpha=rate)
 
         poisoned = _poisoned_ids(cfg.num_nodes, cfg.poison_fraction)
         xs, ys = [], []
@@ -482,7 +488,7 @@ class HiveStepper:
         # draw doesn't batch trivially) — serves_noise gates that.
         eps_live = cfg.epsilon if (cfg.noising or cfg.dp_in_model) else 0.0
         self._sigma = dp_noise.sigma_for(eps_live, cfg.delta)
-        self._noise_alpha = cfg.logreg_alpha if mode == "sgd" else 1.0
+        self._noise_alpha = rate
         # UNCLAMPED batch size, matching Trainer exactly: presample's
         # sqrt scale and noise_at's 1/batch denominator both use
         # cfg.batch_size even when the shard is smaller than a batch

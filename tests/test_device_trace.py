@@ -273,7 +273,7 @@ def test_simulator_carries_its_set_up_phases():
     fresh = Simulator(_cfg(seed=5))
     summary = fresh.phases.summary()
     assert set(summary) == {"sim.shards", "sim.stack", "sim.to_device",
-                            "sim.build"}
+                            "sim.build", "sim.frozen"}  # the last in build
     assert all(row["calls"] == 1 for row in summary.values())
     w, stake = fresh.init_state()
     fresh.round_step(w, stake, 0)

@@ -173,3 +173,118 @@ def test_creditcard_logreg_sim():
     sim = Simulator(cfg)
     w, stake, logs = sim.run(num_rounds=100, stop_at_convergence=False)
     assert logs[-1].error < 0.2, logs[-1].error
+
+
+# ------------------------------- the declared step and the walked peer axis
+
+
+def _deltas(sim, block, w=None):
+    """Round 0's raw and noised deltas and the dispatch's counts, with the
+    peer axis walked in blocks of `block`."""
+    sim.peer_block = block
+    w = sim.init_state()[0] if w is None else w
+    seed = jnp.asarray(sim.cfg.seed, jnp.int32)
+    whole, noised = sim._build_round_step()  # traced with this block
+    _, deltas, noisy = jax.jit(noised)(w, 0, seed, sim.x, sim.y, sim.frozen)
+    counts = jax.jit(whole)(w, sim.init_state()[1], 0, seed, sim.x, sim.y,
+                            sim.x_val, sim.y_val, sim.frozen)[4]
+    return deltas, noisy, counts
+
+
+@pytest.mark.parametrize("block", [1, 2, 6])
+@pytest.mark.parametrize("kind", ["mnist_cnn", "laguna_tiny"])
+def test_walked_peer_axis_gives_the_vmapped_deltas(kind, block):
+    """6 sampled peers, stepped one, two and all at a time: the same
+    deltas, the same noise, and (for the model that counts its expert
+    dispatch) the same counts added up over the blocks."""
+    if kind == "mnist_cnn":
+        cfg = _cfg(num_nodes=6, batch_size=4, model_name="mnist_cnn")
+    else:
+        cfg = _cfg(dataset="lm_tokens_tiny", num_nodes=6, batch_size=2,
+                   learning_rate=0.1, grad_clip=1.0)
+    sim = Simulator(cfg)
+    assert sim.peer_block == 6  # all at once is what the code works out
+    w = sim.model.flat_init(jax.random.PRNGKey(1))
+    whole, whole_noised, whole_counts = _deltas(sim, 6, w)
+    deltas, noised, counts = _deltas(sim, block, w)
+    assert deltas.shape == (6, sim.num_params)
+    np.testing.assert_allclose(deltas, whole, rtol=2e-4, atol=1e-6)
+    np.testing.assert_allclose(noised, whole_noised, rtol=2e-4, atol=1e-6)
+    assert jax.tree.structure(counts) == jax.tree.structure(whole_counts)
+    for got, want in zip(jax.tree.leaves(counts),
+                         jax.tree.leaves(whole_counts)):
+        np.testing.assert_array_equal(got, want)
+    assert bool(counts) == (kind == "laguna_tiny")
+
+
+def test_peer_block_is_worked_out_from_the_bytes():
+    from biscotti_tpu.parallel.sim import BLOCK_SHARE, peer_block
+
+    assert peer_block(21, None, 10**9) == 21       # no activation size: all
+    assert peer_block(21, 0, 10**9) == 21
+    gib = 2**30
+    assert peer_block(21, gib, int(21 * gib / BLOCK_SHARE)) == 21
+    assert peer_block(21, gib, int(8 * gib / BLOCK_SHARE)) == 7  # a divisor
+    assert peer_block(21, gib, int(4 * gib / BLOCK_SHARE)) == 3
+    assert peer_block(21, gib, 10) == 1            # at least one peer
+    assert peer_block(2368, 10, 10**12) == 2368
+    # the published size: 21 peers of a 1,024-token window next to 6 GB
+    from biscotti_tpu.models.zoo import model_for_dataset
+
+    model = model_for_dataset("lm_tokens")
+    per_peer = model.step_bytes(1)
+    assert 0.9e9 < per_peer < 1.4e9
+    assert peer_block(21, per_peer, 16 * gib - int(6.3e9)) == 3
+
+
+@pytest.mark.parametrize("model,dataset,rule,rate", [
+    ("logreg", "creditcard", "sgd", 0.03),
+    ("softmax", "mnist", "grad", 1.0),
+    ("svm", "mnist", "grad", 1.0),
+    ("mnist_cnn", "mnist", "grad", 1.0),
+    ("laguna_tiny", "lm_tokens_tiny", "clipped_sgd", 0.25),
+])
+def test_the_step_rule_follows_the_models_declaration(model, dataset, rule,
+                                                      rate):
+    """Nothing reads a model's name: the rule is the model's own field, and
+    the rate (of the step AND of its noise) the configuration's for it."""
+    import dataclasses
+
+    from biscotti_tpu.models.trainer import (clip_by_global_norm,
+                                             local_step_fn, step_rule)
+    from biscotti_tpu.models.zoo import model_for_dataset
+
+    cfg = _cfg(dataset=dataset, model_name=model, num_nodes=4, batch_size=4,
+               logreg_alpha=0.03, learning_rate=0.25, grad_clip=0.5,
+               epsilon=1.0, noising=True)
+    m = model_for_dataset(dataset, model)
+    assert (m.step_rule, step_rule(m, cfg)) == (rule, (rule, rate))
+    sim = Simulator(cfg)
+    assert sim.mode == rule and sim._noise_alpha == rate
+    # a model of another NAME with the same declaration steps the same
+    renamed = dataclasses.replace(m, name="logreg" if model != "logreg"
+                                  else "softmax")
+    assert step_rule(renamed, cfg) == (rule, rate)
+    w = m.flat_init(jax.random.PRNGKey(0))
+    x, y = sim.x[0, :4], sim.y[0, :4]
+    g = jax.grad(m.loss_flat)(w, x, y, sim.frozen)
+    want = {"grad": -clip_by_global_norm(g, 0.5), "sgd": -0.03 * g,
+            "clipped_sgd": -0.25 * clip_by_global_norm(g, 0.5)}[rule]
+    got = local_step_fn(m, rule, clip=0.5, alpha=rate)(w, x, y, sim.frozen)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-8)
+
+
+def test_an_unknown_step_rule_is_refused():
+    from biscotti_tpu.models.base import make_model
+
+    with pytest.raises(ValueError, match="unknown step rule"):
+        make_model("x", 2, 2, lambda k: {"w": jnp.zeros(2)},
+                   lambda p, x: x, lambda p, x, y: 0.0, step_rule="adam")
+
+
+def test_the_live_planes_refuse_a_frozen_base_for_now():
+    from biscotti_tpu.runtime.hive import HiveStepper
+
+    cfg = _cfg(dataset="lm_tokens_tiny", num_nodes=4)
+    with pytest.raises(NotImplementedError, match="frozen tree"):
+        HiveStepper(cfg, [0, 1])

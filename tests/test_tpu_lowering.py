@@ -70,7 +70,8 @@ def _compile_sharded_step(sim, devices, stack=True):
     args = (_abstract([w], rep)
             + _abstract([sim.x, sim.y], peers, stack=stack)
             + _abstract([jnp.asarray(0),
-                         jnp.asarray(sim.cfg.seed, jnp.int32)], rep))
+                         jnp.asarray(sim.cfg.seed, jnp.int32)], rep)
+            + [sim.frozen])  # `{}`: a classifier holds no frozen tree
     return sharded_round_step_fn(sim, mesh).lower(*args).compile()
 
 
@@ -212,3 +213,97 @@ def test_round_hlo_is_the_program_of_the_stacks_own_format(v5e, sim_1024,
     assert "tpu_custom_call" in hlo and "round_gather" in hlo
     assert _x_entry_layout(hlo).startswith("{2,1,0:T(8,128)}")
     assert sim_1024.whole_stack_instructions(hlo) == []
+
+
+# ------------------------- the frozen tree and the unchanged classifiers (PR 27)
+
+
+def _lowered_round(sim):
+    """The round's lowered program (StableHLO text) at `sim`'s shapes, as
+    `Simulator.round_step` traces it."""
+    def round_step(*args):
+        return sim._round_step_raw(*args)[:4]
+
+    w, stake = sim.init_state()
+    return jax.jit(round_step, donate_argnums=(0, 1)).lower(
+        w, stake, 0, jnp.asarray(sim.cfg.seed, jnp.int32), sim.x, sim.y,
+        sim.x_val, sim.y_val, sim.frozen).as_text()
+
+
+# sha256[:16] of the parent's (c71d5aa, before `Model` had a frozen tree, a
+# declared step rule or a walked peer axis) lowered round at these shapes,
+# read there with this very function less the last argument
+PARENT_ROUNDS = {
+    ("mnist", "softmax"): "2f1f0d7efd64ce2c",
+    ("creditcard", ""): "04e1c79a9ba9c99e",
+    ("mnist", "mnist_cnn"): "0cb8d0fa17f1cd73",
+}
+
+
+@pytest.mark.parametrize("dataset,model", sorted(PARENT_ROUNDS))
+def test_a_classifiers_lowered_round_is_the_parents(dataset, model):
+    """An empty frozen tree adds no argument, `block_step_fn` vmaps the
+    same step, and the declared rule picks what the model's name picked:
+    the program comes out as it was, instruction for instruction."""
+    import hashlib
+
+    sim = Simulator(BiscottiConfig(
+        dataset=dataset, model_name=model, num_nodes=10, seed=3,
+        defense=Defense.KRUM, epsilon=1.0))
+    assert sim.frozen == {} and sim.peer_block == sim.cfg.num_samples
+    text = _lowered_round(sim)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == PARENT_ROUNDS[dataset, model]
+
+
+@pytest.fixture(scope="module")
+def sim_lm():
+    return Simulator(_cfg(dataset="lm_tokens_tiny", num_nodes=8,
+                          batch_size=2, sample_percent=1.0, num_verifiers=1,
+                          num_miners=1, learning_rate=0.1, grad_clip=1.0))
+
+
+def test_the_frozen_tree_is_a_parameter_and_no_constant(sim_lm):
+    """Closed over, the base would be constants of the program (6 GB at
+    the published size): every frozen leaf is an entry parameter of the
+    compiled round, and no constant has a leaf's element count."""
+    import math
+    import re
+
+    hlo = sim_lm.round_hlo()
+    entry = hlo[hlo.index("ENTRY "):]
+    params = re.findall(r"= (\w+)\[([\d,]*)\]\S* parameter\(", entry)
+    shapes = [tuple(int(v) for v in dims.split(",") if v)
+              for _, dims in params]
+    leaves = jax.tree.leaves(sim_lm.frozen)
+    assert len(leaves) > 50
+    for leaf in leaves:
+        assert tuple(leaf.shape) in shapes, leaf.shape
+    assert len(params) == 8 + len(leaves)
+    # (the causal masks [16, 16] and the rotary tables ARE constants; a
+    # weight matrix of the tiny model has 1,024 elements or more)
+    big = 1024
+    assert sum(leaf.size >= big for leaf in leaves) > 10
+    for dims in re.findall(r"= \w+\[([\d,]*)\]\S* constant\(", hlo):
+        assert math.prod(int(v) for v in dims.split(",") if v) < big, dims
+    # and the round's walked blocks are one loop over the same program
+    assert sim_lm.peer_block == sim_lm.cfg.num_samples == 6
+
+
+def test_the_language_model_round_compiles_for_v5e(v5e, sim_lm):
+    """The tiny model's whole round for the chip: the grouped products
+    lower (a compiler-made kernel), under x64."""
+    one = SingleDeviceSharding(v5e[0])
+    w, stake = sim_lm.init_state()
+    args = (_abstract([w, stake, jnp.asarray(0),
+                       jnp.asarray(sim_lm.cfg.seed, jnp.int32)], one)
+            + _abstract([sim_lm.x, sim_lm.y], one, stack=True)
+            + _abstract([sim_lm.x_val, sim_lm.y_val], one)
+            + [jax.tree.map(lambda a: _abstract([a], one)[0],
+                            sim_lm.frozen)])
+    hlo = jax.jit(sim_lm._round_step_raw).lower(*args).compile().as_text()
+    assert "ragged-dot" in hlo
+    wide = [line.strip()[:160] for line in hlo.splitlines()
+            if "f64[" in line or ("s64[" in line and "parameter(" not in line
+                                  and "lm_" in line)]
+    assert not wide, wide[:5]
