@@ -11,11 +11,22 @@ up to the uncut layer).
 
 No token is dropped and there is no capacity factor: the token-expert
 assignments are sorted by expert (those of experts held elsewhere last) and
-ONE grouped product a weight runs over the held rows (`jax.lax.ragged_dot`).
-The sorted buffer is cut to CAPACITY times the rows a uniform router would
-send here (the grouped product's time follows the rows it is given, PERF.md
-section 6, PR 27), and a round whose held rows pass that runs the same code
-on the uncut buffer instead (`lax.cond`): slower, never lossy.
+ONE grouped product a weight runs over the held rows, the groups' sizes the
+held experts' loads: the rows past them are in no group, cost no product
+and come out zeros. The sorted buffer is cut to CAPACITY times the rows a
+uniform router would send here, and a round whose held rows pass that runs
+the same code on the uncut buffer instead (`lax.cond`): slower, never lossy.
+
+The product's time follows the (group, row tile) pairs it visits, not its
+rows (PERF.md section 6, PR 28: the compiler's `ragged-dot` walks row tiles
+of 512, and 64 groups of 120 rows cost it 93 visits, six times the rows).
+So where the shapes allow (`_plan`: hidden size and expert width multiples
+of 128, both buffers whole row tiles, bfloat16 or float32 weights) the
+product is ops/grouped_matmul.py's kernel at the smallest row tile that
+holds the rows a uniform router sends a group; elsewhere (the tiny model of
+the CPU tests) the compiler's `ragged_dot`. One algorithm, one parameter
+read off the shapes: `counts` says which side ran and how full the visited
+tiles were.
 
 Rows go to their sorted places and come back by gathers in both directions
 (`_dispatch`, `_combine`, each the other's transpose): the transpose XLA
@@ -28,6 +39,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+
+from biscotti_tpu.ops import grouped_matmul
 
 
 def route(x: jax.Array, router_w: jax.Array, top_k: int, scale: float):
@@ -98,6 +111,18 @@ def _combine_bwd(res, g):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+def _plan(buffers, h: int, f: int, dtype, rows_a_group: float) -> int:
+    """The row tile of ops/grouped_matmul.py's kernel for sorted buffers of
+    `buffers` rows, a hidden size `h` and an expert width `f`, or 0 where
+    one of the layer's products (forward or backward, either buffer) is
+    not the kernel's to take: the compiler's `ragged_dot` runs them all."""
+    tile = grouped_matmul.row_tile(rows_a_group)
+    taken = all(grouped_matmul.column_tile(c, a, b, dtype, tile)
+                for c in buffers for a, b in ((h, f), (f, h)))
+    return tile if taken else 0
+
+
+@partial(jax.jit, static_argnames=("first", "total"))
 def held_experts(x: jax.Array, experts: jax.Array, coef: jax.Array,
                  weights: dict, first: int = 0, total: int = 0):
     """Σ over the token's assignments that land on a held expert of
@@ -112,9 +137,13 @@ def held_experts(x: jax.Array, experts: jax.Array, coef: jax.Array,
 
     counts: `load` int32[E] assignments a held expert; `dropped` int32:
     held assignments that reached no row of the sorted buffer (0 by
-    construction: the uncut buffer has a row for every assignment)."""
+    construction: the uncut buffer has a row for every assignment);
+    `tile_rows` int32: the rows of the (group, row tile) pairs one grouped
+    product visits, `load`'s sum over it the tiles' fill; `grouped_kernel`
+    int32: 1 where the product is ops/grouped_matmul.py's, 0 the
+    compiler's."""
     n, k = experts.shape
-    e = weights["w_gate"].shape[0]
+    e, h, f = weights["w_gate"].shape
     dtype = weights["w_gate"].dtype
     local = experts - first
     held = (local >= 0) & (local < e)
@@ -135,24 +164,29 @@ def held_experts(x: jax.Array, experts: jax.Array, coef: jax.Array,
         token, slot = order[:capacity] // k, order[:capacity] % k
         valid = held & (inverse.reshape(n, k) < capacity)
         where = jnp.where(valid, inverse.reshape(n, k), capacity)
-        # the rows past the held ones ride in the last group: computed,
-        # and read by nothing (`where` and the weights leave them out)
-        sizes = load.at[e - 1].add(capacity - rows)
-        dot = partial(jax.lax.ragged_dot, group_sizes=sizes,
-                      preferred_element_type=jnp.float32)
         xs = _dispatch(x, token, where, valid)
         hidden = jax.nn.silu(dot(xs, weights["w_gate"])) \
             * dot(xs, weights["w_up"])
         ys = dot(hidden.astype(dtype), weights["w_down"])
         return _combine(ys, jnp.where(valid, coef, 0.0), token, slot, where)
 
-    expected = CAPACITY * n * k * e / max(total, e)
-    capacity = min(n * k, -(-int(expected) // 8) * 8)
+    uniform = n * k / max(total, e)  # rows a group, of a uniform router
+    capacity = min(n * k, -(-int(CAPACITY * uniform * e) // 8) * 8)
+    tile = _plan((capacity, n * k), h, f, dtype, uniform)
+    if tile:
+        dot = partial(grouped_matmul.grouped, sizes=load, tm=tile)
+    else:
+        # the rows past the held ones are in no group here either: zeros
+        dot = partial(jax.lax.ragged_dot, group_sizes=load,
+                      preferred_element_type=jnp.float32)
+    walked = tile or grouped_matmul.COMPILER_ROW_TILE
     if capacity == n * k:
         out = part(n * k)
     else:
         out = jax.lax.cond(rows <= capacity, lambda: part(capacity),
                            lambda: part(n * k))
     counts = {"load": load,
-              "dropped": jnp.sum(held, dtype=jnp.int32) - rows}
+              "dropped": jnp.sum(held, dtype=jnp.int32) - rows,
+              "tile_rows": walked * grouped_matmul.tile_visits(load, walked),
+              "grouped_kernel": jnp.asarray(bool(tile), jnp.int32)}
     return out, counts

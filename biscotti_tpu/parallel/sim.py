@@ -693,6 +693,16 @@ class Simulator:
                             "held assignments of the last round that "
                             "reached no expert (must read 0)").set(
                         moe["tokens_dropped"])
+                    m.gauge("biscotti_moe_tile_fill",
+                            "held rows of the last round's grouped "
+                            "products over the rows of the (group, row "
+                            "tile) pairs they visited").set(
+                        moe["tile_fill"])
+                    m.gauge("biscotti_moe_grouped_kernel",
+                            "1 where the round's grouped products are "
+                            "ops/grouped_matmul.py's kernel, 0 the "
+                            "compiler's ragged_dot").set(
+                        moe["grouped_kernel"])
             if it % log_every == 0 or it == num_rounds - 1:
                 e = float(err)
                 logs.append(RoundLog(it, e, time.time(), int(mask.sum())))
@@ -758,9 +768,12 @@ class Simulator:
         token-expert assignments that landed on experts held here, all
         sparse layers; `load_max_over_mean`, the fullest held expert's over
         the held experts' mean, worst sparse layer; `tokens_dropped`, held
-        assignments that reached no expert (must read 0). From `load`
-        int32[layers, held experts] and `dropped`, which the round
-        returns; reads them back: call it outside a timed round."""
+        assignments that reached no expert (must read 0); `tile_fill`, held
+        rows over the rows of the (group, row tile) pairs the grouped
+        products visited, all calls; `grouped_kernel`, 1.0 where those
+        products are ops/grouped_matmul.py's. From `load` int32[layers,
+        held experts], `dropped`, `tile_rows` and `grouped_kernel`, which
+        the round returns; reads them back: call it outside a timed round."""
         counts = self.last_counts if counts is None else counts
         if "load" not in counts:
             return {}
@@ -770,6 +783,10 @@ class Simulator:
             "load_max_over_mean": float(np.max(load.max(axis=1)
                                                / load.mean(axis=1))),
             "tokens_dropped": float(np.asarray(counts["dropped"]).sum()),
+            "tile_fill": float(load.sum() / max(
+                np.asarray(counts["tile_rows"], np.float64).sum(), 1.0)),
+            "grouped_kernel": float(np.asarray(
+                counts["grouped_kernel"]).any()),
         }
 
     def test_error(self, w) -> float:

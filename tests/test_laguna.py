@@ -315,6 +315,39 @@ def test_no_gradient_of_a_frozen_leaf_is_formed(tiny):
     assert (3, model.num_params) in {shape for _, shape, _ in made}
 
 
+def test_no_gradient_of_a_frozen_leaf_is_formed_where_the_kernel_runs(tiny):
+    """The same claim at a layer ops/grouped_matmul.py takes (hidden size
+    and expert width 128; four peers' 64 tokens, four experts a token: a
+    cut buffer of 128 rows, an uncut one of 256): the grouped products are
+    `pallas_call`s, and no equation of the step's program that COMPUTES
+    (theirs included; a transposed copy of the weights, the compiler's
+    `ragged_dot` backward, is one) puts out an array of the expert stack's
+    shape. What does is JAX's plumbing: the weights handed from the forward
+    `cond` (and the `jit` of `held_experts` around it) to the backward one
+    as residuals, and the zeros a `custom_vjp` rule's `None` stands for,
+    read by nothing (the compiled program has none of them:
+    tests/test_tpu_lowering.py)."""
+    _, _, _, x, y = tiny
+    cfg = dataclasses.replace(TINY, hidden=128, expert_width=128, top_k=4)
+    model = laguna.laguna_model("laguna_wide", cfg, x.shape[-1])
+    frozen = model.frozen(jax.random.PRNGKey(1))
+    w = model.flat_init(jax.random.PRNGKey(2))
+    step = block_step_fn(model, "clipped_sgd", 1.0, 0.1)
+    xb, yb = jnp.asarray(x[:4])[:, None], jnp.asarray(y[:4])[:, None]
+    made = _all_shapes(jax.make_jaxpr(step)(w, xb, yb, frozen).jaxpr, [])
+    names = {name for name, _, _ in made}
+    assert "pallas_call" in names and "ragged_dot" not in names
+    experts = frozen["layers"][1]["experts"]
+    assert experts["w_gate"].shape == (4, 128, 128) == experts["w_down"].shape
+    assert {name for name, shape, _ in made if shape == (4, 128, 128)} \
+        <= {"cond", "jit", "broadcast_in_dim"}
+    assert (4, model.num_params) in {shape for _, shape, _ in made}
+    deltas, counts = step(w, xb, yb, frozen)
+    assert np.isfinite(deltas).all() and np.asarray(deltas).any()
+    assert np.asarray(counts["grouped_kernel"]).all()
+    assert int(counts["dropped"].sum()) == 0
+
+
 def test_two_peers_deltas_applied_once_are_both_applied(tiny):
     """The linearity the commitments and shares need: with `A` frozen and
     shared, the adapters under w + d1 + d2 ARE the base weights moved by

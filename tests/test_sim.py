@@ -211,10 +211,15 @@ def test_walked_peer_axis_gives_the_vmapped_deltas(kind, block):
     np.testing.assert_allclose(deltas, whole, rtol=2e-4, atol=1e-6)
     np.testing.assert_allclose(noised, whole_noised, rtol=2e-4, atol=1e-6)
     assert jax.tree.structure(counts) == jax.tree.structure(whole_counts)
-    for got, want in zip(jax.tree.leaves(counts),
-                         jax.tree.leaves(whole_counts)):
-        np.testing.assert_array_equal(got, want)
     assert bool(counts) == (kind == "laguna_tiny")
+    for name in counts:
+        if name in ("tile_rows", "grouped_kernel"):
+            continue  # a call's own: the row tiles IT visited, its side
+        np.testing.assert_array_equal(counts[name], whole_counts[name])
+    if counts:  # every held row lies in a visited tile, however blocked
+        assert (np.asarray(counts["tile_rows"])
+                >= np.asarray(counts["load"]).sum(axis=-1)).all()
+        assert not np.asarray(counts["grouped_kernel"]).any()  # tiny
 
 
 def test_peer_block_is_worked_out_from_the_bytes():

@@ -8,6 +8,8 @@ run Pallas kernels in interpret mode, where int64 is legal — so a kernel
 that cannot lower for the chip under x64 is invisible to it. Here it fails.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -307,3 +309,48 @@ def test_the_language_model_round_compiles_for_v5e(v5e, sim_lm):
             if "f64[" in line or ("s64[" in line and "parameter(" not in line
                                   and "lm_" in line)]
     assert not wide, wide[:5]
+
+
+def test_the_expert_layer_at_the_published_shapes_takes_the_kernel(v5e):
+    """`held_experts` under `jax.grad` as a peer block of 3 sends it
+    (3,072 tokens, ten a token, 64 of 256 experts held, bfloat16) compiles
+    for the v5e under x64, and every grouped product of it, on the cut
+    buffer and on the uncut one, forward and backward, is
+    ops/grouped_matmul.py's kernel: no `ragged-dot` is left."""
+    from biscotti_tpu.ops import moe
+
+    one = SingleDeviceSharding(v5e[0])
+    n, k, e, total, h, f = 3072, 10, 64, 256, 3072, 1024
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    weights = {"w_gate": shape((e, h, f), jnp.bfloat16),
+               "w_up": shape((e, h, f), jnp.bfloat16),
+               "w_down": shape((e, f, h), jnp.bfloat16)}
+
+    def loss(x, coef, experts, weights):
+        out, counts = moe.held_experts(x, experts, coef, weights, 0, total)
+        return jnp.sum(out * out), counts
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True)).lower(
+        shape((n, h), jnp.float32), shape((n, k), jnp.float32),
+        shape((n, k), jnp.int32), weights).compile().as_text()
+    assert "ragged-dot" not in hlo
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert "conditional(" in hlo
+    for rows in (15360, 30720):  # the two sides of the `lax.cond`
+        of_rows = [line for line in calls if f"[{rows}," in line]
+        # forward: two [rows, 1024] and one [rows, 3072], float32; backward
+        # (the weights read transposed): the same in bfloat16
+        assert len(of_rows) >= 6, (rows, len(of_rows))
+    assert not [line.strip()[:160] for line in hlo.splitlines()
+                if "f64[" in line or ("s64[" in line
+                                      and "parameter(" not in line)]
+    # no array of the expert stack's shape is made: no weight's gradient
+    stack = re.compile(r" = (bf16|f32)\[64,(3072,1024|1024,3072)\]")
+    made = [line.strip()[:160] for line in hlo.splitlines()
+            if stack.search(line) and "parameter(" not in line
+            and "get-tuple-element" not in line]
+    assert not made, made[:5]
