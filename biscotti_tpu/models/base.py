@@ -67,7 +67,7 @@ class Model:
     # vmaps `loss` over the peers
     peer_losses: Optional[Callable] = field(repr=False, default=None)
     # batch rows -> bytes one peer's step holds live at its peak; None: the
-    # round takes all its peers at once (parallel/sim.peer_block)
+    # round takes all its peers at once (models/peer_step.peer_block)
     step_bytes: Optional[Callable[[int], int]] = field(repr=False,
                                                        default=None)
     # what a caller may want to know of the model and cannot see from the

@@ -20,9 +20,10 @@ threefry `random.choice` folded from (seed, iteration) so peers are
 deterministic given their id — required by the chain-equality oracle.
 
 `local_step_fn` is exposed standalone (pure); `block_step_fn` is the same
-rule over a block of peers, which parallel/sim.py, the hive's stepper and
-the device cluster run: vmapped, or, where the model says how its peers
-go through it as one batch (`Model.peer_losses`), as that one batch.
+rule over a block of peers, which models/peer_step.py walks for
+parallel/sim.py and the hive's stepper: vmapped, or, where the model says
+how its peers go through it as one batch (`Model.peer_losses`), as that
+one batch.
 """
 
 from __future__ import annotations
@@ -178,15 +179,19 @@ _EVAL_CACHE: dict = {}
 _FROZEN_CACHE: dict = {}
 
 
-def shared_frozen(model: Model, seed: int):
+def shared_frozen(model: Model, seed: int, sharding=None):
     """`model`'s frozen tree for the run seeded `seed`: the empty tree for
     a classifier, else drawn once a process from `PRNGKey(seed)` (what
-    parallel/sim.py draws too)."""
+    parallel/sim.py draws too). With a `sharding` (the hive's mesh,
+    replicated) the process's copy MOVES there, so that whoever asks
+    afterwards shares it and no device holds the tree twice."""
     if model.init_frozen is None:
         return {}
     key = (model.name, model.num_params, int(seed))
     if key not in _FROZEN_CACHE:
         _FROZEN_CACHE[key] = model.frozen(jax.random.PRNGKey(seed))
+    if sharding is not None:
+        _FROZEN_CACHE[key] = jax.device_put(_FROZEN_CACHE[key], sharding)
     return _FROZEN_CACHE[key]
 
 

@@ -8,9 +8,11 @@ ML/Pytorch/ml_main_mnist.py:24-60). Here one jitted XLA program executes the
 whole round for all peers at once:
 
     deltas   = vmap(local_step)     — S contributors' SGD steps, batched matmuls
-               (in blocks of the peer axis where one step's activations
-               are large, `peer_block`; a model's frozen base is an
-               ARGUMENT of the program, held once for all peers)
+               (models/peer_step.py, which the live runtime's stepper runs
+               too: one composed gather of the minibatch rows, the peer
+               axis in blocks where one step's activations are large; a
+               model's frozen base is an ARGUMENT of the program, held
+               once for all peers)
     noise    = vmap(threefry draw)  — DP noising committee equivalent
     mask     = Krum | RONI kernel   — verifier committee equivalent
     w'       = w + Σ maskᵢ·deltaᵢ   — miner aggregation (sum, ref honest.go:360-375)
@@ -29,7 +31,6 @@ including contributor sampling and stake evolution.
 
 from __future__ import annotations
 
-import contextlib
 import math
 import re
 import time
@@ -40,13 +41,15 @@ from typing import List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.layout import Format, Layout
 
 from biscotti_tpu.config import BiscottiConfig, Defense
 from biscotti_tpu.data import datasets as ds
 from biscotti_tpu.models.base import Model
-from biscotti_tpu.models.trainer import (block_step_fn, local_step_fn,
-                                         sample_batch, step_rule)
+from biscotti_tpu.models.peer_step import (PeerSteps, _poisoned_ids,
+                                           device_bytes, load_shards,
+                                           outside_compile_cache, put_stack,
+                                           stack_info)
+from biscotti_tpu.models.trainer import step_rule
 from biscotti_tpu.models.zoo import model_for_dataset
 from biscotti_tpu.ops import dp_noise
 from biscotti_tpu.ops.krum import default_num_adversaries, krum_accept_mask
@@ -73,107 +76,6 @@ STAGES = (
     "round_ledger",     # stake scatter, the fault plane's drop mask
     "round_eval",       # test error of the next weights
 )
-
-
-# The peer stack is held on the device in the layout the round READS: the
-# round takes S x B single rows out of [N, rows, d], so a row has to be
-# contiguous, i.e. the feature axis minor-most and the peer axis major-most
-# (row-major). Left to itself the TPU runtime picks whatever tiling pads
-# least: for [3383, 480, 784] that is the PEER axis in the lanes (784 is
-# 6.125 lanes of 128), and taking rows from it means a relayout of the whole
-# stack every round (PERF.md section 6, PR 25). Row-major pays for its
-# padding in device memory, so it is asked for only where the padded stack
-# stays within this factor of the compact one: 784 features cost 1.14x,
-# 3,072 and 8,742 at most 1.01x; creditcard's 24 would cost 5.3x, and such
-# stacks (megabytes) keep the runtime's default.
-STACK_PAD_LIMIT = 1.25
-
-# What one chip of the fleet this simulator is written for holds (TPU v5e),
-# where the backend does not say (`memory_stats()` is None on the CPU), and
-# the share of what the round's standing arrays leave free that a block of
-# peers' activations may take: the rest is the compiler's own temporaries
-# and fragmentation (PERF.md section 6, PR 27: read from the compiled
-# program's memory analysis at the published size).
-DEVICE_BYTES = 16 * 2**30
-BLOCK_SHARE = 0.5
-
-
-def peer_block(samples: int, step_bytes: Optional[int], free: int) -> int:
-    """How many of a round's `samples` peers step together: all of them
-    where the model states no activation size (every classifier), else the
-    largest divisor of `samples` whose block of `step_bytes` a peer fits
-    BLOCK_SHARE of the `free` bytes (at least one peer). A divisor, so that
-    every block is the same program."""
-    if not step_bytes:
-        return samples
-    fit = max(1, int(BLOCK_SHARE * free) // step_bytes)
-    return max(b for b in range(1, samples + 1)
-               if samples % b == 0 and b <= fit)
-
-
-def stack_layout(shape, itemsize: int = 4) -> Optional[Layout]:
-    """The device layout the round reads a peer stack of `shape` in, decided
-    from the shape alone: row-major (the last axis minor, the peer axis
-    major) where the TPU's tiling of the two minor-most axes (8 x 128 of a
-    32-bit type, 128 lanes minor) pads it by at most STACK_PAD_LIMIT, else
-    None, the runtime's default. On a backend whose default is row-major
-    already (the CPU) asking for it changes nothing."""
-    if len(shape) < 2 or 0 in shape:
-        return None
-    sublanes = 8 * max(1, 4 // itemsize)
-    padded = (math.prod(shape[:-2])
-              * -(-shape[-2] // sublanes) * sublanes
-              * -(-shape[-1] // 128) * 128)
-    if padded > STACK_PAD_LIMIT * math.prod(shape):
-        return None
-    return Layout(major_to_minor=tuple(range(len(shape))))
-
-
-@contextlib.contextmanager
-def outside_compile_cache():
-    """What compiles inside compiles with the persistent compile cache
-    switched off, and is neither fetched from it nor written to it."""
-    from jax.experimental.compilation_cache import compilation_cache
-
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()  # the switch is read once a process
-    try:
-        yield
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        compilation_cache.reset_cache()
-
-
-def put_stack(a, sharding=None) -> jax.Array:
-    """`a` (a host or a device array) onto `sharding` (None: where
-    `jnp.asarray` puts it), in `stack_layout`'s layout: THE way a peer
-    stack reaches its device, on one chip and on a mesh. Placement first,
-    then the layout: this JAX moves data between host and devices in the
-    runtime's default layout only, and `device_put` to a `Format` is a
-    relayout program on the devices that already hold the array (the
-    default copy is freed when it ends; both exist while it runs). Where
-    the default is the layout asked for, nothing more happens.
-
-    The relayout program compiles outside the persistent compile cache
-    (a fraction of a second): fetched back from it, an executable with a
-    layout of its own on its OUTPUT hands out buffers that report the
-    default layout while holding the other (v5e, JAX 0.9.0; PERF.md section
-    6, PR 25), and every program that then takes the stack is compiled for
-    the wrong one and refused when it runs."""
-    a = jnp.asarray(a) if sharding is None else jax.device_put(a, sharding)
-    layout = stack_layout(a.shape, a.dtype.itemsize)
-    if layout is None or (tuple(a.format.layout.major_to_minor)
-                          == layout.major_to_minor):
-        return a
-    with outside_compile_cache():
-        return jax.device_put(a, Format(layout, a.sharding))
-
-
-def _device_bytes() -> int:
-    """The first device's memory, as the runtime states it."""
-    stats = jax.devices()[0].memory_stats() or {}
-    return int(stats.get("bytes_limit", DEVICE_BYTES))
 
 
 def _array_dims(result_type: str):
@@ -271,17 +173,6 @@ def masked_aggregate(mask: jax.Array, deltas: jax.Array, noised: jax.Array,
     return jnp.sum(jnp.where(mask[:, None], agg_src, 0.0), axis=0)
 
 
-def _poisoned_ids(num_nodes: int, poison_fraction: float) -> set:
-    """Top poison_fraction of node ids load bad shards
-    (ref: DistSys/main.go:836-845, honest.go:102-118). THE formula lives
-    in tools/verdicts.poisoned_ids — one definition shared with the live
-    runtime, the campaign plane's attacker draw, and every verdict
-    reader; this name stays as the sim-side alias."""
-    from biscotti_tpu.tools.verdicts import poisoned_ids
-
-    return poisoned_ids(num_nodes, poison_fraction)
-
-
 class Simulator:
     """N peers on one chip (vmapped) or across a mesh (shard_map)."""
 
@@ -304,14 +195,8 @@ class Simulator:
         # sim.build; sim.round.args / sim.round.dispatch)
         self.phases = PhaseClock()
 
-        poisoned = _poisoned_ids(n, cfg.poison_fraction)
-        xs, ys = [], []
         with self.phases.phase("sim.shards"):
-            for i in range(n):
-                shard = ds.load_shard(
-                    cfg.dataset, ds.shard_name(cfg.dataset, i, i in poisoned))
-                xs.append(shard["x_train"])
-                ys.append(shard["y_train"])
+            xs, ys = load_shards(cfg, range(n))
             test = ds.load_shard(cfg.dataset, f"{cfg.dataset}_test")
             attack = ds.load_shard(cfg.dataset, f"{cfg.dataset}_digit1")
         rows = min(len(x) for x in xs)
@@ -322,11 +207,6 @@ class Simulator:
 
         with self.phases.phase("sim.build"):
             self.root_key = jax.random.PRNGKey(cfg.seed)
-            # one peer's step, and the same over a block of the peer axis
-            self._step = local_step_fn(self.model, self.mode,
-                                       clip=cfg.grad_clip, alpha=rate)
-            self._block_step = block_step_fn(self.model, self.mode,
-                                             clip=cfg.grad_clip, alpha=rate)
             self._use_noise = cfg.noising or cfg.dp_in_model
             self._noise_eps = cfg.epsilon if self._use_noise else 0.0
             self._noise_scale = dp_noise.sigma_for(self._noise_eps, cfg.delta)
@@ -338,11 +218,9 @@ class Simulator:
                 self.frozen = self.model.frozen(self.root_key)
             standing = (self.frozen_bytes() + x_host.nbytes + y_host.nbytes
                         + 4 * (3 * cfg.num_samples + 2) * self.num_params)
-            self.peer_block = peer_block(
-                cfg.num_samples,
-                self.model.step_bytes
-                and self.model.step_bytes(min(cfg.batch_size, rows)),
-                _device_bytes() - standing)
+            # the sampled peers' minibatches and deltas: models/peer_step.py
+            self.steps = PeerSteps(self.model, cfg, rows, cfg.num_samples,
+                                   device_bytes() - standing)
             self.last_counts = {}  # what the last round's dispatch counted
             self._round_hlo_text = None
             self._round_step_raw, noised_raw = self._build_round_step()
@@ -409,45 +287,6 @@ class Simulator:
             )
         return (-self._noise_alpha / b) * draw
 
-    def _minibatches(self, bkey: jax.Array, ids: jax.Array, at: jax.Array,
-                     x: jax.Array, y: jax.Array):
-        """The minibatches [S, B, ...] of the peers `ids`, whose shards are
-        the rows `at` of the stack (x, y): every peer's row numbers from its
-        own key, composed with `at` into S x B row numbers of the stack
-        seen as [N * rows, ...], and taken in ONE gather. The program reads
-        nothing else of the stack: there is no `x[at]` of S whole shards in
-        between, which is also what let the compiler hoist the matmul's
-        bfloat16 cast over every row of every peer (PERF.md, PR 25). The
-        merged view is free in `stack_layout`'s layout (a bitcast: `rows`
-        is a multiple of the 8-row tile in every dataset there is); where
-        it were not, `whole_stack_instructions` names the copy."""
-        n, rows = x.shape[:2]
-        with jax.named_scope("round_sample"):
-            bkeys = jax.vmap(lambda i: jax.random.fold_in(bkey, i))(ids)
-            idx = jax.vmap(lambda k: sample_batch(
-                k, rows, self.cfg.batch_size))(bkeys)  # [S, B]
-            flat = at[:, None] * rows + idx
-        with jax.named_scope("round_gather"):
-            return (x.reshape(n * rows, *x.shape[2:])[flat],
-                    y.reshape(n * rows, *y.shape[2:])[flat])
-
-    def _walk(self, w: jax.Array, xb: jax.Array, yb: jax.Array, frozen):
-        """The [S, d] deltas of the minibatches [S, B, ...] and what the
-        model's dispatch counted: the peer axis in blocks of
-        `self.peer_block` (one block where that is all of them: every
-        classifier), each block the same program, one after the other."""
-        s, block = xb.shape[0], min(self.peer_block, xb.shape[0])
-        if s % block:  # a device's share of the peers (the sharded step)
-            block = math.gcd(s, block)
-        if block == s:
-            return self._block_step(w, xb, yb, frozen)
-        blocks = [a.reshape(s // block, block, *a.shape[1:])
-                  for a in (xb, yb)]
-        deltas, counts = jax.lax.map(
-            lambda b: self._block_step(w, b[0], b[1], frozen), blocks)
-        return (deltas.reshape(s, -1),
-                jax.tree.map(lambda c: jnp.sum(c, axis=0), counts))
-
     def _peer_updates(self, w: jax.Array, bkey: jax.Array, nkey: jax.Array,
                       ids: jax.Array, at: jax.Array, x: jax.Array,
                       y: jax.Array, frozen=None):
@@ -457,9 +296,11 @@ class Simulator:
         streams under the same scopes. `at` says where in the stack (x, y)
         each of those peers' shards sits: the sampled ids themselves on one
         chip, `arange(n_loc)` on a device that holds only its own peers."""
-        xb, yb = self._minibatches(bkey, ids, at, x, y)
+        with jax.named_scope("round_sample"):  # this caller's key stream
+            bkeys = jax.vmap(lambda i: jax.random.fold_in(bkey, i))(ids)
+        xb, yb = self.steps.minibatches(bkeys, at, x, y)
         with jax.named_scope("round_grad"):
-            deltas, counts = self._walk(w, xb, yb, frozen)  # [S, d]
+            deltas, counts = self.steps.deltas(w, xb, yb, frozen)  # [S, d]
         with jax.named_scope("round_noise"):
             if self._use_noise:
                 nkeys = jax.vmap(lambda i: jax.random.fold_in(nkey, i))(ids)
@@ -591,24 +432,14 @@ class Simulator:
         return sum(a.size * a.dtype.itemsize
                    for a in jax.tree.leaves(self.frozen))
 
+    @property
+    def peer_block(self) -> int:
+        """Sampled peers whose local steps the round computes together."""
+        return self.steps.block
+
     def stack_info(self) -> dict:
-        """Where the peer stack sits: its device layout as the runtime
-        prints it, its bytes on the device (tiling padding included) and
-        its compact bytes. Row-major (`2,1,0`) is what `stack_layout` asks
-        for at this shape unless the padding would pass STACK_PAD_LIMIT."""
-        layout = self.x.format.layout
-        tiling = "".join("T(%s)" % ",".join(map(str, t))
-                         for t in layout.tiling or ())
-        minor_to_major = ",".join(
-            str(a) for a in reversed(layout.major_to_minor))
-        shard = self.x.addressable_shards[0].data
-        return {
-            "layout": minor_to_major + (":" + tiling if tiling else ""),
-            "row_major": tuple(layout.major_to_minor)
-            == tuple(range(self.x.ndim)),
-            "device_bytes": int(shard.on_device_size_in_bytes()),
-            "compact_bytes": int(self.x.nbytes),
-        }
+        """Where the peer stack sits (`peer_step.stack_info`)."""
+        return stack_info(self.x)
 
     def whole_stack_instructions(self, hlo: Optional[str] = None
                                  ) -> List[str]:

@@ -97,7 +97,7 @@ class CampaignPlan:
     counters exist, no frame is touched).
 
     Attacker membership mirrors the reference's poisoned-id formula
-    (`parallel/sim._poisoned_ids` → tools/verdicts.poisoned_ids): the top
+    (`models/peer_step._poisoned_ids` → tools/verdicts.poisoned_ids): the top
     `attackers` fraction of node ids, so setting `attackers` equal to
     `poison_fraction` makes the colluding set and the poisoned set the
     SAME peers — the "flood while poisoning" composition is one knob.

@@ -10,11 +10,11 @@ machine, committees, chain, crypto — but shares everything an honest
 co-hosted deployment can share:
 
   * **Batched device plane** (`HiveStepper`): within a round, all
-    co-hosted workers' local SGD steps run as ONE vmapped (or, over a
-    mesh, shard_map'd) XLA call — the `parallel/sim.py` round-step math
-    with the `device_cluster.BatchStepper` executor pattern — and DP
-    noise draws coalesce into one [H, d] device draw per round instead
-    of H presample banks of [iters, d].
+    co-hosted workers' local SGD steps run as ONE XLA call (over a mesh,
+    the same program with the peer stack sharded) — the delta program of
+    `models/peer_step.py` that `parallel/sim.py`'s round runs too — and
+    DP noise draws coalesce into one [H, d] device draw per round
+    instead of H presample banks of [iters, d].
   * **Loopback transport fast path** (`LoopbackHub`): RPC between two
     peers in the same hive skips TCP framing AND serialization — the
     destination handler receives read-only views of the caller's
@@ -354,6 +354,32 @@ class LoopbackHub:
 # ------------------------------------------------------------ device plane
 
 
+async def single_flight_memo(cache: Dict, pending: Dict, key, compute):
+    """Single-flight async memo of the batched device plane: the first
+    caller computes off-loop, every concurrent waiter receives the VALUE
+    from the future itself (never a post-await cache re-read — another
+    peer far enough ahead may evict the key between set_result and a
+    waiter resuming), and a failed compute raises in every caller.
+    Returns (value, computed_here)."""
+    if key in cache:
+        return cache[key], False
+    if key in pending:
+        return await pending[key], False
+    fut = asyncio.get_running_loop().create_future()
+    pending[key] = fut
+    try:
+        val = await asyncio.to_thread(compute)
+    except BaseException as e:
+        fut.set_exception(e)
+        fut.exception()  # mark retrieved if nobody is waiting
+        del pending[key]
+        raise
+    cache[key] = val
+    fut.set_result(val)
+    del pending[key]
+    return val, True
+
+
 class UnequalShardsError(ValueError):
     """Co-hosted peers' train shards disagree on row count, so one
     vmapped minibatch draw cannot reproduce each standalone Trainer's
@@ -363,36 +389,37 @@ class UnequalShardsError(ValueError):
 
 class HiveStepper:
     """Batched device plane for a hive's LOCAL peer subset: all co-hosted
-    workers' SGD deltas in one vmapped XLA call per (iteration, weights),
-    DP noise as one [H, d] draw per iteration, and the shared
-    convergence metric — the `device_cluster.BatchStepper` executor
-    pattern generalized to host a SLICE of the cluster (multi-host
-    hives) with Trainer-parity randomness.
+    workers' SGD deltas in one XLA call per (iteration, weights), DP noise
+    as one [H, d] draw per iteration, and the shared convergence metric.
+    The delta program is `models/peer_step.py`'s, the one the simulator
+    runs: the stack placed by `put_stack`, the minibatch rows taken in one
+    composed gather, the peer axis stepped in blocks, the model's frozen
+    tree (held once, `trainer.shared_frozen`) an argument.
 
     Key derivation matches models/trainer.Trainer exactly — per peer
     `fold_in(PRNGKey(cfg.seed), pid)` split into (noise, batch) keys,
     minibatch key `fold_in(batch_key, it)` — so a hive-hosted peer's
     SGD stream is the same stream its standalone agent would draw
-    (deltas agree to float tolerance; the vmapped reduction order is
+    (deltas agree to float tolerance; the batched reduction order is
     the only difference). Noise draws are generated per round
     (`fold_in(noise_key, it)`) instead of indexed from a presample
     bank: distribution-identical to the bank (the same argument
     parallel/sim.py makes), O(H·d) resident instead of O(H·iters·d).
 
-    With a multi-device `mesh` whose size divides H, the delta batch
-    runs under shard_map over the peer axis (the make_sharded_round_step
-    data plane); otherwise a single-client vmap."""
+    With a multi-device `mesh` whose size divides H, the stack and the
+    keys are sharded over the mesh's first axis, the frozen tree is
+    replicated (the process's copy moves there) and the same program runs
+    partitioned over it; otherwise on one device."""
 
     def __init__(self, cfg, local_ids: Sequence[int], mesh=None):
         import jax
         import jax.numpy as jnp
 
-        from biscotti_tpu.data import datasets as ds
-        from biscotti_tpu.models.trainer import (local_step_fn,
-                                                 sample_batch, step_rule)
+        from biscotti_tpu.models import peer_step
+        from biscotti_tpu.models.trainer import (_shared_eval_arrays,
+                                                 shared_frozen, step_rule)
         from biscotti_tpu.models.zoo import model_for_dataset
         from biscotti_tpu.ops import dp_noise
-        from biscotti_tpu.parallel.sim import _poisoned_ids
 
         self.cfg = cfg
         self.local_ids = sorted(int(i) for i in local_ids)
@@ -402,23 +429,9 @@ class HiveStepper:
         model = model_for_dataset(cfg.dataset,
                                   getattr(cfg, "model_name", ""))
         self.num_params = model.num_params
-        if model.init_frozen is not None:
-            # ROADMAP B0's remainder: the frozen base on the live path
-            raise NotImplementedError(
-                f"model {model.name!r} holds a frozen tree; the batched "
-                "live plane steps classifiers only (the simulator and the "
-                "per-peer Trainer take it)")
-        mode, rate = step_rule(model, cfg)
-        step = local_step_fn(model, mode, clip=cfg.grad_clip, alpha=rate)
+        _, rate = step_rule(model, cfg)
 
-        poisoned = _poisoned_ids(cfg.num_nodes, cfg.poison_fraction)
-        xs, ys = [], []
-        for pid in self.local_ids:
-            shard = ds.load_shard(
-                cfg.dataset, ds.shard_name(cfg.dataset, pid,
-                                           pid in poisoned))
-            xs.append(shard["x_train"])
-            ys.append(shard["y_train"])
+        xs, ys = peer_step.load_shards(cfg, self.local_ids)
         sizes = {len(x) for x in xs}
         if len(sizes) > 1:
             # truncating to a common row count would change which rows
@@ -429,21 +442,6 @@ class HiveStepper:
                 f"co-hosted shards have unequal row counts {sorted(sizes)}; "
                 "batched stepping would break Trainer-parity sampling")
         rows = sizes.pop()
-        self._x = jnp.asarray(np.stack(xs))
-        self._y = jnp.asarray(np.stack(ys))
-        batch = min(cfg.batch_size, rows)
-
-        # Trainer-parity per-peer key streams (see class docstring)
-        bases = [jax.random.fold_in(jax.random.PRNGKey(cfg.seed), pid)
-                 for pid in self.local_ids]
-        pairs = [jax.random.split(b) for b in bases]
-        self._noise_keys = jnp.stack([p[0] for p in pairs])
-        self._batch_keys = jnp.stack([p[1] for p in pairs])
-
-        def one_delta(w, bkey, xi, yi, it):
-            k = jax.random.fold_in(bkey, it)
-            idx = sample_batch(k, rows, batch)
-            return step(w, xi[idx], yi[idx])
 
         n_dev = 1
         if mesh is not None:
@@ -451,36 +449,46 @@ class HiveStepper:
         if n_dev > 1 and h % n_dev != 0:
             n_dev = 1
         self.n_dev = n_dev  # devices the delta batch is spread over
+        peers = whole = None  # one device: where `jnp.asarray` puts things
         if n_dev > 1:
-            # peers-across-devices: the make_sharded_round_step data
-            # plane — each device computes its peer slice, one gather
             from jax.sharding import NamedSharding, PartitionSpec as P
 
-            axis = mesh.axis_names[0]
+            peers = NamedSharding(mesh, P(mesh.axis_names[0]))
+            whole = NamedSharding(mesh, P())
 
-            def local_batch(w, bkeys, x_loc, y_loc, it):
-                return jax.vmap(one_delta,
-                                in_axes=(None, 0, 0, 0, None))(
-                    w, bkeys, x_loc, y_loc, it)
+        # Trainer-parity per-peer key streams (see class docstring)
+        bases = [jax.random.fold_in(jax.random.PRNGKey(cfg.seed), pid)
+                 for pid in self.local_ids]
+        pairs = [jax.random.split(b) for b in bases]
+        self._noise_keys = jnp.stack([p[0] for p in pairs])
+        self._batch_keys = peer_step.put_stack(
+            jnp.stack([p[1] for p in pairs]), peers)
+        # the process's one copy: the co-hosted Trainers built after this
+        # get the same arrays, on a mesh the replicated ones
+        self._frozen = shared_frozen(model, cfg.seed, whole)
 
-            mapped = jax.shard_map(
-                local_batch, mesh=mesh,
-                in_specs=(P(), P(axis), P(axis), P(axis), P()),
-                out_specs=P(axis), check_vma=False)
-            self._deltas = jax.jit(mapped)
-            sharding = NamedSharding(mesh, P(axis))
-            self._x = jax.device_put(self._x, sharding)
-            self._y = jax.device_put(self._y, sharding)
-            self._batch_keys = jax.device_put(self._batch_keys, sharding)
-        else:
+        # what a device holds besides a block's activations: the frozen
+        # tree, its share of the stack, its peers' deltas and the weights.
+        # A block of peers is sized from what ONE device has free: the
+        # program is written over all H peers and partitioned by the
+        # compiler, which for a walked peer axis gathers the minibatches
+        # and has every device step every block (tests/test_tpu_lowering.py
+        # reads a device's temporaries: one device's, not a share of them)
+        x_host, y_host = np.stack(xs), np.stack(ys)
+        standing = (sum(a.nbytes for a in jax.tree.leaves(self._frozen))
+                    + (x_host.nbytes + y_host.nbytes) // n_dev
+                    + 4 * (h // n_dev + 1) * self.num_params)
+        self.steps = steps = peer_step.PeerSteps(
+            model, cfg, rows, h, peer_step.device_bytes() - standing)
 
-            @jax.jit
-            def _deltas(w, bkeys, x, y, it):
-                return jax.vmap(one_delta,
-                                in_axes=(None, 0, 0, 0, None))(
-                    w, bkeys, x, y, it)
+        @jax.jit
+        def _deltas(w, bkeys, x, y, it, frozen):
+            keys = jax.vmap(lambda k: jax.random.fold_in(k, it))(bkeys)
+            # peer i's shard is row i of the stack
+            xb, yb = steps.minibatches(keys, jnp.arange(h), x, y)
+            return steps.deltas(w, xb, yb, frozen)[0]
 
-            self._deltas = _deltas
+        self._deltas = _deltas
 
         # DP noise: fresh per-round batched draw, Σ_batch σ·N(0,1)
         # scaled by −α/batch like trainer.get_noise / sim._peer_noise.
@@ -510,10 +518,13 @@ class HiveStepper:
 
         # shared convergence metric (identical model × identical global
         # test split — peer.py's uniform-convergence requirement)
-        from biscotti_tpu.models.trainer import _shared_eval_arrays
-
         self._x_test, self._y_test, _, _ = _shared_eval_arrays(cfg.dataset)
         self._err_fn = jax.jit(model.error_flat)
+
+        # the stack goes up last: where `put_stack` runs a relayout, every
+        # program queued behind it waits for the whole copy (PERF.md, PR 25)
+        self._x = peer_step.put_stack(x_host, peers)
+        self._y = peer_step.put_stack(y_host, peers)
 
         self._caches: Dict[str, Dict] = {"step": {}, "noise": {},
                                          "eval": {}}
@@ -529,9 +540,14 @@ class HiveStepper:
         # the slowdown TCP layouts emulate (docs/STRAGGLERS.md)
         self.step_cost_s = 0.0
 
-    async def _memo(self, kind: str, key, compute):
-        from biscotti_tpu.runtime.device_cluster import single_flight_memo
+    def stack_info(self) -> dict:
+        """Where the co-hosted peers' stack sits
+        (`peer_step.stack_info`)."""
+        from biscotti_tpu.models.peer_step import stack_info
 
+        return stack_info(self._x)
+
+    async def _memo(self, kind: str, key, compute):
         return await single_flight_memo(self._caches[kind],
                                         self._pending[kind], key, compute)
 
@@ -556,7 +572,8 @@ class HiveStepper:
             t0 = time.perf_counter()
             out = np.asarray(
                 self._deltas(jnp.asarray(wb, jnp.float32),
-                             self._batch_keys, self._x, self._y, it),
+                             self._batch_keys, self._x, self._y, it,
+                             self._frozen),
                 dtype=np.float64)
             self.step_cost_s = time.perf_counter() - t0
             return out
@@ -594,7 +611,8 @@ class HiveStepper:
 
         def compute():
             return float(self._err_fn(jnp.asarray(wb, jnp.float32),
-                                      self._x_test, self._y_test))
+                                      self._x_test, self._y_test,
+                                      self._frozen))
 
         err, computed = await self._memo("eval", key, compute)
         if computed:

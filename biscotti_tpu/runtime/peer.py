@@ -46,11 +46,11 @@ from biscotti_tpu.crypto.vrf import VRFKey
 from biscotti_tpu.data import datasets as ds
 from biscotti_tpu.ledger.block import Block, BlockData, Update
 from biscotti_tpu.ledger.chain import Blockchain, ChainInvariantError
+from biscotti_tpu.models.peer_step import _poisoned_ids
 from biscotti_tpu.models.trainer import Trainer
 from biscotti_tpu.ops import secretshare as ss
 from biscotti_tpu.ops import trust as trustlib
 from biscotti_tpu.parallel import roles as R
-from biscotti_tpu.parallel.sim import _poisoned_ids
 from biscotti_tpu.runtime import admission as adm
 from biscotti_tpu.runtime import adversary
 from biscotti_tpu.runtime import codecs as wcodecs
@@ -206,10 +206,9 @@ class PeerAgent:
                  stepper=None, hive=None, light_trainer: bool = False,
                  ticket: Optional[Dict] = None):
         self.cfg = cfg
-        # peers-as-devices mode: a shared BatchStepper (or the hive's
-        # HiveStepper) computes ALL local peers' SGD deltas in one
-        # batched XLA call per round (runtime/device_cluster.py,
-        # runtime/hive.py); None = per-agent trainer dispatch
+        # co-hosted mode: the hive's shared HiveStepper computes ALL local
+        # peers' SGD deltas in one batched XLA call per round
+        # (runtime/hive.py); None = per-agent trainer dispatch
         self.stepper = stepper
         self.ckpt_dir = ckpt_dir
         self.ckpt_every = max(1, ckpt_every)
@@ -3361,10 +3360,10 @@ class PeerAgent:
         if not (cfg.pipeline and cfg.speculation) or cfg.fedsys:
             return
         if self.stepper is not None:
-            # peers-as-devices mode memoizes the batched SGD per
-            # ITERATION (device_cluster._memo): a speculative call off a
-            # head that later forks would poison the whole co-hosted
-            # group's cache for the real round — speculation stays a
+            # the shared stepper computes the WHOLE co-hosted group's
+            # batch per (iteration, weights) (hive.HiveStepper.step): a
+            # speculative call off a head that later forks would pay for a
+            # batch nobody consumes — speculation stays a
             # per-agent-trainer feature
             return
         it = self.iteration
@@ -3488,7 +3487,7 @@ class PeerAgent:
             # slow identically.
             base = time.monotonic() - t0_sgd
             if self.stepper is not None:
-                base = max(base, getattr(self.stepper, "step_cost_s", 0.0))
+                base = max(base, self.stepper.step_cost_s)
             await self._slow_pad(base)
         self.total_updates += 1
 
@@ -4702,8 +4701,7 @@ class PeerAgent:
         # same height and the chain-equality oracle holds (the reference
         # likewise scores the shared global data, ref: honest.go:141-162)
         with self.tele.span("metrics", it=it):
-            if self.stepper is not None and hasattr(self.stepper,
-                                                    "test_error"):
+            if self.stepper is not None:
                 # co-located peers share one evaluation: identical model ×
                 # identical global split (the uniformity the oracle needs)
                 err = await self.stepper.test_error(

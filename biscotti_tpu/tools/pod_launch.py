@@ -4,9 +4,9 @@ peersFileSent host:port list, ssh-launch nodesInEachVM processes per VM,
 collect logs; azure-util/killall + get-all-LogFiles).
 
 Targets a TPU pod or any ssh-reachable fleet: every host runs
-`nodes_per_host` peer agents (hosts-as-peers mode; for the
-peers-as-devices variant on a single host see
-runtime/device_cluster.py). `localhost` entries execute directly
+`nodes_per_host` peer agents (hosts-as-peers mode; for all peers
+co-hosted in one process over a host's devices see runtime/hive.py).
+`localhost` entries execute directly
 (subprocess), remote entries via ssh; --dry-run prints the exact
 per-host commands without executing, for driving real fleets from an
 orchestrator.

@@ -9,7 +9,7 @@ sweep (eval/eval_poison.py), the live attack matrix
 
   * `poisoned_ids` — the reference's poisoned-membership formula
     (DistSys/main.go:836-845: the top `poison_fraction` of node ids load
-    bad shards). `parallel/sim._poisoned_ids` and
+    bad shards). `models/peer_step._poisoned_ids` and
     `adversary.CampaignPlan.attacker_ids` both delegate/mirror this, so
     "the poisoned set" and "the colluding set" can never disagree on the
     formula.

@@ -88,26 +88,25 @@ async def run_cluster(cfgs, log_dir="", key_dir="", geo_regions=0,
     from biscotti_tpu.runtime.peer import PeerAgent
     from biscotti_tpu.runtime.rpc import geo_latency
 
-    stepper = None
     if use_stepper:
-        # all agents share one BatchStepper: every peer's SGD runs as ONE
-        # vmapped XLA dispatch per round, and the per-round convergence
+        # all agents share one HiveStepper: every peer's SGD runs as ONE
+        # batched XLA dispatch per round, and the per-round convergence
         # metric is computed once instead of N times (VERDICT r3 lever —
-        # device_cluster.py; multi-process deployments keep per-agent
-        # dispatch, this sharing needs co-located peers)
-        import jax
-        import numpy as np
+        # runtime/hive.py; multi-process deployments keep per-agent
+        # dispatch, this sharing needs co-located peers). Real TCP between
+        # the agents and every announce, as without the stepper
+        from biscotti_tpu.runtime.hive import Hive
 
-        from biscotti_tpu.runtime.device_cluster import BatchStepper
-
-        mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("peers",))
-        stepper = BatchStepper(cfgs[0], mesh)
-    agents = [
-        PeerAgent(c, key_dir=key_dir, stepper=stepper,
-                  log_path=os.path.join(log_dir, f"events_{c.node_id}.jsonl")
-                  if log_dir else "")
-        for c in cfgs
-    ]
+        agents = Hive(cfgs[0], key_dir=key_dir, log_dir=log_dir,
+                      loopback=False, skip_local_announce=False).agents
+    else:
+        agents = [
+            PeerAgent(c, key_dir=key_dir,
+                      log_path=os.path.join(log_dir,
+                                            f"events_{c.node_id}.jsonl")
+                      if log_dir else "")
+            for c in cfgs
+        ]
     if pool_conns:
         # single-box fd budget: every loopback conn costs 2 fds in-process
         # (~ 2*N*cap total), so very large N needs a smaller per-peer pool
@@ -166,7 +165,7 @@ def main(argv=None) -> int:
     ap.add_argument("--num-noisers", type=int, default=2)
     ap.add_argument("--seed", type=int, default=3)
     ap.add_argument("--stepper", type=int, default=1,
-                    help="share one BatchStepper across the in-process "
+                    help="share one HiveStepper across the in-process "
                          "agents (batched SGD dispatch + one convergence "
                          "eval per round); 0 = per-agent dispatch, the "
                          "multi-process deployment shape")

@@ -630,7 +630,7 @@ def test_chaos_report_records_crypto_path():
 
 
 @pytest.mark.slow
-def test_device_cluster_bit_identity_guard():
+def test_device_crypto_live_bit_identity_guard():
     """ISSUE 13 acceptance: one seeded live secure-agg cluster with a
     share-corrupting Byzantine peer, run twice — CPU path vs
     --device-crypto — must produce identical chains, identical

@@ -19,7 +19,7 @@ from types import SimpleNamespace
 import numpy as np
 import pytest
 
-from biscotti_tpu.config import BiscottiConfig, Timeouts
+from biscotti_tpu.config import BiscottiConfig, Defense, Timeouts
 from biscotti_tpu.runtime import codecs as wcodecs
 from biscotti_tpu.runtime.admission import AdmissionController, AdmissionPlan
 from biscotti_tpu.runtime.faults import FaultAction, FaultPlan
@@ -207,7 +207,32 @@ def _cfg(i, n, port, **kw):
     return BiscottiConfig(**base)
 
 
-def test_hive_stepper_matches_standalone_trainers():
+# what the stepper is built for in these tests: the classifier it always
+# covered, a wider one, and a model with a frozen tree (refused until the
+# stepper took models/peer_step.py's program: the tree is an argument)
+STEPPED = {
+    "creditcard": dict(),
+    "mnist": dict(dataset="mnist", model_name="softmax", batch_size=10),
+    "lm_tokens_tiny": dict(dataset="lm_tokens_tiny", batch_size=2,
+                           learning_rate=0.1, grad_clip=1.0),
+}
+
+
+def _weights(stepper, kind):
+    """Weights to step from: zeros, as a genesis round does; for the model
+    with adapters, drawn (at zero they do not count in the forward)."""
+    if kind != "lm_tokens_tiny":
+        return np.zeros(stepper.num_params)
+    import jax
+
+    from biscotti_tpu.models.zoo import model_for_dataset
+
+    return np.asarray(model_for_dataset("lm_tokens_tiny").flat_init(
+        jax.random.PRNGKey(1)), np.float64)
+
+
+@pytest.mark.parametrize("kind", sorted(STEPPED))
+def test_hive_stepper_matches_standalone_trainers(kind):
     """Trainer-parity randomness: a hive-hosted peer's SGD delta is the
     same delta its standalone agent would compute (same fold_in key
     streams, same minibatch draw), to float tolerance — and the whole
@@ -217,9 +242,9 @@ def test_hive_stepper_matches_standalone_trainers():
     from biscotti_tpu.models.trainer import Trainer
 
     n = 3
-    cfg = _cfg(0, n, 13810)
+    cfg = _cfg(0, n, 13810, **STEPPED[kind])
     stepper = HiveStepper(cfg, range(n))
-    w = np.zeros(stepper.num_params)
+    w = _weights(stepper, kind)
 
     async def go():
         outs = await asyncio.gather(*(stepper.step(pid, w, 0)
@@ -236,13 +261,143 @@ def test_hive_stepper_matches_standalone_trainers():
     for pid in range(n):
         t = Trainer(cfg.dataset, ds.shard_name(cfg.dataset, pid, False),
                     cfg=cfg, seed=pid)
+        assert np.any(outs[pid])
         np.testing.assert_allclose(outs[pid], t.private_fun(w, 0),
                                    rtol=1e-5, atol=1e-6)
         assert errs[pid] == pytest.approx(t.test_error(w))
     # epsilon=0 run: noise is exactly zero without a per-peer bank
     assert all(not np.any(nz) for nz in noises)
-    # distinct peers draw distinct minibatches (the vmap axis is real)
+    # distinct peers draw distinct minibatches (the peer axis is real)
     assert not np.allclose(outs[0], outs[1])
+
+
+def _mesh():
+    import jax
+
+    devices = jax.devices()
+    if len(devices) < 2:
+        pytest.skip("needs the multi-device CPU mesh")
+    return jax.sharding.Mesh(np.array(devices), ("peers",))
+
+
+def _all_deltas(stepper, w, it):
+    async def go():
+        return np.stack([await stepper.step(pid, w, it)
+                         for pid in stepper.local_ids])
+
+    return asyncio.run(go())
+
+
+@pytest.mark.parametrize("kind", sorted(STEPPED))
+def test_hive_stepper_on_the_mesh_matches_one_device(kind):
+    """One program, two placements: the stack and the keys sharded over
+    eight devices, and on one (chip_smoke.py checks the same on four
+    chips)."""
+    mesh = _mesh()
+    n = 2 * mesh.devices.size
+    cfg = _cfg(0, n, 13814, **STEPPED[kind])
+    on_one = HiveStepper(cfg, range(n))  # first: the mesh moves the tree
+    on_mesh = HiveStepper(cfg, range(n), mesh)
+    assert on_mesh.n_dev == mesh.devices.size and on_one.n_dev == 1
+    assert len({s.device for s in on_mesh._x.addressable_shards}) \
+        == mesh.devices.size
+    assert on_mesh._x.addressable_shards[0].data.shape[0] == 2
+    w = _weights(on_one, kind)
+    got, want = _all_deltas(on_mesh, w, 1), _all_deltas(on_one, w, 1)
+    assert on_mesh.batches == on_one.batches == 1
+    assert got.shape == (n, on_one.num_params) and np.any(want)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+
+    async def errs():
+        return (await on_mesh.test_error(w, 1),
+                await on_one.test_error(w, 1))
+
+    e_mesh, e_one = asyncio.run(errs())
+    assert e_mesh == pytest.approx(e_one)
+    # a span the mesh does not divide stays on one device
+    assert HiveStepper(cfg, range(n - 1), mesh).n_dev == 1
+
+
+def test_hive_stepper_walks_blocks_on_the_mesh_and_shares_the_frozen_tree(
+        monkeypatch):
+    """On a mesh a block of peers is sized from what ONE device has free
+    (not the devices' memory together), the walked program (here: a peer a
+    block, sixteen blocks over the sharded axis) gives what one device
+    gives in one block, and the frozen tree is the process's one copy,
+    replicated: a co-hosted Trainer built afterwards holds the same
+    arrays."""
+    import jax
+
+    from biscotti_tpu.data import datasets as ds
+    from biscotti_tpu.models import peer_step
+    from biscotti_tpu.models.trainer import Trainer
+
+    free = []
+
+    class Sized(peer_step.PeerSteps):
+        def __init__(self, model, cfg, rows, samples, free_bytes):
+            free.append((samples, free_bytes))
+            super().__init__(model, cfg, rows, samples, free_bytes)
+
+    monkeypatch.setattr(peer_step, "PeerSteps", Sized)
+    mesh = _mesh()
+    n = 2 * mesh.devices.size
+    cfg = _cfg(0, n, 13818, **STEPPED["lm_tokens_tiny"])
+    on_one = HiveStepper(cfg, range(n))
+    on_mesh = HiveStepper(cfg, range(n), mesh)
+    assert on_one.steps.block == on_mesh.steps.block == n  # the tiny model
+    (s_one, free_one), (s_mesh, free_mesh) = free
+    assert s_one == s_mesh == n
+    # less of the stack stands on a device of the mesh, and nothing more
+    assert free_one < free_mesh < peer_step.device_bytes()
+    on_mesh.steps.block = 1  # before the program is traced
+    w = _weights(on_one, "lm_tokens_tiny")
+    got, want = _all_deltas(on_mesh, w, 1), _all_deltas(on_one, w, 1)
+    assert np.any(want)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+
+    leaves = jax.tree.leaves(on_mesh._frozen)
+    assert leaves and all(
+        leaf.sharding.is_fully_replicated
+        and len(leaf.addressable_shards) == mesh.devices.size
+        for leaf in leaves)
+    light = Trainer(cfg.dataset, ds.shard_name(cfg.dataset, 0, False),
+                    cfg=cfg, seed=0, light=True)
+    assert all(a is b for a, b in zip(jax.tree.leaves(light.frozen), leaves))
+    assert light.test_error(w) == pytest.approx(
+        asyncio.run(on_mesh.test_error(w, 1)))
+
+
+def test_hive_stack_reports_the_layout_it_was_put_in(monkeypatch):
+    """The stepper's stack goes up through `put_stack`: on a device whose
+    default is not the layout `stack_layout` asks for (here: asked for by
+    the test, peer axis between rows and features; the CPU holds any), the
+    stack sits in that layout, says so, and the deltas are the same."""
+    from jax.experimental.layout import Layout
+
+    from biscotti_tpu.models import peer_step
+
+    cfg = _cfg(0, 4, 13816, **STEPPED["mnist"])
+    plain = HiveStepper(cfg, range(4))
+    info = plain.stack_info()
+    assert info["layout"] == "2,1,0" and info["row_major"] is True
+    assert info["compact_bytes"] == info["device_bytes"] \
+        == 4 * plain._x.shape[1] * 784 * 4
+    assert not plain._x.committed
+
+    def other(shape, itemsize=4):
+        return Layout(major_to_minor=(1, 0, 2)) if len(shape) == 3 else None
+
+    monkeypatch.setattr(peer_step, "stack_layout", other)
+    moved = HiveStepper(cfg, range(4))
+    info = moved.stack_info()
+    assert info["layout"] == "2,0,1" and info["row_major"] is False
+    assert moved._x.committed  # a layout of its own commits the stack
+    assert tuple(moved._x.format.layout.major_to_minor) == (1, 0, 2)
+    assert moved._y.format.layout == plain._y.format.layout
+    w = np.zeros(plain.num_params)
+    np.testing.assert_array_equal(_all_deltas(moved, w, 2),
+                                  _all_deltas(plain, w, 2))
 
 
 def test_hive_stepper_refuses_unequal_shards_and_hive_falls_back(
@@ -326,6 +481,72 @@ def test_hive_small_cluster_tier1_chains_equal():
     snap = hive.agents[0].telemetry_snapshot()
     assert snap["hive"]["id"] == "t1"
     assert snap["hive"]["peers"] == n
+
+
+# warm budgets (tests/test_overlay.py's): the first round compiles the
+# delta program for the eight-device mesh, the noise draw and the share
+# pipeline, beside five other workers' compiles under the driver's command,
+# and a 4 s update timer firing on that mints an empty first block.
+# Deadlines only bound the unhappy path; the happy path proceeds on events
+WARM = Timeouts(update_s=20.0, block_s=60.0, krum_s=20.0, share_s=20.0,
+                rpc_s=10.0)
+
+
+def _device_cfg(n, port, **kw):
+    return _cfg(0, n, port, verification=True, defense=Defense.NONE,
+                timeouts=WARM, **kw)
+
+
+@pytest.mark.parametrize("secure_agg", [False, True],
+                         ids=["plain", "secure_agg"])
+def test_device_peers_mint_real_blocks(secure_agg):
+    """Peers-as-devices: the 8-device CPU mesh hosts all peers' SGD steps
+    as ONE sharded program per round, while the full asyncio protocol —
+    verifier committees, (with `secure_agg`) VSS shares and DP noise,
+    block gossip — runs over real TCP between the agents and the
+    chain-equality oracle closes the loop."""
+    mesh = _mesh()
+    n = mesh.devices.size
+    hive = Hive(_device_cfg(n, 15520 if secure_agg else 15510,
+                            secure_agg=secure_agg, noising=secure_agg),
+                mesh=mesh, loopback=False)
+    assert hive.hub is None and hive.stepper.n_dev == n
+    results = asyncio.run(hive.run())
+    dumps = [r["chain_dump"] for r in results]
+    assert all(d == dumps[0] for d in dumps), "chain-equality oracle violated"
+    lines = dumps[0].splitlines()
+    assert len(lines) >= 2 and "ndeltas=0" not in lines[1], dumps[0]
+    # the data plane really ran on the mesh: one sharded batch per round,
+    # not one XLA call per peer
+    assert hive.stepper.batches >= 1
+    if not secure_agg:
+        assert len(lines) == 3 and hive.stepper.batches <= 3
+    assert _loopback_rpcs(hive.agents) == 0  # TCP between the agents
+
+
+def test_stepper_shared_metric_memoizes():
+    """The per-round convergence metric is computed once per distinct
+    (iteration, weights) and served to every co-located peer — the shared
+    eval the scale harness leans on (identical model × identical global
+    test split, peer.py's uniform-convergence requirement)."""
+    mesh = _mesh()
+    n = mesh.devices.size
+    stepper = HiveStepper(_device_cfg(n, 15530), range(n), mesh)
+    w = np.zeros(stepper.num_params, np.float64)
+    w2 = np.ones(stepper.num_params, np.float64)
+
+    async def drive():
+        # n peers ask for the same (it, w); then one divergent chain
+        a = await asyncio.gather(*(stepper.test_error(w, 0)
+                                   for _ in range(n)))
+        b = await stepper.test_error(w2, 0)
+        c = await stepper.test_error(w, 1)
+        return a, b, c
+
+    a, b, c = asyncio.run(drive())
+    assert len(set(a)) == 1
+    assert stepper.evals == 3  # (0,w) shared by all peers; (0,w2); (1,w)
+    assert a[0] == c  # same weights at a later height: same value
 
 
 def test_two_hives_cross_tcp_chains_equal():

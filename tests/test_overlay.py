@@ -102,6 +102,17 @@ def test_overlay_defaults_off_and_requires_group():
 # --------------------------------------------------- live cluster parity
 
 
+def _accepted_by_round(results):
+    """{round: accepted worker ids}, from the verifiers' verdict streams
+    (every defense records one; docs/DEFENSES.md)."""
+    out = {}
+    for r in results:
+        for row in (r["telemetry"].get("trust") or {}).get("stream", []):
+            out[row["it"]] = [s for s, ok in zip(row["src"], row["accept"])
+                              if ok]
+    return out
+
+
 @pytest.mark.overlay
 def test_secure_agg_overlay_chains_equal_flat_run():
     """THE equivalence oracle: same seed, overlay on vs off -> identical
@@ -111,11 +122,29 @@ def test_secure_agg_overlay_chains_equal_flat_run():
     n=7: this geometry's committees are disjoint both rounds, so the
     worker set equals num_samples and the Krum pool cannot race — the
     precondition for CROSS-RUN bit-equality (with committee overlap the
-    seed protocol itself accepts a timing-dependent subset)."""
-    n = 7
+    seed protocol itself accepts a timing-dependent subset).
+
+    Subtrees of 4: Krum keeps workers 1 and 3 of {1, 3, 4, 6} in both
+    rounds, and a relay combines only what the verifier released, so
+    they must share a subtree for there to be anything to aggregate
+    (with subtrees of 3 they sat alone in {0,1,2} and {3,4,5}: every
+    offer was a lone one, forwarded per member, and the run aggregated
+    nothing, every time). Read from the flat run's own verdicts below,
+    so that a change of data or defence that moves the accept set fails
+    HERE, by name, not at the counter."""
+    n, group = 7, 4
     off = _run_cluster([_cfg(i, n, 15860) for i in range(n)])
-    on = _run_cluster([_cfg(i, n, 15880, overlay=True, overlay_group=3)
+    accepted = _accepted_by_round(off)
+    router = ov.Router(True, group, n, seed=3)
+    assert sorted(accepted) == [0, 1], accepted
+    for it, ids in accepted.items():
+        gids = [router.gid_of(i) for i in ids]
+        assert len(gids) > len(set(gids)), (
+            f"round {it}: accepted workers {ids} share no subtree of "
+            f"{group}: nothing to aggregate, choose another geometry")
+    on = _run_cluster([_cfg(i, n, 15880, overlay=True, overlay_group=group)
                        for i in range(n)])
+    assert _accepted_by_round(on) == accepted
     assert all(r["chain_dump"] == off[0]["chain_dump"] for r in off)
     assert all(r["chain_dump"] == on[0]["chain_dump"] for r in on)
     assert on[0]["chain_dump"] == off[0]["chain_dump"]
@@ -129,7 +158,7 @@ def test_secure_agg_overlay_chains_equal_flat_run():
     # telemetry snapshot carries the overlay readout (docs/OVERLAY.md)
     snap = on[0]["telemetry"]["overlay"]
     assert snap["enabled"] and snap["depth"] == 3 \
-        and snap["group_size"] == 3
+        and snap["group_size"] == group
 
 
 @pytest.mark.overlay

@@ -181,7 +181,7 @@ def test_creditcard_logreg_sim():
 def _deltas(sim, block, w=None):
     """Round 0's raw and noised deltas and the dispatch's counts, with the
     peer axis walked in blocks of `block`."""
-    sim.peer_block = block
+    sim.steps.block = block
     w = sim.init_state()[0] if w is None else w
     seed = jnp.asarray(sim.cfg.seed, jnp.int32)
     whole, noised = sim._build_round_step()  # traced with this block
@@ -223,7 +223,7 @@ def test_walked_peer_axis_gives_the_vmapped_deltas(kind, block):
 
 
 def test_peer_block_is_worked_out_from_the_bytes():
-    from biscotti_tpu.parallel.sim import BLOCK_SHARE, peer_block
+    from biscotti_tpu.models.peer_step import BLOCK_SHARE, peer_block
 
     assert peer_block(21, None, 10**9) == 21       # no activation size: all
     assert peer_block(21, 0, 10**9) == 21
@@ -287,9 +287,23 @@ def test_an_unknown_step_rule_is_refused():
                    lambda p, x: x, lambda p, x, y: 0.0, step_rule="adam")
 
 
-def test_the_live_planes_refuse_a_frozen_base_for_now():
+def test_the_live_plane_holds_the_frozen_base_once():
+    """What was a refusal ("ROADMAP B0's remainder") until the stepper took
+    models/peer_step.py's program: the stepper's frozen tree is the very
+    one a co-hosted peer's Trainer holds, and the simulator's leaf for
+    leaf (all three draw it from the run's seed)."""
+    from biscotti_tpu.data import datasets as ds
+    from biscotti_tpu.models.trainer import Trainer
     from biscotti_tpu.runtime.hive import HiveStepper
 
-    cfg = _cfg(dataset="lm_tokens_tiny", num_nodes=4)
-    with pytest.raises(NotImplementedError, match="frozen tree"):
-        HiveStepper(cfg, [0, 1])
+    cfg = _cfg(dataset="lm_tokens_tiny", num_nodes=4, batch_size=2,
+               learning_rate=0.1, grad_clip=1.0)
+    stepper = HiveStepper(cfg, [0, 1])
+    trainer = Trainer(cfg.dataset, ds.shard_name(cfg.dataset, 0, False),
+                      cfg=cfg, seed=0, light=True)
+    ours, theirs = (jax.tree.leaves(t)
+                    for t in (stepper._frozen, trainer.frozen))
+    assert len(ours) > 50 and all(a is b for a, b in zip(ours, theirs))
+    sim = Simulator(cfg)
+    for a, b in zip(ours, jax.tree.leaves(sim.frozen)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -8,11 +8,12 @@ import numpy as np
 import pytest
 
 from biscotti_tpu.config import BiscottiConfig, Defense
-from biscotti_tpu.models.trainer import sample_batch
+from biscotti_tpu.models.peer_step import (STACK_PAD_LIMIT, put_stack,
+                                           stack_layout)
+from biscotti_tpu.models.trainer import (local_step_fn, sample_batch,
+                                         step_rule)
 from biscotti_tpu.parallel import sim as sim_mod
-from biscotti_tpu.parallel.sim import (STACK_PAD_LIMIT, Simulator,
-                                       make_sharded_round_step, put_stack,
-                                       stack_layout,
+from biscotti_tpu.parallel.sim import (Simulator, make_sharded_round_step,
                                        whole_stack_instructions)
 from biscotti_tpu.telemetry import MetricsRegistry
 
@@ -53,9 +54,12 @@ def case(request):
     xb = np.stack([xi[j] for xi, j in zip(xs, idx)])
     yb = np.stack([yi[j] for yi, j in zip(ys, idx)])
 
+    step = local_step_fn(sim.model, sim.mode, clip=sim.cfg.grad_clip,
+                         alpha=step_rule(sim.model, sim.cfg)[1])
+
     @jax.jit
     def by_hand(w, xb, yb, cidx):
-        deltas = jax.vmap(sim._step, in_axes=(None, 0, 0))(w, xb, yb)
+        deltas = jax.vmap(step, in_axes=(None, 0, 0))(w, xb, yb)
         noise = jax.vmap(lambda i: sim._peer_noise(
             jax.random.fold_in(nkey, i)))(cidx)
         return deltas, deltas + noise  # one program: one rounding of a*b+c
@@ -89,7 +93,8 @@ def test_minibatches_are_the_rows_by_hand_wherever_the_shards_sit():
     sim = Simulator(_cfg(**CASES["softmax"]))
     bkey = jax.random.PRNGKey(8)
     ids, at = jnp.asarray([7, 2, 5]), jnp.asarray([0, 3, 1])
-    xb, yb = jax.jit(sim._minibatches)(bkey, ids, at, sim.x, sim.y)
+    bkeys = jax.vmap(lambda i: jax.random.fold_in(bkey, i))(ids)
+    xb, yb = jax.jit(sim.steps.minibatches)(bkeys, at, sim.x, sim.y)
     assert xb.shape == (3, 10, 784) and yb.shape == (3, 10)
     for k in range(3):
         idx = np.asarray(sample_batch(jax.random.fold_in(bkey, ids[k]),

@@ -19,10 +19,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 from jax.experimental.layout import Format
 
 from biscotti_tpu.config import BiscottiConfig, Defense
-from biscotti_tpu.models.trainer import sample_batch
+from biscotti_tpu.models.peer_step import stack_layout
+from biscotti_tpu.models.trainer import local_step_fn, sample_batch
 from biscotti_tpu.ops.krum_pallas import krum_scores_pallas
 from biscotti_tpu.parallel.sim import (Simulator, sharded_round_step_fn,
-                                       stack_layout,
                                        whole_stack_instructions)
 
 
@@ -160,6 +160,8 @@ def _two_step_round(sim):
     """The round's gather as it was before PR 25: the sampled peers' whole
     shards first, then each peer's minibatch rows."""
 
+    one_step = local_step_fn(sim.model, sim.mode, clip=sim.cfg.grad_clip)
+
     def step(w, stake, it, seed, x, y, x_val, y_val):
         rkey = jax.random.fold_in(
             jax.random.fold_in(jax.random.PRNGKey(0), seed), it)
@@ -169,7 +171,7 @@ def _two_step_round(sim):
 
         def one(key, xi, yi):
             idx = sample_batch(key, sim.rows, sim.cfg.batch_size)
-            return sim._step(w, xi[idx], yi[idx])
+            return one_step(w, xi[idx], yi[idx])
 
         return jax.vmap(one)(bkeys, x[cidx], y[cidx])
 
@@ -217,6 +219,90 @@ def test_round_hlo_is_the_program_of_the_stacks_own_format(v5e, sim_1024,
     assert sim_1024.whole_stack_instructions(hlo) == []
 
 
+# ---------------------- the live path's delta program has the gather (PR 29)
+
+
+def _compile_hive_deltas(sim, devices, stack=True):
+    """runtime/hive.py's delta program for `sim`'s cluster, all of its
+    peers co-hosted: on one described chip, or partitioned over several."""
+    return _hive_deltas_for(sim.cfg, devices, stack).as_text()
+
+
+def _hive_deltas_for(cfg, devices, stack=True, block=None):
+    """The same for `cfg`'s cluster, compiled; with `block`, its peer axis
+    walked that many peers at a time."""
+    from biscotti_tpu.runtime.hive import HiveStepper
+
+    hs = HiveStepper(cfg, range(cfg.num_nodes))
+    if block:
+        hs.steps.block = block  # before the program is traced
+    if len(devices) == 1:
+        whole = peers = SingleDeviceSharding(devices[0])
+    else:
+        mesh = jax.sharding.Mesh(np.array(devices), ("peers",))
+        whole, peers = NamedSharding(mesh, P()), NamedSharding(mesh,
+                                                              P("peers"))
+    w = jnp.zeros((hs.num_params,), jnp.float32)
+    args = (_abstract([w], whole) + _abstract([hs._batch_keys], peers)
+            + _abstract([hs._x, hs._y], peers, stack=stack)
+            + _abstract([jnp.asarray(0)], whole)
+            + [jax.tree.map(lambda a: _abstract([a], whole)[0],
+                            hs._frozen)])
+    return hs._deltas.lower(*args).compile()
+
+
+@pytest.mark.parametrize("stack", ["row_major", "default_layout"])
+def test_the_hives_deltas_at_1024_peers_read_no_whole_stack(v5e, sim_1024,
+                                                            stack):
+    """The stepper places its stack with `put_stack` and takes its rows
+    with models/peer_step.py's composed gather: compiled for the chip
+    there is no pass over the 1.5 GB stack. Left in the runtime's default
+    layout (where `jnp.asarray` put it before) the same program has one,
+    which is what the witness is there to find."""
+    hlo = _compile_hive_deltas(sim_1024, v5e[:1],
+                               stack=stack == "row_major")
+    found = whole_stack_instructions(hlo, 1024, sim_1024.rows)
+    if stack == "row_major":
+        assert _x_entry_layout(hlo).startswith("{2,1,0:T(8,128)}")
+        assert found == []
+    else:
+        assert _x_entry_layout(hlo).startswith("{0,2,1")
+        assert found and all("[1024,480," in f or "[491520," in f
+                             for f in found)
+
+
+def test_the_hives_deltas_partition_over_four_chips(v5e, sim_1024):
+    """The same program with the stack and the keys sharded: every chip
+    steps its 256 peers (the result stays sharded), reads no whole share
+    of the stack, and what crosses chips is the minibatch rows' sum."""
+    hlo = _compile_hive_deltas(sim_1024, v5e)
+    entry = next(l for l in hlo.splitlines()
+                 if "entry_computation_layout" in l)
+    assert "f32[256,480,784]{2,1,0:T(8,128)}" in entry
+    assert "->f32[256,7850]" in entry.replace(" ", "")
+    assert whole_stack_instructions(hlo, 256, sim_1024.rows) == []
+    assert whole_stack_instructions(hlo, 1024, sim_1024.rows) == []
+    assert "f32[1024,10,784]" in hlo and "all-reduce" in hlo
+
+
+def test_a_walked_block_costs_each_of_four_chips_what_it_costs_one(v5e):
+    """Why the stepper sizes a block as ONE device's share
+    (`HiveStepper.__init__`): the walk runs over the sharded peer axis, and
+    the compiler's partition gathers the minibatches and has every chip
+    step every block (the result comes back whole, not a chip's quarter).
+    A chip's temporaries are then one chip's at that block, not a quarter
+    of them, and sized from the four chips' memory together a block would
+    be four times what fits."""
+    cfg = _cfg(**{**LM_TINY, "num_nodes": 16})
+    on_one = _hive_deltas_for(cfg, v5e[:1], block=2)
+    on_four = _hive_deltas_for(cfg, v5e, block=2)
+    assert "all-gather" in on_four.as_text()
+    one, four = on_one.memory_analysis(), on_four.memory_analysis()
+    assert four.output_size_in_bytes == one.output_size_in_bytes  # whole
+    assert 0.9 * one.temp_size_in_bytes < four.temp_size_in_bytes \
+        < 1.1 * one.temp_size_in_bytes
+
+
 # ------------------------- the frozen tree and the unchanged classifiers (PR 27)
 
 
@@ -232,37 +318,61 @@ def _lowered_round(sim):
         sim.x_val, sim.y_val, sim.frozen).as_text()
 
 
-# sha256[:16] of the parent's (c71d5aa, before `Model` had a frozen tree, a
-# declared step rule or a walked peer axis) lowered round at these shapes,
-# read there with this very function less the last argument
+# sha256[:16] of the lowered round at these shapes on the commits that had
+# it first, read there with this very function: the classifiers' on c71d5aa
+# (before `Model` had a frozen tree, a declared step rule or a walked peer
+# axis; less the last argument), the language model's on 430e04f (before
+# the gather and the walk moved to models/peer_step.py)
 PARENT_ROUNDS = {
     ("mnist", "softmax"): "2f1f0d7efd64ce2c",
     ("creditcard", ""): "04e1c79a9ba9c99e",
     ("mnist", "mnist_cnn"): "0cb8d0fa17f1cd73",
+    ("lm_tokens_tiny", ""): "296d45bcd51e4a6d",
 }
+WALKED_IN_THREES = "3a2777fe75774ef2"  # the same, the peer axis in two blocks
+
+LM_TINY = dict(dataset="lm_tokens_tiny", num_nodes=8, batch_size=2,
+               sample_percent=1.0, num_verifiers=1, num_miners=1,
+               learning_rate=0.1, grad_clip=1.0)
+
+
+def _sha(text):
+    import hashlib
+
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
 
 
 @pytest.mark.parametrize("dataset,model", sorted(PARENT_ROUNDS))
 def test_a_classifiers_lowered_round_is_the_parents(dataset, model):
     """An empty frozen tree adds no argument, `block_step_fn` vmaps the
-    same step, and the declared rule picks what the model's name picked:
-    the program comes out as it was, instruction for instruction."""
-    import hashlib
+    same step, the declared rule picks what the model's name picked, and
+    the gather and the walk trace the same from models/peer_step.py: the
+    program comes out as it was, instruction for instruction. So does the
+    language model's, its frozen tree an argument."""
+    if dataset == "lm_tokens_tiny":
+        sim = Simulator(_cfg(**LM_TINY))
+        assert sim.frozen != {}
+    else:
+        sim = Simulator(BiscottiConfig(
+            dataset=dataset, model_name=model, num_nodes=10, seed=3,
+            defense=Defense.KRUM, epsilon=1.0))
+        assert sim.frozen == {}
+    assert sim.peer_block == sim.cfg.num_samples
+    assert _sha(_lowered_round(sim)) == PARENT_ROUNDS[dataset, model]
 
-    sim = Simulator(BiscottiConfig(
-        dataset=dataset, model_name=model, num_nodes=10, seed=3,
-        defense=Defense.KRUM, epsilon=1.0))
-    assert sim.frozen == {} and sim.peer_block == sim.cfg.num_samples
-    text = _lowered_round(sim)
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
-        == PARENT_ROUNDS[dataset, model]
+
+def test_the_walked_round_is_the_parents():
+    """Six peers stepped three at a time (`lax.map` over two blocks of the
+    same program), as the parent traced it with `peer_block` 3."""
+    sim = Simulator(_cfg(**LM_TINY))
+    sim.steps.block = 3
+    assert sim.peer_block == 3
+    assert _sha(_lowered_round(sim)) == WALKED_IN_THREES
 
 
 @pytest.fixture(scope="module")
 def sim_lm():
-    return Simulator(_cfg(dataset="lm_tokens_tiny", num_nodes=8,
-                          batch_size=2, sample_percent=1.0, num_verifiers=1,
-                          num_miners=1, learning_rate=0.1, grad_clip=1.0))
+    return Simulator(_cfg(**LM_TINY))
 
 
 def test_the_frozen_tree_is_a_parameter_and_no_constant(sim_lm):

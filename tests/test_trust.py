@@ -455,22 +455,59 @@ def test_defaults_off_guard_no_ledger_no_trust_metrics():
 # ------------------------------------------ live: transport determinism
 
 
+# budgets that bound only the unhappy path (tests/test_crypto_kernels.py's
+# live guard uses the same): the first cluster of a process pays its JIT
+# compiles inside round 0, and under FAST's 3 s krum timer a cold run
+# minted an empty first block that the warm second run did not
+WIDE = Timeouts(update_s=25.0, block_s=90.0, krum_s=20.0, share_s=25.0,
+                rpc_s=25.0)
+
+
+def _raced_rounds(agents):
+    """Rounds in which more workers asked a verifier than its pool takes:
+    the first `thresh` arrivals win, a timing-dependent subset. Read from
+    the agents' event rings, which must still hold the run's first event."""
+    assert [a.tele.recorder.wrapped for a in agents] == [0] * len(agents)
+    asked = [ev for a in agents for ev in a.tele.recorder.tail(100000)
+             if ev["event"] == "verify_request"]
+    assert len({ev["iter"] for ev in asked}) == 3  # every round's are there
+    return sorted({ev["iter"] for ev in asked if ev["pool"] > ev["thresh"]})
+
+
 @pytest.mark.defense
 def test_trust_state_identical_across_tcp_and_hive_loopback():
     """Same seed => bit-identical verdict streams and ledger snapshots
     on both transport layouts (TCP one-agent-per-peer vs hive loopback
     co-hosting; exact per-agent trainers so chains match by
-    construction) — the ISSUE's determinism criterion."""
+    construction) — the ISSUE's determinism criterion.
+
+    Seed 1: at n=6 its committees are disjoint in all three rounds, so
+    every verifier is asked by exactly the four workers its pool takes.
+    Where a peer sits on both committees (seed 3's third round: five
+    workers for a pool of four) the seed protocol itself accepts
+    whichever four arrive first, and two runs of ONE layout differ. That
+    precondition is read from each run's own events before anything is
+    compared across runs."""
     from biscotti_tpu.runtime.hive import Hive
 
     n = 6
-    tcp_results, _ = _run_cluster(
-        [_cfg(i, n, 15600, defense=Defense.ENSEMBLE) for i in range(n)])
-    hive = Hive(_cfg(0, n, 15660, defense=Defense.ENSEMBLE),
-                hive_id="trust", batch_device=False)
+    kw = dict(defense=Defense.ENSEMBLE, seed=1, timeouts=WIDE)
+    tcp_results, tcp_agents = _run_cluster(
+        [_cfg(i, n, 15600, **kw) for i in range(n)])
+    hive = Hive(_cfg(0, n, 15660, **kw), hive_id="trust",
+                batch_device=False)
     hive_results = asyncio.run(hive.run())
 
+    for results, agents in ((tcp_results, tcp_agents),
+                            (hive_results, hive.agents)):
+        dump = results[0]["chain_dump"]
+        assert all(r["chain_dump"] == dump for r in results)
+        blocks = dump.splitlines()[1:]
+        assert len(blocks) == 3 and not any("ndeltas=0" in b for b in blocks)
+        assert _raced_rounds(agents) == [], "choose another geometry"
+
     assert tcp_results[0]["chain_dump"] == hive_results[0]["chain_dump"]
+    streams = 0
     for i in range(n):
         t = tcp_results[i]["telemetry"].get("trust")
         h = hive_results[i]["telemetry"].get("trust")
@@ -478,3 +515,5 @@ def test_trust_state_identical_across_tcp_and_hive_loopback():
         if t is not None:
             assert t["stream"] == h["stream"]
             assert t.get("ledger") == h.get("ledger")
+            streams += len(t["stream"])
+    assert streams == 3  # one verdict row a round, compared
