@@ -23,6 +23,14 @@ auxiliary load loss.
            scale * p_e / sum_topk p * Expert_e(x)           (ops/moe.py)
     logits = RMSNorm(h) W_head over the held rows of the vocabulary
 
+The attention core (the `softmax(...) v` line) is ops/attention.py's: where
+the shapes allow (heads of 128, windows of whole blocks: the published
+size) ONE fused, blocked kernel a call, forward and backward, that keeps
+the scores in VMEM a (query block, key block) tile at a time and never
+visits a pair of blocks the mask hides; elsewhere (the tiny preset) the
+`einsum` form that writes the float32 scores [heads, T, T] to HBM.
+`attention_plan` says which, from the shapes alone.
+
 The trainable tree is {"layers": [{"k", "o", "q", "v"}: B [r, out]]}; the
 frozen tree holds everything else, in `dtype` (bfloat16 at the published
 size), and every product runs in that dtype with float32 accumulation.
@@ -47,7 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from biscotti_tpu.models.base import make_model
-from biscotti_tpu.ops import moe
+from biscotti_tpu.ops import attention, moe
 
 # scopes inside `round_grad` a device trace is read by (a second
 # vocabulary beside parallel/sim.STAGES; docs/OBSERVABILITY.md)
@@ -196,18 +204,30 @@ def _attention(cfg, at, h, frozen, adapters):
     q, k = _rotate(q, cos, sin, rot), _rotate(k, cos, sin, rot)
     dtype = frozen["wq"].dtype
     q = q.reshape(p * b, kv, n // kv, t, dh).astype(dtype)
-    scores = jnp.einsum("wgqtd,wgsd->wgqts", q, k.astype(dtype),
-                        preferred_element_type=jnp.float32) / math.sqrt(dh)
-    i, j = np.arange(t)[:, None], np.arange(t)[None, :]
-    seen = (j <= i) if kind == "full" else ((j <= i) & (i - j < cfg.window))
-    probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
-    out = jnp.einsum("wgqts,wgsd->wgqtd", probs.astype(dtype),
-                     v.astype(dtype), preferred_element_type=jnp.float32)
+    out = attention.attention(q, k.astype(dtype), v.astype(dtype),
+                              t if kind == "full" else cfg.window)
     gate = jax.nn.sigmoid(_mm(x, frozen["wgate"]))          # [P, b, T, n]
     out = out.reshape(p * b, n, t, dh).transpose(0, 2, 1, 3)  # [W, T, n, dh]
     out = out * gate.reshape(p * b, t, n)[..., None]
     out = out.reshape(p, b, t, n * dh)
     return _adapted(cfg, out, frozen["wo"], lora["o"], adapters["o"])
+
+
+def attention_plan(cfg: LagunaConfig, length: int) -> dict:
+    """How `_attention` is built on windows of `length`, from the shapes
+    alone: `fused` 1 where every layer's core is ops/attention.py's kernel
+    (0: some layer's is the `einsum` form), and `block_share`, the part of
+    the layers' [T, T] scores that is computed at all: the (query block,
+    key block) pairs the kernel visits over all pairs, a layer the `einsum`
+    form runs counting whole."""
+    shares = []
+    for kind, n in zip(cfg.layer_types, cfg.heads):
+        block = attention.blocks(n // cfg.kv_heads, length, cfg.head_dim,
+                                 cfg.dtype)
+        shares.append(block and attention.block_share(
+            length, length if kind == "full" else cfg.window, *block))
+    return {"fused": int(all(shares)),
+            "block_share": sum(s or 1.0 for s in shares) / len(shares)}
 
 
 def _mlp(cfg, at, h, frozen):
@@ -386,8 +406,15 @@ def laguna_model(name: str, cfg: LagunaConfig, length: int):
         [heads, T, T]), the sorted expert rows (in `dtype`) and what the
         grouped products make of them (float32), the logits and their
         cotangents, a dozen hidden states. Within a fifth of what the
-        compiled round's memory analysis reads a peer at the published
-        size (0.9 GB; PERF.md section 6, PR 27)."""
+        compiled round's memory analysis read a peer at the published
+        size (0.9 GB; PERF.md section 6, PR 27) WHILE THE SCORES WERE
+        HELD. Since PR 30 they are not, where ops/attention.py's kernel
+        runs: the first term (604 MB of a peer's 1.15 GB at the published
+        size) now over-counts a peer by them, on purpose: without it the
+        peer block would go from 3 to 7, the experts' groups and the
+        round's memory with it, a change of its own (ROADMAP A8: re-derive
+        this from the compiled round's memory analysis). The over-statement
+        is safe: the block stays 3."""
         t = batch * length
         return (2 * 4 * max(cfg.heads) * batch * length * length
                 + t * cfg.top_k * cfg.hidden * (4 + dtype.itemsize)
@@ -396,7 +423,9 @@ def laguna_model(name: str, cfg: LagunaConfig, length: int):
     return make_model(name, length, cfg.vocab, init, apply, loss,
                       step_rule="clipped_sgd", token_input=True,
                       init_frozen=init_frozen, peer_losses=losses,
-                      step_bytes=step_bytes, info={"config": cfg})
+                      step_bytes=step_bytes,
+                      info={"config": cfg,
+                            "attention": attention_plan(cfg, length)})
 
 
 def frozen_count(model) -> int:

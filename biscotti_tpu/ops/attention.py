@@ -1,0 +1,361 @@
+"""The attention core `softmax(q k^T / sqrt(d) + mask) v` as one fused,
+blocked Pallas TPU kernel a call, forward and backward: the scores live a
+(query block, key block) tile at a time in the chip's own memory, under a
+running maximum and sum, and no array of their size [heads, T, T] is ever
+written to HBM. A pair of blocks the mask hides entirely is never visited.
+
+The `einsum` form (`plain`, what models/laguna.py ran before PR 30) makes
+the scores of a block of 3 windows in float32, [3, 8, 9, 1,024, 1,024] =
+906 MB, and masks, soft-maxes, casts and multiplies them, each a pass over
+HBM: 2.9 ms a call forward and 10.4 with its backward at 72 heads, where
+this kernel takes 1.0 and 3.1 (PERF.md section 6, PR 30).
+
+  layout: the grouped-query one the model has. q [W, kv, G, T, d] (G query
+    heads share a key/value head), k, v [W, kv, T, d]; the result
+    float32[W, kv, G, T, d]. Windows never meet: the grid walks W.
+  mask: query i sees key j where `j <= i` and `i - j < window`; a window of
+    T or more is the causal mask. So the key blocks a query block visits
+    are a RANGE computed from its number (`key_blocks`): program-id
+    arithmetic, no table.
+  grid (W, kv, query block); a step holds its key/value head's whole k and
+    v in VMEM (T x d each: 256 KB at the published size, fetched once a
+    head, not once a query block) and, a query head of the group, walks
+    the visited key blocks:
+      forward   s = q k^T / sqrt(d) masked; m, l, acc the running maximum,
+                sum and unnormalised result; out = acc / l, and the rows'
+                log-sum-exp m + log l kept for the backward;
+      backward  ONE kernel for dq, dk and dv: the scores again, transposed
+                (keys down, queries across: the rows' log-sum-exp and
+                `sum(out * dout)` enter as lane-major rows), p = exp(s -
+                lse), dv += p do, ds = p (v do^T - di), dk += ds q, dq +=
+                ds^T k, the scale 1 / sqrt(d) of ds applied to dq's and
+                dk's sums, once a row. dk and dv add up over the group's heads
+                and the query blocks in float32 scratch and are written
+                once a key/value head.
+  the same arithmetic as `plain`: operands in their own type (bfloat16 at
+    the published size), products accumulated in float32, the scale on
+    the float32 scores, max, exp and sums in float32, the probabilities
+    cast to the operands' type only for the product with v. A skipped
+    block is one whose every probability is exactly 0 in `plain`.
+
+VMEM stays inside the compiler's default: `blocks` takes the first block
+pair whose buffers fit `_VMEM_BUFFERS`, as ops/grouped_matmul.py's
+`column_tile` does (a kernel given more takes it from the fusions of the
+whole program: PERF.md section 6, PR 28). The order is the chip's: a step
+of the walk costs more than the pairs a finer block would skip, so key
+blocks of 512 (three quarters of the pairs visited) run twice as fast as
+key blocks of 128 (half of them), and the forward wants its queries DOWN
+(p the streamed operand of the product with v, not the held one).
+"""
+
+from __future__ import annotations
+
+import math
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# what a kernel's buffers may take of VMEM: one rule for the repo's kernels
+from biscotti_tpu.ops.grouped_matmul import _VMEM_BUFFERS
+
+# (query block, key block), in the order `blocks` tries them: fastest first
+# on the v5e (eval/eval_attention.py)
+BLOCKS = ((256, 512), (256, 256), (256, 128), (128, 128))
+_LANES = 128
+# a hidden score: finite, so that a row whose every key of a block is
+# hidden computes exp(0), not exp(-inf + inf); the row's diagonal block
+# then scales that away by exp(_HIDDEN - m) = 0 exactly
+_HIDDEN = -0.7 * float(np.finfo(np.float32).max)
+_NT = (((1,), (1,)), ((), ()))  # a @ b^T
+_TN = (((0,), (0,)), ((), ()))  # a^T @ b
+
+
+def plain(q, k, v, window: int):
+    """The `einsum` form: q [W, kv, G, T, d], k, v [W, kv, T, d] in one
+    type; float32[W, kv, G, T, d]. Makes the scores [W, kv, G, T, T]."""
+    t, d = q.shape[-2:]
+    scores = jnp.einsum("wgqtd,wgsd->wgqts", q, k,
+                        preferred_element_type=jnp.float32) / math.sqrt(d)
+    i, j = np.arange(t)[:, None], np.arange(t)[None, :]
+    seen = (j <= i) if window >= t else ((j <= i) & (i - j < window))
+    probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
+    return jnp.einsum("wgqts,wgsd->wgqtd", probs.astype(q.dtype), v,
+                      preferred_element_type=jnp.float32)
+
+
+def key_blocks(iq, bq: int, bk: int, window: int, largest=max):
+    """(first, last) of the key blocks of `bk` that hold a key some query
+    of query block `iq` (of `bq`) sees; every block between holds one too.
+    `iq` an integer (the host) or, with `largest=jnp.maximum`, a traced
+    int32 (the kernel): the same arithmetic on both."""
+    return (largest(iq * bq - (window - 1), 0) // bk,
+            (iq * bq + bq - 1) // bk)
+
+
+def visited(t: int, window: int, bq: int, bk: int):
+    """The (query block, key block) pairs a call visits, in its order."""
+    pairs = []
+    for iq in range(t // bq):
+        first, last = key_blocks(iq, bq, bk, window)
+        pairs += [(iq, j) for j in range(first, last + 1)]
+    return pairs
+
+
+def _buffers(g: int, t: int, d: int, bq: int, bk: int, size: int) -> int:
+    """Bytes of VMEM the backward (the larger of the two) holds: two
+    buffers each of q, the result's cotangent (float32) and dq's blocks, of
+    k, v, dk, dv whole and of the two rows' statistics; dk's and dv's
+    float32 sums; six tiles of the scores' size."""
+    rows = g * bq
+    return (2 * rows * d * (2 * size + 4) + 2 * 4 * t * d * size
+            + 2 * 2 * 4 * rows + 2 * 4 * t * d + 6 * 4 * bq * bk)
+
+
+def blocks(g: int, t: int, d: int, dtype):
+    """(query block, key block) of the kernel for `g` query heads a
+    key/value head on windows of `t` and heads of `d`, or None where the
+    kernel does not take the shape: a head size not of 128, a window that
+    is no whole number of blocks, another type, or a key/value head too
+    long to hold whole."""
+    if d % _LANES or jnp.dtype(dtype) not in (jnp.bfloat16, jnp.float32):
+        return None
+    size = jnp.dtype(dtype).itemsize
+    return next(((bq, bk) for bq, bk in BLOCKS if t % bq == 0 and t % bk == 0
+                 and _buffers(g, t, d, bq, bk, size) <= _VMEM_BUFFERS), None)
+
+
+def block_share(t: int, window: int, bq: int, bk: int) -> float:
+    """Visited (query block, key block) pairs over all pairs."""
+    return len(visited(t, window, bq, bk)) / ((t // bq) * (t // bk))
+
+
+def _relative(bq: int, bk: int, keys_down: bool):
+    """int32[bq, bk] (or [bk, bq], keys down): a query's place in its block
+    less a key's in its own. The same for every pair of blocks: made once
+    a step of the grid."""
+    shape = (bk, bq) if keys_down else (bq, bk)
+    return (jax.lax.broadcasted_iota(jnp.int32, shape, 1 if keys_down else 0)
+            - jax.lax.broadcasted_iota(jnp.int32, shape,
+                                       0 if keys_down else 1))
+
+
+def _seen(relative, apart, window: int, t: int):
+    """Which scores of a pair of blocks whose first query is `apart` after
+    its first key the mask lets through: 0 <= query - key < window."""
+    seen = relative >= -apart
+    return seen if window >= t else seen & (relative < window - apart)
+
+
+def _as_row(column):
+    """float32[1, n] of a float32[n, 1]: through the transpose unit."""
+    n = column.shape[0]
+    return jnp.broadcast_to(column, (n, _LANES)).T[:1]
+
+
+def _forward(q_ref, k_ref, v_ref, out_ref, lse_ref, *, bq: int, bk: int,
+             window: int):
+    iq = pl.program_id(2)
+    first, last = key_blocks(iq, bq, bk, window, jnp.maximum)
+    d, t = q_ref.shape[-1], k_ref.shape[0]
+    scale = 1.0 / math.sqrt(d)
+    relative = _relative(bq, bk, False)
+
+    def head(g, _):
+        q = q_ref[g]
+
+        def step(j, carry):
+            m, l, acc = carry
+            at = pl.ds(pl.multiple_of(j * bk, bk), bk)
+            s = jax.lax.dot_general(q, k_ref[at, :], _NT,
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(_seen(relative, iq * bq - j * bk, window, t),
+                          s * scale, _HIDDEN)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
+            acc = alpha * acc + jnp.dot(p.astype(v_ref.dtype), v_ref[at, :],
+                                        preferred_element_type=jnp.float32)
+            return m_new, l, acc
+
+        m, l, acc = jax.lax.fori_loop(
+            first, last + 1, step,
+            (jnp.full((bq, 1), -jnp.inf, jnp.float32),
+             jnp.zeros((bq, 1), jnp.float32),
+             jnp.zeros((bq, d), jnp.float32)))
+        out_ref[g] = (acc / l).astype(out_ref.dtype)
+        lse_ref[g] = _as_row(m + jnp.log(l))
+
+    # the group's heads one after the other, as a loop: unrolled, a trace
+    # of the kernel and its lowering cost the host nine times as much
+    jax.lax.fori_loop(0, q_ref.shape[0], head, None)
+
+
+def _backward(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, dk_ref,
+              dv_ref, dk_sum, dv_sum, *, bq: int, bk: int, window: int):
+    iq = pl.program_id(2)
+
+    @pl.when(iq == 0)
+    def _():
+        dk_sum[...] = jnp.zeros_like(dk_sum)
+        dv_sum[...] = jnp.zeros_like(dv_sum)
+
+    first, last = key_blocks(iq, bq, bk, window, jnp.maximum)
+    d, t = q_ref.shape[-1], k_ref.shape[0]
+    scale = 1.0 / math.sqrt(d)
+    relative = _relative(bq, bk, True)
+
+    def head(g, _):
+        q = q_ref[g]
+        do = do_ref[g].astype(q.dtype)
+        lse, di = lse_ref[g], di_ref[g]                       # [1, bq]
+
+        def step(j, dq):
+            at = pl.ds(pl.multiple_of(j * bk, bk), bk)
+            k, v = k_ref[at, :], v_ref[at, :]
+            s = jax.lax.dot_general(k, q, _NT,
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(_seen(relative, iq * bq - j * bk, window, t),
+                          s * scale, _HIDDEN)
+            p = jnp.exp(s - lse)                              # [bk, bq]
+            dv_sum[at, :] += jnp.dot(p.astype(do.dtype), do,
+                                     preferred_element_type=jnp.float32)
+            dp = jax.lax.dot_general(v, do, _NT,
+                                     preferred_element_type=jnp.float32)
+            # ds less its scale, which dq's and dk's sums take once a row
+            ds = (p * (dp - di)).astype(q.dtype)
+            dk_sum[at, :] += jnp.dot(ds, q,
+                                     preferred_element_type=jnp.float32)
+            return dq + jax.lax.dot_general(
+                ds, k, _TN, preferred_element_type=jnp.float32)
+
+        dq = jax.lax.fori_loop(first, last + 1, step,
+                               jnp.zeros((bq, d), jnp.float32))
+        dq_ref[g] = (dq * scale).astype(dq_ref.dtype)
+
+    jax.lax.fori_loop(0, q_ref.shape[0], head, None)
+
+    @pl.when(iq == pl.num_programs(2) - 1)
+    def _():
+        dk_ref[...] = (dk_sum[...] * scale).astype(dk_ref.dtype)
+        dv_ref[...] = dv_sum[...].astype(dv_ref.dtype)
+
+
+def _specs(g: int, t: int, d: int, bq: int):
+    """BlockSpecs of (a query head group's block, a key/value head whole,
+    a group's rows' statistics, [W, kv, G, 1, T]: a head's are one
+    lane-major row) on the grid (W, kv, query block)."""
+    return (pl.BlockSpec((None, None, g, bq, d),
+                         lambda w, h, i: (w, h, 0, i, 0)),
+            pl.BlockSpec((None, None, t, d), lambda w, h, i: (w, h, 0, 0)),
+            pl.BlockSpec((None, None, g, 1, bq),
+                         lambda w, h, i: (w, h, 0, 0, i)))
+
+
+_SEMANTICS = ("parallel", "parallel", "arbitrary")
+
+
+def _call_forward(interpret, q, k, v, *, window, bq, bk):
+    w, kv, g, t, d = q.shape
+    heads, whole, rows = _specs(g, t, d, bq)
+    # Mosaic has no 64-bit types: traced with x64 off, as the repo's other
+    # kernels are; every operand is 32 bits or narrower already
+    with jax.enable_x64(False):
+        return pl.pallas_call(
+            partial(_forward, bq=bq, bk=bk, window=window),
+            grid=(w, kv, t // bq),
+            in_specs=[heads, whole, whole],
+            out_specs=[heads, rows],
+            out_shape=[jax.ShapeDtypeStruct(q.shape, jnp.float32),
+                       jax.ShapeDtypeStruct((w, kv, g, 1, t), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=_SEMANTICS),
+            interpret=interpret,
+            name="attention_forward",
+        )(q, k, v)
+
+
+def _call_backward(interpret, q, k, v, do, lse, di, *, window, bq, bk):
+    w, kv, g, t, d = q.shape
+    heads, whole, rows = _specs(g, t, d, bq)
+    with jax.enable_x64(False):
+        return pl.pallas_call(
+            partial(_backward, bq=bq, bk=bk, window=window),
+            grid=(w, kv, t // bq),
+            in_specs=[heads, whole, whole, heads, rows, rows],
+            out_specs=[heads, whole, whole],
+            out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                       jax.ShapeDtypeStruct(k.shape, k.dtype),
+                       jax.ShapeDtypeStruct(v.shape, v.dtype)],
+            scratch_shapes=[pltpu.VMEM((t, d), jnp.float32),
+                            pltpu.VMEM((t, d), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=_SEMANTICS),
+            interpret=interpret,
+            name="attention_backward",
+        )(q, k, v, do, lse, di)
+
+
+def _dispatched(call, *operands, **static):
+    # the platform being LOWERED FOR picks the branch, so an ahead-of-time
+    # compile for a TPU from a CPU host lowers through Mosaic; interpret
+    # mode exists for the JAX_PLATFORMS=cpu tests
+    call = partial(call, **static)
+    return jax.lax.platform_dependent(
+        *operands, tpu=partial(call, False), default=partial(call, True))
+
+
+# jitted so that a program traces each shape of them once, however many
+# layers and passes call them (a round has two head counts x two masks x
+# three window counts, forward, recomputation and backward)
+@partial(jax.jit, static_argnames=("window", "bq", "bk"))
+def _run_forward(q, k, v, window, bq, bk):
+    return _dispatched(_call_forward, q, k, v, window=window, bq=bq, bk=bk)
+
+
+@partial(jax.jit, static_argnames=("window", "bq", "bk"))
+def _run_backward(q, k, v, out, lse, do, window, bq, bk):
+    di = jnp.sum(out * do, axis=-1)[..., None, :]
+    return _dispatched(_call_backward, q, k, v, do, lse, di, window=window,
+                       bq=bq, bk=bk)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def fused(q, k, v, window: int, block=None):
+    """float32[W, kv, G, T, d]: `plain(q, k, v, window)` by the kernel, at
+    `block` (query block, key block), or at `blocks(G, T, d, q.dtype)`,
+    which then must take the shape."""
+    return _fused_fwd(q, k, v, window, block)[0]
+
+
+def _static(q, window, block):
+    """(window, query block, key block) of a call on q."""
+    _, _, g, t, d = q.shape
+    return (min(window, t),) + tuple(block or blocks(g, t, d, q.dtype))
+
+
+def _fused_fwd(q, k, v, window, block):
+    out, lse = _run_forward(q, k, v, *_static(q, window, block))
+    return out, (q, k, v, out, lse)
+
+
+def _fused_bwd(window, block, res, do):
+    q, k, v, out, lse = res
+    return _run_backward(q, k, v, out, lse, do, *_static(q, window, block))
+
+
+fused.defvjp(_fused_fwd, _fused_bwd)
+
+
+def attention(q, k, v, window: int):
+    """float32[W, kv, G, T, d] = softmax(q k^T / sqrt(d) + mask) v, the
+    mask `j <= i and i - j < window`: the kernel where `blocks` takes the
+    shape, the `einsum` form elsewhere. One algorithm, its parameters read
+    off the shapes."""
+    if blocks(*q.shape[2:], q.dtype) is None:
+        return plain(q, k, v, window)
+    return fused(q, k, v, window)

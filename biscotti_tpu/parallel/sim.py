@@ -500,6 +500,16 @@ class Simulator:
                     "sampled peers whose local steps the round computes "
                     "together (num_samples: all of them at once)").set(
                 self.peer_block)
+            plan = self.model.info.get("attention")
+            if plan:
+                m.gauge("biscotti_lm_attention_fused",
+                        "1 where the round's attention cores are "
+                        "ops/attention.py's kernel, 0 the einsum form "
+                        "that writes the scores to HBM").set(plan["fused"])
+                m.gauge("biscotti_lm_attention_block_share",
+                        "(query block, key block) pairs of the scores the "
+                        "attention visits over all pairs, all layers "
+                        "(the einsum form: 1)").set(plan["block_share"])
         for it in range(num_rounds):
             t0 = time.perf_counter()
             w, stake, mask, err = self.round_step(w, stake, it)

@@ -107,14 +107,31 @@ def test_the_router_picks_what_the_reference_picks(tiny):
         np.testing.assert_allclose(got_p, want_p, atol=1e-6)
 
 
-def test_a_block_of_peers_is_each_peer_alone(tiny):
+def _wide_heads(t=128):
+    """The tiny preset with heads of 128 on windows of `t` tokens: shapes
+    ops/attention.py's kernel takes (interpreted here), where the tiny
+    preset's own stay on the `einsum` form."""
+    cfg = dataclasses.replace(TINY, head_dim=128)
+    model = laguna.laguna_model("laguna_wide_heads", cfg, t)
+    assert model.info["attention"]["fused"] == 1
+    assert not model_for_dataset(DATASET).info["attention"]["fused"]
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (6, t + 1), 0,
+                                cfg.vocab, jnp.int32)
+    return (cfg, model, model.frozen(jax.random.PRNGKey(1)),
+            model.flat_init(jax.random.PRNGKey(2)), tokens[:, :-1],
+            tokens[:, 1:])
+
+
+@pytest.mark.parametrize("side", ["einsum", "kernel"])
+def test_a_block_of_peers_is_each_peer_alone(tiny, side):
     """One dispatch over the block's tokens, the per-peer part confined to
-    the adapters: every row of the block's deltas is that peer's own step."""
-    model, frozen, w, x, y = tiny
+    the adapters: every row of the block's deltas is that peer's own step
+    (on the kernel's side too: its grid walks the windows)."""
+    model, frozen, w, x, y = tiny if side == "einsum" else _wide_heads()[1:]
     block = jax.jit(block_step_fn(model, "clipped_sgd", 0.05, 0.1))
     one = local_step_fn(model, "clipped_sgd", 0.05, 0.1)
-    xb = jnp.asarray(x[:6]).reshape(3, 2, 16)
-    yb = jnp.asarray(y[:6]).reshape(3, 2, 16)
+    xb = jnp.asarray(x[:6]).reshape(3, 2, -1)
+    yb = jnp.asarray(y[:6]).reshape(3, 2, -1)
     deltas, counts = block(w, xb, yb, frozen)
     assert deltas.shape == (3, model.num_params)
     for peer in range(3):
@@ -245,16 +262,20 @@ def test_nothing_held_here_gives_nothing():
 # --------------------------------------------------------- the attention
 
 
-@pytest.mark.parametrize("window", [4, 16])
+@pytest.mark.parametrize("side,window", [("einsum", 4), ("einsum", 16),
+                                         ("kernel", 40), ("kernel", 128)])
 def test_the_sliding_mask_differs_from_the_causal_one_beyond_the_window(
-        tiny, window):
-    model, frozen, w, x, _ = tiny
+        tiny, side, window):
+    if side == "einsum":
+        (model, frozen, w, x, _), cfg = tiny, TINY
+    else:
+        cfg, model, frozen, w, x, _ = _wide_heads()
     at = 1  # the sliding layer
     h = frozen["embed"][jnp.asarray(x[:1])][None]
     adapters = jax.tree.map(lambda a: a[None], model.unravel(w))["layers"][at]
-    sliding = laguna._attention(dataclasses.replace(TINY, window=window), at,
+    sliding = laguna._attention(dataclasses.replace(cfg, window=window), at,
                                 h, frozen["layers"][at], adapters)[0, 0]
-    causal = laguna._attention(dataclasses.replace(TINY, window=10**6), at,
+    causal = laguna._attention(dataclasses.replace(cfg, window=10**6), at,
                                h, frozen["layers"][at], adapters)[0, 0]
     same = np.isclose(sliding, causal, atol=1e-6).all(axis=-1)   # [T]
     assert same[:window].all()            # key j is seen iff 0 <= i - j < W

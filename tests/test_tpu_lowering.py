@@ -8,6 +8,7 @@ run Pallas kernels in interpret mode, where int64 is legal — so a kernel
 that cannot lower for the chip under x64 is invisible to it. Here it fails.
 """
 
+import math
 import re
 
 import jax
@@ -464,3 +465,59 @@ def test_the_expert_layer_at_the_published_shapes_takes_the_kernel(v5e):
             if stack.search(line) and "parameter(" not in line
             and "get-tuple-element" not in line]
     assert not made, made[:5]
+
+
+@pytest.mark.parametrize("at,heads,kind", [(0, 48, "full"),
+                                           (1, 72, "sliding")])
+def test_the_attention_at_the_published_shapes_takes_the_kernel(v5e, at,
+                                                                heads, kind):
+    """`_attention` as a peer block of 3 sends it (3 windows of 1,024, the
+    published widths, bfloat16) under `jax.checkpoint` and `jax.grad`
+    compiles for the v5e under x64 with ops/attention.py's kernel as its
+    core, forward, recomputation and backward, and makes NO float32 array of
+    the scores' size."""
+    from biscotti_tpu.models import laguna
+
+    cfg = laguna.PRESETS["laguna_s_fedlora"]
+    assert (cfg.heads[at], cfg.layer_types[at]) == (heads, kind)
+    one = SingleDeviceSharding(v5e[0])
+    model = laguna.laguna_model("lm", cfg, 1024)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one), tree)
+
+    frozen = on_chip(jax.eval_shape(
+        model.init_frozen, jax.random.PRNGKey(0))["layers"][at])
+    adapters = on_chip(jax.eval_shape(
+        lambda key: jax.tree.map(lambda b: jnp.stack([b] * 3),
+                                 model.init(key)["layers"][at]),
+        jax.random.PRNGKey(0)))
+
+    def loss(adapters, h, frozen):
+        out = jax.checkpoint(lambda h, f, a: laguna._attention(
+            cfg, at, h, f, a))(h, frozen, adapters)
+        return jnp.sum(out * out)
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        adapters, jax.ShapeDtypeStruct((3, 1, 1024, cfg.hidden), jnp.float32,
+                                       sharding=one), frozen
+    ).compile().as_text()
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    # forward (the primal's is dead code under the gradient: the
+    # recomputation's is what is left) and the fused backward
+    assert 2 <= len(calls) <= 3, len(calls)
+    assert any(f"f32[3,8,{heads // 8},1024,128]" in c for c in calls)
+    assert any(f"bf16[3,8,{heads // 8},1024,128]" in c for c in calls)
+    # (k's and v's projections are f32[3, 1024, 8 * 128]: a head's scores
+    # of one window are as many elements again, and no array ends in
+    # [1024, 1024] and has more)
+    square = re.compile(r"f32\[([\d,]*1024,1024)\]")
+    made = [line.strip()[:160] for line in hlo.splitlines()
+            for dims in square.findall(line)
+            if math.prod(int(v) for v in dims.split(",")) > 3 * 1024 * 1024]
+    assert not made, made[:5]
+    assert not [line.strip()[:160] for line in hlo.splitlines()
+                if "f64[" in line or ("s64[" in line
+                                      and "parameter(" not in line)]
