@@ -23,8 +23,9 @@ Two REAL datasets ship alongside them, loaded from scikit-learn's bundled
 Real shards are disjoint slices of a deterministic dataset-keyed shuffle, so
 they are bit-identical across peer processes exactly like the synthetic ones.
 
-Token shards (`lm_tokens`, and `lm_tokens_tiny` at the tests' size) feed the
-language model of models/laguna.py: a row is a WINDOW of `d_in` token ids
+Token shards (`lm_tokens`, `lm_tokens_dsv2` over DeepSeek-V2's held
+25,600 rows, and `lm_tokens_tiny` at the tests' size) feed the language
+models (models/laguna.py, models/deepseek_v2.py): a row is a WINDOW of `d_in` token ids
 (int32) and its label row is the same window one position on, so `y` holds
 a label a position. Ids are drawn from the slice of the vocabulary held
 here, `[0, n_classes)`, by shard name: half from one Zipf unigram law that
@@ -95,6 +96,9 @@ DATASETS: Dict[str, DatasetSpec] = {
     # Laguna-S-2.1's vocabulary (a toy one)
     "lm_tokens": DatasetSpec("lm_tokens", 1024, 25088, 80, 2, tokens=True),
     "lm_tokens_tiny": DatasetSpec("lm_tokens_tiny", 16, 64, 10, 2,
+                                  tokens=True),
+    # the same windows over the held quarter of DeepSeek-V2's vocabulary
+    "lm_tokens_dsv2": DatasetSpec("lm_tokens_dsv2", 1024, 25600, 80, 2,
                                   tokens=True),
 }
 

@@ -45,16 +45,14 @@ gradient, because no operation mixes two windows.
 
 from __future__ import annotations
 
-import math
 from dataclasses import dataclass, field
-from functools import partial
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from biscotti_tpu.models.base import make_model
+from biscotti_tpu.models import lm
+from biscotti_tpu.models.lm import frozen_count  # noqa: F401  (its callers')
 from biscotti_tpu.ops import attention, moe
 
 # scopes inside `round_grad` a device trace is read by (a second
@@ -130,25 +128,7 @@ def rotary_tables(cfg: LagunaConfig, kind: str, length: int):
     """(cos, sin) float32[T, rot / 2] and the rotated width `rot`."""
     rope = cfg.rope_full if kind == "full" else cfg.rope_sliding
     rot = int(cfg.head_dim * rope["partial_rotary_factor"])
-    base = float(rope["rope_theta"])
-    inv = 1.0 / base ** (np.arange(0, rot, 2, dtype=np.float64) / rot)
-    factor = 1.0
-    if "factor" in rope:  # YaRN
-        original = rope["original_max_position_embeddings"]
-
-        def correction_dim(rotations):
-            return (rot * math.log(original / (rotations * 2 * math.pi))
-                    / (2 * math.log(base)))
-
-        low = max(math.floor(correction_dim(rope["beta_fast"])), 0)
-        high = min(math.ceil(correction_dim(rope["beta_slow"])), rot - 1)
-        ramp = np.clip((np.arange(rot // 2, dtype=np.float64) - low)
-                       / max(high - low, 1e-3), 0.0, 1.0)
-        inv = inv / rope["factor"] * ramp + inv * (1.0 - ramp)
-        factor = float(rope["attention_factor"])
-    angles = np.arange(length, dtype=np.float64)[:, None] * inv[None, :]
-    return ((np.cos(angles) * factor).astype(np.float32),
-            (np.sin(angles) * factor).astype(np.float32), rot)
+    return lm.yarn_tables(rot, rope, length) + (rot,)
 
 
 def _rotate(x, cos, sin, rot):
@@ -163,40 +143,16 @@ def _rotate(x, cos, sin, rot):
 # ----------------------------------------------------------------- forward
 
 
-def _rms(x, weight, eps):
-    x = x.astype(jnp.float32)
-    scale = jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
-    return x * scale * weight.astype(jnp.float32)
-
-
-def _mm(a, w):
-    """a @ w in w's dtype, accumulated in float32."""
-    return jnp.dot(a.astype(w.dtype), w, preferred_element_type=jnp.float32)
-
-
-def _swiglu(x, w):
-    hidden = jax.nn.silu(_mm(x, w["w_gate"])) * _mm(x, w["w_up"])
-    return _mm(hidden, w["w_down"])
-
-
-def _adapted(cfg, x, w, a, b):
-    """x W + (alpha / r) (x A) B, B with a peer axis: x [P, b, T, in],
-    B [P, r, out]."""
-    low = jnp.einsum("pbtr,pro->pbto", _mm(x, a).astype(a.dtype),
-                     b.astype(a.dtype), preferred_element_type=jnp.float32)
-    return _mm(x, w) + (cfg.alpha / cfg.rank) * low
-
-
 def _attention(cfg, at, h, frozen, adapters):
     """The attention block of layer `at` on h [P, b, T, H]."""
     kind, n = cfg.layer_types[at], cfg.heads[at]
     p, b, t, _ = h.shape
     dh, kv = cfg.head_dim, cfg.kv_heads
-    x = _rms(h, frozen["attn_norm"], cfg.eps)
+    x = lm.rms(h, frozen["attn_norm"], cfg.eps)
     lora = frozen["lora_a"]
 
     def heads(name, count):
-        y = _adapted(cfg, x, frozen["w" + name], lora[name], adapters[name])
+        y = lm.adapted(cfg, x, frozen["w" + name], lora[name], adapters[name])
         return y.reshape(p * b, t, count, dh).transpose(0, 2, 1, 3)
 
     q, k, v = heads("q", n), heads("k", kv), heads("v", kv)
@@ -206,11 +162,11 @@ def _attention(cfg, at, h, frozen, adapters):
     q = q.reshape(p * b, kv, n // kv, t, dh).astype(dtype)
     out = attention.attention(q, k.astype(dtype), v.astype(dtype),
                               t if kind == "full" else cfg.window)
-    gate = jax.nn.sigmoid(_mm(x, frozen["wgate"]))          # [P, b, T, n]
+    gate = jax.nn.sigmoid(lm.mm(x, frozen["wgate"]))          # [P, b, T, n]
     out = out.reshape(p * b, n, t, dh).transpose(0, 2, 1, 3)  # [W, T, n, dh]
     out = out * gate.reshape(p * b, t, n)[..., None]
     out = out.reshape(p, b, t, n * dh)
-    return _adapted(cfg, out, frozen["wo"], lora["o"], adapters["o"])
+    return lm.adapted(cfg, out, frozen["wo"], lora["o"], adapters["o"])
 
 
 def attention_plan(cfg: LagunaConfig, length: int) -> dict:
@@ -234,15 +190,15 @@ def _mlp(cfg, at, h, frozen):
     """The MLP block of layer `at` on h [N, H]: (result, the dispatch's
     counts, the router's (experts, probabilities)); the last two None on a
     dense layer."""
-    x = _rms(h, frozen["mlp_norm"], cfg.eps)
+    x = lm.rms(h, frozen["mlp_norm"], cfg.eps)
     if at in cfg.dense_layers:
         with jax.named_scope("lm_dense"):
-            return _swiglu(x, frozen["dense"]), None, None
+            return lm.swiglu(x, frozen["dense"]), None, None
     with jax.named_scope("lm_router"):
         experts, coef, probs = moe.route(x, frozen["router"], cfg.top_k,
                                          cfg.routed_scale)
     with jax.named_scope("lm_dense"):
-        shared = _swiglu(x, frozen["shared"])
+        shared = lm.swiglu(x, frozen["shared"])
     with jax.named_scope("lm_experts"):
         routed, counts = moe.held_experts(x, experts, coef,
                                           frozen["experts"],
@@ -257,60 +213,16 @@ def _layer(cfg, at, h, frozen, adapters):
     return h + out.reshape(h.shape), counts, picks
 
 
-def _stacked(found):
-    found = [f for f in found if f is not None]
-    return jax.tree.map(lambda *a: jnp.stack(a), *found) if found else {}
-
-
-def hidden_states(cfg, params, tokens, frozen, remat=True):
-    """(final hidden states [P, b, T, H], the dispatch's counts, the
-    router's picks) of `tokens` int32[P, b, T] under adapters with a peer
-    axis (every leaf of `params` [P, r, out]); counts and picks stacked
-    over the sparse layers, in layer order."""
-    with jax.named_scope("lm_embed"):
-        h = frozen["embed"][tokens].astype(jnp.float32)
-    counted, picked = [], []
-    for at in range(cfg.layers):
-        def step(h, layer, adapters, at=at):
-            return _layer(cfg, at, h, layer, adapters)
-
-        if remat:
-            step = jax.checkpoint(step)
-        h, counts, picks = step(h, frozen["layers"][at],
-                                params["layers"][at])
-        counted.append(counts)
-        picked.append(picks)
-    return h, _stacked(counted), _stacked(picked)
-
-
-def _logits(cfg, h, frozen):
-    return _mm(_rms(h, frozen["final_norm"], cfg.eps), frozen["head"])
-
-
-def peer_losses(cfg, params, tokens, labels, frozen):
-    """Each peer's mean next-token cross-entropy over its own windows,
-    float32[P], and the dispatch's counts: `params` leaves [P, r, out],
-    tokens/labels int32[P, b, T]."""
-    h, counts, _ = hidden_states(cfg, params, tokens, frozen)
-    with jax.named_scope("lm_head_loss"):
-        logits = _logits(cfg, h, frozen)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        picked = jnp.sum(jnp.where(
-            jnp.arange(logp.shape[-1], dtype=jnp.int32)
-            == labels[..., None].astype(jnp.int32), logp, 0.0), axis=-1)
-        return -jnp.mean(picked, axis=(1, 2)), counts
-
-
-def _one_peer(tree):
-    return jax.tree.map(lambda a: a[None], tree)
+# (h [P, b, T, H], counts, picks) of tokens int32[P, b, T] under adapters
+# with a peer axis: lm.decoder's walk over this model's layers
+hidden_states = lm.decoder(_layer)
 
 
 def routing(cfg, params, tokens, frozen):
     """The router's choices for `tokens` int32[b, T] under adapters
     `params` (no peer axis): experts int32[L, b*T, k] and probabilities
     float32[L, b*T, E_all], one row a sparse layer, in layer order."""
-    return hidden_states(cfg, _one_peer(params), tokens[None], frozen,
-                         remat=False)[2]
+    return lm.routing(hidden_states, cfg, params, tokens, frozen)
 
 
 # ------------------------------------------------------------------- model
@@ -333,71 +245,23 @@ def _shapes(cfg: LagunaConfig):
                  "lora_a": {"q": ((hdim, r), hdim), "k": ((hdim, r), hdim),
                             "v": ((hdim, r), hdim), "o": ((n, r), n)}}
 
-        def swiglu(width, lead=()):
-            return {"w_gate": (lead + (hdim, width), hdim),
-                    "w_up": (lead + (hdim, width), hdim),
-                    "w_down": (lead + (width, hdim), width)}
-
         if at in cfg.dense_layers:
-            layer["dense"] = swiglu(cfg.dense_width)
+            layer["dense"] = lm.swiglu_shapes(hdim, cfg.dense_width)
         else:
             layer["router"] = ((hdim, cfg.num_experts), hdim)
-            layer["shared"] = swiglu(cfg.shared_width)
-            layer["experts"] = swiglu(cfg.expert_width,
-                                      (cfg.experts_held,))
+            layer["shared"] = lm.swiglu_shapes(hdim, cfg.shared_width)
+            layer["experts"] = lm.swiglu_shapes(hdim, cfg.expert_width,
+                                                (cfg.experts_held,))
         frozen["layers"].append(layer)
         trained.append({"q": (r, n), "k": (r, kv), "v": (r, kv),
                         "o": (r, hdim)})
     return frozen, trained
 
 
-def _is_leaf(node):
-    return isinstance(node, tuple) and isinstance(node[0], tuple)
-
-
-@partial(jax.jit, static_argnames=("shape", "fan_in", "dtype"))
-def _draw(key, shape, fan_in, dtype):
-    """One frozen leaf, drawn where it will live: norm weights around 1,
-    the rest fan-in scaled normal."""
-    noise = jax.random.normal(key, shape, jnp.float32)
-    if fan_in == 0:
-        return (1.0 + 0.1 * noise).astype(dtype)
-    return (noise / math.sqrt(fan_in)).astype(dtype)
-
-
 def laguna_model(name: str, cfg: LagunaConfig, length: int):
     """The Biscotti `Model` of `cfg` on windows of `length` tokens."""
     frozen_shapes, trained_shapes = _shapes(cfg)
     dtype = jnp.dtype(cfg.dtype)
-
-    def init(key):
-        """Seeded NON-zero adapters (a round's start is zeros, as LoRA's
-        `B` starts; tests and the benchmark's checked round draw these)."""
-        leaves, treedef = jax.tree.flatten(
-            {"layers": trained_shapes}, is_leaf=lambda n: isinstance(n, tuple))
-        keys = jax.random.split(key, len(leaves))
-        return jax.tree.unflatten(treedef, [
-            0.02 * jax.random.normal(k, shape, jnp.float32)
-            for k, shape in zip(keys, leaves)])
-
-    def init_frozen(key):
-        """Leaf by leaf, each drawn on the device: never the whole base
-        on the host."""
-        leaves, treedef = jax.tree.flatten(frozen_shapes, is_leaf=_is_leaf)
-        return jax.tree.unflatten(treedef, [
-            _draw(jax.random.fold_in(key, i), shape, fan_in, dtype)
-            for i, (shape, fan_in) in enumerate(leaves)])
-
-    def losses(params, x, y, frozen):
-        return peer_losses(cfg, params, x, y, frozen)
-
-    def apply(params, x, frozen):
-        h = hidden_states(cfg, _one_peer(params), x[None], frozen,
-                          remat=False)[0]
-        return _logits(cfg, h[0], frozen)
-
-    def loss(params, x, y, frozen):
-        return losses(_one_peer(params), x[None], y[None], frozen)[0][0]
 
     def step_bytes(batch):
         """Bytes one peer's step adds to what a block holds live at its
@@ -420,15 +284,7 @@ def laguna_model(name: str, cfg: LagunaConfig, length: int):
                 + t * cfg.top_k * cfg.hidden * (4 + dtype.itemsize)
                 + 2 * 4 * t * cfg.vocab + 12 * 4 * t * cfg.hidden)
 
-    return make_model(name, length, cfg.vocab, init, apply, loss,
-                      step_rule="clipped_sgd", token_input=True,
-                      init_frozen=init_frozen, peer_losses=losses,
-                      step_bytes=step_bytes,
-                      info={"config": cfg,
-                            "attention": attention_plan(cfg, length)})
-
-
-def frozen_count(model) -> int:
-    """Parameters in the model's frozen tree, from shapes alone."""
-    tree = jax.eval_shape(model.init_frozen, jax.random.PRNGKey(0))
-    return sum(math.prod(leaf.shape) for leaf in jax.tree.leaves(tree))
+    return lm.lm_model(name, cfg, length,
+                       (frozen_shapes, {"layers": trained_shapes}),
+                       hidden_states, step_bytes,
+                       {"attention": attention_plan(cfg, length)})

@@ -1,0 +1,218 @@
+"""What the language models of this package share (models/laguna.py,
+models/deepseek_v2.py): a decoder whose frozen base is held once beside
+rank-r adapters `B [r, out]`, which are what the peers train, commit and
+aggregate (the FFA-LoRA form: `A` frozen and shared, so that the sum of the
+peers' updates IS the update of the sum).
+
+  the arithmetic   `rms`, `mm`, `swiglu`, `adapted`: every product in the
+                   frozen weights' dtype with float32 accumulation, norms
+                   in float32
+  rotary           `yarn_tables`: (cos, sin) of plain or YaRN-scaled
+                   frequencies, made on the host in float64
+  the peer axis    `decoder`: a model's `hidden_states` takes adapters with
+                   a leading peer axis on every leaf and the peers' windows
+                   as ONE batch (module doc of models/laguna.py), a layer
+                   at a time, each rematerialised; `peer_losses` closes it
+                   with the head and the loss
+  the `Model`      `lm_model`: init (seeded non-zero adapters), the frozen
+                   tree drawn leaf by leaf on the device, apply / loss /
+                   peer_losses over the model's own `hidden_states`
+"""
+
+from __future__ import annotations
+
+import math
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from biscotti_tpu.models.base import make_model
+
+
+def yarn_tables(rot: int, rope: dict, length: int):
+    """(cos, sin) float32[T, rot / 2] of a rotary embedding over `rot`
+    dimensions: `rope` holds `rope_theta` and, for YaRN, `factor`,
+    `original_max_position_embeddings`, `beta_fast`, `beta_slow` (the
+    linear ramp between the floor and the ceiling of the correction
+    dimensions, as transformers' `_compute_yarn_parameters`) and
+    `attention_factor`, which multiplies cos and sin."""
+    base = float(rope["rope_theta"])
+    inv = 1.0 / base ** (np.arange(0, rot, 2, dtype=np.float64) / rot)
+    factor = 1.0
+    if "factor" in rope:  # YaRN
+        original = rope["original_max_position_embeddings"]
+
+        def correction_dim(rotations):
+            return (rot * math.log(original / (rotations * 2 * math.pi))
+                    / (2 * math.log(base)))
+
+        low = max(math.floor(correction_dim(rope["beta_fast"])), 0)
+        high = min(math.ceil(correction_dim(rope["beta_slow"])), rot - 1)
+        ramp = np.clip((np.arange(rot // 2, dtype=np.float64) - low)
+                       / max(high - low, 1e-3), 0.0, 1.0)
+        inv = inv / rope["factor"] * ramp + inv * (1.0 - ramp)
+        factor = float(rope["attention_factor"])
+    angles = np.arange(length, dtype=np.float64)[:, None] * inv[None, :]
+    return ((np.cos(angles) * factor).astype(np.float32),
+            (np.sin(angles) * factor).astype(np.float32))
+
+
+def rms(x, weight, eps):
+    x = x.astype(jnp.float32)
+    scale = jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+    return x * scale * weight.astype(jnp.float32)
+
+
+def mm(a, w):
+    """a @ w in w's dtype, accumulated in float32."""
+    return jnp.dot(a.astype(w.dtype), w, preferred_element_type=jnp.float32)
+
+
+def swiglu(x, w):
+    hidden = jax.nn.silu(mm(x, w["w_gate"])) * mm(x, w["w_up"])
+    return mm(hidden, w["w_down"])
+
+
+def swiglu_shapes(hidden: int, width: int, lead=()):
+    """{name: (shape, fan_in)} of a SwiGLU's three weights (`lead`: a
+    stack of experts)."""
+    return {"w_gate": (lead + (hidden, width), hidden),
+            "w_up": (lead + (hidden, width), hidden),
+            "w_down": (lead + (width, hidden), width)}
+
+
+def adapted(cfg, x, w, a, b):
+    """x W + (alpha / r) (x A) B, B with a peer axis: x [P, b, T, in],
+    B [P, r, out]."""
+    low = jnp.einsum("pbtr,pro->pbto", mm(x, a).astype(a.dtype),
+                     b.astype(a.dtype), preferred_element_type=jnp.float32)
+    return mm(x, w) + (cfg.alpha / cfg.rank) * low
+
+
+def stacked(found):
+    found = [f for f in found if f is not None]
+    return jax.tree.map(lambda *a: jnp.stack(a), *found) if found else {}
+
+
+def decoder(layer):
+    """`hidden_states(cfg, params, tokens, frozen, remat=True)` of a model
+    whose `layer(cfg, at, h, frozen_layer, adapters_layer)` gives (h', the
+    dispatch's counts, the router's picks; the last two None on a dense
+    layer): (final hidden states [P, b, T, H], counts, picks) of `tokens`
+    int32[P, b, T] under adapters with a peer axis (every leaf of `params`
+    [P, r, out]), each layer rematerialised in the backward pass; counts
+    and picks stacked over the sparse layers, in layer order."""
+    def hidden_states(cfg, params, tokens, frozen, remat=True):
+        with jax.named_scope("lm_embed"):
+            h = frozen["embed"][tokens].astype(jnp.float32)
+        counted, picked = [], []
+        for at in range(cfg.layers):
+            def step(h, layer_frozen, adapters, at=at):
+                return layer(cfg, at, h, layer_frozen, adapters)
+
+            if remat:
+                step = jax.checkpoint(step)
+            h, counts, picks = step(h, frozen["layers"][at],
+                                    params["layers"][at])
+            counted.append(counts)
+            picked.append(picks)
+        return h, stacked(counted), stacked(picked)
+
+    return hidden_states
+
+
+def logits(cfg, h, frozen):
+    return mm(rms(h, frozen["final_norm"], cfg.eps), frozen["head"])
+
+
+def peer_losses(hidden_states, cfg, params, tokens, labels, frozen):
+    """Each peer's mean next-token cross-entropy over its own windows,
+    float32[P], and the dispatch's counts: `params` leaves [P, r, out],
+    tokens/labels int32[P, b, T]."""
+    h, counts, _ = hidden_states(cfg, params, tokens, frozen)
+    with jax.named_scope("lm_head_loss"):
+        logp = jax.nn.log_softmax(logits(cfg, h, frozen), axis=-1)
+        picked = jnp.sum(jnp.where(
+            jnp.arange(logp.shape[-1], dtype=jnp.int32)
+            == labels[..., None].astype(jnp.int32), logp, 0.0), axis=-1)
+        return -jnp.mean(picked, axis=(1, 2)), counts
+
+
+def one_peer(tree):
+    return jax.tree.map(lambda a: a[None], tree)
+
+
+def routing(hidden_states, cfg, params, tokens, frozen):
+    """The router's choices for `tokens` int32[b, T] under adapters
+    `params` (no peer axis): experts int32[L, b*T, k] and probabilities
+    float32[L, b*T, E_all], one row a sparse layer, in layer order."""
+    return hidden_states(cfg, one_peer(params), tokens[None], frozen,
+                         remat=False)[2]
+
+
+def _is_leaf(node):
+    return isinstance(node, tuple) and isinstance(node[0], tuple)
+
+
+@partial(jax.jit, static_argnames=("shape", "fan_in", "dtype"))
+def _draw(key, shape, fan_in, dtype):
+    """One frozen leaf, drawn where it will live: norm weights around 1,
+    the rest fan-in scaled normal."""
+    noise = jax.random.normal(key, shape, jnp.float32)
+    if fan_in == 0:
+        return (1.0 + 0.1 * noise).astype(dtype)
+    return (noise / math.sqrt(fan_in)).astype(dtype)
+
+
+def lm_model(name: str, cfg, length: int, shapes, hidden_states, step_bytes,
+             info: dict):
+    """The Biscotti `Model` of a decoder on windows of `length` tokens.
+
+    `shapes` = ({path: (shape, fan_in)} of the frozen leaves, the trained
+    tree's {path: shape}); `hidden_states(cfg, params, tokens, frozen,
+    remat=True)` -> (h [P, b, T, H], counts, picks); `cfg` has `vocab`,
+    `eps`, `dtype`."""
+    frozen_shapes, trained_shapes = shapes
+    dtype = jnp.dtype(cfg.dtype)
+
+    def init(key):
+        """Seeded NON-zero adapters (a round's start is zeros, as LoRA's
+        `B` starts; tests and the benchmark's checked round draw these)."""
+        leaves, treedef = jax.tree.flatten(
+            trained_shapes, is_leaf=lambda n: isinstance(n, tuple))
+        keys = jax.random.split(key, len(leaves))
+        return jax.tree.unflatten(treedef, [
+            0.02 * jax.random.normal(k, shape, jnp.float32)
+            for k, shape in zip(keys, leaves)])
+
+    def init_frozen(key):
+        """Leaf by leaf, each drawn on the device: never the whole base
+        on the host."""
+        leaves, treedef = jax.tree.flatten(frozen_shapes, is_leaf=_is_leaf)
+        return jax.tree.unflatten(treedef, [
+            _draw(jax.random.fold_in(key, i), shape, fan_in, dtype)
+            for i, (shape, fan_in) in enumerate(leaves)])
+
+    def losses(params, x, y, frozen):
+        return peer_losses(hidden_states, cfg, params, x, y, frozen)
+
+    def apply(params, x, frozen):
+        h = hidden_states(cfg, one_peer(params), x[None], frozen,
+                          remat=False)[0]
+        return logits(cfg, h[0], frozen)
+
+    def loss(params, x, y, frozen):
+        return losses(one_peer(params), x[None], y[None], frozen)[0][0]
+
+    return make_model(name, length, cfg.vocab, init, apply, loss,
+                      step_rule="clipped_sgd", token_input=True,
+                      init_frozen=init_frozen, peer_losses=losses,
+                      step_bytes=step_bytes, info=dict(info, config=cfg))
+
+
+def frozen_count(model) -> int:
+    """Parameters in the model's frozen tree, from shapes alone."""
+    tree = jax.eval_shape(model.init_frozen, jax.random.PRNGKey(0))
+    return sum(math.prod(leaf.shape) for leaf in jax.tree.leaves(tree))

@@ -10,6 +10,13 @@
              (models/laguna.py): a frozen 3.0 B-parameter share at the
              published widths, rank-16 adapters trained, d = 1,048,576
   laguna_tiny  the same mechanism at the CPU tests' size
+  deepseek_v2_fedlora  DeepSeek-V2's latent-attention (MLA), group-routed
+             sparse-expert decoder (models/deepseek_v2.py): a frozen 5.2
+             B-parameter share at the published widths, rank-16 adapters on
+             the five attention projections trained, d = 5,166,080; reads
+             the dataset `lm_tokens_dsv2` (25,600 classes)
+  deepseek_v2_tiny  the same mechanism at the CPU tests' size
+             (`lm_tokens_tiny`, as laguna_tiny)
 
 Inits are MXU-friendly (fan-in scaled normal) and every model is expressed in
 channels-last NHWC, the layout XLA prefers on TPU.
@@ -24,7 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from biscotti_tpu.data.datasets import base_name, spec as dspec
-from biscotti_tpu.models import laguna
+from biscotti_tpu.models import deepseek_v2, laguna
 from biscotti_tpu.models.base import Model, cross_entropy, make_model, multiclass_hinge
 
 
@@ -205,23 +212,28 @@ MODELS: Dict[str, callable] = {
     "mnist_cnn": lambda ds: mnist_cnn_model(),
     "cifar_cnn": lambda ds: cifar_cnn_model(),
     "lfw_cnn": lambda ds: lfw_cnn_model(),
-    **{name: (lambda ds, name=name: _laguna(name, ds))
-       for name in laguna.PRESETS},
+    **{name: (lambda ds, name=name, build=build, presets=presets:
+              _language_model(build, name, presets[name], ds))
+       for build, presets in ((laguna.laguna_model, laguna.PRESETS),
+                              (deepseek_v2.deepseek_v2_model,
+                               deepseek_v2.PRESETS))
+       for name in presets},
 }
 
 # what a dataset trains where no model is named (softmax otherwise)
 DEFAULTS = {"creditcard": "logreg", "lm_tokens": "laguna_s_fedlora",
-            "lm_tokens_tiny": "laguna_tiny"}
+            "lm_tokens_tiny": "laguna_tiny",
+            "lm_tokens_dsv2": "deepseek_v2_fedlora"}
 
 
-def _laguna(name: str, dataset: str) -> Model:
-    spec, cfg = dspec(dataset), laguna.PRESETS[name]
+def _language_model(build, name: str, cfg, dataset: str) -> Model:
+    spec = dspec(dataset)
     if not spec.tokens or spec.n_classes != cfg.vocab:
         raise ValueError(
             f"model {name!r} reads windows of token ids below {cfg.vocab}; "
             f"dataset {dataset!r} has {spec.n_classes} classes"
             + ("" if spec.tokens else " and no tokens"))
-    return laguna.laguna_model(name, cfg, spec.d_in)
+    return build(name, cfg, spec.d_in)
 
 
 def model_for_dataset(dataset: str, model: str = "") -> Model:

@@ -1,4 +1,4 @@
-"""The attention core `softmax(q k^T / sqrt(d) + mask) v` as one fused,
+"""The attention core `softmax(scale q k^T + mask) v` as one fused,
 blocked Pallas TPU kernel a call, forward and backward: the scores live a
 (query block, key block) tile at a time in the chip's own memory, under a
 running maximum and sum, and no array of their size [heads, T, T] is ever
@@ -11,8 +11,17 @@ HBM: 2.9 ms a call forward and 10.4 with its backward at 72 heads, where
 this kernel takes 1.0 and 3.1 (PERF.md section 6, PR 30).
 
   layout: the grouped-query one the model has. q [W, kv, G, T, d] (G query
-    heads share a key/value head), k, v [W, kv, T, d]; the result
-    float32[W, kv, G, T, d]. Windows never meet: the grid walks W.
+    heads share a key/value head), k [W, kv, T, d], v [W, kv, T, e]; the
+    result float32[W, kv, G, T, e]. Windows never meet: the grid walks W.
+    The scores' width d and the values' e are each their own: Laguna's
+    heads have 128 | 128 and G = 6 or 9, DeepSeek-V2's latent attention
+    192 | 128 (128 + the 64 rotary dimensions) and G = 1. A d of a lane
+    tile and a half is contracted as it is: on the v5e a forward call of
+    3 x 128 heads took 3.48 ms and with its backward 9.20, zero-padded to
+    256 outside the kernel 4.70 and 10.42, the `einsum` form 6.18 and
+    20.26 (eval/eval_attention.py --layers mla; PERF.md section 6, PR 31).
+    `scale` multiplies the scores: 1 / sqrt(d) unless the caller has its
+    own (DeepSeek-V2's carries YaRN's m^2).
   mask: query i sees key j where `j <= i` and `i - j < window`; a window of
     T or more is the causal mask. So the key blocks a query block visits
     are a RANGE computed from its number (`key_blocks`): program-id
@@ -21,14 +30,14 @@ this kernel takes 1.0 and 3.1 (PERF.md section 6, PR 30).
     v in VMEM (T x d each: 256 KB at the published size, fetched once a
     head, not once a query block) and, a query head of the group, walks
     the visited key blocks:
-      forward   s = q k^T / sqrt(d) masked; m, l, acc the running maximum,
+      forward   s = scale q k^T masked; m, l, acc the running maximum,
                 sum and unnormalised result; out = acc / l, and the rows'
                 log-sum-exp m + log l kept for the backward;
       backward  ONE kernel for dq, dk and dv: the scores again, transposed
                 (keys down, queries across: the rows' log-sum-exp and
                 `sum(out * dout)` enter as lane-major rows), p = exp(s -
                 lse), dv += p do, ds = p (v do^T - di), dk += ds q, dq +=
-                ds^T k, the scale 1 / sqrt(d) of ds applied to dq's and
+                ds^T k, the scale of ds applied to dq's and
                 dk's sums, once a row. dk and dv add up over the group's heads
                 and the query blocks in float32 scratch and are written
                 once a key/value head.
@@ -74,16 +83,18 @@ _NT = (((1,), (1,)), ((), ()))  # a @ b^T
 _TN = (((0,), (0,)), ((), ()))  # a^T @ b
 
 
-def plain(q, k, v, window: int):
-    """The `einsum` form: q [W, kv, G, T, d], k, v [W, kv, T, d] in one
-    type; float32[W, kv, G, T, d]. Makes the scores [W, kv, G, T, T]."""
+def plain(q, k, v, window: int, scale=None):
+    """The `einsum` form: q [W, kv, G, T, d], k [W, kv, T, d], v [W, kv, T,
+    e] in one type; float32[W, kv, G, T, e]. Makes the scores [W, kv, G, T,
+    T]; `scale` multiplies them (None: 1 / sqrt(d))."""
     t, d = q.shape[-2:]
     scores = jnp.einsum("wgqtd,wgsd->wgqts", q, k,
-                        preferred_element_type=jnp.float32) / math.sqrt(d)
+                        preferred_element_type=jnp.float32)
+    scores = scores / math.sqrt(d) if scale is None else scores * scale
     i, j = np.arange(t)[:, None], np.arange(t)[None, :]
     seen = (j <= i) if window >= t else ((j <= i) & (i - j < window))
     probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
-    return jnp.einsum("wgqts,wgsd->wgqtd", probs.astype(q.dtype), v,
+    return jnp.einsum("wgqts,wgse->wgqte", probs.astype(q.dtype), v,
                       preferred_element_type=jnp.float32)
 
 
@@ -105,27 +116,39 @@ def visited(t: int, window: int, bq: int, bk: int):
     return pairs
 
 
-def _buffers(g: int, t: int, d: int, bq: int, bk: int, size: int) -> int:
+def _buffers(g: int, t: int, d: int, bq: int, bk: int, size: int,
+             e: int = None) -> int:
     """Bytes of VMEM the backward (the larger of the two) holds: two
-    buffers each of q, the result's cotangent (float32) and dq's blocks, of
-    k, v, dk, dv whole and of the two rows' statistics; dk's and dv's
-    float32 sums; six tiles of the scores' size."""
-    rows = g * bq
-    return (2 * rows * d * (2 * size + 4) + 2 * 4 * t * d * size
-            + 2 * 2 * 4 * rows + 2 * 4 * t * d + 6 * 4 * bq * bk)
+    buffers each of q's and dq's blocks (`d` wide, the scores' width), of
+    the result's cotangent (float32, `e` wide, the values' width; None: d),
+    of k, dk (d) and v, dv (e) whole and of the two rows' statistics; dk's
+    and dv's float32 sums; six tiles of the scores' size."""
+    rows, e = g * bq, d if e is None else e
+    return (2 * rows * (2 * d * size + 4 * e) + 2 * 2 * t * (d + e) * size
+            + 2 * 2 * 4 * rows + 4 * t * (d + e) + 6 * 4 * bq * bk)
 
 
-def blocks(g: int, t: int, d: int, dtype):
+def _padded(d: int) -> int:
+    """`d` in whole lane tiles: what a last axis of d takes in VMEM."""
+    return -(-d // _LANES) * _LANES
+
+
+def blocks(g: int, t: int, d: int, dtype, e: int = None):
     """(query block, key block) of the kernel for `g` query heads a
-    key/value head on windows of `t` and heads of `d`, or None where the
-    kernel does not take the shape: a head size not of 128, a window that
+    key/value head on windows of `t`, scores that contract `d` and values
+    of `e` (None: d), or None where the kernel does not take the shape: a
+    value width not of 128 or a score width not of 64 (half a lane tile:
+    the kernel's products contract whole tiles and one half), a window that
     is no whole number of blocks, another type, or a key/value head too
     long to hold whole."""
-    if d % _LANES or jnp.dtype(dtype) not in (jnp.bfloat16, jnp.float32):
+    e = d if e is None else e
+    if (e % _LANES or d % (_LANES // 2)
+            or jnp.dtype(dtype) not in (jnp.bfloat16, jnp.float32)):
         return None
     size = jnp.dtype(dtype).itemsize
     return next(((bq, bk) for bq, bk in BLOCKS if t % bq == 0 and t % bk == 0
-                 and _buffers(g, t, d, bq, bk, size) <= _VMEM_BUFFERS), None)
+                 and _buffers(g, t, _padded(d), bq, bk, size, e)
+                 <= _VMEM_BUFFERS), None)
 
 
 def block_share(t: int, window: int, bq: int, bk: int) -> float:
@@ -157,11 +180,10 @@ def _as_row(column):
 
 
 def _forward(q_ref, k_ref, v_ref, out_ref, lse_ref, *, bq: int, bk: int,
-             window: int):
+             window: int, scale: float):
     iq = pl.program_id(2)
     first, last = key_blocks(iq, bq, bk, window, jnp.maximum)
-    d, t = q_ref.shape[-1], k_ref.shape[0]
-    scale = 1.0 / math.sqrt(d)
+    e, t = v_ref.shape[-1], k_ref.shape[0]
     relative = _relative(bq, bk, False)
 
     def head(g, _):
@@ -186,7 +208,7 @@ def _forward(q_ref, k_ref, v_ref, out_ref, lse_ref, *, bq: int, bk: int,
             first, last + 1, step,
             (jnp.full((bq, 1), -jnp.inf, jnp.float32),
              jnp.zeros((bq, 1), jnp.float32),
-             jnp.zeros((bq, d), jnp.float32)))
+             jnp.zeros((bq, e), jnp.float32)))
         out_ref[g] = (acc / l).astype(out_ref.dtype)
         lse_ref[g] = _as_row(m + jnp.log(l))
 
@@ -196,7 +218,8 @@ def _forward(q_ref, k_ref, v_ref, out_ref, lse_ref, *, bq: int, bk: int,
 
 
 def _backward(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, dk_ref,
-              dv_ref, dk_sum, dv_sum, *, bq: int, bk: int, window: int):
+              dv_ref, dk_sum, dv_sum, *, bq: int, bk: int, window: int,
+              scale: float):
     iq = pl.program_id(2)
 
     @pl.when(iq == 0)
@@ -206,7 +229,6 @@ def _backward(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, dk_ref,
 
     first, last = key_blocks(iq, bq, bk, window, jnp.maximum)
     d, t = q_ref.shape[-1], k_ref.shape[0]
-    scale = 1.0 / math.sqrt(d)
     relative = _relative(bq, bk, True)
 
     def head(g, _):
@@ -246,9 +268,9 @@ def _backward(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, dk_ref,
 
 
 def _specs(g: int, t: int, d: int, bq: int):
-    """BlockSpecs of (a query head group's block, a key/value head whole,
-    a group's rows' statistics, [W, kv, G, 1, T]: a head's are one
-    lane-major row) on the grid (W, kv, query block)."""
+    """BlockSpecs of (a query head group's block of `d`, a key/value head
+    of `d` whole, a group's rows' statistics, [W, kv, G, 1, T]: a head's
+    are one lane-major row) on the grid (W, kv, query block)."""
     return (pl.BlockSpec((None, None, g, bq, d),
                          lambda w, h, i: (w, h, 0, i, 0)),
             pl.BlockSpec((None, None, t, d), lambda w, h, i: (w, h, 0, 0)),
@@ -259,18 +281,20 @@ def _specs(g: int, t: int, d: int, bq: int):
 _SEMANTICS = ("parallel", "parallel", "arbitrary")
 
 
-def _call_forward(interpret, q, k, v, *, window, bq, bk):
+def _call_forward(interpret, q, k, v, *, window, bq, bk, scale):
     w, kv, g, t, d = q.shape
+    e = v.shape[-1]  # the values' width, the scores' apart (== d: the same)
     heads, whole, rows = _specs(g, t, d, bq)
+    heads_e, whole_e, _ = _specs(g, t, e, bq)
     # Mosaic has no 64-bit types: traced with x64 off, as the repo's other
     # kernels are; every operand is 32 bits or narrower already
     with jax.enable_x64(False):
         return pl.pallas_call(
-            partial(_forward, bq=bq, bk=bk, window=window),
+            partial(_forward, bq=bq, bk=bk, window=window, scale=scale),
             grid=(w, kv, t // bq),
-            in_specs=[heads, whole, whole],
-            out_specs=[heads, rows],
-            out_shape=[jax.ShapeDtypeStruct(q.shape, jnp.float32),
+            in_specs=[heads, whole, whole_e],
+            out_specs=[heads_e, rows],
+            out_shape=[jax.ShapeDtypeStruct(q.shape[:-1] + (e,), jnp.float32),
                        jax.ShapeDtypeStruct((w, kv, g, 1, t), jnp.float32)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=_SEMANTICS),
@@ -279,20 +303,23 @@ def _call_forward(interpret, q, k, v, *, window, bq, bk):
         )(q, k, v)
 
 
-def _call_backward(interpret, q, k, v, do, lse, di, *, window, bq, bk):
+def _call_backward(interpret, q, k, v, do, lse, di, *, window, bq, bk,
+                   scale):
     w, kv, g, t, d = q.shape
+    e = v.shape[-1]
     heads, whole, rows = _specs(g, t, d, bq)
+    heads_e, whole_e, _ = _specs(g, t, e, bq)
     with jax.enable_x64(False):
         return pl.pallas_call(
-            partial(_backward, bq=bq, bk=bk, window=window),
+            partial(_backward, bq=bq, bk=bk, window=window, scale=scale),
             grid=(w, kv, t // bq),
-            in_specs=[heads, whole, whole, heads, rows, rows],
-            out_specs=[heads, whole, whole],
+            in_specs=[heads, whole, whole_e, heads_e, rows, rows],
+            out_specs=[heads, whole, whole_e],
             out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                        jax.ShapeDtypeStruct(k.shape, k.dtype),
                        jax.ShapeDtypeStruct(v.shape, v.dtype)],
             scratch_shapes=[pltpu.VMEM((t, d), jnp.float32),
-                            pltpu.VMEM((t, d), jnp.float32)],
+                            pltpu.VMEM((t, e), jnp.float32)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=_SEMANTICS),
             interpret=interpret,
@@ -312,50 +339,56 @@ def _dispatched(call, *operands, **static):
 # jitted so that a program traces each shape of them once, however many
 # layers and passes call them (a round has two head counts x two masks x
 # three window counts, forward, recomputation and backward)
-@partial(jax.jit, static_argnames=("window", "bq", "bk"))
-def _run_forward(q, k, v, window, bq, bk):
-    return _dispatched(_call_forward, q, k, v, window=window, bq=bq, bk=bk)
+@partial(jax.jit, static_argnames=("window", "bq", "bk", "scale"))
+def _run_forward(q, k, v, window, bq, bk, scale):
+    return _dispatched(_call_forward, q, k, v, window=window, bq=bq, bk=bk,
+                       scale=scale)
 
 
-@partial(jax.jit, static_argnames=("window", "bq", "bk"))
-def _run_backward(q, k, v, out, lse, do, window, bq, bk):
+@partial(jax.jit, static_argnames=("window", "bq", "bk", "scale"))
+def _run_backward(q, k, v, out, lse, do, window, bq, bk, scale):
     di = jnp.sum(out * do, axis=-1)[..., None, :]
     return _dispatched(_call_backward, q, k, v, do, lse, di, window=window,
-                       bq=bq, bk=bk)
+                       bq=bq, bk=bk, scale=scale)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def fused(q, k, v, window: int, block=None):
-    """float32[W, kv, G, T, d]: `plain(q, k, v, window)` by the kernel, at
-    `block` (query block, key block), or at `blocks(G, T, d, q.dtype)`,
-    which then must take the shape."""
-    return _fused_fwd(q, k, v, window, block)[0]
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def fused(q, k, v, window: int, block=None, scale=None):
+    """float32[W, kv, G, T, e]: `plain(q, k, v, window, scale)` by the
+    kernel, at `block` (query block, key block), or at `blocks(G, T, d,
+    q.dtype, e)`, which then must take the shape."""
+    return _fused_fwd(q, k, v, window, block, scale)[0]
 
 
-def _static(q, window, block):
-    """(window, query block, key block) of a call on q."""
+def _static(q, v, window, block, scale):
+    """(window, query block, key block, scale) of a call on q and v."""
     _, _, g, t, d = q.shape
-    return (min(window, t),) + tuple(block or blocks(g, t, d, q.dtype))
+    return ((min(window, t),)
+            + tuple(block or blocks(g, t, d, q.dtype, v.shape[-1]))
+            + (1.0 / math.sqrt(d) if scale is None else float(scale),))
 
 
-def _fused_fwd(q, k, v, window, block):
-    out, lse = _run_forward(q, k, v, *_static(q, window, block))
+def _fused_fwd(q, k, v, window, block, scale):
+    out, lse = _run_forward(q, k, v, *_static(q, v, window, block, scale))
     return out, (q, k, v, out, lse)
 
 
-def _fused_bwd(window, block, res, do):
+def _fused_bwd(window, block, scale, res, do):
     q, k, v, out, lse = res
-    return _run_backward(q, k, v, out, lse, do, *_static(q, window, block))
+    return _run_backward(q, k, v, out, lse, do,
+                         *_static(q, v, window, block, scale))
 
 
 fused.defvjp(_fused_fwd, _fused_bwd)
 
 
-def attention(q, k, v, window: int):
-    """float32[W, kv, G, T, d] = softmax(q k^T / sqrt(d) + mask) v, the
-    mask `j <= i and i - j < window`: the kernel where `blocks` takes the
-    shape, the `einsum` form elsewhere. One algorithm, its parameters read
-    off the shapes."""
-    if blocks(*q.shape[2:], q.dtype) is None:
-        return plain(q, k, v, window)
-    return fused(q, k, v, window)
+def attention(q, k, v, window: int, scale=None):
+    """float32[W, kv, G, T, e] = softmax(scale q k^T + mask) v, the mask
+    `j <= i and i - j < window`, `scale` 1 / sqrt(d) where None: q [W, kv,
+    G, T, d], k [W, kv, T, d], v [W, kv, T, e], the scores' width d and
+    the values' e each its own. The kernel where `blocks` takes the shape,
+    the `einsum` form elsewhere. One algorithm, its parameters read off the
+    shapes."""
+    if blocks(*q.shape[2:], q.dtype, v.shape[-1]) is None:
+        return plain(q, k, v, window, scale)
+    return fused(q, k, v, window, None, scale)

@@ -1,7 +1,8 @@
 """A sparse-expert layer that is told which experts it holds.
 
-The router keeps its published width (it scores ALL the model's experts)
-and its experts a token; this device holds the contiguous slice
+The router keeps its published width (it scores ALL the model's experts),
+its experts a token and its rule (`route`: plain top-k, or group-limited
+greedy; the chosen probabilities renormalised or not); this device holds the contiguous slice
 `[first, first + E)` of them (the `E` leading rows of the stacked expert
 weights) and computes ITS experts' part of the layer's result for the
 tokens routed to them. What the absent experts would add is left out, as
@@ -16,6 +17,13 @@ held experts' loads: the rows past them are in no group, cost no product
 and come out zeros. The sorted buffer is cut to CAPACITY times the rows a
 uniform router would send here, and a round whose held rows pass that runs
 the same code on the uncut buffer instead (`lax.cond`): slower, never lossy.
+A caller may ask for the uncut buffer alone (`capacity=None`): under
+`jax.grad` the `lax.cond` hands the frozen expert weights out of its
+branches as residuals, a copy of every stack a layer, held for the whole
+program (7.03 GB for four layers of 40 experts of 5,120 x 1,536 by the
+compiled round's memory analysis; PERF.md section 6, PR 31), where the
+rows the cut saves are a few hundred megabytes and cost no product (the
+kernel's tail tiles read no operand).
 
 The product's time follows the (group, row tile) pairs it visits, not its
 rows (PERF.md section 6, PR 28: the compiler's `ragged-dot` walks row tiles
@@ -43,15 +51,35 @@ import jax.numpy as jnp
 from biscotti_tpu.ops import grouped_matmul
 
 
-def route(x: jax.Array, router_w: jax.Array, top_k: int, scale: float):
+def route(x: jax.Array, router_w: jax.Array, top_k: int, scale: float,
+          groups: int = 1, groups_kept: int = 1, renormalise: bool = True):
     """softmax over ALL experts, the `top_k` largest, their probabilities
-    renormalised to one and scaled: (experts int32[N, k], coefficients
-    float32[N, k], probabilities float32[N, E_all])."""
+    scaled: (experts int32[N, k], coefficients float32[N, k], probabilities
+    float32[N, E_all]).
+
+    `groups` > 1 is the group-limited greedy choice (DeepSeek-V2's
+    `topk_method`): the experts in `groups` equal runs, the `groups_kept`
+    runs with the largest maximum probability, and the `top_k` largest of
+    the experts in those (the others' probabilities count as 0). One group
+    is the plain top-k. `renormalise` divides the chosen probabilities by
+    their sum before the scale (`norm_topk_prob`); without it the
+    coefficients are `scale * p`."""
     logits = jnp.dot(x.astype(router_w.dtype), router_w,
                      preferred_element_type=jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_i = jax.lax.top_k(probs, top_k)
-    coef = scale * top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    eligible = probs
+    if groups > 1:
+        n, e = probs.shape
+        best = jnp.max(probs.reshape(n, groups, e // groups), axis=-1)
+        _, kept = jax.lax.top_k(best, groups_kept)            # [N, kept]
+        of = jnp.arange(e, dtype=jnp.int32) // (e // groups)  # [E]'s group
+        inside = jnp.any(of[None, :, None] == kept[:, None, :], axis=-1)
+        eligible = jnp.where(inside, probs, 0.0)
+    top_p, top_i = jax.lax.top_k(eligible, top_k)
+    if renormalise:
+        coef = scale * top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    else:
+        coef = scale * top_p
     return top_i.astype(jnp.int32), coef, probs
 
 
@@ -122,9 +150,10 @@ def _plan(buffers, h: int, f: int, dtype, rows_a_group: float) -> int:
     return tile if taken else 0
 
 
-@partial(jax.jit, static_argnames=("first", "total"))
+@partial(jax.jit, static_argnames=("first", "total", "capacity"))
 def held_experts(x: jax.Array, experts: jax.Array, coef: jax.Array,
-                 weights: dict, first: int = 0, total: int = 0):
+                 weights: dict, first: int = 0, total: int = 0,
+                 capacity=CAPACITY):
     """Σ over the token's assignments that land on a held expert of
     coefficient · SwiGLU_e(x): float32[N, H], and what the dispatch
     counted.
@@ -133,7 +162,8 @@ def held_experts(x: jax.Array, experts: jax.Array, coef: jax.Array,
     `total` experts of the model; 0: the held ones are all there are);
     weights: `w_gate`, `w_up` [E, H, F], `w_down` [E, F, H], the E experts
     `first .. first + E - 1`. The products run in the weights' dtype with
-    float32 accumulation.
+    float32 accumulation. `capacity`: the sorted buffer's cut, x the rows a
+    uniform router sends here (None: the uncut buffer alone, no `lax.cond`).
 
     counts: `load` int32[E] assignments a held expert; `dropped` int32:
     held assignments that reached no row of the sorted buffer (0 by
@@ -171,7 +201,8 @@ def held_experts(x: jax.Array, experts: jax.Array, coef: jax.Array,
         return _combine(ys, jnp.where(valid, coef, 0.0), token, slot, where)
 
     uniform = n * k / max(total, e)  # rows a group, of a uniform router
-    capacity = min(n * k, -(-int(CAPACITY * uniform * e) // 8) * 8)
+    capacity = n * k if capacity is None else min(
+        n * k, -(-int(capacity * uniform * e) // 8) * 8)
     tile = _plan((capacity, n * k), h, f, dtype, uniform)
     if tile:
         dot = partial(grouped_matmul.grouped, sizes=load, tm=tile)

@@ -544,6 +544,13 @@ class Simulator:
                             "ops/grouped_matmul.py's kernel, 0 the "
                             "compiler's ragged_dot").set(
                         moe["grouped_kernel"])
+                    if "groups_kept" in moe:
+                        m.gauge("biscotti_moe_groups_kept",
+                                "groups of experts a token's chosen "
+                                "experts lie in, mean over the last "
+                                "round's tokens and sparse layers (a "
+                                "group-limited router keeps at most its "
+                                "topk_group)").set(moe["groups_kept"])
             if it % log_every == 0 or it == num_rounds - 1:
                 e = float(err)
                 logs.append(RoundLog(it, e, time.time(), int(mask.sum())))
@@ -612,14 +619,23 @@ class Simulator:
         assignments that reached no expert (must read 0); `tile_fill`, held
         rows over the rows of the (group, row tile) pairs the grouped
         products visited, all calls; `grouped_kernel`, 1.0 where those
-        products are ops/grouped_matmul.py's. From `load` int32[layers,
-        held experts], `dropped`, `tile_rows` and `grouped_kernel`, which
-        the round returns; reads them back: call it outside a timed round."""
+        products are ops/grouped_matmul.py's; and, where the router limits
+        a token to some groups of experts (models/deepseek_v2.py),
+        `groups_kept`, the groups a token's chosen experts lie in, mean over
+        tokens and sparse layers. From `load` int32[layers, held experts],
+        `dropped`, `tile_rows` and `grouped_kernel` (and `groups_spanned`,
+        `tokens`), which the round returns; reads them back: call it
+        outside a timed round."""
         counts = self.last_counts if counts is None else counts
         if "load" not in counts:
             return {}
         load = np.asarray(counts["load"], np.float64)
+        grouped = {} if "groups_spanned" not in counts else {
+            "groups_kept": float(
+                np.asarray(counts["groups_spanned"], np.float64).sum()
+                / max(np.asarray(counts["tokens"], np.float64).sum(), 1.0))}
         return {
+            **grouped,
             "assignments_held": float(load.sum()),
             "load_max_over_mean": float(np.max(load.max(axis=1)
                                                / load.mean(axis=1))),

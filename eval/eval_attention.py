@@ -1,15 +1,20 @@
 #!/usr/bin/env python
-"""One attention core alone, at the shapes the Laguna round sends it: the
-`einsum` form (`ops/attention.plain`) against ops/attention.py's kernel at
-each admissible pair of blocks, timed from the DEVICE trace (per-program
+"""One attention core alone, at the shapes the rounds send it: the `einsum`
+form (`ops/attention.plain`) against ops/attention.py's kernel at each
+admissible pair of blocks, timed from the DEVICE trace (per-program
 durations).
 
-A peer block of 3 windows of 1,024 tokens, 8 key/value heads of 128: the
-full layers' 48 query heads under the causal mask and the sliding layers'
-72 under a window of 512, each forward alone and forward + backward (what
-a `jax.checkpoint`ed layer runs in the backward pass). Beside each time:
-the products of the visited (query block, key block) pairs (2 forward, 5
-more backward, 2 * bq * bk * 128 each) over the chip's bfloat16 peak.
+A peer block of 3 windows of 1,024 tokens. Laguna's: 8 key/value heads of
+128, the full layers' 48 query heads under the causal mask and the sliding
+layers' 72 under a window of 512. DeepSeek-V2's latent attention (`--layers
+mla`): 128 heads, none shared, scores that contract 192 (128 + the 64
+rotary dimensions) and values of 128, causal; the 192 as they are (the
+kernel's products contract a lane tile and a half) and, `padded256_*`,
+zero-padded to 256 inside the timed program. Each forward alone and forward
++ backward (what a `jax.checkpoint`ed layer runs in the backward pass).
+Beside each time: the products of the visited (query block, key block)
+pairs (forward 2 bq bk (d + e), backward 2 bq bk (3 d + 2 e) more; d the
+scores' width, e the values') over the chip's bfloat16 peak.
 
 Needs the chip. Artifact: <out>/attention.json, and the table on standard
 error.
@@ -27,8 +32,11 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 ITERS = 8
-W, KV, T, D = 3, 8, 1024, 128
-LAYERS = (("full", 48, T), ("sliding", 72, 512))  # kind, heads, window
+W, T = 3, 1024
+# kind: (query heads, window, key/value heads, scores' width, values', scale)
+LAYERS = {"full": (48, T, 8, 128, 128, None),
+          "sliding": (72, 512, 8, 128, 128, None),
+          "mla": (128, T, 128, 192, 128, 192 ** -0.5 * 1.2608 ** 2)}
 PAIRS = tuple((bq, bk) for bq in (128, 256, 512) for bk in (128, 256, 512))
 
 
@@ -36,6 +44,10 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="chiprun_out")
     ap.add_argument("--seed", type=int, default=30)
+    ap.add_argument("--layers", default="full,sliding",
+                    help="of " + ",".join(LAYERS))
+    ap.add_argument("--pairs", default="",
+                    help="256x512,... (all nine where empty)")
     args = ap.parse_args(argv)
 
     import jax
@@ -55,22 +67,35 @@ def main(argv=None) -> int:
     dt = jnp.bfloat16
     keys = jax.random.split(jax.random.PRNGKey(args.seed), 4)
     rows = []
-    for kind, heads, window in LAYERS:
-        g = heads // KV
-        q = jax.random.normal(keys[0], (W, KV, g, T, D), jnp.float32)
-        k, v = (jax.random.normal(key, (W, KV, T, D), jnp.float32).astype(dt)
-                for key in keys[1:3])
+    pairs_run = tuple(tuple(int(x) for x in p.split("x"))
+                      for p in args.pairs.split(",") if p) or PAIRS
+    for kind in args.layers.split(","):
+        heads, window, kv, d, e, scale = LAYERS[kind]
+        g = heads // kv
+        q = jax.random.normal(keys[0], (W, kv, g, T, d), jnp.float32)
+        k = jax.random.normal(keys[1], (W, kv, T, d), jnp.float32).astype(dt)
+        v = jax.random.normal(keys[2], (W, kv, T, e), jnp.float32).astype(dt)
         q = q.astype(dt)
-        cot = jax.random.normal(keys[3], q.shape, jnp.float32)
-        own = at.blocks(g, T, D, dt)
+        cot = jax.random.normal(keys[3], q.shape[:-1] + (e,), jnp.float32)
+        own = at.blocks(g, T, d, dt, e)
         # every pair is timed where the chip's compiler takes it; `admitted`
         # are those whose buffers `blocks` counts inside the default VMEM
         admitted = [pair for pair in PAIRS if at._buffers(
-            g, T, D, *pair, dt.dtype.itemsize) <= at._VMEM_BUFFERS]
-        forms = {"einsum": lambda q, k, v: at.plain(q, k, v, window)}
-        for pair in PAIRS:
+            g, T, at._padded(d), *pair, dt.dtype.itemsize, e)
+            <= at._VMEM_BUFFERS]
+        forms = {"einsum": lambda q, k, v: at.plain(q, k, v, window, scale)}
+        for pair in pairs_run:
             forms["kernel_%dx%d" % pair] = (
-                lambda q, k, v, pair=pair: at.fused(q, k, v, window, pair))
+                lambda q, k, v, pair=pair: at.fused(q, k, v, window, pair,
+                                                    scale))
+            if d % 128:  # the scores' width in whole lane tiles, zeros added
+                def padded(q, k, v, pair=pair):
+                    wide = [(0, 0)] * 4 + [(0, at._padded(d) - d)]
+                    return at.fused(jnp.pad(q, wide), jnp.pad(k, wide[1:]),
+                                    v, window, pair,
+                                    scale or d ** -0.5)
+
+                forms["padded256_%dx%d" % pair] = padded
         def gaps(got, want):
             return [float(jnp.max(jnp.abs(a.astype(jnp.float32)
                                           - b.astype(jnp.float32)))
@@ -109,22 +134,24 @@ def main(argv=None) -> int:
                 jax.block_until_ready(out)
         ms = device_program_ms(trace_dir)
         for label in worst:
-            pair = (tuple(int(x) for x in label[7:].split("x"))
+            pair = (tuple(int(x) for x in label.split("_")[1].split("x"))
                     if label != "einsum" else None)
             pairs = len(at.visited(T, window, *pair)) if pair else None
             row = {"layer": kind, "heads": heads, "window": window,
-                   "form": label, "the_programs_own": pair == own,
+                   "form": label, "the_programs_own": (
+                       pair == own and label.startswith("kernel")),
                    "admitted": pair in admitted,
                    "block_share": (round(at.block_share(T, window, *pair), 4)
                                    if pair else 1.0),
                    "gap_to_einsum_out_dq_dk_dv": worst[label]}
-            for passes, products in (("forward", 2), ("both", 7)):
+            for passes, products in (("forward", d + e),
+                                     ("both", 4 * d + 3 * e)):
                 took = sorted(ms.get(f"jit_{programs[label, passes][1]}", []))
                 took = took[len(took) // 2] if took else None
                 row[f"{passes}_ms"] = took and round(took, 4)
                 if pair and took:
                     flop = (W * heads * pairs * products * 2 * pair[0]
-                            * pair[1] * D)
+                            * pair[1])
                     row[f"{passes}_share_of_bf16_peak"] = round(
                         flop / (took * 1e-3) / flops_s, 4)
             rows.append(row)
@@ -134,8 +161,11 @@ def main(argv=None) -> int:
     payload = {"experiment": "attention", **jaxenv.device_info(),
                "timing": "median per-program device duration, "
                          f"{ITERS} calls, jax.profiler trace",
-               "shape": {"windows": W, "kv_heads": KV, "tokens": T,
-                         "head_dim": D, "dtype": "bfloat16"},
+               "shape": {"windows": W, "tokens": T, "dtype": "bfloat16",
+                         "layers": {kind: dict(zip(
+                             ("heads", "window", "kv_heads", "score_width",
+                              "value_width", "scale"), LAYERS[kind]))
+                             for kind in args.layers.split(",")}},
                "rows": rows}
     with open(os.path.join(args.out, "attention.json"), "w") as fp:
         json.dump(payload, fp, indent=1)

@@ -521,3 +521,93 @@ def test_the_attention_at_the_published_shapes_takes_the_kernel(v5e, at,
     assert not [line.strip()[:160] for line in hlo.splitlines()
                 if "f64[" in line or ("s64[" in line
                                       and "parameter(" not in line)]
+
+
+def test_the_latent_attention_at_the_published_shapes_takes_the_kernel(v5e):
+    """DeepSeek-V2's `_attention` as a peer block of 3 sends it (3 windows
+    of 1,024, 128 heads, scores over 192 and values of 128, bfloat16) under
+    `jax.checkpoint` and `jax.grad` compiles for the v5e under x64 with
+    ops/attention.py's kernel as its core, the 192 as they are, and makes
+    NO float32 array of the scores' size."""
+    from biscotti_tpu.models import deepseek_v2
+
+    cfg = deepseek_v2.PRESETS["deepseek_v2_fedlora"]
+    one = SingleDeviceSharding(v5e[0])
+    model = deepseek_v2.deepseek_v2_model("lm", cfg, 1024)
+    assert model.info["attention"] == {"fused": 1, "block_share": 0.75}
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one), tree)
+
+    frozen = on_chip(jax.eval_shape(
+        model.init_frozen, jax.random.PRNGKey(0))["layers"][1])
+    adapters = on_chip(jax.eval_shape(
+        lambda key: jax.tree.map(lambda b: jnp.stack([b] * 3),
+                                 model.init(key)["layers"][1]),
+        jax.random.PRNGKey(0)))
+
+    def loss(adapters, h, frozen):
+        out = jax.checkpoint(lambda h, f, a: deepseek_v2._attention(
+            cfg, h, f, a))(h, frozen, adapters)
+        return jnp.sum(out * out)
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        adapters, jax.ShapeDtypeStruct((3, 1, 1024, cfg.hidden), jnp.float32,
+                                       sharding=one), frozen
+    ).compile().as_text()
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert 2 <= len(calls) <= 3, len(calls)
+    assert any("f32[3,128,1,1024,128]" in c for c in calls)    # the result
+    assert any("bf16[3,128,1,1024,192]" in c for c in calls)   # q, dq
+    assert any("mla_core" in c for c in calls)
+    square = re.compile(r"f32\[([\d,]*1024,1024)\]")
+    made = [line.strip()[:160] for line in hlo.splitlines()
+            for dims in square.findall(line)
+            if math.prod(int(v) for v in dims.split(",")) > 3 * 1024 * 1024]
+    assert not made, made[:5]
+    assert not [line.strip()[:160] for line in hlo.splitlines()
+                if "f64[" in line or ("s64[" in line
+                                      and "parameter(" not in line)]
+
+
+def test_experts_of_5120_by_1536_take_the_kernel_in_column_tiles(v5e):
+    """`held_experts` at DeepSeek-V2's published shapes (a peer block of 3:
+    3,072 tokens, six a token, 40 of 160 experts held, H = 5,120, F =
+    1,536, bfloat16): one expert's weight is 15.7 MB, past what the
+    kernel's buffers hold whole, so `column_tile` cuts it (256 columns of
+    the 1,536, 1,024 of the 5,120) and every grouped product is still
+    ops/grouped_matmul.py's."""
+    from biscotti_tpu.ops import grouped_matmul, moe
+
+    one = SingleDeviceSharding(v5e[0])
+    n, k, e, total, h, f = 3072, 6, 40, 160, 5120, 1536
+    tile = grouped_matmul.row_tile(n * k / total)
+    assert tile == 128
+    assert grouped_matmul.column_tile(n * k, h, f, jnp.bfloat16, tile) == 256
+    assert grouped_matmul.column_tile(n * k, f, h, jnp.bfloat16, tile) == 1024
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    weights = {"w_gate": shape((e, h, f), jnp.bfloat16),
+               "w_up": shape((e, h, f), jnp.bfloat16),
+               "w_down": shape((e, f, h), jnp.bfloat16)}
+
+    def loss(x, coef, experts, weights):
+        out, counts = moe.held_experts(x, experts, coef, weights, 0, total)
+        return jnp.sum(out * out), counts
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True)).lower(
+        shape((n, h), jnp.float32), shape((n, k), jnp.float32),
+        shape((n, k), jnp.int32), weights).compile().as_text()
+    assert "ragged-dot" not in hlo
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) >= 12, len(calls)  # six a side of the `lax.cond`
+    stack = re.compile(r" = (bf16|f32)\[40,(5120,1536|1536,5120)\]")
+    made = [line.strip()[:160] for line in hlo.splitlines()
+            if stack.search(line) and "parameter(" not in line
+            and "get-tuple-element" not in line]
+    assert not made, made[:5]
