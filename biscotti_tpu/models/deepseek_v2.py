@@ -234,12 +234,9 @@ def _mlp(cfg, at, h, frozen):
     with jax.named_scope("lm_dense"):
         shared = lm.swiglu(x, frozen["shared"])
     with jax.named_scope("lm_experts"):
-        # the uncut buffer alone: the cut one's `lax.cond` would hold a
-        # copy of every layer's expert stacks (ops/moe.py)
         routed, counts = moe.held_experts(x, experts, coef,
                                           frozen["experts"],
-                                          cfg.first_expert, cfg.num_experts,
-                                          capacity=None)
+                                          cfg.first_expert, cfg.num_experts)
     counts = dict(counts, groups_spanned=spanned,
                   tokens=jnp.asarray(x.shape[0], jnp.int32))
     return shared + routed, counts, (experts, probs)

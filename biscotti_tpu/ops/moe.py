@@ -15,15 +15,17 @@ assignments are sorted by expert (those of experts held elsewhere last) and
 ONE grouped product a weight runs over the held rows, the groups' sizes the
 held experts' loads: the rows past them are in no group, cost no product
 and come out zeros. The sorted buffer is cut to CAPACITY times the rows a
-uniform router would send here, and a round whose held rows pass that runs
-the same code on the uncut buffer instead (`lax.cond`): slower, never lossy.
-A caller may ask for the uncut buffer alone (`capacity=None`): under
-`jax.grad` the `lax.cond` hands the frozen expert weights out of its
-branches as residuals, a copy of every stack a layer, held for the whole
-program (7.03 GB for four layers of 40 experts of 5,120 x 1,536 by the
-compiled round's memory analysis; PERF.md section 6, PR 31), where the
-rows the cut saves are a few hundred megabytes and cost no product (the
-kernel's tail tiles read no operand).
+uniform router would send here, and a call whose held rows pass that runs
+the same code on the uncut buffer instead (`lax.cond`): slower, never lossy
+(`counts["uncut"]` says which ran). The choice is made where no residual of
+a differentiated program crosses it (`_routed`, a `custom_vjp` in the rows
+and their coefficients): the forward chooses outside any differentiation
+and keeps only its own inputs, the backward chooses again and runs the
+chosen side's forward and transpose inside ONE branch, handing out the two
+cotangents and nothing else. A `lax.cond` that `jax.grad` splits hands the
+frozen expert stacks out of its branches as residuals instead, and the
+compiled round holds a copy of every stack (7.03 GB at DeepSeek-V2's sizes;
+PERF.md section 6, PRs 31 and 32).
 
 The product's time follows the (group, row tile) pairs it visits, not its
 rows (PERF.md section 6, PR 28: the compiler's `ragged-dot` walks row tiles
@@ -150,10 +152,71 @@ def _plan(buffers, h: int, f: int, dtype, rows_a_group: float) -> int:
     return tile if taken else 0
 
 
-@partial(jax.jit, static_argnames=("first", "total", "capacity"))
+def _part(buffer, tile, x, coef, weights, order, inverse, held, load):
+    """The held rows' result through a sorted buffer of `buffer` rows
+    (static), all of them in it: float32[N, H]. `tile`: the kernel's row
+    tile, 0 for the compiler's `ragged_dot`."""
+    k = coef.shape[1]
+    dtype = weights["w_gate"].dtype
+    if tile:
+        dot = partial(grouped_matmul.grouped, sizes=load, tm=tile)
+    else:
+        # the rows past the held ones are in no group here either: zeros
+        dot = partial(jax.lax.ragged_dot, group_sizes=load,
+                      preferred_element_type=jnp.float32)
+    token, slot = order[:buffer] // k, order[:buffer] % k
+    valid = held & (inverse < buffer)
+    where = jnp.where(valid, inverse, buffer)
+    xs = _dispatch(x, token, where, valid)
+    hidden = jax.nn.silu(dot(xs, weights["w_gate"])) \
+        * dot(xs, weights["w_up"])
+    ys = dot(hidden.astype(dtype), weights["w_down"])
+    return _combine(ys, jnp.where(valid, coef, 0.0), token, slot, where)
+
+
+def _either(buffers, load, side):
+    """`side(rows)` of the cut buffer where the held rows fit it, of the
+    uncut one where they do not; `buffers` = (cut, uncut) rows, static."""
+    cut, uncut = buffers
+    if cut == uncut:
+        return side(uncut)
+    return jax.lax.cond(jnp.sum(load, dtype=jnp.int32) <= cut,
+                        lambda: side(cut), lambda: side(uncut))
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _routed(buffers, tile, x, coef, *sort):
+    """`_part` on the side of `_either` the call's load picks. Its rule
+    keeps the arguments and nothing a branch made; the backward picks
+    again and differentiates the picked side inside its branch (the
+    forward it runs there is the layer's recomputation: the rule's own
+    forward feeds nothing under `jax.checkpoint` and is dropped). `sort`:
+    weights, order, inverse, held, load (no cotangent: the experts are
+    frozen, the rest integers)."""
+    return _either(buffers, sort[-1], lambda rows: _part(
+        rows, tile, x, coef, *sort))
+
+
+def _routed_fwd(buffers, tile, x, coef, *sort):
+    return _routed(buffers, tile, x, coef, *sort), (x, coef, sort)
+
+
+def _routed_bwd(buffers, tile, res, g):
+    x, coef, sort = res
+
+    def back(rows):
+        return jax.vjp(lambda x, coef: _part(rows, tile, x, coef, *sort),
+                       x, coef)[1](g)
+
+    return (*_either(buffers, sort[-1], back), *(None,) * len(sort))
+
+
+_routed.defvjp(_routed_fwd, _routed_bwd)
+
+
+@partial(jax.jit, static_argnames=("first", "total"))
 def held_experts(x: jax.Array, experts: jax.Array, coef: jax.Array,
-                 weights: dict, first: int = 0, total: int = 0,
-                 capacity=CAPACITY):
+                 weights: dict, first: int = 0, total: int = 0):
     """Σ over the token's assignments that land on a held expert of
     coefficient · SwiGLU_e(x): float32[N, H], and what the dispatch
     counted.
@@ -162,8 +225,7 @@ def held_experts(x: jax.Array, experts: jax.Array, coef: jax.Array,
     `total` experts of the model; 0: the held ones are all there are);
     weights: `w_gate`, `w_up` [E, H, F], `w_down` [E, F, H], the E experts
     `first .. first + E - 1`. The products run in the weights' dtype with
-    float32 accumulation. `capacity`: the sorted buffer's cut, x the rows a
-    uniform router sends here (None: the uncut buffer alone, no `lax.cond`).
+    float32 accumulation.
 
     counts: `load` int32[E] assignments a held expert; `dropped` int32:
     held assignments that reached no row of the sorted buffer (0 by
@@ -171,7 +233,9 @@ def held_experts(x: jax.Array, experts: jax.Array, coef: jax.Array,
     `tile_rows` int32: the rows of the (group, row tile) pairs one grouped
     product visits, `load`'s sum over it the tiles' fill; `grouped_kernel`
     int32: 1 where the product is ops/grouped_matmul.py's, 0 the
-    compiler's."""
+    compiler's; `buffer_rows` int32: the cut buffer's rows (static);
+    `uncut` int32: 1 where the call ran on the uncut buffer, its held rows
+    more than those."""
     n, k = experts.shape
     e, h, f = weights["w_gate"].shape
     dtype = weights["w_gate"].dtype
@@ -186,38 +250,16 @@ def held_experts(x: jax.Array, experts: jax.Array, coef: jax.Array,
     load = jnp.sum(group[:, None] == jnp.arange(e, dtype=jnp.int32)[None],
                    axis=0, dtype=jnp.int32)
     rows = jnp.sum(load, dtype=jnp.int32)
-    x = x.astype(dtype)
-
-    def part(capacity):
-        """The held rows' result through a sorted buffer of `capacity`
-        rows (static), all of them in it."""
-        token, slot = order[:capacity] // k, order[:capacity] % k
-        valid = held & (inverse.reshape(n, k) < capacity)
-        where = jnp.where(valid, inverse.reshape(n, k), capacity)
-        xs = _dispatch(x, token, where, valid)
-        hidden = jax.nn.silu(dot(xs, weights["w_gate"])) \
-            * dot(xs, weights["w_up"])
-        ys = dot(hidden.astype(dtype), weights["w_down"])
-        return _combine(ys, jnp.where(valid, coef, 0.0), token, slot, where)
-
     uniform = n * k / max(total, e)  # rows a group, of a uniform router
-    capacity = n * k if capacity is None else min(
-        n * k, -(-int(capacity * uniform * e) // 8) * 8)
-    tile = _plan((capacity, n * k), h, f, dtype, uniform)
-    if tile:
-        dot = partial(grouped_matmul.grouped, sizes=load, tm=tile)
-    else:
-        # the rows past the held ones are in no group here either: zeros
-        dot = partial(jax.lax.ragged_dot, group_sizes=load,
-                      preferred_element_type=jnp.float32)
+    cut = min(n * k, -(-int(CAPACITY * uniform * e) // 8) * 8)
+    tile = _plan((cut, n * k), h, f, dtype, uniform)
+    out = _routed((cut, n * k), tile, x.astype(dtype), coef, weights, order,
+                  inverse.reshape(n, k), held, load)
     walked = tile or grouped_matmul.COMPILER_ROW_TILE
-    if capacity == n * k:
-        out = part(n * k)
-    else:
-        out = jax.lax.cond(rows <= capacity, lambda: part(capacity),
-                           lambda: part(n * k))
     counts = {"load": load,
               "dropped": jnp.sum(held, dtype=jnp.int32) - rows,
               "tile_rows": walked * grouped_matmul.tile_visits(load, walked),
-              "grouped_kernel": jnp.asarray(bool(tile), jnp.int32)}
+              "grouped_kernel": jnp.asarray(bool(tile), jnp.int32),
+              "buffer_rows": jnp.asarray(cut, jnp.int32),
+              "uncut": (rows > cut).astype(jnp.int32)}
     return out, counts

@@ -544,6 +544,16 @@ class Simulator:
                             "ops/grouped_matmul.py's kernel, 0 the "
                             "compiler's ragged_dot").set(
                         moe["grouped_kernel"])
+                    m.gauge("biscotti_moe_uncut_calls",
+                            "calls of the last round's expert layers that "
+                            "ran on the uncut sorted buffer; 0 unless a "
+                            "block's held rows pass CAPACITY x the uniform "
+                            "router's").set(moe["uncut_calls"])
+                    m.gauge("biscotti_moe_buffer_rows",
+                            "rows of the sorted buffer a call of an expert "
+                            "layer runs on (the cut one: CAPACITY x the "
+                            "uniform router's, from the shapes)").set(
+                        moe["buffer_rows"])
                     if "groups_kept" in moe:
                         m.gauge("biscotti_moe_groups_kept",
                                 "groups of experts a token's chosen "
@@ -619,13 +629,17 @@ class Simulator:
         assignments that reached no expert (must read 0); `tile_fill`, held
         rows over the rows of the (group, row tile) pairs the grouped
         products visited, all calls; `grouped_kernel`, 1.0 where those
-        products are ops/grouped_matmul.py's; and, where the router limits
-        a token to some groups of experts (models/deepseek_v2.py),
+        products are ops/grouped_matmul.py's; `uncut_calls`, the (block,
+        sparse layer) calls that ran on the uncut sorted buffer (0 unless a
+        block's held rows pass `moe.CAPACITY` x the uniform router's);
+        `buffer_rows`, the cut buffer's rows a call; and, where the router
+        limits a token to some groups of experts (models/deepseek_v2.py),
         `groups_kept`, the groups a token's chosen experts lie in, mean over
         tokens and sparse layers. From `load` int32[layers, held experts],
-        `dropped`, `tile_rows` and `grouped_kernel` (and `groups_spanned`,
-        `tokens`), which the round returns; reads them back: call it
-        outside a timed round."""
+        `dropped`, `tile_rows`, `grouped_kernel`, `uncut` and `buffer_rows`
+        (and `groups_spanned`, `tokens`), which the round returns summed
+        over its peer blocks; reads them back: call it outside a timed
+        round."""
         counts = self.last_counts if counts is None else counts
         if "load" not in counts:
             return {}
@@ -644,6 +658,10 @@ class Simulator:
                 np.asarray(counts["tile_rows"], np.float64).sum(), 1.0)),
             "grouped_kernel": float(np.asarray(
                 counts["grouped_kernel"]).any()),
+            "uncut_calls": float(np.asarray(counts["uncut"]).sum()),
+            "buffer_rows": float(
+                np.asarray(counts["buffer_rows"], np.float64).sum()
+                / (load.shape[0] * self.cfg.num_samples / self.peer_block)),
         }
 
     def test_error(self, w) -> float:

@@ -1,19 +1,22 @@
 #!/usr/bin/env python
-"""One grouped product alone, at the shapes the Laguna round sends it: the
-compiler's `ragged-dot` against ops/grouped_matmul.py at each row tile,
-timed from the DEVICE trace (per-program durations).
+"""One grouped product alone, at the shapes a language model's round sends
+it: the compiler's `ragged-dot` against ops/grouped_matmul.py at each row
+tile, timed from the DEVICE trace (per-program durations).
 
 The round's three products a sparse layer and their activation backward
-are, a peer block of 3: `[15,360, 3,072] x [64, 3,072, 1,024]` (`w_gate`,
-`w_up`), `[15,360, 1,024] x [64, 1,024, 3,072]` (`w_down`), the two read
-transposed (the backward), and all four at 30,720 rows (the uncut side of
-ops/moe.py's `lax.cond`). The groups are drawn as the cell draws them: 64
-groups, 120 rows the mean, the fullest about three times that, the rest of
-the buffer in no group. The compiler's call is timed both ways: with those
+are, a peer block of 3 of Laguna's: `[15,360, 3,072] x [64, 3,072, 1,024]`
+(`w_gate`, `w_up`), `[15,360, 1,024] x [64, 1,024, 3,072]` (`w_down`), the
+two read transposed (the backward), and all four at 30,720 rows (the uncut
+side of ops/moe.py's `lax.cond`). The groups are drawn as the cell draws
+them: 64 groups, 120 rows the mean, the fullest about three times that,
+the rest of the buffer in no group. `--shapes deepseek_v2` is that model's
+block: 40 groups of 115 rows in buffers of 9,216 and 18,432 rows, K | N =
+5,120 | 1,536 and 1,536 | 5,120 (what a call pays for its tail: PERF.md
+section 6, PR 32). The compiler's call is timed both ways: with the tail's
 rows added to the last group (the program before PR 28) and left out.
 
-Needs the chip. Artifact: <out>/grouped_matmul.json, and the table on
-standard error.
+Needs the chip. Artifact: <out>/grouped_matmul_<shapes>.json, and the table
+on standard error.
 """
 
 from __future__ import annotations
@@ -28,18 +31,19 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 ITERS = 8
-E, H, F = 64, 3072, 1024
-ROWS = (15360, 30720)  # the cut buffer of a peer block of 3, and the uncut
-HELD = 7680
+# held experts, hidden size, expert width, (the cut buffer of a peer block
+# of 3, the uncut one), the held rows a uniform router sends
+SHAPES = {"laguna": (64, 3072, 1024, (15360, 30720), 7680),
+          "deepseek_v2": (40, 5120, 1536, (9216, 18432), 4608)}
 
 
-def draw_sizes(rng, held_rows):
-    """64 group sizes that add up to `held_rows`, the fullest about three
+def draw_sizes(rng, groups, held_rows):
+    """`groups` sizes that add up to `held_rows`, the fullest about three
     times the mean (a Zipf vocabulary behind a random router, PERF.md
     section 5)."""
     import numpy as np
 
-    p = np.exp(0.6 * rng.normal(size=E))
+    p = np.exp(0.6 * rng.normal(size=groups))
     return rng.multinomial(held_rows, p / p.sum()).astype(np.int32)
 
 
@@ -47,6 +51,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="chiprun_out")
     ap.add_argument("--seed", type=int, default=28)
+    ap.add_argument("--shapes", choices=sorted(SHAPES), default="laguna")
     ap.add_argument("--column-tiles", default="",
                     help="also time the kernel at these column tiles "
                          "(the program's own choice is always timed)")
@@ -65,21 +70,25 @@ def main(argv=None) -> int:
     if jax.default_backend() != "tpu":
         print("a device time comes only from the chip", file=sys.stderr)
         return 2
+    groups, hidden, width, buffers, held_rows = SHAPES[args.shapes]
     rng = np.random.default_rng(args.seed)
     extra = [int(t) for t in args.column_tiles.split(",") if t]
     rows = []
-    for c in ROWS:
-        sizes = draw_sizes(rng, HELD)
+    for c in buffers:
+        sizes = draw_sizes(rng, groups, held_rows)
         padded = sizes.copy()
         padded[-1] += c - sizes.sum()
-        for k, n, transposed in ((H, F, False), (F, H, False),
-                                 (H, F, True), (F, H, True)):
+        for k, n, transposed in ((hidden, width, False),
+                                 (width, hidden, False),
+                                 (hidden, width, True),
+                                 (width, hidden, True)):
             # transposed: xs [C, k] against w [E, n, k], the backward of
             # the product whose weights are [E, n, k]
             dt = jnp.bfloat16
             xs = jnp.asarray(rng.normal(size=(c, k)), dt)
-            w = jnp.asarray(rng.normal(size=(E, n, k) if transposed
-                                       else (E, k, n)) / np.sqrt(k), dt)
+            w = jnp.asarray(rng.normal(size=(groups, n, k) if transposed
+                                       else (groups, k, n)) / np.sqrt(k),
+                            dt)
             programs = {}
 
             def compilers(xs, w, sz):
@@ -102,6 +111,8 @@ def main(argv=None) -> int:
                     compilers(xs, w, sz))
             for tm in gm.ROW_TILES:
                 own = gm.column_tile(c, k, n, dt, tm)  # the program's
+                if own is None:  # no column tile fits VMEM at this row tile
+                    continue
                 for tn in sorted({own, *extra}):
                     if n % tn:
                         continue
@@ -144,11 +155,13 @@ def main(argv=None) -> int:
             print(json.dumps(row), file=sys.stderr, flush=True)
 
     os.makedirs(args.out, exist_ok=True)
-    payload = {"experiment": "grouped_matmul", **jaxenv.device_info(),
+    payload = {"experiment": "grouped_matmul", "shapes": args.shapes,
+               **jaxenv.device_info(),
                "timing": "median per-program device duration, "
                          f"{ITERS} calls, jax.profiler trace",
                "rows": rows}
-    with open(os.path.join(args.out, "grouped_matmul.json"), "w") as fp:
+    with open(os.path.join(args.out, f"grouped_matmul_{args.shapes}.json"),
+              "w") as fp:
         json.dump(payload, fp, indent=1)
     best = [min((v, k) for k, v in r["device_ms"].items()
                 if v and k.startswith("kernel"))

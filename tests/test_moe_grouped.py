@@ -87,16 +87,24 @@ def _layer(seed, crowded):
     return x, experts, coef, weights
 
 
+def _held(x, experts, coef, weights):
+    # under the `jit` of `held_experts`: its cache cannot see `_plan`
+    return moe.held_experts.__wrapped__(x, experts, coef, weights, 0, 16)
+
+
+@pytest.mark.parametrize("remat", [False, True],
+                         ids=["grad", "checkpoint_grad"])
 @pytest.mark.parametrize("crowded", [False, True],
                          ids=["the_cut_buffer", "the_uncut_buffer"])
-def test_the_layer_is_the_same_on_both_sides_of_the_dispatch(crowded,
+def test_the_layer_is_the_same_on_both_sides_of_the_dispatch(crowded, remat,
                                                              monkeypatch):
     x, experts, coef, weights = _layer(3, crowded)
 
     def run(x, coef):
-        # under the `jit` of `held_experts`: its cache cannot see `_plan`
-        out, counts = moe.held_experts.__wrapped__(x, experts, coef,
-                                                   weights, 0, 16)
+        # as models/lm.py's decoder has it: the layer rematerialised (a
+        # function of this trace: `checkpoint` keeps what it traced)
+        layer = jax.checkpoint(lambda *a: _held(*a)) if remat else _held
+        out, counts = layer(x, experts, coef, weights)
         return jnp.sum(out * jnp.cos(out)), (out, counts)
 
     grad = jax.jit(jax.value_and_grad(run, argnums=(0, 1), has_aux=True))
@@ -105,6 +113,9 @@ def test_the_layer_is_the_same_on_both_sides_of_the_dispatch(crowded,
     assert int(counts["dropped"]) == 0
     held = int(counts["load"].sum())
     assert held == 512 if crowded else 0 < held <= 256
+    # the mechanism's counter: which buffer the call ran on
+    assert int(counts["buffer_rows"]) == 256 == moe.CAPACITY * 128 * 4 / 16 * E
+    assert int(counts["uncut"]) == int(crowded)
     assert int(counts["tile_rows"]) == TILE * int(
         gm.tile_visits(counts["load"], TILE)) >= held
     monkeypatch.setattr(moe, "_plan", lambda *a: 0)
@@ -118,3 +129,95 @@ def test_the_layer_is_the_same_on_both_sides_of_the_dispatch(crowded,
         scale = float(jnp.max(jnp.abs(theirs)))
         assert scale > 0
         np.testing.assert_allclose(mine, theirs, atol=1e-5 * scale, rtol=0)
+
+
+def _products(jaxpr, found):
+    """Grouped products in `jaxpr` and every jaxpr nested in it, by the
+    rows of their result: {rows: count}. The kernel's are `pallas_call`s,
+    one a platform `_run` lowers for (`platform_dependent`): the chip's."""
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name == "ragged_dot_general" or (
+                name == "pallas_call" and not eqn.params["interpret"]):
+            rows = eqn.outvars[0].aval.shape[0]
+            found[rows] = found.get(rows, 0) + 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _products(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("side", ["kernel", "compiler"])
+def test_a_rematerialised_layers_gradient_has_nine_products_a_side(
+        side, monkeypatch):
+    """Under `jax.checkpoint` and `jax.grad` (models/lm.py's decoder) a
+    sparse layer costs 9 grouped products on the buffer it runs on: the
+    primal's 3 and, in the backward's ONE branch, 3 recomputed and 3
+    transposed. The layer's recomputed forward feeds nothing (the experts'
+    result enters the layer's output by a sum) and is gone from the
+    program: 12 would be its three kept."""
+    if side == "compiler":
+        monkeypatch.setattr(moe, "_plan", lambda *a: 0)
+    x, experts, coef, weights = _layer(3, False)
+
+    def loss(x, coef):
+        out = jax.checkpoint(lambda x, coef: x + _held(
+            x, experts, coef, weights)[0])(x, coef)
+        return jnp.sum(out * jnp.cos(out))
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(x, coef)
+    assert _products(jaxpr.jaxpr, {}) == {256: 9, 512: 9}
+    # the choice is made twice, and what the backward's hands out is the
+    # two cotangents: no residual crosses it
+    choices = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "cond":
+                sides = [_products(b.jaxpr, {}) for b in
+                         eqn.params["branches"]]
+                if sorted(map(sorted, sides)) == [[256], [512]]:
+                    choices.append(([v.aval.shape for v in eqn.outvars],
+                                    sorted(n for s in sides
+                                           for n in s.values())))
+                    continue
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr.jaxpr)
+    assert sorted(choices) == [([(128, 128)], [3, 3]),
+                               ([(128, 128), (128, 4)], [6, 6])]
+
+
+@pytest.mark.parametrize("model", ["laguna_tiny", "deepseek_v2_tiny"])
+def test_a_round_counts_its_uncut_calls_and_its_buffers_rows(model):
+    """Both models make the same call: a tiny round of each, its four
+    sampled peers walked in two blocks, runs every (block, sparse layer)
+    call on a sorted buffer of CAPACITY x the uniform router's rows and
+    says so (`dispatch_stats`, the two gauges)."""
+    from biscotti_tpu.config import BiscottiConfig, Defense
+    from biscotti_tpu.parallel.sim import Simulator
+    from biscotti_tpu.telemetry import MetricsRegistry
+
+    registry = MetricsRegistry()
+    sim = Simulator(BiscottiConfig(
+        dataset="lm_tokens_tiny", model_name=model, num_nodes=6,
+        batch_size=2, epsilon=1.0, noising=True, verification=True,
+        defense=Defense.KRUM, sample_percent=1.0, num_verifiers=1,
+        num_miners=1, num_noisers=1, learning_rate=0.1, grad_clip=0.05,
+        seed=9), metrics=registry)
+    sim.steps.block = 2  # before the round is traced
+    sim.run(num_rounds=1, stop_at_convergence=False)
+    cfg = sim.model.info["config"]
+    assert (cfg.num_experts, cfg.experts_held, cfg.top_k) == (16, 4, 3)
+    tokens = 2 * 2 * 16  # a block: 2 peers x 2 windows of 16
+    rows = moe.CAPACITY * tokens * 3 / 16 * 4
+    assert rows == 96 < tokens * 3
+    counts = sim.last_counts  # a sparse layer, summed over the two blocks
+    np.testing.assert_array_equal(counts["buffer_rows"], [2 * rows] * 2)
+    np.testing.assert_array_equal(counts["uncut"], [0, 0])
+    stats = sim.dispatch_stats()
+    assert stats["buffer_rows"] == rows and stats["uncut_calls"] == 0
+    assert stats["tokens_dropped"] == 0
+    page = registry.render()
+    assert "biscotti_moe_uncut_calls 0" in page
+    assert "biscotti_moe_buffer_rows 96" in page

@@ -213,8 +213,9 @@ def test_walked_peer_axis_gives_the_vmapped_deltas(kind, block):
     assert jax.tree.structure(counts) == jax.tree.structure(whole_counts)
     assert bool(counts) == (kind == "laguna_tiny")
     for name in counts:
-        if name in ("tile_rows", "grouped_kernel"):
-            continue  # a call's own: the row tiles IT visited, its side
+        if name in ("tile_rows", "grouped_kernel", "buffer_rows"):
+            continue  # a call's own: the row tiles IT visited, its side,
+            # its buffer's rows
         np.testing.assert_array_equal(counts[name], whole_counts[name])
     if counts:  # every held row lies in a visited tile, however blocked
         assert (np.asarray(counts["tile_rows"])

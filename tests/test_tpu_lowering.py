@@ -322,15 +322,17 @@ def _lowered_round(sim):
 # sha256[:16] of the lowered round at these shapes on the commits that had
 # it first, read there with this very function: the classifiers' on c71d5aa
 # (before `Model` had a frozen tree, a declared step rule or a walked peer
-# axis; less the last argument), the language model's on 430e04f (before
-# the gather and the walk moved to models/peer_step.py)
+# axis; less the last argument), the language model's two on PR 32's tree,
+# the commit after ffcbf59, for one reason: the routed experts' part of
+# ops/moe.py became a `custom_vjp` (they were 296d45bcd51e4a6d and
+# 3a2777fe75774ef2 from 430e04f to ffcbf59)
 PARENT_ROUNDS = {
     ("mnist", "softmax"): "2f1f0d7efd64ce2c",
     ("creditcard", ""): "04e1c79a9ba9c99e",
     ("mnist", "mnist_cnn"): "0cb8d0fa17f1cd73",
-    ("lm_tokens_tiny", ""): "296d45bcd51e4a6d",
+    ("lm_tokens_tiny", ""): "f9cffd8297eaba60",
 }
-WALKED_IN_THREES = "3a2777fe75774ef2"  # the same, the peer axis in two blocks
+WALKED_IN_THREES = "7ae27af2f9aa6149"  # the same, the peer axis in two blocks
 
 LM_TINY = dict(dataset="lm_tokens_tiny", num_nodes=8, batch_size=2,
                sample_percent=1.0, num_verifiers=1, num_miners=1,
@@ -422,16 +424,22 @@ def test_the_language_model_round_compiles_for_v5e(v5e, sim_lm):
     assert not wide, wide[:5]
 
 
-def test_the_expert_layer_at_the_published_shapes_takes_the_kernel(v5e):
-    """`held_experts` under `jax.grad` as a peer block of 3 sends it
-    (3,072 tokens, ten a token, 64 of 256 experts held, bfloat16) compiles
-    for the v5e under x64, and every grouped product of it, on the cut
-    buffer and on the uncut one, forward and backward, is
-    ops/grouped_matmul.py's kernel: no `ragged-dot` is left."""
+# (experts a token, held, of all, hidden size, expert width) as published
+EXPERTS = {"deepseek_v2": (6, 40, 160, 5120, 1536),
+           "laguna": (10, 64, 256, 3072, 1024)}
+
+
+def _experts_gradient(device, model, remat=False):
+    """`held_experts`' gradient in the rows and their coefficients as a
+    peer block of 3 sends it (3,072 tokens, `model`'s published expert
+    shapes, bfloat16), compiled for the chip; with `remat`, inside a layer
+    that adds the result to its input, rematerialised as models/lm.py's
+    decoder has it."""
     from biscotti_tpu.ops import moe
 
-    one = SingleDeviceSharding(v5e[0])
-    n, k, e, total, h, f = 3072, 10, 64, 256, 3072, 1024
+    one = SingleDeviceSharding(device)
+    k, e, total, h, f = EXPERTS[model]
+    n = 3072
 
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
@@ -440,13 +448,26 @@ def test_the_expert_layer_at_the_published_shapes_takes_the_kernel(v5e):
                "w_up": shape((e, h, f), jnp.bfloat16),
                "w_down": shape((e, f, h), jnp.bfloat16)}
 
-    def loss(x, coef, experts, weights):
+    def layer(x, coef, experts, weights):
         out, counts = moe.held_experts(x, experts, coef, weights, 0, total)
+        return (x + out if remat else out), counts
+
+    def loss(*args):
+        out, counts = (jax.checkpoint(layer) if remat else layer)(*args)
         return jnp.sum(out * out), counts
 
-    hlo = jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True)).lower(
+    return jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True)).lower(
         shape((n, h), jnp.float32), shape((n, k), jnp.float32),
-        shape((n, k), jnp.int32), weights).compile().as_text()
+        shape((n, k), jnp.int32), weights).compile()
+
+
+def test_the_expert_layer_at_the_published_shapes_takes_the_kernel(v5e):
+    """`held_experts` under `jax.grad` as a peer block of 3 sends it
+    (3,072 tokens, ten a token, 64 of 256 experts held, bfloat16) compiles
+    for the v5e under x64, and every grouped product of it, on the cut
+    buffer and on the uncut one, forward and backward, is
+    ops/grouped_matmul.py's kernel: no `ragged-dot` is left."""
+    hlo = _experts_gradient(v5e[0], "laguna").as_text()
     assert "ragged-dot" not in hlo
     calls = [line for line in hlo.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
@@ -579,29 +600,17 @@ def test_experts_of_5120_by_1536_take_the_kernel_in_column_tiles(v5e):
     kernel's buffers hold whole, so `column_tile` cuts it (256 columns of
     the 1,536, 1,024 of the 5,120) and every grouped product is still
     ops/grouped_matmul.py's."""
-    from biscotti_tpu.ops import grouped_matmul, moe
+    from biscotti_tpu.ops import grouped_matmul
 
-    one = SingleDeviceSharding(v5e[0])
-    n, k, e, total, h, f = 3072, 6, 40, 160, 5120, 1536
+    n, (k, e, total, h, f) = 3072, EXPERTS["deepseek_v2"]
     tile = grouped_matmul.row_tile(n * k / total)
     assert tile == 128
-    assert grouped_matmul.column_tile(n * k, h, f, jnp.bfloat16, tile) == 256
-    assert grouped_matmul.column_tile(n * k, f, h, jnp.bfloat16, tile) == 1024
-
-    def shape(dims, dtype):
-        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
-
-    weights = {"w_gate": shape((e, h, f), jnp.bfloat16),
-               "w_up": shape((e, h, f), jnp.bfloat16),
-               "w_down": shape((e, f, h), jnp.bfloat16)}
-
-    def loss(x, coef, experts, weights):
-        out, counts = moe.held_experts(x, experts, coef, weights, 0, total)
-        return jnp.sum(out * out), counts
-
-    hlo = jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True)).lower(
-        shape((n, h), jnp.float32), shape((n, k), jnp.float32),
-        shape((n, k), jnp.int32), weights).compile().as_text()
+    for rows in (n * k // 2, n * k):  # the cut buffer and the uncut one
+        assert grouped_matmul.column_tile(rows, h, f, jnp.bfloat16,
+                                          tile) == 256
+        assert grouped_matmul.column_tile(rows, f, h, jnp.bfloat16,
+                                          tile) == 1024
+    hlo = _experts_gradient(v5e[0], "deepseek_v2").as_text()
     assert "ragged-dot" not in hlo
     calls = [line for line in hlo.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
@@ -611,3 +620,42 @@ def test_experts_of_5120_by_1536_take_the_kernel_in_column_tiles(v5e):
             if stack.search(line) and "parameter(" not in line
             and "get-tuple-element" not in line]
     assert not made, made[:5]
+
+
+# temporaries of one sparse layer's rematerialised gradient, bytes: read
+# here at 1,238,101,504 (DeepSeek-V2's shapes) and 1,237,422,592 (Laguna's);
+# with the `lax.cond` that `jax.grad` split (ffcbf59, by this very program)
+# they read as below: its backward's branches handed the stacks out
+EXPERTS_TEMPORARIES = 1_500_000_000
+SPLIT_COND_TEMPORARIES = {"deepseek_v2": 2_555_475_968,
+                          "laguna": 2_650_463_232}
+
+
+@pytest.mark.parametrize("model", sorted(EXPERTS))
+def test_a_sparse_layers_gradient_holds_no_copy_of_an_expert_stack(v5e,
+                                                                   model):
+    """The choice between the cut and the uncut sorted buffer is made where
+    no residual crosses it (ops/moe.py: `_routed`): compiled for the v5e,
+    a rematerialised sparse layer's gradient at the published expert shapes
+    makes no array of a stack's shape, copies none, hands none out of a
+    `conditional`, and its temporaries stay under a bound that the split
+    `lax.cond` passed by more than a stack (629 MB and 403 MB)."""
+    _, e, _, h, f = EXPERTS[model]
+    compiled = _experts_gradient(v5e[0], model, remat=True)
+    hlo = compiled.as_text()
+    stack = rf"bf16\[{e},({h},{f}|{f},{h})\]"
+    lines = [line.strip() for line in hlo.splitlines()]
+    made = [line[:160] for line in lines
+            if re.search(" = " + stack, line) and "parameter(" not in line
+            and "get-tuple-element(" not in line]
+    assert not made, made[:5]
+    handed = [line[:160] for line in lines if " conditional(" in line
+              and re.search(stack, line.split(" conditional(")[0])]
+    assert not handed, handed[:5]
+    assert len([line for line in lines if " conditional(" in line]) == 2
+    calls = [line for line in lines
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 18  # 9 a side: primal 3, recomputed 3, transposed 3
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    assert temporaries < EXPERTS_TEMPORARIES, temporaries
+    assert EXPERTS_TEMPORARIES + 2 * e * h * f < SPLIT_COND_TEMPORARIES[model]

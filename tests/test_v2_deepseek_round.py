@@ -19,6 +19,7 @@ from biscotti_tpu.models import deepseek_v2, laguna, lm
 from biscotti_tpu.models.trainer import (Trainer, block_step_fn,
                                          local_step_fn)
 from biscotti_tpu.models.zoo import MODELS, model_for_dataset
+from biscotti_tpu.ops import moe
 from biscotti_tpu.parallel.sim import Simulator
 
 DATASET = "lm_tokens_tiny"
@@ -90,6 +91,11 @@ def test_a_block_of_peers_is_each_peer_alone(tiny, side):
                                rtol=1e-4)
     assert counts["load"].shape == (2, 4)
     assert int(counts["dropped"].sum()) == 0
+    # the sorted buffer is cut as Laguna's is: CAPACITY x the rows a
+    # uniform router sends the 4 held of 16 experts, three a token
+    cut = moe.CAPACITY * (6 * xb.shape[-1] * 3 / 16) * 4
+    np.testing.assert_array_equal(counts["buffer_rows"], [cut, cut])
+    assert cut < 6 * xb.shape[-1] * 3 and not counts["uncut"].any()
     # 3 peers x 2 windows of tokens a sparse layer; 1 to 2 groups a token
     tokens = 6 * xb.shape[-1]
     np.testing.assert_array_equal(counts["tokens"], [tokens, tokens])
@@ -194,6 +200,12 @@ def test_the_round_trains_the_adapters_and_reports_its_routing():
     assert 1.0 <= stats["groups_kept"] <= TINY.groups_kept
     assert stats["load_max_over_mean"] >= 1.0
     assert 0 < stats["assignments_held"] < 768
+    # four peers x 2 windows of 16 tokens, three of 16 experts a token, 4
+    # held: the round's calls ran on CAPACITY x 96 rows, none on all 384
+    assert stats["buffer_rows"] == moe.CAPACITY * 96 == 192
+    assert stats["uncut_calls"] == 0
+    assert "biscotti_moe_buffer_rows 192" in page
+    assert "biscotti_moe_uncut_calls 0" in page
     # a model whose router has one group reports no groups
     other = Simulator(_cfg(model_name="laguna_tiny", batch_size=2))
     other.run(num_rounds=1, stop_at_convergence=False)
