@@ -24,8 +24,10 @@ Real shards are disjoint slices of a deterministic dataset-keyed shuffle, so
 they are bit-identical across peer processes exactly like the synthetic ones.
 
 Token shards (`lm_tokens`, `lm_tokens_dsv2` over DeepSeek-V2's held
-25,600 rows, and `lm_tokens_tiny` at the tests' size) feed the language
-models (models/laguna.py, models/deepseek_v2.py): a row is a WINDOW of `d_in` token ids
+25,600 rows, `lm_tokens_granite` over all 100,352 of Granite-4.0-H-Micro's,
+and `lm_tokens_tiny` at the tests' size) feed the language models
+(models/laguna.py, models/deepseek_v2.py, models/granite_hybrid.py): a row
+is a WINDOW of `d_in` token ids
 (int32) and its label row is the same window one position on, so `y` holds
 a label a position. Ids are drawn from the slice of the vocabulary held
 here, `[0, n_classes)`, by shard name: half from one Zipf unigram law that
@@ -100,6 +102,9 @@ DATASETS: Dict[str, DatasetSpec] = {
     # the same windows over the held quarter of DeepSeek-V2's vocabulary
     "lm_tokens_dsv2": DatasetSpec("lm_tokens_dsv2", 1024, 25600, 80, 2,
                                   tokens=True),
+    # and over Granite-4.0-H-Micro's whole vocabulary
+    "lm_tokens_granite": DatasetSpec("lm_tokens_granite", 1024, 100352, 80,
+                                     2, tokens=True),
 }
 
 ZIPF_EXPONENT = 1.1  # the unigram law of token shards: p(rank) ∝ rank^-1.1

@@ -1,5 +1,5 @@
 """What the language models of this package share (models/laguna.py,
-models/deepseek_v2.py): a decoder whose frozen base is held once beside
+models/deepseek_v2.py, models/granite_hybrid.py): a decoder whose frozen base is held once beside
 rank-r adapters `B [r, out]`, which are what the peers train, commit and
 aggregate (the FFA-LoRA form: `A` frozen and shared, so that the sum of the
 peers' updates IS the update of the sum).
@@ -106,7 +106,7 @@ def decoder(layer):
     and picks stacked over the sparse layers, in layer order."""
     def hidden_states(cfg, params, tokens, frozen, remat=True):
         with jax.named_scope("lm_embed"):
-            h = frozen["embed"][tokens].astype(jnp.float32)
+            h = embedded(cfg, tokens, frozen)
         counted, picked = [], []
         for at in range(cfg.layers):
             def step(h, layer_frozen, adapters, at=at):
@@ -123,8 +123,25 @@ def decoder(layer):
     return hidden_states
 
 
+def embedded(cfg, tokens, frozen):
+    """float32[..., H]: the tokens' rows of the embedding, times the
+    model's `embedding_multiplier` where its config states one."""
+    h = frozen["embed"][tokens].astype(jnp.float32)
+    scale = getattr(cfg, "embedding_multiplier", None)
+    return h if scale is None else scale * h
+
+
 def logits(cfg, h, frozen):
-    return mm(rms(h, frozen["final_norm"], cfg.eps), frozen["head"])
+    """float32[..., V]. A frozen tree without a `head` is a model whose
+    head is TIED to its embedding: the one leaf `embed` [V, H] is read
+    twice, here contracted over its columns, and the logits divided by the
+    config's `logits_scaling`."""
+    x = rms(h, frozen["final_norm"], cfg.eps)
+    if "head" in frozen:
+        return mm(x, frozen["head"])
+    embed = frozen["embed"]
+    return jnp.einsum("...h,vh->...v", x.astype(embed.dtype), embed,
+                      preferred_element_type=jnp.float32) / cfg.logits_scaling
 
 
 def peer_losses(hidden_states, cfg, params, tokens, labels, frozen):
@@ -158,8 +175,11 @@ def _is_leaf(node):
 
 @partial(jax.jit, static_argnames=("shape", "fan_in", "dtype"))
 def _draw(key, shape, fan_in, dtype):
-    """One frozen leaf, drawn where it will live: norm weights around 1,
-    the rest fan-in scaled normal."""
+    """One frozen leaf, drawn where it will live: norm weights around 1
+    (`fan_in` 0), a law of the model's own (`fan_in` a function of (key,
+    shape) that gives float32), the rest fan-in scaled normal."""
+    if callable(fan_in):
+        return fan_in(key, shape).astype(dtype)
     noise = jax.random.normal(key, shape, jnp.float32)
     if fan_in == 0:
         return (1.0 + 0.1 * noise).astype(dtype)

@@ -17,6 +17,13 @@
              the dataset `lm_tokens_dsv2` (25,600 classes)
   deepseek_v2_tiny  the same mechanism at the CPU tests' size
              (`lm_tokens_tiny`, as laguna_tiny)
+  granite_h_micro_fedlora  Granite-4.0-H-Micro's Mamba-2 / attention hybrid
+             (models/granite_hybrid.py), WHOLE: 3.2 B frozen parameters (40
+             layers, 36 of them state-space, a tied embedding of 100,352
+             rows), rank-16 adapters on in_proj / out_proj and q, k, v, o
+             trained, d = 6,410,240; reads the dataset `lm_tokens_granite`
+  granite_h_tiny  the same mechanism at the CPU tests' size
+             (`lm_tokens_tiny`; four chunks a window)
 
 Inits are MXU-friendly (fan-in scaled normal) and every model is expressed in
 channels-last NHWC, the layout XLA prefers on TPU.
@@ -31,7 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from biscotti_tpu.data.datasets import base_name, spec as dspec
-from biscotti_tpu.models import deepseek_v2, laguna
+from biscotti_tpu.models import deepseek_v2, granite_hybrid, laguna
 from biscotti_tpu.models.base import Model, cross_entropy, make_model, multiclass_hinge
 
 
@@ -216,14 +223,17 @@ MODELS: Dict[str, callable] = {
               _language_model(build, name, presets[name], ds))
        for build, presets in ((laguna.laguna_model, laguna.PRESETS),
                               (deepseek_v2.deepseek_v2_model,
-                               deepseek_v2.PRESETS))
+                               deepseek_v2.PRESETS),
+                              (granite_hybrid.granite_hybrid_model,
+                               granite_hybrid.PRESETS))
        for name in presets},
 }
 
 # what a dataset trains where no model is named (softmax otherwise)
 DEFAULTS = {"creditcard": "logreg", "lm_tokens": "laguna_s_fedlora",
             "lm_tokens_tiny": "laguna_tiny",
-            "lm_tokens_dsv2": "deepseek_v2_fedlora"}
+            "lm_tokens_dsv2": "deepseek_v2_fedlora",
+            "lm_tokens_granite": "granite_h_micro_fedlora"}
 
 
 def _language_model(build, name: str, cfg, dataset: str) -> Model:

@@ -14,8 +14,8 @@ this kernel takes 1.0 and 3.1 (PERF.md section 6, PR 30).
     heads share a key/value head), k [W, kv, T, d], v [W, kv, T, e]; the
     result float32[W, kv, G, T, e]. Windows never meet: the grid walks W.
     The scores' width d and the values' e are each their own: Laguna's
-    heads have 128 | 128 and G = 6 or 9, DeepSeek-V2's latent attention
-    192 | 128 (128 + the 64 rotary dimensions) and G = 1. A d of a lane
+    heads 128 | 128 with G = 6 or 9, DeepSeek-V2's latent attention 192 |
+    128 with G = 1, Granite-4.0-H's 64 | 64 with G = 4. A d of a lane
     tile and a half is contracted as it is: on the v5e a forward call of
     3 x 128 heads took 3.48 ms and with its backward 9.20, zero-padded to
     256 outside the kernel 4.70 and 10.42, the `einsum` form 6.18 and
@@ -137,17 +137,17 @@ def blocks(g: int, t: int, d: int, dtype, e: int = None):
     """(query block, key block) of the kernel for `g` query heads a
     key/value head on windows of `t`, scores that contract `d` and values
     of `e` (None: d), or None where the kernel does not take the shape: a
-    value width not of 128 or a score width not of 64 (half a lane tile:
-    the kernel's products contract whole tiles and one half), a window that
-    is no whole number of blocks, another type, or a key/value head too
-    long to hold whole."""
+    score or a value width not of 64 (half a lane tile: Mosaic takes whole
+    tiles and one half, as they are; Granite's heads are 64 | 64), a window
+    that is no whole number of blocks, another type, or a key/value head
+    too long to hold whole."""
     e = d if e is None else e
-    if (e % _LANES or d % (_LANES // 2)
+    if (e % (_LANES // 2) or d % (_LANES // 2)
             or jnp.dtype(dtype) not in (jnp.bfloat16, jnp.float32)):
         return None
     size = jnp.dtype(dtype).itemsize
     return next(((bq, bk) for bq, bk in BLOCKS if t % bq == 0 and t % bk == 0
-                 and _buffers(g, t, _padded(d), bq, bk, size, e)
+                 and _buffers(g, t, _padded(d), bq, bk, size, _padded(e))
                  <= _VMEM_BUFFERS), None)
 
 

@@ -510,6 +510,11 @@ class Simulator:
                         "(query block, key block) pairs of the scores the "
                         "attention visits over all pairs, all layers "
                         "(the einsum form: 1)").set(plan["block_share"])
+            if "ssm_chunks" in self.model.info:
+                m.gauge("biscotti_ssm_chunks",
+                        "chunks a window's state-space scan is walked in "
+                        "(ops/ssm.py; static: the window over the model's "
+                        "chunk size)").set(self.model.info["ssm_chunks"])
         for it in range(num_rounds):
             t0 = time.perf_counter()
             w, stake, mask, err = self.round_step(w, stake, it)

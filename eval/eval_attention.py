@@ -10,7 +10,11 @@ layers' 72 under a window of 512. DeepSeek-V2's latent attention (`--layers
 mla`): 128 heads, none shared, scores that contract 192 (128 + the 64
 rotary dimensions) and values of 128, causal; the 192 as they are (the
 kernel's products contract a lane tile and a half) and, `padded256_*`,
-zero-padded to 256 inside the timed program. Each forward alone and forward
+zero-padded to 256 inside the timed program. Granite-4.0-H-Micro's
+attention layers (`--layers granite`): 32 query heads on 8 key/value heads
+of 64 | 64, causal, the scores times 1 / 64; the values as they are (half
+a lane tile) and, `values128_*`, zero-padded to 128 inside the timed
+program and cut again. Each forward alone and forward
 + backward (what a `jax.checkpoint`ed layer runs in the backward pass).
 Beside each time: the products of the visited (query block, key block)
 pairs (forward 2 bq bk (d + e), backward 2 bq bk (3 d + 2 e) more; d the
@@ -36,7 +40,8 @@ W, T = 3, 1024
 # kind: (query heads, window, key/value heads, scores' width, values', scale)
 LAYERS = {"full": (48, T, 8, 128, 128, None),
           "sliding": (72, 512, 8, 128, 128, None),
-          "mla": (128, T, 128, 192, 128, 192 ** -0.5 * 1.2608 ** 2)}
+          "mla": (128, T, 128, 192, 128, 192 ** -0.5 * 1.2608 ** 2),
+          "granite": (32, T, 8, 64, 64, 0.015625)}
 PAIRS = tuple((bq, bk) for bq in (128, 256, 512) for bk in (128, 256, 512))
 
 
@@ -96,6 +101,13 @@ def main(argv=None) -> int:
                                     scale or d ** -0.5)
 
                 forms["padded256_%dx%d" % pair] = padded
+            if e % 128:  # the values' width in whole lane tiles
+                def widened(q, k, v, pair=pair):
+                    wide = [(0, 0)] * 3 + [(0, at._padded(e) - e)]
+                    return at.fused(q, k, jnp.pad(v, wide), window, pair,
+                                    scale)[..., :e]
+
+                forms["values128_%dx%d" % pair] = widened
         def gaps(got, want):
             return [float(jnp.max(jnp.abs(a.astype(jnp.float32)
                                           - b.astype(jnp.float32)))

@@ -80,7 +80,7 @@ def test_the_published_layers_take_the_first_blocks_and_skip_a_quarter():
 
 @pytest.mark.parametrize("why,g,t,d,dtype", [
     ("the tiny preset's heads", 2, 16, 8, "float32"),
-    ("a head size not of 128", 6, 256, 64, "bfloat16"),
+    ("a head size not of 64", 6, 256, 32, "bfloat16"),
     ("a window that is no whole number of blocks", 6, 200, 128, "bfloat16"),
     ("another type", 6, 256, 128, "float16"),
     ("a key/value head too long to hold whole", 9, 1 << 16, 128, "float32")])
@@ -164,3 +164,31 @@ def test_a_row_whose_first_visited_block_hides_every_key_is_finite():
         for a, b in zip(got, want):
             assert np.isfinite(a).all()
             np.testing.assert_allclose(a, b, atol=5e-5)
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 2e-2)])
+def test_heads_of_64_with_their_own_scale_run_the_kernel(dtype, tol):
+    """Granite-4.0-H's attention (PR 33): four query heads a key/value head,
+    scores over 64 and values of 64 (half a lane tile each, as they are),
+    the scores times 1 / 64 and not 1 / sqrt(64): `blocks` takes the shape
+    and the kernel's forward and backward are the `einsum` form's."""
+    dtype = jnp.dtype(dtype)
+    keys = jax.random.split(jax.random.PRNGKey(5), 4)
+    q = jax.random.normal(keys[0], (2, 2, 4, 256, 64), jnp.float32)
+    k, v = (jax.random.normal(key, (2, 2, 256, 64), jnp.float32)
+            for key in keys[1:3])
+    cot = jax.random.normal(keys[3], q.shape, jnp.float32)
+    q, k, v = (a.astype(dtype) for a in (q, k, v))
+    assert at.blocks(4, 256, 64, dtype, 64) == (256, 256)
+    assert at.blocks(4, 1024, 64, jnp.bfloat16, 64) == (256, 512)
+    text = str(jax.make_jaxpr(lambda *a: at.attention(*a, 256, 1 / 64))(
+        q, k, v))
+    assert "pallas_call" in text
+    got = _both_passes(lambda *a: at.attention(*a, 256, 1 / 64), q, k, v,
+                       cot)
+    want = _both_passes(lambda *a: at.plain(*a, 256, 1 / 64), q, k, v, cot)
+    for a, b in zip(got, want):
+        a, b = (np.asarray(x, np.float32) for x in (a, b))
+        assert np.abs(a - b).max() <= tol * max(1.0, np.abs(b).max())
+    other = at.plain(q, k, v, 256)  # 1 / sqrt(64): another result
+    assert float(jnp.max(jnp.abs(other - want[0]))) > 0.05

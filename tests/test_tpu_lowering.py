@@ -331,6 +331,9 @@ PARENT_ROUNDS = {
     ("creditcard", ""): "04e1c79a9ba9c99e",
     ("mnist", "mnist_cnn"): "0cb8d0fa17f1cd73",
     ("lm_tokens_tiny", ""): "f9cffd8297eaba60",
+    # DeepSeek-V2's tiny round, read on dfd0195 (PR 32's tree) by PR 33,
+    # which touched models/lm.py and ops/attention.py for a third model
+    ("lm_tokens_tiny", "deepseek_v2_tiny"): "25251d8a018f991c",
 }
 WALKED_IN_THREES = "7ae27af2f9aa6149"  # the same, the peer axis in two blocks
 
@@ -353,7 +356,7 @@ def test_a_classifiers_lowered_round_is_the_parents(dataset, model):
     program comes out as it was, instruction for instruction. So does the
     language model's, its frozen tree an argument."""
     if dataset == "lm_tokens_tiny":
-        sim = Simulator(_cfg(**LM_TINY))
+        sim = Simulator(_cfg(**dict(LM_TINY, model_name=model)))
         assert sim.frozen != {}
     else:
         sim = Simulator(BiscottiConfig(
@@ -659,3 +662,112 @@ def test_a_sparse_layers_gradient_holds_no_copy_of_an_expert_stack(v5e,
     temporaries = compiled.memory_analysis().temp_size_in_bytes
     assert temporaries < EXPERTS_TEMPORARIES, temporaries
     assert EXPERTS_TEMPORARIES + 2 * e * h * f < SPLIT_COND_TEMPORARIES[model]
+
+
+# ------------------------- Granite-4.0-H-Micro, whole, on one chip (PR 33)
+
+
+def test_the_hybrids_sizes_from_shapes_alone():
+    """d = 6,410,240 and 3,195,459,328 frozen parameters (6.39 GB in
+    bfloat16, 39.9% of the chip), the tied embedding counted once, with no
+    parameter drawn; the sibling models' plans are the parent's."""
+    from biscotti_tpu.models import lm
+    from biscotti_tpu.models.zoo import model_for_dataset
+
+    model = model_for_dataset("lm_tokens_granite")
+    assert model.num_params == 6410240
+    assert lm.frozen_count(model) == 3195459328
+    tree = jax.eval_shape(model.init_frozen, jax.random.PRNGKey(0))
+    assert {leaf.dtype for leaf in jax.tree.leaves(tree)} == {
+        jnp.dtype(jnp.bfloat16)}
+    assert model.info["attention"] == {"fused": 1, "block_share": 0.75}
+    assert model_for_dataset("lm_tokens").info["attention"] == {
+        "fused": 1, "block_share": 0.75}
+    assert model_for_dataset("lm_tokens_dsv2").info["attention"] == {
+        "fused": 1, "block_share": 0.75}
+    # a peer's step holds 2.05 GB by the model's count: a 16 GB chip with
+    # 6.39 GB of base and 1.67 GB of deltas standing steps ONE at a time
+    from biscotti_tpu.models.peer_step import DEVICE_BYTES, peer_block
+
+    step = model.step_bytes(1)
+    assert 2.0e9 < step < 2.1e9
+    free = DEVICE_BYTES - 2 * 3195459328 - 4 * (3 * 21 + 2) * 6410240
+    assert peer_block(21, step, free) == 1
+
+
+def _hybrid_layer(v5e, at):
+    """(config, sharding, frozen leaves, adapters with a peer axis of 1) of
+    layer `at` of the published hybrid, as shapes on the described chip."""
+    from biscotti_tpu.models import granite_hybrid
+
+    cfg = granite_hybrid.PRESETS["granite_h_micro_fedlora"]
+    one = SingleDeviceSharding(v5e[0])
+    model = granite_hybrid.granite_hybrid_model("lm", cfg, 1024)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one), tree)
+
+    frozen = on_chip(jax.eval_shape(
+        model.init_frozen, jax.random.PRNGKey(0))["layers"][at])
+    adapters = on_chip(jax.eval_shape(
+        lambda key: jax.tree.map(lambda b: b[None],
+                                 model.init(key)["layers"][at]),
+        jax.random.PRNGKey(0)))
+    return cfg, one, frozen, adapters
+
+
+def test_the_state_space_layer_at_the_published_shapes_compiles(v5e):
+    """One Mamba-2 layer of the published size as a peer sends it (1
+    window of 1,024 tokens: 4 chunks of 256, 64 heads of 64, state 128,
+    bfloat16) under `jax.checkpoint` and `jax.grad` compiles for the v5e
+    under x64, and its scan makes no float32 array of the decays' size
+    [chunks, heads, 256, 256] more than a handful of times."""
+    from biscotti_tpu.models import granite_hybrid
+
+    cfg, one, frozen, adapters = _hybrid_layer(v5e, 0)
+
+    def loss(adapters, h, frozen):
+        out, _, _ = jax.checkpoint(lambda h, f, a: granite_hybrid._layer(
+            cfg, 0, h, f, a))(h, frozen, adapters)
+        return jnp.sum(out * out)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        adapters, jax.ShapeDtypeStruct((1, 1, 1024, cfg.hidden), jnp.float32,
+                                       sharding=one), frozen).compile()
+    hlo = compiled.as_text()
+    assert "ssm_scan" in hlo and "ssm_conv" in hlo and "ssm_gate" in hlo
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+    assert not [line.strip()[:160] for line in hlo.splitlines()
+                if "f64[" in line or ("s64[" in line
+                                      and "parameter(" not in line)]
+
+
+def test_the_hybrids_attention_at_the_published_shapes_takes_the_kernel(v5e):
+    """An attention layer of the published size (32 query heads on 8
+    key/value heads of 64 | 64, no rotary, the scores times 1 / 64) under
+    `jax.checkpoint` and `jax.grad`: ops/attention.py's kernel with the
+    values' 64 as they are, and no float32 array of the scores' size."""
+    from biscotti_tpu.models import granite_hybrid
+
+    cfg, one, frozen, adapters = _hybrid_layer(v5e, 5)
+
+    def loss(adapters, h, frozen):
+        out = jax.checkpoint(lambda h, f, a: granite_hybrid._attention(
+            cfg, h, f, a))(h, frozen, adapters)
+        return jnp.sum(out * out)
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        adapters, jax.ShapeDtypeStruct((1, 1, 1024, cfg.hidden), jnp.float32,
+                                       sharding=one), frozen
+    ).compile().as_text()
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert 2 <= len(calls) <= 3, len(calls)
+    assert any("f32[1,8,4,1024,64]" in c for c in calls)     # the result
+    assert any("bf16[1,8,4,1024,64]" in c for c in calls)    # q, dq
+    square = re.compile(r"f32\[([\d,]*1024,1024)\]")
+    made = [line.strip()[:160] for line in hlo.splitlines()
+            for dims in square.findall(line)
+            if math.prod(int(v) for v in dims.split(",")) > 1024 * 1024]
+    assert not made, made[:5]

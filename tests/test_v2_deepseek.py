@@ -227,10 +227,12 @@ def test_blocks_decides_from_the_shapes_alone():
     assert at.blocks(6, 1024, 128, bf16) == at.blocks(6, 1024, 128, bf16,
                                                       128) == (256, 512)
     assert at.blocks(9, 1024, 128, bf16) == (256, 512)
-    # the tiny presets, a value width not of 128, a score width not of 64
+    # the tiny presets, a value or a score width not of 64 (since PR 33 a
+    # value width of half a lane tile is taken as it is: Granite's 64 | 64)
     assert at.blocks(1, 16, 6, jnp.float32, 4) is None
-    assert at.blocks(1, 1024, 192, bf16, 64) is None
+    assert at.blocks(1, 1024, 192, bf16, 96) is None
     assert at.blocks(1, 1024, 160, bf16, 128) is None
+    assert at.blocks(4, 1024, 64, bf16, 64) == (256, 512)
     # the einsum side takes the scale and the two widths too
     q, k, v, _ = _mla_inputs(jnp.float32, t=16, d=6, e=4)
     out = at.attention(q, k, v, 16, 0.3)

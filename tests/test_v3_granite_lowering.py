@@ -1,0 +1,51 @@
+"""The WHOLE published round of Granite-4.0-H-Micro compiled ahead of time
+for a described v5e (tests/test_tpu_lowering.py has the model's sizes and
+its two kinds of layer; this is that file's heaviest compile, three minutes
+on every core, in a file of its own that is collected LAST: beside
+tests/test_runtime.py's live clusters and their 4 s timeouts it cost them
+their updates, PR 33's first whole run)."""
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from biscotti_tpu.parallel.sim import Simulator
+from test_tpu_lowering import _abstract, _cfg, v5e  # noqa: F401  (fixture)
+
+HYBRID = dict(dataset="lm_tokens_granite", num_nodes=30, batch_size=1,
+              sample_percent=0.7, num_verifiers=3, num_miners=3,
+              num_noisers=2, learning_rate=0.1, grad_clip=1.0)
+
+
+def test_the_published_hybrid_round_compiles_for_v5e(v5e, monkeypatch):
+    """The WHOLE round of `granite_h_fedlora.device_round` (30 peers, 21
+    sampled, one window each, DP noise, Krum, the held-out windows'
+    forward; 40 layers unrolled, each rematerialised) compiles for a
+    described v5e with the base NEVER drawn (zeros in its place: the
+    compile sees shapes), walks its peers one at a time and fits: the
+    base and the stacks as arguments, 3.3 GB of temporaries."""
+    from biscotti_tpu.models import lm
+
+    monkeypatch.setattr(lm, "_draw", lambda key, shape, fan_in, dtype:
+                        jnp.zeros(shape, dtype))
+    sim = Simulator(_cfg(**HYBRID))
+    assert sim.num_params == 6410240 and sim.cfg.num_samples == 21
+    assert sim.frozen_bytes() == 2 * 3195459328
+    assert sim.peer_block == 1
+    one = SingleDeviceSharding(v5e[0])
+    w, stake = sim.init_state()
+    args = (_abstract([w, stake, jnp.asarray(0),
+                       jnp.asarray(sim.cfg.seed, jnp.int32)], one)
+            + _abstract([sim.x, sim.y], one, stack=True)
+            + _abstract([sim.x_val, sim.y_val], one)
+            + [jax.tree.map(lambda a: _abstract([a], one)[0], sim.frozen)])
+    compiled = jax.jit(sim._round_step_raw).lower(*args).compile()
+    memory = compiled.memory_analysis()
+    assert 6.4e9 < memory.argument_size_in_bytes < 6.5e9
+    assert memory.temp_size_in_bytes < 3.6e9
+    assert memory.generated_code_size_in_bytes < 0.3e9  # no stack copied
+    hlo = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' in hlo  # the attention
+    for scope in ("ssm_scan", "ssm_proj", "ssm_conv", "ssm_gate",
+                  "lm_attention", "lm_dense", "lm_head_loss"):
+        assert scope in hlo, scope
