@@ -45,6 +45,7 @@ out]]}; the frozen tree holds everything else in `dtype`.
 
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass, field
 from typing import Tuple
@@ -214,12 +215,12 @@ def attention_plan(cfg: DeepSeekV2Config, length: int) -> dict:
             if block else 1.0}
 
 
-def _mlp(cfg, at, h, frozen):
-    """The MLP block of layer `at` on h [N, H]: (result, the dispatch's
-    counts, the router's (experts, probabilities)); the last two None on a
-    dense layer."""
+def _mlp(cfg, dense, h, frozen):
+    """The MLP block of a layer on h [N, H], `dense` or sparse: (result,
+    the dispatch's counts, the router's (experts, probabilities)); the
+    last two None on a dense layer."""
     x = lm.rms(h, frozen["mlp_norm"], cfg.eps)
-    if at in cfg.dense_layers:
+    if dense:
         with jax.named_scope("lm_dense"):
             return lm.swiglu(x, frozen["dense"]), None, None
     with jax.named_scope("lm_router"):
@@ -242,10 +243,20 @@ def _mlp(cfg, at, h, frozen):
     return shared + routed, counts, (experts, probs)
 
 
-def _layer(cfg, at, h, frozen, adapters):
-    h = h + _attention(cfg, h, frozen, adapters)
-    out, counts, picks = _mlp(cfg, at, h.reshape(-1, h.shape[-1]), frozen)
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _layer_of(cfg, dense, h, frozen, adapters):
+    """A layer, `dense` or sparse. Jitted, so that a round traces the two
+    kinds of layer and not the five layers: the walked attention (a loop,
+    rematerialised and transposed) is slow to trace, and set-up pays it
+    once a layer otherwise (`setup_s`; PERF.md section 6, PR 35)."""
+    h = h + lm.peer_at_a_time(
+        lambda h, adapters: _attention(cfg, h, frozen, adapters), h, adapters)
+    out, counts, picks = _mlp(cfg, dense, h.reshape(-1, h.shape[-1]), frozen)
     return h + out.reshape(h.shape), counts, picks
+
+
+def _layer(cfg, at, h, frozen, adapters):
+    return _layer_of(cfg, at in cfg.dense_layers, h, frozen, adapters)
 
 
 # (h [P, b, T, H], counts, picks) of tokens int32[P, b, T] under adapters
@@ -310,14 +321,20 @@ def deepseek_v2_model(name: str, cfg: DeepSeekV2Config, length: int):
         peak (a sparse layer's recomputation and backward), with NO term
         for the attention's scores: the kernel holds none in HBM. Read off
         the compiled round's memory analysis at the published size (v5e,
-        ahead of time; PERF.md section 6, PR 31): its temporaries are 2.19
-        GB at a peer block of 1 and 4.03 GB at 3, so a peer adds 0.92 GB
-        to 1.27 GB that every block pays. The terms that come to it within
-        a twentieth (0.881 GB): the heads' float32 arrays of a layer (q and k
-        at the scores' width, [k_nope | v]) and their cotangents, the
-        logits and theirs. A block of 3 then fits half of what the 10.33
-        GB base leaves of a 16 GB chip, a block of 7 (7.7 GB of
-        temporaries by the same line) does not fit the chip at all."""
+        ahead of time; PERF.md section 6, PR 31): its temporaries were
+        2.19 GB at a peer block of 1 and 4.03 GB at 3, so a peer added
+        0.92 GB to 1.27 GB that every block pays. The terms that come to
+        it within a twentieth (0.881 GB): the heads' float32 arrays of a
+        layer (q and k at the scores' width, [k_nope | v]) and their
+        cotangents, the logits and theirs. Of the 5.22 GB that the 10.33
+        GB base, the stacks and 1.34 GB of deltas and noise leave free of
+        the 15.75 GiB the chip's runtime states, three peers take 0.506:
+        33 MB more than the half that was `peer_step.BLOCK_SHARE` until
+        PR 35, so the cell ran a block of 1 while this said 3. With the
+        block's attention walked a peer at a time (`lm.peer_at_a_time`)
+        the round of 3 compiles at 4.50 GB of temporaries, 15.00 GB with
+        its arguments and code of the 16.91 the chip states; a block of 7
+        (7.7 GB of temporaries by the line above) does not fit at all."""
         t = batch * length
         per_head = 2 * (cfg.nope + cfg.rope) + cfg.nope + cfg.v_dim
         return 2 * 4 * t * (cfg.heads * per_head + cfg.vocab)

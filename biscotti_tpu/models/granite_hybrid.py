@@ -309,8 +309,9 @@ def granite_hybrid_model(name: str, cfg: GraniteHybridConfig, length: int):
         1); every layer's input, kept for its recomputation; a layer's
         scan at its backward, four arrays [chunks, heads, chunk, chunk];
         six arrays of `in_proj`'s width. With 6.39 GB of base and 1.67 GB
-        of deltas and noise standing, half of what a 16 GB chip has left
-        holds one such peer, not three."""
+        of deltas and noise standing, three such peers are 0.695 of what
+        the chip's 15.75 GiB have left, over `peer_step.BLOCK_SHARE`: the
+        round walks one at a time."""
         t = batch * length
         wide = 2 * cfg.inner + 2 * cfg.ssm_state + cfg.ssm_heads
         return 4 * t * (3 * cfg.vocab + cfg.layers * cfg.hidden

@@ -45,6 +45,7 @@ gradient, because no operation mixes two windows.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 from typing import Tuple
 
@@ -206,11 +207,27 @@ def _mlp(cfg, at, h, frozen):
     return shared + routed, counts, (experts, probs)
 
 
-def _layer(cfg, at, h, frozen, adapters):
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _layer_as(cfg, at, h, frozen, adapters):
+    """A layer built as layer `at` is. Jitted, so that a round traces the
+    three kinds of layer and not the five layers: the walked attention (a
+    loop, rematerialised and transposed) is slow to trace, and set-up
+    pays it once a layer otherwise (`setup_s`; PERF.md section 6, PR 35)."""
     with jax.named_scope("lm_attention"):
-        h = h + _attention(cfg, at, h, frozen, adapters)
+        h = h + lm.peer_at_a_time(
+            lambda h, adapters: _attention(cfg, at, h, frozen, adapters),
+            h, adapters)
     out, counts, picks = _mlp(cfg, at, h.reshape(-1, h.shape[-1]), frozen)
     return h + out.reshape(h.shape), counts, picks
+
+
+def _layer(cfg, at, h, frozen, adapters):
+    """Layer `at`, as the first layer of its kind (attention, heads, MLP)."""
+    def kind(i):
+        return cfg.layer_types[i], cfg.heads[i], i in cfg.dense_layers
+
+    first = next(i for i in range(cfg.layers) if kind(i) == kind(at))
+    return _layer_as(cfg, first, h, frozen, adapters)
 
 
 # (h [P, b, T, H], counts, picks) of tokens int32[P, b, T] under adapters
@@ -274,11 +291,15 @@ def laguna_model(name: str, cfg: LagunaConfig, length: int):
         size (0.9 GB; PERF.md section 6, PR 27) WHILE THE SCORES WERE
         HELD. Since PR 30 they are not, where ops/attention.py's kernel
         runs: the first term (604 MB of a peer's 1.15 GB at the published
-        size) now over-counts a peer by them, on purpose: without it the
-        peer block would go from 3 to 7, the experts' groups and the
-        round's memory with it, a change of its own (ROADMAP A8: re-derive
-        this from the compiled round's memory analysis). The over-statement
-        is safe: the block stays 3."""
+        size) over-counts a peer by them, and is KEPT: without it
+        `peer_step.peer_block` takes 7 peers where 3 (the round then
+        compiles at 4.59 GB of temporaries where 2.24 and fits), and on
+        the chip the round of 7 is slower, 1,207 ms against 1,110 (PERF.md
+        section 6, PR 35): a grouped call on 280-row groups takes row
+        tiles of 512 and computes 2.8 rows for each one held, and the
+        gathers and scatters around it stream 36,000-row buffers from
+        HBM. Count the scores out when ops/moe.py's row tile and movement
+        are made for the larger block (ROADMAP A8c), not before."""
         t = batch * length
         return (2 * 4 * max(cfg.heads) * batch * length * length
                 + t * cfg.top_k * cfg.hidden * (4 + dtype.itemsize)

@@ -91,6 +91,24 @@ def adapted(cfg, x, w, a, b):
     return mm(x, w) + (cfg.alpha / cfg.rank) * low
 
 
+def peer_at_a_time(fn, h, adapters):
+    """`fn(h, adapters)` of a block h [P, b, T, H] under adapters with a
+    peer axis, computed one peer after the other and stacked: the same
+    numbers in arrays a P-th the size. A block of peers is there for the
+    routed experts (one stream of an expert stack serves the block's
+    tokens); the attention's elementwise passes, copies and slices around
+    its kernel gain nothing from it and lose the chip's fast memory: the
+    compiler keeps an operand of tens of MB there (one peer's
+    `f32[128, 1024, 64]` is 32 MB) and streams three peers' from HBM
+    (DeepSeek-V2's projections at a block of 3: 2,007 ms a round against
+    1,602 at a block of 1; PERF.md section 6, PR 35)."""
+    if h.shape[0] == 1:
+        return fn(h, adapters)
+    return jax.lax.map(
+        lambda one: fn(*jax.tree.map(lambda a: a[None], one))[0],
+        (h, adapters))
+
+
 def stacked(found):
     found = [f for f in found if f is not None]
     return jax.tree.map(lambda *a: jnp.stack(a), *found) if found else {}

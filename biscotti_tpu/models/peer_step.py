@@ -40,14 +40,31 @@ from biscotti_tpu.models.trainer import block_step_fn, sample_batch, step_rule
 # stacks (megabytes) keep the runtime's default.
 STACK_PAD_LIMIT = 1.25
 
-# What one chip of the fleet this is written for holds (TPU v5e), where the
-# backend does not say (`memory_stats()` is None on the CPU), and the share
-# of what the standing arrays leave free that a block of peers' activations
-# may take: the rest is the compiler's own temporaries and fragmentation
-# (PERF.md section 6, PR 27: read from the compiled program's memory
-# analysis at the published size).
-DEVICE_BYTES = 16 * 2**30
-BLOCK_SHARE = 0.5
+# What the runtime of one chip of the fleet this is written for (TPU v5e)
+# states as its memory, `memory_stats()["bytes_limit"]` read on the chip,
+# for where the backend does not say (`memory_stats()` is None on the CPU):
+# 15.75 GiB, NOT the 16 GiB of the data sheet. With 16 the CPU tests worked
+# out a DeepSeek-V2 block of 3 for a cell that ran 1 on the chip through
+# two PRs' records (PERF.md section 6, PR 35).
+DEVICE_BYTES = 16909336064
+
+# The share of what the standing arrays leave free (the caller's `standing`:
+# the base, the stacks, the round's deltas and noise) that a block of
+# peers' `step_bytes` may take. By `b x step_bytes / free` the published
+# cells' candidates read: DeepSeek-V2 3: 0.506 (7: 1.18); Granite 3: 0.695
+# (1: 0.23); Laguna 3: 0.325 (7: 0.758, its scores still counted:
+# `laguna.step_bytes` says why). 0.6 takes DeepSeek-V2's 3, which half
+# missed by 33 MB: that round compiles for the v5e at 15.00 GB with its
+# arguments and code of the 16.91 the chip states, and on the chip is 10%
+# faster than a peer at a time (264 streams of a 629 MB expert stack where
+# 768; PERF.md section 6, PR 35). It leaves Granite at 1: its 3 compiles
+# (7.43 GB of temporaries) but nothing says it is faster and one scan call
+# of three windows takes 1.94 x three calls of one. Any share in 0.51-0.69
+# gives the same three blocks. The other 40% of the free bytes (2.1 GB in
+# the tightest cell) are for what a compiled round needs beyond `standing`
+# and its peers' count (DeepSeek-V2: 0.5 GB of the walked attention's
+# stacked results), its code (0.13 GB) and fragmentation.
+BLOCK_SHARE = 0.6
 
 
 def peer_block(samples: int, step_bytes: Optional[int], free: int) -> int:

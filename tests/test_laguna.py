@@ -144,6 +144,48 @@ def test_a_block_of_peers_is_each_peer_alone(tiny, side):
     assert counts["load"].shape == (2, 4) and int(counts["dropped"].sum()) == 0
 
 
+@pytest.mark.parametrize("name", ["laguna_tiny", "deepseek_v2_tiny"])
+def test_a_blocks_attention_is_walked_a_peer_at_a_time(name):
+    """A block of peers is there for the routed experts: the attention of
+    a block of 3 is a loop over its peers (`lm.peer_at_a_time`), a peer's
+    alone is the program it was (no loop), and the block's hidden states
+    and every adapter's gradient are its peers' own, each computed alone,
+    where no token of one peer meets another's in the experts
+    (they share the sorted buffer, not a row of it)."""
+    from biscotti_tpu.models import deepseek_v2
+
+    model = model_for_dataset(DATASET, name)
+    module = {"laguna_tiny": laguna, "deepseek_v2_tiny": deepseek_v2}[name]
+    cfg = model.info["config"]
+    frozen = model.frozen(jax.random.PRNGKey(1))
+    one = model.init(jax.random.PRNGKey(2))
+    shard = ds.load_shard(DATASET, f"{DATASET}0")
+    tokens = jnp.asarray(shard["x_train"][:6]).reshape(3, 2, -1)
+    params = jax.tree.map(lambda b: jnp.stack([b, 2.0 * b, -b]), one)
+
+    def loss(params, tokens):
+        h, _, _ = module.hidden_states(cfg, params, tokens, frozen)
+        return jnp.sum(h * h), h
+
+    def loops(peers):
+        some = jax.tree.map(lambda b: b[:peers], params)
+        return str(jax.make_jaxpr(lambda p: loss(p, tokens[:peers])[0])(
+            some)).count("scan[")
+
+    assert loops(1) == 0 and loops(3) >= 1
+    grad = jax.jit(jax.value_and_grad(loss, has_aux=True))
+    (_, h), grads = grad(params, tokens)
+    for peer in (0, 2):
+        own = jax.tree.map(lambda b: b[peer:peer + 1], params)
+        (_, want), want_grads = grad(own, tokens[peer:peer + 1])
+        got = (h[peer:peer + 1],
+               jax.tree.map(lambda g: g[peer:peer + 1], grads))
+        for a, b in zip(jax.tree.leaves(got),
+                        jax.tree.leaves((want, want_grads))):
+            np.testing.assert_allclose(
+                a, b, atol=1e-5 * float(jnp.max(jnp.abs(b))))
+
+
 # ------------------------------------------------------ the expert layer
 
 

@@ -224,23 +224,72 @@ def test_walked_peer_axis_gives_the_vmapped_deltas(kind, block):
 
 
 def test_peer_block_is_worked_out_from_the_bytes():
-    from biscotti_tpu.models.peer_step import BLOCK_SHARE, peer_block
+    from biscotti_tpu.models.peer_step import (BLOCK_SHARE, DEVICE_BYTES,
+                                               peer_block)
 
     assert peer_block(21, None, 10**9) == 21       # no activation size: all
     assert peer_block(21, 0, 10**9) == 21
     gib = 2**30
-    assert peer_block(21, gib, int(21 * gib / BLOCK_SHARE)) == 21
-    assert peer_block(21, gib, int(8 * gib / BLOCK_SHARE)) == 7  # a divisor
-    assert peer_block(21, gib, int(4 * gib / BLOCK_SHARE)) == 3
+    # `free` such that BLOCK_SHARE of it is b GiB: the division truncates
+    # (exact at a share of 0.5, a byte short at 0.6), so a byte more
+    assert peer_block(21, gib, int(21 * gib / BLOCK_SHARE) + 1) == 21
+    assert peer_block(21, gib, int(8 * gib / BLOCK_SHARE) + 1) == 7  # a divisor
+    assert peer_block(21, gib, int(4 * gib / BLOCK_SHARE) + 1) == 3
     assert peer_block(21, gib, 10) == 1            # at least one peer
     assert peer_block(2368, 10, 10**12) == 2368
-    # the published size: 21 peers of a 1,024-token window next to 6 GB
+    # the published size: 21 peers of a 1,024-token window next to 6 GB, at
+    # what the chip's runtime states. The model counts the attention's
+    # scores on both sides of ops/attention.py's dispatch, also where the
+    # kernel holds none (`laguna.step_bytes` says why): two float32 arrays
+    # [heads, T, T] of the widest layer
+    from biscotti_tpu.models import laguna
     from biscotti_tpu.models.zoo import model_for_dataset
 
     model = model_for_dataset("lm_tokens")
+    assert model.info["attention"]["fused"] == 1
     per_peer = model.step_bytes(1)
     assert 0.9e9 < per_peer < 1.4e9
-    assert peer_block(21, per_peer, 16 * gib - int(6.3e9)) == 3
+    assert peer_block(21, per_peer, DEVICE_BYTES - int(6.3e9)) == 3
+    # (every other term is linear in the window's length)
+    for name, length, fused in (("laguna_s_fedlora", 1024, 1),
+                                ("laguna_tiny", 16, 0)):
+        cfg = laguna.PRESETS[name]
+        short, long = (laguna.laguna_model(name, cfg, t)
+                       for t in (length, 2 * length))
+        assert short.info["attention"]["fused"] == fused
+        assert (long.step_bytes(1) - 2 * short.step_bytes(1)
+                == 2 * 4 * max(cfg.heads) * 2 * length * length)
+
+
+# (dataset, frozen parameters in bfloat16, the block the chip runs): the
+# three published language models' cells, 21 of 30 peers sampled, 64
+# windows of 1,024 int32 tokens and as many labels a peer
+PUBLISHED_BLOCKS = [("lm_tokens", 3003393024, 3),
+                    ("lm_tokens_dsv2", 5166269440, 3),
+                    ("lm_tokens_granite", 3195459328, 1)]
+
+
+@pytest.mark.parametrize("dataset,frozen,block", PUBLISHED_BLOCKS)
+def test_the_published_cells_blocks_at_the_chips_stated_bytes(dataset, frozen,
+                                                              block):
+    """What `Simulator` works out on the chip, worked out here: `free` is
+    DEVICE_BYTES (the runtime's `bytes_limit` of a v5e, not the data
+    sheet's 16 GiB, with which DeepSeek-V2's cell read 3 here while it ran
+    1 there) less the cell's `standing`, and the block holds with a tenth
+    less free and a tenth more."""
+    from biscotti_tpu.models import lm
+    from biscotti_tpu.models.peer_step import DEVICE_BYTES, peer_block
+    from biscotti_tpu.models.zoo import model_for_dataset
+
+    model = model_for_dataset(dataset)
+    assert lm.frozen_count(model) == frozen
+    standing = (2 * frozen + 30 * 2 * 64 * 1024 * 4
+                + 4 * (3 * 21 + 2) * model.num_params)
+    free = DEVICE_BYTES - standing
+    step = model.step_bytes(1)
+    assert [peer_block(21, step, int(share * free))
+            for share in (0.9, 1.0, 1.1)] == [block] * 3
+    assert DEVICE_BYTES < 16 * 2**30
 
 
 @pytest.mark.parametrize("model,dataset,rule,rate", [

@@ -322,20 +322,21 @@ def _lowered_round(sim):
 # sha256[:16] of the lowered round at these shapes on the commits that had
 # it first, read there with this very function: the classifiers' on c71d5aa
 # (before `Model` had a frozen tree, a declared step rule or a walked peer
-# axis; less the last argument), the language model's two on PR 32's tree,
-# the commit after ffcbf59, for one reason: the routed experts' part of
-# ops/moe.py became a `custom_vjp` (they were 296d45bcd51e4a6d and
-# 3a2777fe75774ef2 from 430e04f to ffcbf59)
+# axis; less the last argument); the two language models' on PR 35's tree,
+# for one reason: a block's attention is one `lax.map` over its peers
+# (`lm.peer_at_a_time`; they were f9cffd8297eaba60, 25251d8a018f991c and
+# 7ae27af2f9aa6149 from PR 32's tree to 6b90dd1), and a layer is a jitted
+# function of its kind, traced once a kind and not once a layer. A
+# block of ONE peer walks nothing: the published Granite round lowers to
+# 6b90dd1's text (de6de7cab9843f86; PERF.md section 5)
 PARENT_ROUNDS = {
     ("mnist", "softmax"): "2f1f0d7efd64ce2c",
     ("creditcard", ""): "04e1c79a9ba9c99e",
     ("mnist", "mnist_cnn"): "0cb8d0fa17f1cd73",
-    ("lm_tokens_tiny", ""): "f9cffd8297eaba60",
-    # DeepSeek-V2's tiny round, read on dfd0195 (PR 32's tree) by PR 33,
-    # which touched models/lm.py and ops/attention.py for a third model
-    ("lm_tokens_tiny", "deepseek_v2_tiny"): "25251d8a018f991c",
+    ("lm_tokens_tiny", ""): "f0ca8f3e4ab9ba5e",
+    ("lm_tokens_tiny", "deepseek_v2_tiny"): "9c84a7f51791d196",
 }
-WALKED_IN_THREES = "7ae27af2f9aa6149"  # the same, the peer axis in two blocks
+WALKED_IN_THREES = "83f7ac9576367014"  # the same, the peer axis in two blocks
 
 LM_TINY = dict(dataset="lm_tokens_tiny", num_nodes=8, batch_size=2,
                sample_percent=1.0, num_verifiers=1, num_miners=1,
