@@ -60,9 +60,19 @@ from biscotti_tpu.ops import attention, moe
 # vocabulary, as models/laguna.SCOPES is Laguna's; docs/OBSERVABILITY.md).
 # `mla_proj`: norms, the four compressions, rotary, adapters, W_o;
 # `mla_core`: the `softmax(...) v` call alone, whichever side of
-# ops/attention.py's dispatch runs
+# ops/attention.py's dispatch runs; `peer_walk`: the loop of
+# `lm.peer_at_a_time` itself (slices, stacked results and residuals)
 SCOPES = ("lm_embed", "mla_proj", "mla_core", "lm_router", "lm_experts",
-          "lm_dense", "lm_head_loss", "peer_clip")
+          "lm_dense", "lm_head_loss", "peer_clip", "peer_walk")
+# what `mla_proj` is made of, each opened INSIDE it and read under SCOPES +
+# SUBSCOPES (under SCOPES alone an instruction's last token is still
+# `mla_proj`, and `mla_proj_ms.device` reads what it read): the three
+# norms; the four adapted products into the core; the rotation, the one
+# shared key's broadcast and the concatenations; the reshapes, head-major
+# transposes, casts and the value's slice; `W_o` with its adapter. The
+# names are models/laguna.py's, none part of another or a frozen leaf's
+SUBSCOPES = ("attn_norms", "attn_in", "attn_rotary", "attn_layout",
+             "attn_out")
 ADAPTED = ("kva", "kvb", "o", "qa", "qb")
 
 
@@ -170,36 +180,51 @@ def _attention(cfg, h, frozen, adapters):
     p, b, t, _ = h.shape
     n, nope, rope, dv = cfg.heads, cfg.nope, cfg.rope, cfg.v_dim
     lora = frozen["lora_a"]
+    scope = jax.named_scope
 
-    def proj(x, name):
-        return lm.adapted(cfg, x, frozen["w_" + name], lora[name],
-                          adapters[name])
+    def proj(x, name, part="attn_in"):
+        with scope(part):
+            return lm.adapted(cfg, x, frozen["w_" + name], lora[name],
+                              adapters[name])
 
-    with jax.named_scope("mla_proj"):
-        x = lm.rms(h, frozen["attn_norm"], cfg.eps)
-        c_q = lm.rms(proj(x, "qa"), frozen["q_norm"], cfg.eps)
-        q = proj(c_q, "qb").reshape(p * b, t, n, nope + rope)
-        q = q.transpose(0, 2, 1, 3)                          # [W, n, T, 192]
+    def norm(x, name):
+        with scope("attn_norms"):
+            return lm.rms(x, frozen[name], cfg.eps)
+
+    def heads(y, width):
+        """[P, b, T, n x width] head-major: [W, n, T, width]."""
+        with scope("attn_layout"):
+            return y.reshape(p * b, t, n, width).transpose(0, 2, 1, 3)
+
+    with scope("mla_proj"):
+        x = norm(h, "attn_norm")
+        c_q = norm(proj(x, "qa"), "q_norm")
+        q = heads(proj(c_q, "qb"), nope + rope)              # [W, n, T, 192]
         latent = proj(x, "kva")                              # [P, b, T, 576]
-        c_kv = lm.rms(latent[..., :cfg.kv_rank], frozen["kv_norm"], cfg.eps)
-        k_r = latent[..., cfg.kv_rank:].reshape(p * b, 1, t, rope)
-        kv = proj(c_kv, "kvb").reshape(p * b, t, n, nope + dv)
-        kv = kv.transpose(0, 2, 1, 3)                        # [W, n, T, 256]
-        cos, sin = rotary_tables(cfg, t)
-        q = jnp.concatenate([q[..., :nope], _rotate(q[..., nope:], cos, sin)],
-                            axis=-1)
-        k = jnp.concatenate([
-            kv[..., :nope],
-            jnp.broadcast_to(_rotate(k_r, cos, sin), (p * b, n, t, rope))],
-            axis=-1)
-        dtype = frozen["w_qb"].dtype
-        q = q[:, :, None].astype(dtype)                      # no head shared
-        k, v = k.astype(dtype), kv[..., nope:].astype(dtype)
-    with jax.named_scope("mla_core"):
+        with scope("attn_in"):
+            c_kv = latent[..., :cfg.kv_rank]
+        c_kv = norm(c_kv, "kv_norm")
+        with scope("attn_rotary"):
+            k_r = latent[..., cfg.kv_rank:].reshape(p * b, 1, t, rope)
+        kv = heads(proj(c_kv, "kvb"), nope + dv)             # [W, n, T, 256]
+        with scope("attn_rotary"):
+            cos, sin = rotary_tables(cfg, t)
+            q = jnp.concatenate(
+                [q[..., :nope], _rotate(q[..., nope:], cos, sin)], axis=-1)
+            k = jnp.concatenate([
+                kv[..., :nope],
+                jnp.broadcast_to(_rotate(k_r, cos, sin),
+                                 (p * b, n, t, rope))], axis=-1)
+        with scope("attn_layout"):
+            dtype = frozen["w_qb"].dtype
+            q = q[:, :, None].astype(dtype)                  # no head shared
+            k, v = k.astype(dtype), kv[..., nope:].astype(dtype)
+    with scope("mla_core"):
         out = attention.attention(q, k, v, t, softmax_scale(cfg))
-    with jax.named_scope("mla_proj"):
-        out = out[:, :, 0].transpose(0, 2, 1, 3).reshape(p, b, t, n * dv)
-        return proj(out, "o")
+    with scope("mla_proj"):
+        with scope("attn_layout"):
+            out = out[:, :, 0].transpose(0, 2, 1, 3).reshape(p, b, t, n * dv)
+        return proj(out, "o", "attn_out")
 
 
 def attention_plan(cfg: DeepSeekV2Config, length: int) -> dict:

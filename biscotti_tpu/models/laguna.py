@@ -57,9 +57,20 @@ from biscotti_tpu.models.lm import frozen_count  # noqa: F401  (its callers')
 from biscotti_tpu.ops import attention, moe
 
 # scopes inside `round_grad` a device trace is read by (a second
-# vocabulary beside parallel/sim.STAGES; docs/OBSERVABILITY.md)
+# vocabulary beside parallel/sim.STAGES; docs/OBSERVABILITY.md);
+# `peer_walk`: the loop of `lm.peer_at_a_time` itself (slices, stacked
+# results and residuals)
 SCOPES = ("lm_embed", "lm_attention", "lm_router", "lm_experts", "lm_dense",
-          "lm_head_loss", "peer_clip")
+          "lm_head_loss", "peer_clip", "peer_walk")
+# what `lm_attention` is made of, each opened INSIDE it and read under
+# SCOPES + SUBSCOPES (under SCOPES alone an instruction's last token is
+# still `lm_attention`, and `lm_attention_ms.device` reads what it read):
+# the block norm; the adapted q, k, v products; the rotation and its
+# concatenations; the reshapes, head-major transposes and casts; the
+# `attention.attention` call alone; the per-head gate and `W_o` with its
+# adapter. None is part of another or a frozen leaf's name (`attn_norm`)
+SUBSCOPES = ("attn_norms", "attn_in", "attn_rotary", "attn_layout",
+             "attn_core", "attn_out")
 
 
 @dataclass(frozen=True)
@@ -149,25 +160,39 @@ def _attention(cfg, at, h, frozen, adapters):
     kind, n = cfg.layer_types[at], cfg.heads[at]
     p, b, t, _ = h.shape
     dh, kv = cfg.head_dim, cfg.kv_heads
-    x = lm.rms(h, frozen["attn_norm"], cfg.eps)
     lora = frozen["lora_a"]
+    scope = jax.named_scope
 
     def heads(name, count):
-        y = lm.adapted(cfg, x, frozen["w" + name], lora[name], adapters[name])
-        return y.reshape(p * b, t, count, dh).transpose(0, 2, 1, 3)
+        with scope("attn_in"):
+            y = lm.adapted(cfg, x, frozen["w" + name], lora[name],
+                           adapters[name])
+        with scope("attn_layout"):
+            return y.reshape(p * b, t, count, dh).transpose(0, 2, 1, 3)
 
-    q, k, v = heads("q", n), heads("k", kv), heads("v", kv)
-    cos, sin, rot = rotary_tables(cfg, kind, t)
-    q, k = _rotate(q, cos, sin, rot), _rotate(k, cos, sin, rot)
-    dtype = frozen["wq"].dtype
-    q = q.reshape(p * b, kv, n // kv, t, dh).astype(dtype)
-    out = attention.attention(q, k.astype(dtype), v.astype(dtype),
-                              t if kind == "full" else cfg.window)
-    gate = jax.nn.sigmoid(lm.mm(x, frozen["wgate"]))          # [P, b, T, n]
-    out = out.reshape(p * b, n, t, dh).transpose(0, 2, 1, 3)  # [W, T, n, dh]
-    out = out * gate.reshape(p * b, t, n)[..., None]
-    out = out.reshape(p, b, t, n * dh)
-    return lm.adapted(cfg, out, frozen["wo"], lora["o"], adapters["o"])
+    with scope("lm_attention"):
+        with scope("attn_norms"):
+            x = lm.rms(h, frozen["attn_norm"], cfg.eps)
+        q, k, v = heads("q", n), heads("k", kv), heads("v", kv)
+        with scope("attn_rotary"):
+            cos, sin, rot = rotary_tables(cfg, kind, t)
+            q, k = _rotate(q, cos, sin, rot), _rotate(k, cos, sin, rot)
+        with scope("attn_layout"):
+            dtype = frozen["wq"].dtype
+            q = q.reshape(p * b, kv, n // kv, t, dh).astype(dtype)
+            k, v = k.astype(dtype), v.astype(dtype)
+        with scope("attn_core"):
+            out = attention.attention(q, k, v,
+                                      t if kind == "full" else cfg.window)
+        with scope("attn_out"):
+            gate = jax.nn.sigmoid(lm.mm(x, frozen["wgate"]))  # [P, b, T, n]
+        with scope("attn_layout"):                            # [W, T, n, dh]
+            out = out.reshape(p * b, n, t, dh).transpose(0, 2, 1, 3)
+        with scope("attn_out"):
+            out = out * gate.reshape(p * b, t, n)[..., None]
+            out = out.reshape(p, b, t, n * dh)
+            return lm.adapted(cfg, out, frozen["wo"], lora["o"],
+                              adapters["o"])
 
 
 def attention_plan(cfg: LagunaConfig, length: int) -> dict:
@@ -213,10 +238,11 @@ def _layer_as(cfg, at, h, frozen, adapters):
     three kinds of layer and not the five layers: the walked attention (a
     loop, rematerialised and transposed) is slow to trace, and set-up
     pays it once a layer otherwise (`setup_s`; PERF.md section 6, PR 35)."""
-    with jax.named_scope("lm_attention"):
-        h = h + lm.peer_at_a_time(
-            lambda h, adapters: _attention(cfg, at, h, frozen, adapters),
-            h, adapters)
+    walked = lm.peer_at_a_time(
+        lambda h, adapters: _attention(cfg, at, h, frozen, adapters),
+        h, adapters)
+    with jax.named_scope("lm_attention"):  # the residual is the block's too
+        h = h + walked
     out, counts, picks = _mlp(cfg, at, h.reshape(-1, h.shape[-1]), frozen)
     return h + out.reshape(h.shape), counts, picks
 

@@ -101,12 +101,21 @@ def peer_at_a_time(fn, h, adapters):
     compiler keeps an operand of tens of MB there (one peer's
     `f32[128, 1024, 64]` is 32 MB) and streams three peers' from HBM
     (DeepSeek-V2's projections at a block of 3: 2,007 ms a round against
-    1,602 at a block of 1; PERF.md section 6, PR 35)."""
+    1,602 at a block of 1; PERF.md section 6, PR 35).
+
+    The walk books its own work: the loop's instructions (a peer's rows
+    sliced out, the results and the backward pass's residuals stacked,
+    the counters) carry the scope `peer_walk`, which joins the model's
+    `SCOPES`. A traced instruction takes the LAST scope of its `op_name`,
+    so whatever `fn` computes must open its scope INSIDE `fn`, or it
+    reads as the walk's (docs/OBSERVABILITY.md, "Device trace"). A block
+    of one peer walks nothing and opens no such scope."""
     if h.shape[0] == 1:
         return fn(h, adapters)
-    return jax.lax.map(
-        lambda one: fn(*jax.tree.map(lambda a: a[None], one))[0],
-        (h, adapters))
+    with jax.named_scope("peer_walk"):
+        return jax.lax.map(
+            lambda one: fn(*jax.tree.map(lambda a: a[None], one))[0],
+            (h, adapters))
 
 
 def stacked(found):

@@ -19,6 +19,7 @@ import pytest
 
 from biscotti_tpu.config import BiscottiConfig, Defense
 from biscotti_tpu.data import datasets as ds
+from biscotti_tpu.models import deepseek_v2, granite_hybrid, laguna
 from biscotti_tpu.parallel import sim as sim_module
 from biscotti_tpu.parallel.sim import (STAGES, Simulator,
                                        sharded_round_step_fn)
@@ -295,3 +296,116 @@ def test_sim_module_keeps_the_vocabulary_where_the_readers_look():
     STAGES on the module that defines the traced object."""
     assert sys.modules[Simulator.__module__] is sim_module
     assert sim_module.STAGES is STAGES
+
+
+# ---- the walked round books its own work (PR 36): `peer_walk` around
+# `lm.peer_at_a_time`, the attention block in parts (`SUBSCOPES`)
+
+LM_TINY = dict(dataset="lm_tokens_tiny", num_nodes=8, batch_size=2,
+               sample_percent=1.0, learning_rate=0.1, grad_clip=1.0, seed=0)
+# model name of `lm_tokens_tiny` -> the scope its parts are opened inside
+COARSE = {"": "lm_attention", "deepseek_v2_tiny": "mla_proj"}
+
+
+def _vocabulary(sim):
+    module = sys.modules[type(sim.model.info["config"]).__module__]
+    return module.SCOPES, module.SUBSCOPES
+
+
+@pytest.fixture(scope="module")
+def walked_names():
+    """{model: (every `op_name` of `round_hlo()`, SCOPES, SUBSCOPES)}, the
+    six sampled peers stepped three a block: only the `op_name`s, because
+    the text's tables also hold file and function names (this file's)."""
+    found = {}
+    for model in COARSE:
+        walked = Simulator(_cfg(**dict(LM_TINY, model_name=model)))
+        walked.steps.block = 3  # before the program is traced
+        found[model] = (re.findall(r'op_name="([^"]*)"', walked.round_hlo()),
+                        *_vocabulary(walked))
+    return found
+
+
+def _tokens(op_name, vocabulary):
+    return re.findall(r"(?<![\w])(?:%s)(?![\w])" % "|".join(vocabulary),
+                      op_name)
+
+
+@pytest.mark.parametrize("model,scope", [
+    (model, scope) for model, module in (("", laguna),
+                                         ("deepseek_v2_tiny", deepseek_v2))
+    for scope in ("peer_walk",) + module.SUBSCOPES])
+def test_the_walk_and_every_part_are_named_in_round_hlo(walked_names, model,
+                                                        scope):
+    names, scopes, parts = walked_names[model]
+    assert scope in scopes + parts
+    assert any(_names(name, scope) for name in names), scope
+
+
+@pytest.mark.parametrize("model", sorted(COARSE))
+def test_a_part_stands_inside_its_scope_and_the_walk_computes_nothing(
+        walked_names, model):
+    """Under SCOPES alone an instruction of a part still reads as the
+    coarse scope (its token stands BEFORE the part's, so the last SCOPES
+    token of the `op_name` is the coarse one and every reader that was
+    reads what it read); and what the loop's body computes re-opens its
+    scope inside the body, so the walk's own token is the last only on
+    the loop's plumbing."""
+    names, scopes, parts = walked_names[model]
+    for name in names:
+        found = _tokens(name, scopes + parts)
+        if any(token in parts for token in found):
+            part = next(i for i, token in enumerate(found)
+                        if token in parts)
+            assert COARSE[model] in found[:part], name
+            assert _tokens(name, scopes)[-1] == COARSE[model], name
+    walks = [name.rsplit("/", 1)[-1] for name in names
+             if _tokens(name, scopes)[-1:] == ["peer_walk"]]
+    assert "dot_general" not in walks
+    assert {"dynamic_slice", "dynamic_update_slice", "while"} <= set(walks)
+    # the products of the block are read under their parts (the tiny
+    # preset's core is the `einsum` form: Laguna's has products too)
+    products = {_tokens(name, scopes + parts)[-1] for name in names
+                if name.endswith("dot_general")
+                and COARSE[model] in _tokens(name, scopes)[-1:]}
+    assert products - {"attn_core"} == {"attn_in", "attn_out"}
+
+
+@pytest.mark.parametrize("model", sorted(COARSE))
+def test_a_block_of_one_peer_walks_nothing(model):
+    """`peer_at_a_time` hands a block of one peer straight to its
+    function: no loop, no `peer_walk` in any `op_name` (Granite's cell
+    runs such a block), and the parts are there all the same."""
+    alone = Simulator(_cfg(**dict(LM_TINY, model_name=model)))
+    alone.steps.block = 1
+    names = re.findall(r'op_name="([^"]*)"', alone.round_hlo())
+    assert not [name for name in names if _names(name, "peer_walk")]
+    assert any(_names(name, "attn_in") for name in names)
+
+
+def test_no_scope_is_part_of_another_or_a_frozen_leafs_name():
+    """The join takes the LAST token of an `op_name`, and a parameter's
+    `op_name` holds its path in the frozen tree (`attn_norm`, `q_norm`):
+    over the round's stages, the three language models' scopes and their
+    parts, no name is part of another and none is a leaf's key."""
+    names, leaves = set(STAGES), set()
+
+    def keys(tree):
+        if isinstance(tree, dict):
+            for key, below in tree.items():
+                leaves.add(key)
+                keys(below)
+        elif isinstance(tree, list):
+            for below in tree:
+                keys(below)
+
+    for module in (laguna, deepseek_v2, granite_hybrid):
+        names |= set(module.SCOPES) | set(getattr(module, "SUBSCOPES", ()))
+        for cfg in module.PRESETS.values():
+            for tree in module._shapes(cfg):
+                keys(tree)
+    assert {"attn_norm", "q_norm", "wo", "lora_a"} <= leaves
+    assert {"peer_walk", "attn_norms", "attn_core"} <= names
+    assert not [(a, b) for a in names for b in names if a != b and a in b]
+    assert not names & leaves
+    assert not hasattr(granite_hybrid, "SUBSCOPES")  # its cell is untouched
