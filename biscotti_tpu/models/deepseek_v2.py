@@ -19,7 +19,9 @@ elsewhere would add is left out (ops/moe.py).
       k = [k_nope | rot(k_r)], rot(k_r) the SAME for every head
       rot: rotary on the rope dimensions in interleaved pairs (2i, 2i + 1),
            theta 10,000, YaRN frequencies; cos, sin x
-           mscale(factor, mscale) / mscale(factor, mscale_all_dim) = 1
+           mscale(factor, mscale) / mscale(factor, mscale_all_dim) = 1;
+           in place (ops/rotary.py): q's and the key's turned dimensions
+           stay where they were, so every score is that of the pairs
       o = softmax(s q k^T + causal) v,
           s = (nope + rope)^-0.5 x m^2,  m = 0.1 mscale_all_dim ln factor + 1
       h += concat(o) W_o          (adapters on W_qa, W_qb, W_kva, W_kvb, W_o)
@@ -32,12 +34,14 @@ elsewhere would add is left out (ops/moe.py).
 
 The attention core (the `softmax(...) v` line) is ops/attention.py's, with
 a score width (nope + rope = 192) that is not the value width (128) and no
-head shared: at the published size ONE fused, blocked kernel a call that
-contracts the 192 as they are (a lane tile and a half: 3.5 ms a forward
-call of a block of 3 windows where the scores zero-padded to 256 take 4.7
-and the `einsum` form 6.2; PERF.md section 6, PR 31), the scores in VMEM;
-at the tiny preset the `einsum` form. `attention_plan` says which, from
-the shapes alone.
+head shared: at the published size ONE fused, blocked kernel a call, the
+scores in VMEM; at the tiny preset the `einsum` form. `attention_plan`
+says which, from the shapes alone. No array holds `k`: the core takes
+`k_nope` [W, heads, T, 128] and the ONE turned `k_r` [W, 1, T, 64] as two
+operands and contracts q's 192 as 128 + 64 (PR 37; until then `k_r` was
+broadcast to 128 heads and concatenated, and q sliced, turned and
+concatenated, each in float32 over `[128, 1,024, 192]`: 445 ms of a 2,948
+ms round, PERF.md section 6).
 
 The trainable tree is {"layers": [{"kva", "kvb", "o", "qa", "qb"}: B [r,
 out]]}; the frozen tree holds everything else in `dtype`.
@@ -54,7 +58,7 @@ import jax
 import jax.numpy as jnp
 
 from biscotti_tpu.models import lm
-from biscotti_tpu.ops import attention, moe
+from biscotti_tpu.ops import attention, moe, rotary
 
 # scopes inside `round_grad` a device trace is read by (the model's own
 # vocabulary, as models/laguna.SCOPES is Laguna's; docs/OBSERVABILITY.md).
@@ -67,9 +71,11 @@ SCOPES = ("lm_embed", "mla_proj", "mla_core", "lm_router", "lm_experts",
 # what `mla_proj` is made of, each opened INSIDE it and read under SCOPES +
 # SUBSCOPES (under SCOPES alone an instruction's last token is still
 # `mla_proj`, and `mla_proj_ms.device` reads what it read): the three
-# norms; the four adapted products into the core; the rotation, the one
-# shared key's broadcast and the concatenations; the reshapes, head-major
-# transposes, casts and the value's slice; `W_o` with its adapter. The
+# norms; the four adapted products into the core; the rotation of q's 64
+# and of the one shared key, with the cast to the base's type and q's
+# head-major write (ops/rotary.py: `rotary_to_heads`, `rotary_from_heads` in
+# a trace); `[k_nope | v]`'s cast, head-major transposes and two slices and
+# the result's transpose; `W_o` with its adapter. The
 # names are models/laguna.py's, none part of another or a frozen leaf's
 SUBSCOPES = ("attn_norms", "attn_in", "attn_rotary", "attn_layout",
              "attn_out")
@@ -161,17 +167,6 @@ def rotary_tables(cfg: DeepSeekV2Config, length: int):
         / mscale(scaling["factor"], scaling["mscale_all_dim"])), length)
 
 
-def _rotate(x, cos, sin):
-    """Rotary in interleaved pairs: dimensions (2i, 2i + 1) of the last
-    axis turn by angle i; x [..., T, rope], cos/sin [T, rope / 2]. The
-    result holds the pairs' first halves, then their second halves (the
-    order DeepSeek's own code leaves them in: q's and k's alike, so every
-    score is that of the interleaved order)."""
-    pairs = x.reshape(x.shape[:-1] + (x.shape[-1] // 2, 2))
-    a, b = pairs[..., 0], pairs[..., 1]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
-
-
 # ----------------------------------------------------------------- forward
 
 
@@ -179,7 +174,7 @@ def _attention(cfg, h, frozen, adapters):
     """The latent-attention block on h [P, b, T, H]."""
     p, b, t, _ = h.shape
     n, nope, rope, dv = cfg.heads, cfg.nope, cfg.rope, cfg.v_dim
-    lora = frozen["lora_a"]
+    lora, dtype = frozen["lora_a"], frozen["w_qb"].dtype
     scope = jax.named_scope
 
     def proj(x, name, part="attn_in"):
@@ -191,36 +186,31 @@ def _attention(cfg, h, frozen, adapters):
         with scope("attn_norms"):
             return lm.rms(x, frozen[name], cfg.eps)
 
-    def heads(y, width):
-        """[P, b, T, n x width] head-major: [W, n, T, width]."""
-        with scope("attn_layout"):
-            return y.reshape(p * b, t, n, width).transpose(0, 2, 1, 3)
+    def turned(y, width):
+        """y [P, b, T, n x width] with every head's last `rope` dimensions
+        turned, in float32, then cast to the base's type, head-major: [W,
+        n, T, width]."""
+        return rotary.turn(y.reshape(p * b, t, -1), *rotary.tables(
+            *rotary_tables(cfg, t), width), dtype)
 
     with scope("mla_proj"):
         x = norm(h, "attn_norm")
         c_q = norm(proj(x, "qa"), "q_norm")
-        q = heads(proj(c_q, "qb"), nope + rope)              # [W, n, T, 192]
-        latent = proj(x, "kva")                              # [P, b, T, 576]
+        q = proj(c_q, "qb")                              # [P, b, T, n x 192]
+        latent = proj(x, "kva")                          # [P, b, T, 576]
         with scope("attn_in"):
             c_kv = latent[..., :cfg.kv_rank]
         c_kv = norm(c_kv, "kv_norm")
+        kv = proj(c_kv, "kvb")                           # [P, b, T, n x 256]
         with scope("attn_rotary"):
-            k_r = latent[..., cfg.kv_rank:].reshape(p * b, 1, t, rope)
-        kv = heads(proj(c_kv, "kvb"), nope + dv)             # [W, n, T, 256]
-        with scope("attn_rotary"):
-            cos, sin = rotary_tables(cfg, t)
-            q = jnp.concatenate(
-                [q[..., :nope], _rotate(q[..., nope:], cos, sin)], axis=-1)
-            k = jnp.concatenate([
-                kv[..., :nope],
-                jnp.broadcast_to(_rotate(k_r, cos, sin),
-                                 (p * b, n, t, rope))], axis=-1)
+            q = turned(q, nope + rope)[:, :, None]       # no head shared
+            k_r = turned(latent[..., cfg.kv_rank:], rope)   # [W, 1, T, 64]
         with scope("attn_layout"):
-            dtype = frozen["w_qb"].dtype
-            q = q[:, :, None].astype(dtype)                  # no head shared
-            k, v = k.astype(dtype), kv[..., nope:].astype(dtype)
+            kv = kv.astype(dtype).reshape(p * b, t, n, nope + dv)
+            kv = kv.transpose(0, 2, 1, 3)                # [W, n, T, 256]
+            k, v = kv[..., :nope], kv[..., nope:]
     with scope("mla_core"):
-        out = attention.attention(q, k, v, t, softmax_scale(cfg))
+        out = attention.attention(q, k, v, t, softmax_scale(cfg), k_r)
     with scope("mla_proj"):
         with scope("attn_layout"):
             out = out[:, :, 0].transpose(0, 2, 1, 3).reshape(p, b, t, n * dv)
@@ -230,14 +220,17 @@ def _attention(cfg, h, frozen, adapters):
 def attention_plan(cfg: DeepSeekV2Config, length: int) -> dict:
     """How `_attention`'s core is built on windows of `length`, from the
     shapes alone: `fused` 1 where it is ops/attention.py's kernel (0: the
-    `einsum` form), and `block_share`, the (query block, key block) pairs
-    of the [T, T] scores the kernel visits over all pairs (the `einsum`
-    form: 1). Every layer is the same."""
+    `einsum` form), `block_share`, the (query block, key block) pairs of
+    the [T, T] scores the kernel visits over all pairs (the `einsum` form:
+    1), and `shared_key`, 1 where the core receives a key part ONCE for
+    all heads (the turned `k_r`, `rope` wide) beside every head's own.
+    Every layer is the same."""
     block = attention.blocks(1, length, cfg.nope + cfg.rope, cfg.dtype,
-                             cfg.v_dim)
+                             cfg.v_dim, cfg.rope)
     return {"fused": int(bool(block)),
             "block_share": attention.block_share(length, length, *block)
-            if block else 1.0}
+            if block else 1.0,
+            "shared_key": 1}
 
 
 def _mlp(cfg, dense, h, frozen):
@@ -359,7 +352,14 @@ def deepseek_v2_model(name: str, cfg: DeepSeekV2Config, length: int):
         block's attention walked a peer at a time (`lm.peer_at_a_time`)
         the round of 3 compiles at 4.50 GB of temporaries, 15.00 GB with
         its arguments and code of the 16.91 the chip states; a block of 7
-        (7.7 GB of temporaries by the line above) does not fit at all."""
+        (7.7 GB of temporaries by the line above) does not fit at all.
+        Since PR 37 no float32 k at the scores' width exists (the core
+        takes `k_nope` and the one rotary key apart, q is cast as it is
+        turned): the round of 3 compiles at 4.29 GB of temporaries and the
+        round of 1 at 2.14, so a peer adds 1.08 GB where it added 1.16,
+        still MORE than the 0.881 counted here (the walk's stacked results
+        and residuals are in it). The count is left as it is: it answers
+        3 on the chip, and 3 is what fits."""
         t = batch * length
         per_head = 2 * (cfg.nope + cfg.rope) + cfg.nope + cfg.v_dim
         return 2 * 4 * t * (cfg.heads * per_head + cfg.vocab)

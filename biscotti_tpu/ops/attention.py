@@ -22,6 +22,19 @@ this kernel takes 1.0 and 3.1 (PERF.md section 6, PR 30).
     20.26 (eval/eval_attention.py --layers mla; PERF.md section 6, PR 31).
     `scale` multiplies the scores: 1 / sqrt(d) unless the caller has its
     own (DeepSeek-V2's carries YaRN's m^2).
+  a SHARED key part (optional; PR 37): `shared` [W, 1, T, r], the part of
+    every head's key that all heads have alike (DeepSeek-V2's ONE rotary
+    key, r = 64). k is then [W, kv, T, d - r], q stays [W, kv, G, T, d]
+    and the scores are scale (q[..., :d - r] k^T + q[..., d - r:]
+    shared^T): the same products, and no array holds [k | shared]. The
+    part's block ignores the head index, so it is fetched once a window;
+    q's block is cut at k's width (lane 128: a tile boundary); dq is
+    written in its two lane ranges; the part's cotangent adds up over the
+    heads AND the query blocks in float32 scratch and is written once a
+    window (that call's head axis is `arbitrary`), cast once, where a
+    broadcast key's would be cast a head and summed afterwards. A call
+    WITHOUT the part has the operands, the kernels and the lowered text it
+    had before the part existed (tests/test_tpu_lowering.py pins them).
   mask: query i sees key j where `j <= i` and `i - j < window`; a window of
     T or more is the causal mask. So the key blocks a query block visits
     are a RANGE computed from its number (`key_blocks`): program-id
@@ -60,7 +73,8 @@ key blocks of 128 (half of them), and the forward wants its queries DOWN
 from __future__ import annotations
 
 import math
-from functools import partial
+import operator
+from functools import partial, reduce
 
 import jax
 import jax.numpy as jnp
@@ -83,13 +97,20 @@ _NT = (((1,), (1,)), ((), ()))  # a @ b^T
 _TN = (((0,), (0,)), ((), ()))  # a^T @ b
 
 
-def plain(q, k, v, window: int, scale=None):
+def plain(q, k, v, window: int, scale=None, shared=None):
     """The `einsum` form: q [W, kv, G, T, d], k [W, kv, T, d], v [W, kv, T,
     e] in one type; float32[W, kv, G, T, e]. Makes the scores [W, kv, G, T,
-    T]; `scale` multiplies them (None: 1 / sqrt(d))."""
+    T]; `scale` multiplies them (None: 1 / sqrt(d)). With `shared` [W, 1,
+    T, r], a key part every head has alike, k is [W, kv, T, d - r] and
+    q's last r dimensions are contracted with `shared`."""
     t, d = q.shape[-2:]
-    scores = jnp.einsum("wgqtd,wgsd->wgqts", q, k,
+    own = k.shape[-1]
+    scores = jnp.einsum("wgqtd,wgsd->wgqts",
+                        q if shared is None else q[..., :own], k,
                         preferred_element_type=jnp.float32)
+    if shared is not None:
+        scores += jnp.einsum("wgqtd,wsd->wgqts", q[..., own:], shared[:, 0],
+                             preferred_element_type=jnp.float32)
     scores = scores / math.sqrt(d) if scale is None else scores * scale
     i, j = np.arange(t)[:, None], np.arange(t)[None, :]
     seen = (j <= i) if window >= t else ((j <= i) & (i - j < window))
@@ -117,15 +138,19 @@ def visited(t: int, window: int, bq: int, bk: int):
 
 
 def _buffers(g: int, t: int, d: int, bq: int, bk: int, size: int,
-             e: int = None) -> int:
+             e: int = None, shared: int = 0) -> int:
     """Bytes of VMEM the backward (the larger of the two) holds: two
     buffers each of q's and dq's blocks (`d` wide, the scores' width), of
     the result's cotangent (float32, `e` wide, the values' width; None: d),
-    of k, dk (d) and v, dv (e) whole and of the two rows' statistics; dk's
-    and dv's float32 sums; six tiles of the scores' size."""
-    rows, e = g * bq, d if e is None else e
-    return (2 * rows * (2 * d * size + 4 * e) + 2 * 2 * t * (d + e) * size
-            + 2 * 2 * 4 * rows + 4 * t * (d + e) + 6 * 4 * bq * bk)
+    of k, dk and v, dv (e) whole and of the two rows' statistics; dk's
+    and dv's float32 sums; six tiles of the scores' size. k and dk are d
+    wide less the `shared` dimensions of a key part all heads have alike,
+    which is held like k: itself, its cotangent and that cotangent's
+    float32 sum. Every width in whole lane tiles."""
+    rows, e = g * bq, _padded(d if e is None else e)
+    keys, d = _padded(d - shared) + _padded(shared), _padded(d)
+    return (2 * rows * (2 * d * size + 4 * e) + 2 * 2 * t * (keys + e) * size
+            + 2 * 2 * 4 * rows + 4 * t * (keys + e) + 6 * 4 * bq * bk)
 
 
 def _padded(d: int) -> int:
@@ -133,21 +158,22 @@ def _padded(d: int) -> int:
     return -(-d // _LANES) * _LANES
 
 
-def blocks(g: int, t: int, d: int, dtype, e: int = None):
+def blocks(g: int, t: int, d: int, dtype, e: int = None, shared: int = 0):
     """(query block, key block) of the kernel for `g` query heads a
     key/value head on windows of `t`, scores that contract `d` and values
-    of `e` (None: d), or None where the kernel does not take the shape: a
-    score or a value width not of 64 (half a lane tile: Mosaic takes whole
-    tiles and one half, as they are; Granite's heads are 64 | 64), a window
-    that is no whole number of blocks, another type, or a key/value head
-    too long to hold whole."""
+    of `e` (None: d), `shared` of the d with a key part all heads have
+    alike (0: every key is its head's own), or None where the kernel does
+    not take the shape: a score, a shared or a value width not of 64 (half
+    a lane tile: Mosaic takes whole tiles and one half, as they are;
+    Granite's heads are 64 | 64), a window that is no whole number of
+    blocks, another type, or a key/value head too long to hold whole."""
     e = d if e is None else e
-    if (e % (_LANES // 2) or d % (_LANES // 2)
+    if (e % (_LANES // 2) or d % (_LANES // 2) or shared % (_LANES // 2)
             or jnp.dtype(dtype) not in (jnp.bfloat16, jnp.float32)):
         return None
     size = jnp.dtype(dtype).itemsize
     return next(((bq, bk) for bq, bk in BLOCKS if t % bq == 0 and t % bk == 0
-                 and _buffers(g, t, _padded(d), bq, bk, size, _padded(e))
+                 and _buffers(g, t, d, bq, bk, size, e, shared)
                  <= _VMEM_BUFFERS), None)
 
 
@@ -179,21 +205,40 @@ def _as_row(column):
     return jnp.broadcast_to(column, (n, _LANES)).T[:1]
 
 
-def _forward(q_ref, k_ref, v_ref, out_ref, lse_ref, *, bq: int, bk: int,
-             window: int, scale: float):
+def _cut(ref, g, keys):
+    """Head `g` of the group's block `ref` [G, rows, d], one part a key of
+    `keys`, each as wide as its key: whole where every key is the head's
+    own, cut at k's width (a lane tile boundary at the published size)
+    where a shared part follows."""
+    if len(keys) == 1:
+        return (ref[g],)
+    own = keys[0].shape[-1]
+    return ref[g, :, :own], ref[g, :, own:]
+
+
+def _scores(rows, columns):
+    """float32 sum over the parts of rows[i] columns[i]^T."""
+    return reduce(operator.add, (
+        jax.lax.dot_general(a, b, _NT, preferred_element_type=jnp.float32)
+        for a, b in zip(rows, columns)))
+
+
+def _forward(q_ref, k_ref, v_ref, *rest, bq: int, bk: int, window: int,
+             scale: float):
+    *shared_ref, out_ref, lse_ref = rest  # the shared key part, if any
+    keys = (k_ref, *shared_ref)
     iq = pl.program_id(2)
     first, last = key_blocks(iq, bq, bk, window, jnp.maximum)
     e, t = v_ref.shape[-1], k_ref.shape[0]
     relative = _relative(bq, bk, False)
 
     def head(g, _):
-        q = q_ref[g]
+        q = _cut(q_ref, g, keys)
 
         def step(j, carry):
             m, l, acc = carry
             at = pl.ds(pl.multiple_of(j * bk, bk), bk)
-            s = jax.lax.dot_general(q, k_ref[at, :], _NT,
-                                    preferred_element_type=jnp.float32)
+            s = _scores(q, [key[at, :] for key in keys])
             s = jnp.where(_seen(relative, iq * bq - j * bk, window, t),
                           s * scale, _HIDDEN)
             m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
@@ -217,9 +262,14 @@ def _forward(q_ref, k_ref, v_ref, out_ref, lse_ref, *, bq: int, bk: int,
     jax.lax.fori_loop(0, q_ref.shape[0], head, None)
 
 
-def _backward(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, dk_ref,
-              dv_ref, dk_sum, dv_sum, *, bq: int, bk: int, window: int,
+def _backward(*refs, shared: bool, bq: int, bk: int, window: int,
               scale: float):
+    # inputs, results and float32 sums: after each three the shared key
+    # part's own (the part, its cotangent, that cotangent's sum), if any
+    q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, *r_ref = refs[:6 + shared]
+    dq_ref, dk_ref, dv_ref, *dr_ref = refs[6 + shared:9 + 2 * shared]
+    dk_sum, dv_sum, *dr_sum = refs[9 + 2 * shared:]
+    keys, key_sums = (k_ref, *r_ref), (dk_sum, *dr_sum)
     iq = pl.program_id(2)
 
     @pl.when(iq == 0)
@@ -227,20 +277,24 @@ def _backward(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, dk_ref,
         dk_sum[...] = jnp.zeros_like(dk_sum)
         dv_sum[...] = jnp.zeros_like(dv_sum)
 
+    if shared:  # its sum runs over a window's heads too
+        @pl.when((iq == 0) & (pl.program_id(1) == 0))
+        def _():
+            dr_sum[0][...] = jnp.zeros_like(dr_sum[0])
+
     first, last = key_blocks(iq, bq, bk, window, jnp.maximum)
-    d, t = q_ref.shape[-1], k_ref.shape[0]
+    t = k_ref.shape[0]
     relative = _relative(bq, bk, True)
 
     def head(g, _):
-        q = q_ref[g]
-        do = do_ref[g].astype(q.dtype)
+        q = _cut(q_ref, g, keys)
+        do = do_ref[g].astype(q[0].dtype)
         lse, di = lse_ref[g], di_ref[g]                       # [1, bq]
 
         def step(j, dq):
             at = pl.ds(pl.multiple_of(j * bk, bk), bk)
-            k, v = k_ref[at, :], v_ref[at, :]
-            s = jax.lax.dot_general(k, q, _NT,
-                                    preferred_element_type=jnp.float32)
+            k, v = [key[at, :] for key in keys], v_ref[at, :]
+            s = _scores(k, q)
             s = jnp.where(_seen(relative, iq * bq - j * bk, window, t),
                           s * scale, _HIDDEN)
             p = jnp.exp(s - lse)                              # [bk, bq]
@@ -249,15 +303,23 @@ def _backward(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, dk_ref,
             dp = jax.lax.dot_general(v, do, _NT,
                                      preferred_element_type=jnp.float32)
             # ds less its scale, which dq's and dk's sums take once a row
-            ds = (p * (dp - di)).astype(q.dtype)
-            dk_sum[at, :] += jnp.dot(ds, q,
-                                     preferred_element_type=jnp.float32)
-            return dq + jax.lax.dot_general(
-                ds, k, _TN, preferred_element_type=jnp.float32)
+            ds = (p * (dp - di)).astype(do.dtype)
+            for total, part in zip(key_sums, q):
+                total[at, :] += jnp.dot(ds, part,
+                                        preferred_element_type=jnp.float32)
+            return tuple(part + jax.lax.dot_general(
+                ds, key, _TN, preferred_element_type=jnp.float32)
+                for part, key in zip(dq, k))
 
-        dq = jax.lax.fori_loop(first, last + 1, step,
-                               jnp.zeros((bq, d), jnp.float32))
-        dq_ref[g] = (dq * scale).astype(dq_ref.dtype)
+        dq = jax.lax.fori_loop(
+            first, last + 1, step,
+            tuple(jnp.zeros(part.shape, jnp.float32) for part in q))
+        if len(dq) == 1:
+            dq_ref[g] = (dq[0] * scale).astype(dq_ref.dtype)
+        else:  # in q's two lane ranges
+            own = k_ref.shape[-1]
+            dq_ref[g, :, :own] = (dq[0] * scale).astype(dq_ref.dtype)
+            dq_ref[g, :, own:] = (dq[1] * scale).astype(dq_ref.dtype)
 
     jax.lax.fori_loop(0, q_ref.shape[0], head, None)
 
@@ -265,6 +327,12 @@ def _backward(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, dk_ref,
     def _():
         dk_ref[...] = (dk_sum[...] * scale).astype(dk_ref.dtype)
         dv_ref[...] = dv_sum[...].astype(dv_ref.dtype)
+
+    if shared:  # written once a window
+        @pl.when((iq == pl.num_programs(2) - 1)
+                 & (pl.program_id(1) == pl.num_programs(1) - 1))
+        def _():
+            dr_ref[0][...] = (dr_sum[0][...] * scale).astype(dr_ref[0].dtype)
 
 
 def _specs(g: int, t: int, d: int, bq: int):
@@ -278,13 +346,25 @@ def _specs(g: int, t: int, d: int, bq: int):
                          lambda w, h, i: (w, h, 0, 0, i)))
 
 
+def _shared_spec(shared):
+    """[BlockSpec] of a shared key part [W, 1, T, r], whole: its block
+    ignores the head, so it stays in VMEM across a window's heads (and
+    its cotangent's block is written back once a window); [] of none."""
+    return [pl.BlockSpec((None, None) + part.shape[2:],
+                         lambda w, h, i: (w, 0, 0, 0)) for part in shared]
+
+
 _SEMANTICS = ("parallel", "parallel", "arbitrary")
+# with a shared key part the backward's heads run in order: the part's
+# cotangent adds up over them in one scratch (the v5e has one core a chip)
+_SEMANTICS_SHARED = ("parallel", "arbitrary", "arbitrary")
 
 
-def _call_forward(interpret, q, k, v, *, window, bq, bk, scale):
+def _call_forward(interpret, q, k, v, *shared, window, bq, bk, scale):
     w, kv, g, t, d = q.shape
     e = v.shape[-1]  # the values' width, the scores' apart (== d: the same)
-    heads, whole, rows = _specs(g, t, d, bq)
+    heads, _, rows = _specs(g, t, d, bq)
+    _, whole, _ = _specs(g, t, k.shape[-1], bq)
     heads_e, whole_e, _ = _specs(g, t, e, bq)
     # Mosaic has no 64-bit types: traced with x64 off, as the repo's other
     # kernels are; every operand is 32 bits or narrower already
@@ -292,7 +372,7 @@ def _call_forward(interpret, q, k, v, *, window, bq, bk, scale):
         return pl.pallas_call(
             partial(_forward, bq=bq, bk=bk, window=window, scale=scale),
             grid=(w, kv, t // bq),
-            in_specs=[heads, whole, whole_e],
+            in_specs=[heads, whole, whole_e] + _shared_spec(shared),
             out_specs=[heads_e, rows],
             out_shape=[jax.ShapeDtypeStruct(q.shape[:-1] + (e,), jnp.float32),
                        jax.ShapeDtypeStruct((w, kv, g, 1, t), jnp.float32)],
@@ -300,31 +380,34 @@ def _call_forward(interpret, q, k, v, *, window, bq, bk, scale):
                 dimension_semantics=_SEMANTICS),
             interpret=interpret,
             name="attention_forward",
-        )(q, k, v)
+        )(q, k, v, *shared)
 
 
-def _call_backward(interpret, q, k, v, do, lse, di, *, window, bq, bk,
+def _call_backward(interpret, q, k, v, do, lse, di, *shared, window, bq, bk,
                    scale):
     w, kv, g, t, d = q.shape
     e = v.shape[-1]
-    heads, whole, rows = _specs(g, t, d, bq)
+    heads, _, rows = _specs(g, t, d, bq)
+    _, whole, _ = _specs(g, t, k.shape[-1], bq)
     heads_e, whole_e, _ = _specs(g, t, e, bq)
+    part = _shared_spec(shared)
     with jax.enable_x64(False):
         return pl.pallas_call(
-            partial(_backward, bq=bq, bk=bk, window=window, scale=scale),
+            partial(_backward, shared=bool(shared), bq=bq, bk=bk,
+                    window=window, scale=scale),
             grid=(w, kv, t // bq),
-            in_specs=[heads, whole, whole_e, heads_e, rows, rows],
-            out_specs=[heads, whole, whole_e],
-            out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
-                       jax.ShapeDtypeStruct(k.shape, k.dtype),
-                       jax.ShapeDtypeStruct(v.shape, v.dtype)],
-            scratch_shapes=[pltpu.VMEM((t, d), jnp.float32),
-                            pltpu.VMEM((t, e), jnp.float32)],
+            in_specs=[heads, whole, whole_e, heads_e, rows, rows] + part,
+            out_specs=[heads, whole, whole_e] + part,
+            out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype)
+                       for a in (q, k, v) + shared],
+            scratch_shapes=[pltpu.VMEM(a.shape[2:], jnp.float32)
+                            for a in (k, v) + shared],
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=_SEMANTICS),
+                dimension_semantics=_SEMANTICS_SHARED if shared
+                else _SEMANTICS),
             interpret=interpret,
             name="attention_backward",
-        )(q, k, v, do, lse, di)
+        )(q, k, v, do, lse, di, *shared)
 
 
 def _dispatched(call, *operands, **static):
@@ -336,59 +419,74 @@ def _dispatched(call, *operands, **static):
         *operands, tpu=partial(call, False), default=partial(call, True))
 
 
+def _some(shared):
+    """() of None, (shared,) of a shared key part: a kernel's operand
+    list is read off what the call was given."""
+    return () if shared is None else (shared,)
+
+
 # jitted so that a program traces each shape of them once, however many
 # layers and passes call them (a round has two head counts x two masks x
 # three window counts, forward, recomputation and backward)
 @partial(jax.jit, static_argnames=("window", "bq", "bk", "scale"))
-def _run_forward(q, k, v, window, bq, bk, scale):
-    return _dispatched(_call_forward, q, k, v, window=window, bq=bq, bk=bk,
-                       scale=scale)
-
-
-@partial(jax.jit, static_argnames=("window", "bq", "bk", "scale"))
-def _run_backward(q, k, v, out, lse, do, window, bq, bk, scale):
-    di = jnp.sum(out * do, axis=-1)[..., None, :]
-    return _dispatched(_call_backward, q, k, v, do, lse, di, window=window,
+def _run_forward(q, k, v, window, bq, bk, scale, shared=None):
+    return _dispatched(_call_forward, q, k, v, *_some(shared), window=window,
                        bq=bq, bk=bk, scale=scale)
 
 
+@partial(jax.jit, static_argnames=("window", "bq", "bk", "scale"))
+def _run_backward(q, k, v, out, lse, do, window, bq, bk, scale, shared=None):
+    di = jnp.sum(out * do, axis=-1)[..., None, :]
+    return _dispatched(_call_backward, q, k, v, do, lse, di, *_some(shared),
+                       window=window, bq=bq, bk=bk, scale=scale)
+
+
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def fused(q, k, v, window: int, block=None, scale=None):
-    """float32[W, kv, G, T, e]: `plain(q, k, v, window, scale)` by the
-    kernel, at `block` (query block, key block), or at `blocks(G, T, d,
-    q.dtype, e)`, which then must take the shape."""
-    return _fused_fwd(q, k, v, window, block, scale)[0]
+def fused(q, k, v, window: int, block=None, scale=None, shared=None):
+    """float32[W, kv, G, T, e]: `plain(q, k, v, window, scale, shared)` by
+    the kernel, at `block` (query block, key block), or at `blocks(G, T, d,
+    q.dtype, e, r)`, which then must take the shape."""
+    return _fused_fwd(q, k, v, window, block, scale, shared)[0]
 
 
-def _static(q, v, window, block, scale):
-    """(window, query block, key block, scale) of a call on q and v."""
+def _static(q, k, v, window, block, scale):
+    """(window, query block, key block, scale) of a call on q, k and v."""
     _, _, g, t, d = q.shape
     return ((min(window, t),)
-            + tuple(block or blocks(g, t, d, q.dtype, v.shape[-1]))
+            + tuple(block or blocks(g, t, d, q.dtype, v.shape[-1],
+                                    d - k.shape[-1]))
             + (1.0 / math.sqrt(d) if scale is None else float(scale),))
 
 
-def _fused_fwd(q, k, v, window, block, scale):
-    out, lse = _run_forward(q, k, v, *_static(q, v, window, block, scale))
-    return out, (q, k, v, out, lse)
+def _fused_fwd(q, k, v, window, block, scale, shared=None):
+    out, lse = _run_forward(q, k, v, *_static(q, k, v, window, block, scale),
+                            shared=shared)
+    return out, (q, k, v, shared, out, lse)
 
 
 def _fused_bwd(window, block, scale, res, do):
-    q, k, v, out, lse = res
-    return _run_backward(q, k, v, out, lse, do,
-                         *_static(q, v, window, block, scale))
+    q, k, v, shared, out, lse = res
+    dq, dk, dv, *dr = _run_backward(
+        q, k, v, out, lse, do, *_static(q, k, v, window, block, scale),
+        shared=shared)
+    return dq, dk, dv, (dr[0] if dr else None)
 
 
 fused.defvjp(_fused_fwd, _fused_bwd)
 
 
-def attention(q, k, v, window: int, scale=None):
+def attention(q, k, v, window: int, scale=None, shared=None):
     """float32[W, kv, G, T, e] = softmax(scale q k^T + mask) v, the mask
     `j <= i and i - j < window`, `scale` 1 / sqrt(d) where None: q [W, kv,
     G, T, d], k [W, kv, T, d], v [W, kv, T, e], the scores' width d and
-    the values' e each its own. The kernel where `blocks` takes the shape,
-    the `einsum` form elsewhere. One algorithm, its parameters read off the
-    shapes."""
-    if blocks(*q.shape[2:], q.dtype, v.shape[-1]) is None:
-        return plain(q, k, v, window, scale)
-    return fused(q, k, v, window, None, scale)
+    the values' e each its own. With `shared` [W, 1, T, r], a key part
+    every head has alike (DeepSeek-V2's one rotary key), k is [W, kv, T,
+    d - r], a head's key is [k | shared] and no array holds it: the
+    scores are q[..., :d - r] k^T + q[..., d - r:] shared^T. The kernel
+    where `blocks` takes the shape, the `einsum` form elsewhere. One
+    algorithm, its parameters read off the shapes and its operands off
+    what it was given."""
+    r = q.shape[-1] - k.shape[-1]
+    if blocks(*q.shape[2:], q.dtype, v.shape[-1], r) is None:
+        return plain(q, k, v, window, scale, shared)
+    return fused(q, k, v, window, None, scale, shared)

@@ -510,6 +510,12 @@ class Simulator:
                         "(query block, key block) pairs of the scores the "
                         "attention visits over all pairs, all layers "
                         "(the einsum form: 1)").set(plan["block_share"])
+                m.gauge("biscotti_lm_attention_shared_key",
+                        "1 where the attention core receives a key part "
+                        "once for all heads beside each head's own "
+                        "(DeepSeek-V2's one rotary key), 0 where every "
+                        "key is its head's own").set(
+                    plan.get("shared_key", 0))
             if "ssm_chunks" in self.model.info:
                 m.gauge("biscotti_ssm_chunks",
                         "chunks a window's state-space scan is walked in "

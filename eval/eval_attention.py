@@ -4,13 +4,17 @@ form (`ops/attention.plain`) against ops/attention.py's kernel at each
 admissible pair of blocks, timed from the DEVICE trace (per-program
 durations).
 
-A peer block of 3 windows of 1,024 tokens. Laguna's: 8 key/value heads of
-128, the full layers' 48 query heads under the causal mask and the sliding
-layers' 72 under a window of 512. DeepSeek-V2's latent attention (`--layers
-mla`): 128 heads, none shared, scores that contract 192 (128 + the 64
-rotary dimensions) and values of 128, causal; the 192 as they are (the
-kernel's products contract a lane tile and a half) and, `padded256_*`,
-zero-padded to 256 inside the timed program. Granite-4.0-H-Micro's
+A peer block of 3 windows of 1,024 tokens (`--windows 1,3`: also one
+window, what a block walked a peer at a time sends). Laguna's: 8 key/value
+heads of 128, the full layers' 48 query heads under the causal mask and the
+sliding layers' 72 under a window of 512. DeepSeek-V2's latent attention
+(`--layers mla`): 128 heads, none shared, scores that contract 192 (128 +
+the 64 rotary dimensions, the SAME for every head) and values of 128,
+causal; `shared_*`, what the model runs since PR 37: k 128 wide and the
+one rotary key `[W, 1, 1,024, 64]` an operand of its own; `kernel_*`, the
+192-wide key assembled outside the timed program (the kernel's products
+contract a lane tile and a half) and, `padded256_*`, zero-padded to 256
+inside the timed program. Granite-4.0-H-Micro's
 attention layers (`--layers granite`): 32 query heads on 8 key/value heads
 of 64 | 64, causal, the scores times 1 / 64; the values as they are (half
 a lane tile) and, `values128_*`, zero-padded to 128 inside the timed
@@ -36,12 +40,13 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 ITERS = 8
-W, T = 3, 1024
+T = 1024
 # kind: (query heads, window, key/value heads, scores' width, values', scale)
 LAYERS = {"full": (48, T, 8, 128, 128, None),
           "sliding": (72, 512, 8, 128, 128, None),
           "mla": (128, T, 128, 192, 128, 192 ** -0.5 * 1.2608 ** 2),
           "granite": (32, T, 8, 64, 64, 0.015625)}
+SHARED = {"mla": 64}  # of the scores' width, a key part every head shares
 PAIRS = tuple((bq, bk) for bq in (128, 256, 512) for bk in (128, 256, 512))
 
 
@@ -53,6 +58,8 @@ def main(argv=None) -> int:
                     help="of " + ",".join(LAYERS))
     ap.add_argument("--pairs", default="",
                     help="256x512,... (all nine where empty)")
+    ap.add_argument("--windows", default="3",
+                    help="windows a call, e.g. 1,3")
     args = ap.parse_args(argv)
 
     import jax
@@ -74,25 +81,39 @@ def main(argv=None) -> int:
     rows = []
     pairs_run = tuple(tuple(int(x) for x in p.split("x"))
                       for p in args.pairs.split(",") if p) or PAIRS
-    for kind in args.layers.split(","):
+    for kind, W in ((kind, int(w)) for kind in args.layers.split(",")
+                    for w in args.windows.split(",")):
         heads, window, kv, d, e, scale = LAYERS[kind]
-        g = heads // kv
+        g, r = heads // kv, SHARED.get(kind, 0)
         q = jax.random.normal(keys[0], (W, kv, g, T, d), jnp.float32)
         k = jax.random.normal(keys[1], (W, kv, T, d), jnp.float32).astype(dt)
         v = jax.random.normal(keys[2], (W, kv, T, e), jnp.float32).astype(dt)
         q = q.astype(dt)
         cot = jax.random.normal(keys[3], q.shape[:-1] + (e,), jnp.float32)
-        own = at.blocks(g, T, d, dt, e)
+        own = at.blocks(g, T, d, dt, e, r)
+        # the one shared part, and the whole key with it in every head
+        part = k[:, :1, :, d - r:]
+        if r:
+            k = jnp.concatenate([k[..., :d - r], jnp.broadcast_to(
+                part, k.shape[:-1] + (r,))], -1)
         # every pair is timed where the chip's compiler takes it; `admitted`
         # are those whose buffers `blocks` counts inside the default VMEM
         admitted = [pair for pair in PAIRS if at._buffers(
-            g, T, at._padded(d), *pair, dt.dtype.itemsize, e)
-            <= at._VMEM_BUFFERS]
-        forms = {"einsum": lambda q, k, v: at.plain(q, k, v, window, scale)}
+            g, T, d, *pair, dt.dtype.itemsize, e, r) <= at._VMEM_BUFFERS]
+        # label -> (form, its operands, its (dq, dk, dv[, dshared]) as the
+        # whole key's (dq, dk, dv))
+        whole = (q, k, v)
+        forms = {"einsum": (lambda q, k, v: at.plain(q, k, v, window, scale),
+                            whole)}
         for pair in pairs_run:
+            if r:
+                forms["shared_%dx%d" % pair] = (
+                    lambda q, k, v, part, pair=pair: at.fused(
+                        q, k, v, window, pair, scale, part),
+                    (q, k[..., :d - r], v, part))
             forms["kernel_%dx%d" % pair] = (
                 lambda q, k, v, pair=pair: at.fused(q, k, v, window, pair,
-                                                    scale))
+                                                    scale), whole)
             if d % 128:  # the scores' width in whole lane tiles, zeros added
                 def padded(q, k, v, pair=pair):
                     wide = [(0, 0)] * 4 + [(0, at._padded(d) - d)]
@@ -100,37 +121,46 @@ def main(argv=None) -> int:
                                     v, window, pair,
                                     scale or d ** -0.5)
 
-                forms["padded256_%dx%d" % pair] = padded
+                forms["padded256_%dx%d" % pair] = (padded, whole)
             if e % 128:  # the values' width in whole lane tiles
                 def widened(q, k, v, pair=pair):
                     wide = [(0, 0)] * 3 + [(0, at._padded(e) - e)]
                     return at.fused(q, k, jnp.pad(v, wide), window, pair,
                                     scale)[..., :e]
 
-                forms["values128_%dx%d" % pair] = widened
+                forms["values128_%dx%d" % pair] = (widened, whole)
+
+        def comparable(got):
+            """(out, dq, dk's own part, dv, the shared part's cotangent
+            summed over the heads) of either operand list."""
+            out, (dq, dk, dv, *dpart) = got
+            dpart = dpart[0] if dpart else jnp.sum(
+                dk[..., d - r:].astype(jnp.float32), 1, keepdims=True)
+            return out, dq, dk[..., :d - r], dv, dpart
+
         def gaps(got, want):
             return [float(jnp.max(jnp.abs(a.astype(jnp.float32)
                                           - b.astype(jnp.float32)))
                           / jnp.max(jnp.abs(b.astype(jnp.float32))))
-                    for a, b in zip(jax.tree.leaves(got),
-                                    jax.tree.leaves(want))]
+                    for a, b in zip(comparable(got)[:4 + bool(r)],
+                                    comparable(want))]
 
         programs, worst, want = {}, {}, None
-        for label, form in forms.items():
-            def forward(q, k, v, cot, form=form):
-                return form(q, k, v)
+        for label, (form, operands) in forms.items():
+            def forward(cot, *operands, form=form):
+                return form(*operands)
 
-            def both(q, k, v, cot, form=form):
-                out, back = jax.vjp(form, q, k, v)
+            def both(cot, *operands, form=form):
+                out, back = jax.vjp(form, *operands)
                 return out, back(cot)
 
             made = {}
             for fn, passes in ((forward, "forward"), (both, "both")):
-                fn.__name__ = fn.__qualname__ = f"{kind}_{label}_{passes}"
-                made[label, passes] = (jax.jit(fn), fn.__name__)
+                fn.__name__ = fn.__qualname__ = f"{kind}{W}_{label}_{passes}"
+                made[label, passes] = (jax.jit(fn), fn.__name__, operands)
             try:  # compiles, and the result
-                got = [jax.block_until_ready(fn(q, k, v, cot))
-                       for fn, _ in made.values()][-1]
+                got = [jax.block_until_ready(fn(cot, *operands))
+                       for fn, _, _ in made.values()][-1]
             except Exception as e:  # more VMEM than a kernel may use
                 print(f"{kind} {label}: refused: {str(e)[-300:]}",
                       file=sys.stderr)
@@ -140,18 +170,20 @@ def main(argv=None) -> int:
             programs.update(made)
         trace_dir = tempfile.mkdtemp(prefix="attention_trace_")
         with device_trace(trace_dir):
-            for fn, _ in programs.values():
+            for fn, _, operands in programs.values():
                 for _ in range(ITERS):
-                    out = fn(q, k, v, cot)
+                    out = fn(cot, *operands)
                 jax.block_until_ready(out)
         ms = device_program_ms(trace_dir)
         for label in worst:
             pair = (tuple(int(x) for x in label.split("_")[1].split("x"))
                     if label != "einsum" else None)
             pairs = len(at.visited(T, window, *pair)) if pair else None
-            row = {"layer": kind, "heads": heads, "window": window,
+            row = {"layer": kind, "windows": W, "heads": heads,
+                   "window": window,
                    "form": label, "the_programs_own": (
-                       pair == own and label.startswith("kernel")),
+                       pair == own and label.startswith(
+                           "shared" if r else "kernel")),
                    "admitted": pair in admitted,
                    "block_share": (round(at.block_share(T, window, *pair), 4)
                                    if pair else 1.0),
@@ -173,7 +205,8 @@ def main(argv=None) -> int:
     payload = {"experiment": "attention", **jaxenv.device_info(),
                "timing": "median per-program device duration, "
                          f"{ITERS} calls, jax.profiler trace",
-               "shape": {"windows": W, "tokens": T, "dtype": "bfloat16",
+               "shape": {"windows": args.windows, "tokens": T,
+                         "dtype": "bfloat16",
                          "layers": {kind: dict(zip(
                              ("heads", "window", "kv_heads", "score_width",
                               "value_width", "scale"), LAYERS[kind]))
@@ -184,11 +217,12 @@ def main(argv=None) -> int:
     print(json.dumps({
         "experiment": "attention",
         "einsum_over_the_programs_own": {
-            r["layer"]: {p: round(e[f"{p}_ms"] / r[f"{p}_ms"], 2)
-                         for p in ("forward", "both")}
+            "%s x %d" % (r["layer"], r["windows"]): {
+                p: round(e[f"{p}_ms"] / r[f"{p}_ms"], 2)
+                for p in ("forward", "both")}
             for r in rows if r["the_programs_own"]
-            for e in rows if e["layer"] == r["layer"]
-            and e["form"] == "einsum"}}))
+            for e in rows if (e["layer"], e["windows"]) == (
+                r["layer"], r["windows"]) and e["form"] == "einsum"}}))
     return 0
 
 

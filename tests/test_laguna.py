@@ -521,6 +521,7 @@ def test_the_round_trains_the_adapters_and_reports_its_routing():
     assert logs[-1].accepted == 4 - 4 // 2
     page = registry.render()
     for name in ("biscotti_sim_frozen_bytes", "biscotti_sim_peer_block",
+                 "biscotti_lm_attention_shared_key 0",  # each head's own
                  "biscotti_moe_assignments_held",
                  "biscotti_moe_load_max_over_mean",
                  "biscotti_moe_tokens_dropped 0"):

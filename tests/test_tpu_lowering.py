@@ -328,13 +328,15 @@ def _lowered_round(sim):
 # 7ae27af2f9aa6149 from PR 32's tree to 6b90dd1), and a layer is a jitted
 # function of its kind, traced once a kind and not once a layer. A
 # block of ONE peer walks nothing: the published Granite round lowers to
-# 6b90dd1's text (de6de7cab9843f86; PERF.md section 5)
+# 6b90dd1's text (de6de7cab9843f86; PERF.md section 5). DeepSeek-V2's tiny
+# round is PR 37's (9c84a7f51791d196 until then): its one rotary key is an
+# operand of the core, q and the key turn in place (ops/rotary.py)
 PARENT_ROUNDS = {
     ("mnist", "softmax"): "2f1f0d7efd64ce2c",
     ("creditcard", ""): "04e1c79a9ba9c99e",
     ("mnist", "mnist_cnn"): "0cb8d0fa17f1cd73",
     ("lm_tokens_tiny", ""): "f0ca8f3e4ab9ba5e",
-    ("lm_tokens_tiny", "deepseek_v2_tiny"): "9c84a7f51791d196",
+    ("lm_tokens_tiny", "deepseek_v2_tiny"): "ef6a2362a6303adf",
 }
 WALKED_IN_THREES = "83f7ac9576367014"  # the same, the peer axis in two blocks
 
@@ -553,13 +555,17 @@ def test_the_latent_attention_at_the_published_shapes_takes_the_kernel(v5e):
     of 1,024, 128 heads, scores over 192 and values of 128, bfloat16) under
     `jax.checkpoint` and `jax.grad` compiles for the v5e under x64 with
     ops/attention.py's kernel as its core, the 192 as they are, and makes
-    NO float32 array of the scores' size."""
+    NO float32 array of the scores' size. Since PR 37 the one rotary key
+    reaches the core as an operand of its own, `bf16[3, 1, 1024, 64]`: no
+    array holds a head's 192-wide key, none is a broadcast of the one key
+    to 128 heads, and q's 64 turn in ops/rotary.py's one pass."""
     from biscotti_tpu.models import deepseek_v2
 
     cfg = deepseek_v2.PRESETS["deepseek_v2_fedlora"]
     one = SingleDeviceSharding(v5e[0])
     model = deepseek_v2.deepseek_v2_model("lm", cfg, 1024)
-    assert model.info["attention"] == {"fused": 1, "block_share": 0.75}
+    assert model.info["attention"] == {"fused": 1, "block_share": 0.75,
+                                       "shared_key": 1}
 
     def on_chip(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
@@ -581,12 +587,41 @@ def test_the_latent_attention_at_the_published_shapes_takes_the_kernel(v5e):
         adapters, jax.ShapeDtypeStruct((3, 1, 1024, cfg.hidden), jnp.float32,
                                        sharding=one), frozen
     ).compile().as_text()
-    calls = [line for line in hlo.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
+    kernels = [line for line in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    calls = [c for c in kernels if "attention_" in c.split(" = ")[0]]
     assert 2 <= len(calls) <= 3, len(calls)
     assert any("f32[3,128,1,1024,128]" in c for c in calls)    # the result
     assert any("bf16[3,128,1,1024,192]" in c for c in calls)   # q, dq
-    assert any("mla_core" in c for c in calls)
+    assert all("mla_core" in c for c in calls)
+    # every call takes the one key, 64 wide, and k 128 wide; the backward
+    # hands the one key's cotangent out, summed over the heads inside
+    for c in calls:
+        operands = c[c.index("operand_layout_constraints="):]
+        assert "bf16[3,1,1024,64]" in operands, c[:200]
+        assert "bf16[3,128,1024,192]" not in operands, c[:200]
+    assert any("bf16[3,1,1024,64]" in c.split(" custom-call(")[0]
+               for c in calls)
+    # q's turn, token-major float32 in and head-major bfloat16 out: forward
+    # and recomputed; and the cotangent's turn back, the other way
+    turns = [c.split(" custom-call(")[0].strip() for c in kernels
+             if "attn_rotary" in c]
+    assert len(turns) == 3, turns
+    assert sum(c.startswith("%rotary_to_heads")
+               and "bf16[3,128,1024,192]" in c for c in turns) == 2
+    assert sum(c.startswith("%rotary_from_heads")
+               and "f32[3,1024,24576]" in c for c in turns) == 1
+    # no float32 array of a head-major or token-major [128 heads, 1,024,
+    # 192] comes out of a concatenation, a pad or a broadcast (the parent
+    # made q and k so, and their cotangents by pads), and the one key is
+    # never broadcast to the heads
+    wide = re.compile(r" = f32\[(\d+,)?(128,1024|1024,128),192\]\S* "
+                      r"(concatenate|pad|broadcast)\(")
+    keys = re.compile(r" = \w+\[(\d+,)?(128,1024|1024,128),64\]\S* "
+                      r"broadcast\(")
+    made = [line.strip()[:160] for line in hlo.splitlines()
+            if wide.search(line) or keys.search(line)]
+    assert not made, made[:5]
     square = re.compile(r"f32\[([\d,]*1024,1024)\]")
     made = [line.strip()[:160] for line in hlo.splitlines()
             for dims in square.findall(line)
@@ -595,6 +630,53 @@ def test_the_latent_attention_at_the_published_shapes_takes_the_kernel(v5e):
     assert not [line.strip()[:160] for line in hlo.splitlines()
                 if "f64[" in line or ("s64[" in line
                                       and "parameter(" not in line)]
+
+
+# sha256[:16] of the lowered gradient of ONE `attention.attention` call at
+# the sibling models' published shapes (a window, bfloat16), read on
+# 7bdda71 with `_lowered_core` before ops/attention.py learnt to take a
+# shared key part: a call without one traces and lowers as it did (on the
+# CPU the kernel lowers interpreted: its every operation, grid and block
+# index is in the text)
+PARENT_CORES = {
+    "laguna_full": ((8, 6, 128, 1024, None), "9d053ecc4199a93b"),
+    "laguna_sliding": ((8, 9, 128, 512, None), "74f9a694f33eac20"),
+    "granite": ((8, 4, 64, 1024, 0.015625), "aab27e2608273730"),
+}
+
+
+def _lowered_core(kv, g, d, window, scale, t=1024):
+    from biscotti_tpu.ops import attention
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.bfloat16)
+
+    def loss(q, k, v):
+        out = attention.attention(q, k, v, window, scale)
+        return jnp.sum(out * out)
+
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        shape(1, kv, g, t, d), shape(1, kv, t, d), shape(1, kv, t, d)
+    ).as_text()
+
+
+@pytest.mark.parametrize("layer", sorted(PARENT_CORES))
+def test_a_core_without_a_shared_key_lowers_as_the_parents(layer):
+    """Laguna's two attention calls (G = 6 under the causal mask, G = 9
+    under a window of 512; heads of 128 | 128) and Granite's (G = 4, 64 |
+    64, the scores times 1 / 64) pass no shared key part: the operand is
+    not there, and the program is the parent's."""
+    from biscotti_tpu.models import granite_hybrid, laguna
+
+    big = laguna.PRESETS["laguna_s_fedlora"]
+    assert (big.kv_heads, big.head_dim, big.window) == (8, 128, 512)
+    assert {n // big.kv_heads for n in big.heads} == {6, 9}
+    hybrid = granite_hybrid.PRESETS["granite_h_micro_fedlora"]
+    assert (hybrid.kv_heads, hybrid.heads // hybrid.kv_heads,
+            hybrid.head_dim, hybrid.attention_multiplier) == (8, 4, 64,
+                                                              0.015625)
+    shape, parent = PARENT_CORES[layer]
+    assert _sha(_lowered_core(*shape)) == parent
 
 
 def test_experts_of_5120_by_1536_take_the_kernel_in_column_tiles(v5e):
@@ -685,7 +767,7 @@ def test_the_hybrids_sizes_from_shapes_alone():
     assert model_for_dataset("lm_tokens").info["attention"] == {
         "fused": 1, "block_share": 0.75}
     assert model_for_dataset("lm_tokens_dsv2").info["attention"] == {
-        "fused": 1, "block_share": 0.75}
+        "fused": 1, "block_share": 0.75, "shared_key": 1}
     # a peer's step holds 2.05 GB by the model's count: a 16 GB chip with
     # 6.39 GB of base and 1.67 GB of deltas standing steps ONE at a time
     from biscotti_tpu.models.peer_step import DEVICE_BYTES, peer_block

@@ -17,7 +17,7 @@ from biscotti_tpu.data import datasets as ds
 from biscotti_tpu.models import deepseek_v2
 from biscotti_tpu.models.zoo import model_for_dataset
 from biscotti_tpu.ops import attention as at
-from biscotti_tpu.ops import moe
+from biscotti_tpu.ops import moe, rotary
 
 DATASET = "lm_tokens_tiny"
 NAME = "deepseek_v2_tiny"
@@ -220,10 +220,63 @@ def test_the_kernel_at_a_score_width_unlike_the_value_width(dtype, tol,
     assert got[0].dtype == jnp.float32
 
 
+def _assembled(k, shared):
+    """k = [k_nope | the one shared part, broadcast to every head]."""
+    return jnp.concatenate(
+        [k, jnp.broadcast_to(shared, k.shape[:-1] + shared.shape[-1:])], -1)
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("block", [(128, 128), (256, 128), (128, 256)])
+@pytest.mark.parametrize("kv,g", [(3, 1), (2, 2)])
+def test_a_shared_key_part_is_the_assembled_key(dtype, tol, block, kv, g):
+    """The core given `k_nope` [W, kv, T, 128] and ONE key part [W, 1, T,
+    64] for all heads: the kernel (interpreted), the `einsum` form with the
+    same operand, and the `einsum` form on the assembled 192-wide key are
+    one function, forward and backward; the part's cotangent is the sum
+    over the heads of the assembled key's last 64. Two windows, so a sum
+    that is not zeroed a window shows."""
+    dtype, t, own, r = jnp.dtype(dtype), 256, 128, 64
+    keys = jax.random.split(jax.random.PRNGKey(7), 5)
+    q = jax.random.normal(keys[0], (2, kv, g, t, own + r)).astype(dtype)
+    k = jax.random.normal(keys[1], (2, kv, t, own)).astype(dtype)
+    shared = jax.random.normal(keys[2], (2, 1, t, r)).astype(dtype)
+    v = jax.random.normal(keys[3], (2, kv, t, 128)).astype(dtype)
+    cot = jax.random.normal(keys[4], (2, kv, g, t, 128), jnp.float32)
+    scale = 192 ** -0.5 * 1.2608 ** 2
+
+    def both(form, *operands):
+        out, back = jax.vjp(form, *operands)
+        return (out,) + back(cot)
+
+    out, dq, dk_whole, dv = both(lambda *a: at.plain(*a, t, scale), q,
+                                 _assembled(k, shared), v)
+    want = (out, dq, dk_whole[..., :own], dv, jnp.sum(
+        dk_whole[..., own:].astype(jnp.float32), 1, keepdims=True))
+    names = ("out", "dq", "dk_nope", "dv", "dshared")
+    for form in (lambda q, k, v, s: at.fused(q, k, v, t, block, scale, s),
+                 lambda q, k, v, s: at.plain(q, k, v, t, scale, s)):
+        got = both(form, q, k, v, shared)
+        for name, a, b in zip(names, got, want):
+            assert a.shape == b.shape, name
+            assert a.dtype == (jnp.float32 if name == "out" else dtype), name
+            a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+            assert np.max(np.abs(a - b)) <= tol * np.max(np.abs(b)), name
+    # and `attention` reads the operand list off what it was given
+    np.testing.assert_array_equal(at.attention(q, k, v, t, scale, shared),
+                                  at.fused(q, k, v, t, None, scale, shared))
+
+
 def test_blocks_decides_from_the_shapes_alone():
     bf16 = jnp.bfloat16
     # the published MLA core and Laguna's two, unchanged
     assert at.blocks(1, 1024, 192, bf16, 128) == (256, 512)
+    # and with the 64 rotary dimensions as a key part all heads share: k is
+    # 128 wide, the part is held like k, the count is the same
+    assert at.blocks(1, 1024, 192, bf16, 128, 64) == (256, 512)
+    assert (at._buffers(1, 1024, 192, 256, 512, 2, 128, 64)
+            == at._buffers(1, 1024, 192, 256, 512, 2, 128) <= at._VMEM_BUFFERS)
+    assert at.blocks(1, 1024, 192, bf16, 128, 32) is None
     assert at.blocks(6, 1024, 128, bf16) == at.blocks(6, 1024, 128, bf16,
                                                       128) == (256, 512)
     assert at.blocks(9, 1024, 128, bf16) == (256, 512)
@@ -318,8 +371,84 @@ def test_rotary_is_interleaved_pairs_under_yarn():
                                192 ** -0.5 * 1.2608 ** 2, rtol=1e-4)
     x = jax.random.normal(jax.random.PRNGKey(0), (16, 2), jnp.float32)
     cos, sin = deepseek_v2.rotary_tables(TINY, 16)
-    turned = deepseek_v2._rotate(x, cos, sin)
+    turned = rotary.turn(x[None], *rotary.tables(cos, sin, 2),
+                         jnp.float32)[0, 0]
     angle = np.arctan2(np.asarray(sin[:, 0]), np.asarray(cos[:, 0]))
     want = np.stack([x[:, 0] * np.cos(angle) - x[:, 1] * np.sin(angle),
                      x[:, 1] * np.cos(angle) + x[:, 0] * np.sin(angle)], 1)
     np.testing.assert_allclose(turned, want, atol=1e-6)
+
+
+def _halves(x, cos, sin):
+    """Rotary in interleaved pairs as DeepSeek's own code leaves it (and
+    this model did until PR 37): the pairs' first halves, then their
+    second."""
+    pairs = x.reshape(x.shape[:-1] + (x.shape[-1] // 2, 2))
+    a, b = pairs[..., 0], pairs[..., 1]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+
+
+@pytest.mark.parametrize("side", ["compiler", "kernel"])
+def test_q_and_the_key_turn_in_the_same_order(side):
+    """In place (ops/rotary.py) q's and the key's turned dimensions stay
+    where they were, every head's first 128 untouched: each score is the
+    sum of the same products as under `_halves`, on the kernel's side
+    (heads that come to whole lane tiles, interpreted) and the compiler's."""
+    big = deepseek_v2.PRESETS["deepseek_v2_fedlora"]
+    t, n = 64, 2 if side == "kernel" else 3
+    cos, sin = deepseek_v2.rotary_tables(big, t)
+    keys = jax.random.split(jax.random.PRNGKey(4), 2)
+    q = jax.random.normal(keys[0], (1, t, n * 192), jnp.float32)
+    k_r = jax.random.normal(keys[1], (1, t, 64), jnp.float32)
+    assert (rotary.rows(t, n * 192, 192, 4, 4) is not None) == (
+        side == "kernel")
+    assert rotary.rows(t, 64, 64, 4, 4) is None  # the one key: the compiler's
+    got_q = np.asarray(rotary.turn(q, *rotary.tables(cos, sin, 192),
+                                   jnp.float32), np.float64)[0]
+    got_k = np.asarray(rotary.turn(k_r, *rotary.tables(cos, sin, 64),
+                                   jnp.float32), np.float64)[0, 0]
+    assert got_q.shape == (n, t, 192) and got_k.shape == (t, 64)
+    heads = np.asarray(q).reshape(t, n, 192)
+    np.testing.assert_array_equal(got_q[..., :128],
+                                  heads[..., :128].transpose(1, 0, 2))
+    want_k = np.asarray(_halves(k_r[0], cos, sin), np.float64)
+    for h in range(n):
+        want_q = np.asarray(_halves(heads[:, h, 128:], cos, sin), np.float64)
+        scores = got_q[h, :, 128:] @ got_k.T
+        np.testing.assert_allclose(scores, want_q @ want_k.T,
+                                   atol=1e-6 * np.abs(scores).max())
+
+
+@pytest.mark.parametrize("operand,result", [("float32", "bfloat16"),
+                                            ("bfloat16", "float32")])
+def test_the_turn_is_one_pass_and_its_transpose_is_the_turn_back(operand,
+                                                                 result):
+    """The kernels (interpreted) are the compiler's form, the forward's
+    float32 token-major rows into the base's type head-major and the
+    backward's cotangent back; the gradient is the cotangent turned by the
+    negated sine."""
+    t, n, d = 64, 4, 192
+    angles = np.random.RandomState(0).uniform(0, 6, (t, 32))
+    cos, sin = rotary.tables(np.cos(angles), np.sin(angles), d)
+    assert cos.shape == sin.shape == (t, d) and (cos[:, :128] == 1).all()
+    assert not sin[:, :128].any()
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, t, n * d)).astype(
+        operand)
+    assert rotary.rows(t, n * d, d, 4, 2) == 64
+    assert rotary.rows(1024, 128 * 192, 192, 4, 2) == 32  # the published q
+    got = rotary.turn(x, cos, sin, result)
+    want = rotary.plain(x, cos, sin, result)
+    assert got.dtype == want.dtype == jnp.dtype(result)
+    assert got.shape == want.shape == (2, n, t, d)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=1e-6)
+    cot = jax.random.normal(jax.random.PRNGKey(1), got.shape).astype(result)
+    _, back = jax.vjp(lambda x: rotary.turn(x, cos, sin, result), x)
+    _, plain_back = jax.vjp(lambda x: rotary.plain(x, cos, sin, result), x)
+    (dx,), (plain_dx,) = back(cot), plain_back(cot)
+    assert dx.dtype == x.dtype and dx.shape == x.shape
+    for other in (plain_dx, rotary._plain_back(cot, cos, -sin, operand)):
+        np.testing.assert_allclose(np.asarray(dx, np.float32),
+                                   np.asarray(other, np.float32),
+                                   atol=2e-2 if operand == "bfloat16"
+                                   else 1e-6)

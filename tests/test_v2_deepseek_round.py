@@ -62,7 +62,8 @@ def _wide(t=128):
     of `t`: what ops/attention.py's kernel takes (interpreted here)."""
     cfg = dataclasses.replace(TINY, nope=128, rope=64, v_dim=128, heads=2)
     model = deepseek_v2.deepseek_v2_model("deepseek_v2_wide", cfg, t)
-    assert model.info["attention"] == {"fused": 1, "block_share": 1.0}
+    assert model.info["attention"] == {"fused": 1, "block_share": 1.0,
+                                       "shared_key": 1}
     tokens = jax.random.randint(jax.random.PRNGKey(3), (6, t + 1), 0,
                                 cfg.vocab, jnp.int32)
     return (model, model.frozen(jax.random.PRNGKey(1)),
@@ -145,7 +146,8 @@ def test_the_zoo_registers_both_presets_and_their_datasets():
     assert big.name == "deepseek_v2_fedlora"
     assert big.num_params == 5166080
     assert lm.frozen_count(big) == 5166269440
-    assert big.info["attention"] == {"fused": 1, "block_share": 0.75}
+    assert big.info["attention"] == {"fused": 1, "block_share": 0.75,
+                                     "shared_key": 1}
     # the scopes are the model's own, and Laguna's stay Laguna's
     assert "mla_core" in deepseek_v2.SCOPES
     assert "mla_core" not in laguna.SCOPES
@@ -191,6 +193,7 @@ def test_the_round_trains_the_adapters_and_reports_its_routing():
     for name in ("biscotti_sim_frozen_bytes", "biscotti_sim_peer_block",
                  "biscotti_lm_attention_fused 0",
                  "biscotti_lm_attention_block_share 1",
+                 "biscotti_lm_attention_shared_key 1",
                  "biscotti_moe_assignments_held",
                  "biscotti_moe_load_max_over_mean",
                  "biscotti_moe_groups_kept",

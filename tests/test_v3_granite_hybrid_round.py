@@ -169,7 +169,9 @@ def test_the_round_trains_the_adapters_and_reports_its_chunks():
     assert logs[-1].accepted == 4 - 4 // 2
     page = registry.render()
     for name in ("biscotti_sim_frozen_bytes", "biscotti_sim_peer_block",
-                 "biscotti_lm_attention_fused 0", "biscotti_ssm_chunks 4"):
+                 "biscotti_lm_attention_fused 0",
+                 "biscotti_lm_attention_shared_key 0",
+                 "biscotti_ssm_chunks 4"):
         assert name in page, name
     assert "biscotti_moe_" not in page  # no router, nothing dispatched
     assert sim.dispatch_stats() == {}
