@@ -25,6 +25,7 @@ they are bit-identical across peer processes exactly like the synthetic ones.
 
 Token shards (`lm_tokens`, `lm_tokens_dsv2` over DeepSeek-V2's held
 25,600 rows, `lm_tokens_granite` over all 100,352 of Granite-4.0-H-Micro's,
+`lm_tokens_qwen3next` over Qwen3-Next-80B-A3B's held 37,984,
 and `lm_tokens_tiny` at the tests' size) feed the language models
 (models/laguna.py, models/deepseek_v2.py, models/granite_hybrid.py): a row
 is a WINDOW of `d_in` token ids
@@ -105,6 +106,9 @@ DATASETS: Dict[str, DatasetSpec] = {
     # and over Granite-4.0-H-Micro's whole vocabulary
     "lm_tokens_granite": DatasetSpec("lm_tokens_granite", 1024, 100352, 80,
                                      2, tokens=True),
+    # and over the held quarter of Qwen3-Next-80B-A3B's
+    "lm_tokens_qwen3next": DatasetSpec("lm_tokens_qwen3next", 1024, 37984,
+                                       80, 2, tokens=True),
 }
 
 ZIPF_EXPONENT = 1.1  # the unigram law of token shards: p(rank) ∝ rank^-1.1

@@ -128,32 +128,16 @@ def a_log(key, shape):
     return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0))
 
 
-def dt_bias(key, shape):
-    """The inverse softplus of a step log-uniform in [1e-3, 1e-1]
-    (Mamba-2's `dt_min`, `dt_max`)."""
-    dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32,
-                                    math.log(1e-3), math.log(1e-1)))
-    return dt + jnp.log(-jnp.expm1(-dt))
+dt_bias = lm.step_bias  # Mamba-2's own law of the step (models/lm.py)
 
 
 # ----------------------------------------------------------------- forward
 
 
-def causal_conv(x, weight, bias):
-    """The causal depthwise conv over the tokens: x float32[W, T, C],
-    weight [K, C], bias [C]; out_t = bias + sum_i weight[i] x_{t - (K-1) +
-    i}, the tokens before the window counting 0."""
-    taps, t = weight.shape[0], x.shape[1]
-    weight = weight.astype(jnp.float32)
-    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
-    return bias.astype(jnp.float32) + sum(
-        weight[i] * padded[:, i:i + t] for i in range(taps))
-
-
-def gated_norm(y, z, weight, eps):
-    """RMSNorm(y * silu(z)) x weight: the gate BEFORE the norm, the mean
-    over all of d_inner (one group)."""
-    return lm.rms(y * jax.nn.silu(z), weight, eps)
+# the conv (with its bias) and the gated norm (the gate BEFORE the norm, the
+# mean over all of d_inner: one group) are models/lm.py's, which the other
+# hybrid shares
+causal_conv, gated_norm = lm.causal_conv, lm.gated_norm
 
 
 def _mamba(cfg, h, frozen, adapters):

@@ -143,15 +143,6 @@ def rotary_tables(cfg: LagunaConfig, kind: str, length: int):
     return lm.yarn_tables(rot, rope, length) + (rot,)
 
 
-def _rotate(x, cos, sin, rot):
-    """Rotate-half rotary on the first `rot` of the last axis; x [..., T,
-    head_dim], cos/sin [T, rot / 2]."""
-    turned, rest = x[..., :rot], x[..., rot:]
-    a, b = turned[..., :rot // 2], turned[..., rot // 2:]
-    turned = jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
-    return jnp.concatenate([turned, rest], axis=-1)
-
-
 # ----------------------------------------------------------------- forward
 
 
@@ -176,7 +167,8 @@ def _attention(cfg, at, h, frozen, adapters):
         q, k, v = heads("q", n), heads("k", kv), heads("v", kv)
         with scope("attn_rotary"):
             cos, sin, rot = rotary_tables(cfg, kind, t)
-            q, k = _rotate(q, cos, sin, rot), _rotate(k, cos, sin, rot)
+            q = lm.rotate_half(q, cos, sin, rot)
+            k = lm.rotate_half(k, cos, sin, rot)
         with scope("attn_layout"):
             dtype = frozen["wq"].dtype
             q = q.reshape(p * b, kv, n // kv, t, dh).astype(dtype)

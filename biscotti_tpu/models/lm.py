@@ -1,14 +1,19 @@
 """What the language models of this package share (models/laguna.py,
-models/deepseek_v2.py, models/granite_hybrid.py): a decoder whose frozen base is held once beside
-rank-r adapters `B [r, out]`, which are what the peers train, commit and
-aggregate (the FFA-LoRA form: `A` frozen and shared, so that the sum of the
-peers' updates IS the update of the sum).
+models/deepseek_v2.py, models/granite_hybrid.py, models/qwen3_next.py): a
+decoder whose frozen base is held once beside rank-r adapters `B [r, out]`,
+which are what the peers train, commit and aggregate (the FFA-LoRA form:
+`A` frozen and shared, so that the sum of the peers' updates IS the update
+of the sum).
 
-  the arithmetic   `rms`, `mm`, `swiglu`, `adapted`: every product in the
+  the arithmetic   `rms` (its weight the scale, or the scale less one),
+                   `mm`, `swiglu`, `adapted`: every product in the
                    frozen weights' dtype with float32 accumulation, norms
-                   in float32
+                   in float32; what the two hybrids' mixers share:
+                   `causal_conv` (with a bias or none), `gated_norm` (the
+                   gate before the norm or after it)
   rotary           `yarn_tables`: (cos, sin) of plain or YaRN-scaled
-                   frequencies, made on the host in float64
+                   frequencies, made on the host in float64;
+                   `rotate_half` on the first dimensions of a head
   the peer axis    `decoder`: a model's `hidden_states` takes adapters with
                    a leading peer axis on every leaf and the peers' windows
                    as ONE batch (module doc of models/laguna.py), a layer
@@ -59,10 +64,43 @@ def yarn_tables(rot: int, rope: dict, length: int):
             (np.sin(angles) * factor).astype(np.float32))
 
 
-def rms(x, weight, eps):
+def rotate_half(x, cos, sin, rot):
+    """Rotate-half rotary on the first `rot` of the last axis; x [..., T,
+    head_dim], cos/sin [T, rot / 2]."""
+    turned, rest = x[..., :rot], x[..., rot:]
+    a, b = turned[..., :rot // 2], turned[..., rot // 2:]
+    turned = jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+    return jnp.concatenate([turned, rest], axis=-1)
+
+
+def rms(x, weight, eps, zero_centred=False):
+    """RMSNorm over the last axis in float32; `zero_centred`: the stored
+    weight is the scale less one (a norm read as `1 + w`)."""
     x = x.astype(jnp.float32)
     scale = jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+    if zero_centred:
+        return x * scale * (1.0 + weight.astype(jnp.float32))
     return x * scale * weight.astype(jnp.float32)
+
+
+def causal_conv(x, weight, bias=None):
+    """The causal depthwise conv over the tokens: x float32[W, T, C],
+    weight [K, C], bias [C] or None; out_t = bias + sum_i weight[i] x_{t -
+    (K-1) + i}, the tokens before the window counting 0."""
+    taps, t = weight.shape[0], x.shape[1]
+    weight = weight.astype(jnp.float32)
+    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    out = sum(weight[i] * padded[:, i:i + t] for i in range(taps))
+    return out if bias is None else bias.astype(jnp.float32) + out
+
+
+def gated_norm(y, z, weight, eps, gate_first=True):
+    """A mixer's gated RMSNorm over the last axis, the gate silu(z) BEFORE
+    the norm (`gate_first`: RMSNorm(y * silu(z)) x weight) or after it
+    (RMSNorm(y) x weight * silu(z))."""
+    if gate_first:
+        return rms(y * jax.nn.silu(z), weight, eps)
+    return rms(y, weight, eps) * jax.nn.silu(z)
 
 
 def mm(a, w):
@@ -163,7 +201,8 @@ def logits(cfg, h, frozen):
     head is TIED to its embedding: the one leaf `embed` [V, H] is read
     twice, here contracted over its columns, and the logits divided by the
     config's `logits_scaling`."""
-    x = rms(h, frozen["final_norm"], cfg.eps)
+    x = rms(h, frozen["final_norm"], cfg.eps,
+            getattr(cfg, "zero_centred", False))
     if "head" in frozen:
         return mm(x, frozen["head"])
     embed = frozen["embed"]
@@ -198,6 +237,14 @@ def routing(hidden_states, cfg, params, tokens, frozen):
 
 def _is_leaf(node):
     return isinstance(node, tuple) and isinstance(node[0], tuple)
+
+
+def step_bias(key, shape):
+    """A law of a frozen leaf: the inverse softplus of a step log-uniform
+    in [1e-3, 1e-1] (Mamba-2's `dt_min`, `dt_max`)."""
+    dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32,
+                                    math.log(1e-3), math.log(1e-1)))
+    return dt + jnp.log(-jnp.expm1(-dt))
 
 
 @partial(jax.jit, static_argnames=("shape", "fan_in", "dtype"))

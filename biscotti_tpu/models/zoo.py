@@ -24,6 +24,15 @@
              trained, d = 6,410,240; reads the dataset `lm_tokens_granite`
   granite_h_tiny  the same mechanism at the CPU tests' size
              (`lm_tokens_tiny`; four chunks a window)
+  qwen3_next_fedlora  Qwen3-Next-80B-A3B-Instruct's gated delta-net / gated
+             attention hybrid (models/qwen3_next.py): a frozen 5.4
+             B-parameter share at the published widths (12 layers, 9 of them
+             the delta rule, 128 of 512 experts a layer, 37,984 rows of an
+             untied vocabulary), rank-16 adapters on in_proj_qkvz /
+             out_proj and q, k, v, o trained, d = 2,605,056; reads the
+             dataset `lm_tokens_qwen3next`
+  qwen3_next_tiny  the same mechanism at the CPU tests' size
+             (`lm_tokens_tiny`; two periods, four chunks a window)
 
 Inits are MXU-friendly (fan-in scaled normal) and every model is expressed in
 channels-last NHWC, the layout XLA prefers on TPU.
@@ -38,7 +47,8 @@ import jax
 import jax.numpy as jnp
 
 from biscotti_tpu.data.datasets import base_name, spec as dspec
-from biscotti_tpu.models import deepseek_v2, granite_hybrid, laguna
+from biscotti_tpu.models import (deepseek_v2, granite_hybrid, laguna,
+                                 qwen3_next)
 from biscotti_tpu.models.base import Model, cross_entropy, make_model, multiclass_hinge
 
 
@@ -225,7 +235,9 @@ MODELS: Dict[str, callable] = {
                               (deepseek_v2.deepseek_v2_model,
                                deepseek_v2.PRESETS),
                               (granite_hybrid.granite_hybrid_model,
-                               granite_hybrid.PRESETS))
+                               granite_hybrid.PRESETS),
+                              (qwen3_next.qwen3_next_model,
+                               qwen3_next.PRESETS))
        for name in presets},
 }
 
@@ -233,7 +245,8 @@ MODELS: Dict[str, callable] = {
 DEFAULTS = {"creditcard": "logreg", "lm_tokens": "laguna_s_fedlora",
             "lm_tokens_tiny": "laguna_tiny",
             "lm_tokens_dsv2": "deepseek_v2_fedlora",
-            "lm_tokens_granite": "granite_h_micro_fedlora"}
+            "lm_tokens_granite": "granite_h_micro_fedlora",
+            "lm_tokens_qwen3next": "qwen3_next_fedlora"}
 
 
 def _language_model(build, name: str, cfg, dataset: str) -> Model:

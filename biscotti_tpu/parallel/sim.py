@@ -521,6 +521,12 @@ class Simulator:
                         "chunks a window's state-space scan is walked in "
                         "(ops/ssm.py; static: the window over the model's "
                         "chunk size)").set(self.model.info["ssm_chunks"])
+            if "gdn_chunks" in self.model.info:
+                m.gauge("biscotti_gdn_chunks",
+                        "chunks a window's gated delta rule is walked in "
+                        "(ops/delta_rule.py; static: the window over the "
+                        "model's chunk size)").set(
+                    self.model.info["gdn_chunks"])
         for it in range(num_rounds):
             t0 = time.perf_counter()
             w, stake, mask, err = self.round_step(w, stake, it)

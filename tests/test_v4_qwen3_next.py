@@ -1,0 +1,442 @@
+"""Qwen3-Next-80B-A3B-Instruct's gated delta-net / gated attention hybrid
+with adapters (models/qwen3_next.py, ops/delta_rule.py, ops/attention.py,
+ops/moe.py) against the plain float64 reference
+(benchmark/reference/qwen3_next.py: the delta rule a token at a time), at
+the tiny preset: two periods of three delta-net layers and an attention
+layer, four chunks a 16-token window, two value heads a key head, a head
+group of 2, 4 of 16 experts held, an untied head.
+
+(Named `test_v4_...` so that it is collected LAST: the driver's workers
+take files in alphabetical order, and a new heavy file in the middle moves
+the neighbours of tests/test_runtime.py's live clusters; PR 31's lesson,
+.claude/skills/verify/SKILL.md.)"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.reference import qwen3_next as ref
+from biscotti_tpu.data import datasets as ds
+from biscotti_tpu.models import granite_hybrid, lm, qwen3_next
+from biscotti_tpu.models.zoo import model_for_dataset
+from biscotti_tpu.ops import delta_rule
+
+DATASET = "lm_tokens_tiny"
+NAME = "qwen3_next_tiny"
+TINY = qwen3_next.PRESETS[NAME]
+
+
+def published(cfg):
+    """The preset in the published config.json's keys: the reference's."""
+    return {
+        "hidden_size": cfg.hidden, "num_hidden_layers": cfg.layers,
+        "full_attention_interval": cfg.full_attention_interval,
+        "num_attention_heads": cfg.heads,
+        "num_key_value_heads": cfg.kv_heads, "head_dim": cfg.head_dim,
+        "partial_rotary_factor": cfg.rotary_factor,
+        "rope_theta": cfg.rope_theta,
+        "linear_num_key_heads": cfg.key_heads,
+        "linear_num_value_heads": cfg.value_heads,
+        "linear_key_head_dim": cfg.key_dim,
+        "linear_value_head_dim": cfg.value_dim,
+        "linear_conv_kernel_dim": cfg.conv,
+        "num_experts_per_tok": cfg.top_k, "norm_topk_prob": True,
+        "rms_norm_eps": cfg.eps, "first_expert": cfg.first_expert,
+        "lora_rank": cfg.rank, "lora_alpha": cfg.alpha}
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    model = model_for_dataset(DATASET, NAME)
+    frozen = model.frozen(jax.random.PRNGKey(1))
+    w = model.flat_init(jax.random.PRNGKey(2))
+    shard = ds.load_shard(DATASET, f"{DATASET}0")
+    return model, frozen, w, shard["x_train"], shard["y_train"]
+
+
+def _ref64(variant=None):
+    return ref.compiled(published(TINY), jnp.float64, variant)
+
+
+# --------------------------------------------- the rule, ops/delta_rule.py
+
+
+def _rule_inputs(windows=2, t=16, groups=2, each=2, d=4, e=5,
+                 dtype=jnp.float64):
+    keys = jax.random.split(jax.random.PRNGKey(0), 5)
+    heads = groups * each
+    return (delta_rule.l2norm(jax.random.normal(
+                keys[0], (windows, t, groups, d), dtype)) * d ** -0.5,
+            delta_rule.l2norm(jax.random.normal(
+                keys[1], (windows, t, groups, d), dtype)),
+            jax.random.normal(keys[2], (windows, t, heads, e), dtype),
+            -0.3 * jax.nn.softplus(jax.random.normal(
+                keys[3], (windows, t, heads), dtype)),
+            jax.nn.sigmoid(jax.random.normal(keys[4], (windows, t, heads),
+                                             dtype)))
+
+
+def _token_by_token(q, k, v, g, beta):
+    """The reference's recurrence (its own code), window by window."""
+    return jax.vmap(lambda *a: ref.delta_rule(*a, {}))(q, k, v, g, beta)
+
+
+@pytest.mark.parametrize("t,chunk", [(16, 4), (16, 8), (16, 16), (12, 64),
+                                     (64, 64), (96, 32)])
+def test_the_chunked_rule_is_the_token_by_token_recurrence(t, chunk):
+    """Values and every gradient (q, k, v, g, beta) in float64, at windows
+    of four, two and one chunk, at one SHORTER than a chunk (three blocks
+    of four rows in its solve) and at chunks of 64 and 32 (four and two
+    blocks of `SUB` rows): against the reference's recurrence and against
+    `delta_rule.sequential`. 1e-12: the two forms differ by the order of
+    float64 sums alone."""
+    inputs = _rule_inputs(t=t)
+    assert delta_rule.chunks(t, chunk) == max(1, t // chunk)
+    assert delta_rule.SUB == 16
+    got = delta_rule.chunked(*inputs, chunk)
+    assert got.shape == (2, t, 4, 5)
+    np.testing.assert_allclose(got, _token_by_token(*inputs), atol=1e-12)
+    np.testing.assert_allclose(got, delta_rule.sequential(*inputs),
+                               atol=1e-12)
+
+    def through(f):
+        return jax.grad(lambda *a: jnp.sum(jnp.sin(f(*a))),
+                        argnums=tuple(range(5)))(*inputs)
+
+    want = through(_token_by_token)
+    for name, g, r in zip("q k v g beta".split(),
+                          through(lambda *a: delta_rule.chunked(*a, chunk)),
+                          want):
+        assert np.isfinite(g).all() and np.abs(r).max() > 0, name
+        np.testing.assert_allclose(g, r, atol=1e-11, err_msg=name)
+
+
+def test_a_window_that_is_no_whole_number_of_chunks_is_refused():
+    with pytest.raises(ValueError, match="whole number"):
+        delta_rule.chunks(24, 16)
+    assert delta_rule.chunks(16, 64) == 1  # shorter than a chunk: one
+    with pytest.raises(ValueError, match="whole number"):
+        qwen3_next.qwen3_next_model("a", TINY, 18)
+    with pytest.raises(ValueError, match="value heads"):
+        delta_rule.chunked(*_rule_inputs(groups=3, each=1)[:2],
+                           *_rule_inputs(groups=2, each=2)[2:], 4)
+
+
+def test_the_rules_state_starts_from_zero_at_every_window():
+    """Two windows in one batch are the two alone, whatever the chunk, and
+    a window's first token sees only itself: o_0 = beta_0 (k_0 . q_0)
+    v_0."""
+    q, k, v, g, beta = inputs = _rule_inputs()
+    both = delta_rule.chunked(*inputs, 4)
+    for at in range(2):
+        alone = delta_rule.chunked(*(a[at:at + 1] for a in inputs), 4)
+        np.testing.assert_allclose(both[at:at + 1], alone, atol=1e-15)
+    kq = jnp.repeat(jnp.sum(k[1, 0] * q[1, 0], -1), 2)       # [H]
+    np.testing.assert_allclose(both[1, 0],
+                               (beta[1, 0] * kq)[:, None] * v[1, 0],
+                               atol=1e-12)
+
+
+def test_a_key_head_serves_its_value_heads():
+    """Value head h reads key head h // (H / G): two value heads a key
+    head are four heads on q and k written out twice."""
+    q, k, v, g, beta = _rule_inputs()
+    apart = delta_rule.chunked(jnp.repeat(q, 2, axis=2),
+                               jnp.repeat(k, 2, axis=2), v, g, beta, 4)
+    np.testing.assert_allclose(delta_rule.chunked(q, k, v, g, beta, 4),
+                               apart, atol=1e-14)
+
+
+def test_the_correction_is_in_the_rule():
+    """Without `S^T k` in d the rule is plain gated linear attention (the
+    reference's `no_delta`), with beta = 1 another: both far from it."""
+    inputs = _rule_inputs()
+    got = delta_rule.chunked(*inputs, 4)
+    for variant in ({"delta": False}, {"beta": 1.0}):
+        q, k, v, g, beta = inputs
+        if "beta" in variant:
+            beta = jnp.ones_like(beta)
+        other = jax.vmap(lambda *a: ref.delta_rule(*a, variant))(
+            q, k, v, g, beta)
+        assert float(jnp.max(jnp.abs(other - got))) > 0.05, variant
+
+
+def test_the_rules_operands_are_rounded_and_its_decays_are_not():
+    """bfloat16 operands with float32 accumulation, the solve and the
+    carried state in float32: close to the float32 rule at bfloat16's
+    resolution."""
+    q, k, v, g, beta = _rule_inputs(t=64, d=16, e=16, dtype=jnp.float32)
+    exact = delta_rule.chunked(q, k, v, g, beta, 16)
+    low = delta_rule.chunked(*(a.astype(jnp.bfloat16) for a in (q, k, v)),
+                             g, beta, 16)
+    assert low.dtype == jnp.float32
+    gap = float(jnp.linalg.norm(low - exact) / jnp.linalg.norm(exact))
+    assert 1e-4 < gap < 2e-2, gap
+
+
+def test_l2norm_divides_by_the_length():
+    x = jax.random.normal(jax.random.PRNGKey(1), (3, 7), jnp.float64)
+    got = delta_rule.l2norm(x, 1e-6)
+    np.testing.assert_allclose(
+        got, x / np.sqrt(np.sum(np.square(x), -1, keepdims=True) + 1e-6),
+        atol=1e-14)
+    np.testing.assert_allclose(jnp.linalg.norm(got, axis=-1), 1.0, atol=1e-6)
+
+
+# ------------------------------------- what the two hybrids' mixers share
+
+
+def test_the_conv_without_a_bias_is_granites_with_none():
+    keys = jax.random.split(jax.random.PRNGKey(3), 2)
+    x = jax.random.normal(keys[0], (2, 9, 5), jnp.float32)
+    weight = jax.random.normal(keys[1], (4, 5), jnp.float32)
+    got = lm.causal_conv(x, weight)
+    np.testing.assert_allclose(
+        got, granite_hybrid.causal_conv(x, weight, jnp.zeros((5,))),
+        atol=0)
+    np.testing.assert_allclose(got[:, 0], weight[3] * x[:, 0], atol=1e-6)
+    assert granite_hybrid.causal_conv is lm.causal_conv
+    assert granite_hybrid.gated_norm is lm.gated_norm
+
+
+@pytest.mark.parametrize("gate_first", [True, False])
+def test_the_gated_norm_in_either_order(gate_first):
+    """Granite's gate-then-norm and this model's norm-then-gate from one
+    function, each against its formula written out; the two differ."""
+    keys = jax.random.split(jax.random.PRNGKey(4), 3)
+    y, z = (jax.random.normal(k, (3, 4, 8), jnp.float64) for k in keys[:2])
+    weight = 1.0 + 0.1 * jax.random.normal(keys[2], (8,), jnp.float64)
+    silu = z / (1.0 + jnp.exp(-z))
+
+    def normed(u):
+        return weight * u / jnp.sqrt(jnp.mean(u * u, -1, keepdims=True)
+                                     + 1e-6)
+
+    want = normed(y * silu) if gate_first else normed(y) * silu
+    got = lm.gated_norm(y, z, weight, 1e-6, gate_first=gate_first)
+    np.testing.assert_allclose(got, want, atol=1e-6)
+    other = lm.gated_norm(y, z, weight, 1e-6, gate_first=not gate_first)
+    assert float(jnp.max(jnp.abs(other - want))) > 0.1
+    if gate_first:  # the default is Granite's
+        np.testing.assert_array_equal(lm.gated_norm(y, z, weight, 1e-6), got)
+
+
+def test_a_zero_centred_norm_reads_its_weight_as_one_plus():
+    x = jax.random.normal(jax.random.PRNGKey(5), (3, 8), jnp.float32)
+    w = 0.1 * jax.random.normal(jax.random.PRNGKey(6), (8,), jnp.float32)
+    np.testing.assert_allclose(lm.rms(x, w, 1e-6, zero_centred=True),
+                               lm.rms(x, 1.0 + w, 1e-6), atol=1e-6)
+    assert float(jnp.max(jnp.abs(lm.rms(x, w, 1e-6)))) < 1.0
+
+
+# ------------------------------------------------- against the reference
+
+
+@pytest.mark.parametrize("windows", [1, 3])
+def test_logits_match_the_reference(tiny, windows):
+    """float32 against float64 on the same weights: 5e-5 absolute on logits
+    of magnitude 4 (read at 1.5e-5)."""
+    model, frozen, w, x, _ = tiny
+    tokens = jnp.asarray(x[:windows])
+    got = model.apply_flat(w, tokens, frozen)
+    want, _ = _ref64()[1](frozen, w, tokens)
+    assert got.shape == (windows, 16, 64) and got.dtype == jnp.float32
+    np.testing.assert_allclose(got, want, atol=5e-5)
+
+
+@pytest.mark.parametrize("windows", [1, 3])
+def test_loss_matches_the_reference(tiny, windows):
+    model, frozen, w, x, y = tiny
+    tokens, labels = jnp.asarray(x[:windows]), jnp.asarray(y[:windows])
+    spec = published(TINY)
+    want = jax.jit(lambda frozen, w, tokens, labels: ref.loss(
+        spec, frozen, ref.unflatten(spec, w, jnp.float64), tokens, labels,
+        jnp.float64))(frozen, w, tokens, labels)
+    np.testing.assert_allclose(model.loss_flat(w, tokens, labels, frozen),
+                               want, rtol=1e-5)
+
+
+@pytest.mark.parametrize("windows", [1, 2])
+def test_every_adapter_gradient_matches_the_reference(tiny, windows):
+    """Through the chunked rule's backward (its solve's included), the
+    conv's, the gated norm's, the attention's and the dispatch's, against
+    `jax.grad` of the token-by-token reference: float32's rounding on
+    gradients up to 1.3 (read at 1.4e-5), relative to each leaf's
+    largest."""
+    model, frozen, w, x, y = tiny
+    tokens, labels = jnp.asarray(x[:windows]), jnp.asarray(y[:windows])
+    got = jax.grad(model.loss_flat)(w, tokens, labels, frozen)
+    want = _ref64()[0](frozen, w, tokens, labels)
+    spec = published(TINY)
+    assert ref.num_params(spec) == model.num_params == got.shape[0]
+    for (name, g), (_, r) in zip(ref.leaves(spec, np.asarray(got)),
+                                 ref.leaves(spec, np.asarray(want))):
+        assert np.linalg.norm(r) > 0, name  # every B counts in the loss
+        np.testing.assert_allclose(g, r, atol=2e-6 + 1e-4 * np.abs(r).max(),
+                                   err_msg=name)
+
+
+def test_the_wire_vector_is_the_references_layout(tiny):
+    model, _, w, _, _ = tiny
+    tree = model.unravel(w)
+    names = [name for name, _ in ref.layout(published(TINY))]
+    assert names[:3] == ["layers[0].out", "layers[0].qkvz", "layers[1].out"]
+    assert names[6:10] == [f"layers[3].{n}" for n in "koqv"]
+    assert ref.kinds(published(TINY)) == list(TINY.layer_types) \
+        == (["gdn"] * 3 + ["attention"]) * 2
+    for name, piece in ref.leaves(published(TINY), np.asarray(w)):
+        layer, leaf = name.split(".")
+        mine = tree["layers"][int(layer[len("layers["):-1])][leaf]
+        np.testing.assert_array_equal(np.ravel(mine), piece, err_msg=name)
+
+
+def test_the_router_picks_what_the_reference_picks(tiny):
+    """All 16 experts scored, three a token, at every one of the eight
+    layers; the chosen sets agree wherever the reference's third and
+    fourth probabilities are not within float32's rounding."""
+    model, frozen, w, x, _ = tiny
+    tokens = jnp.asarray(x[:2])
+    experts, probs = qwen3_next.routing(TINY, model.unravel(w), tokens,
+                                        frozen)
+    _, picks = _ref64()[1](frozen, w, tokens)
+    assert experts.shape == (8, 32, 3) and probs.shape == (8, 32, 16)
+    for at, (want_i, want_p) in enumerate(picks):
+        np.testing.assert_allclose(probs[at], want_p, atol=1e-5)
+        ordered = np.sort(np.asarray(want_p), -1)
+        clear = ordered[:, -3] - ordered[:, -4] > 1e-4
+        np.testing.assert_array_equal(
+            np.sort(np.asarray(experts[at]), -1)[clear],
+            np.sort(np.asarray(want_i), -1)[clear])
+
+
+def test_the_head_is_untied_and_the_last_norm_zero_centred(tiny):
+    model, frozen, w, x, _ = tiny
+    assert frozen["head"].shape == (32, 64)
+    assert frozen["embed"].shape == (64, 32)
+    assert abs(float(jnp.mean(frozen["final_norm"]))) < 0.1  # around ZERO
+    tokens = jnp.asarray(x[:1])
+    h = qwen3_next.hidden_states(TINY, lm.one_peer(model.unravel(w)),
+                                 tokens[None], frozen, remat=False)[0]
+    want = lm.rms(h[0], 1.0 + frozen["final_norm"], TINY.eps) \
+        @ frozen["head"]
+    np.testing.assert_allclose(model.apply_flat(w, tokens, frozen), want,
+                               atol=1e-5)
+
+
+def test_the_four_shares_add_up_through_the_whole_layer(tiny):
+    """The model's own layer (a delta-net one and an attention one) on
+    each of four chips' 4 of the 16 experts, against the reference's UNCUT
+    layer: four shares' results less three times what every chip computes
+    alike (the residual, the mixer, the GATED shared expert), so the
+    shared expert counts once."""
+    model, frozen, w, x, _ = tiny
+    spec = published(TINY)
+    key = jax.random.PRNGKey(5)
+    h = frozen["embed"][jnp.asarray(x[:2])][None]         # [1, 2, T, H]
+    adapters = jax.tree.map(lambda a: a[None], model.unravel(w))
+    for layer in (1, 3):
+        kind = TINY.layer_types[layer]
+        full = dict(frozen["layers"][layer])
+        full["experts"] = {
+            name: jax.random.normal(jax.random.fold_in(key, i),
+                                    (16,) + leaf.shape[1:], jnp.float32) / 5
+            for i, (name, leaf) in enumerate(
+                sorted(full["experts"].items()))}
+        lora64 = ref.unflatten(spec, w, jnp.float64)[layer]
+        h64 = jnp.asarray(h[0], jnp.float64)
+        uncut, _ = ref.layer(spec, kind, h64, full, lora64, jnp.float64, {})
+        none = dict(full, experts=jax.tree.map(lambda a: a[:0],
+                                               full["experts"]))
+        alike, _ = ref.layer(dict(spec), kind, h64, none, lora64,
+                             jnp.float64, {})
+        total, held = 0.0, 0
+        for share in range(4):
+            cfg = dataclasses.replace(TINY, first_expert=4 * share)
+            mine = dict(full, experts=jax.tree.map(
+                lambda a: a[4 * share:4 * share + 4], full["experts"]))
+            out, counts, _ = qwen3_next._layer(cfg, layer, h, mine,
+                                               adapters["layers"][layer])
+            total = total + np.asarray(out[0], np.float64)
+            held += int(counts["load"].sum())
+            assert int(counts["dropped"]) == 0
+        assert held == 2 * 16 * TINY.top_k  # every assignment, once
+        np.testing.assert_allclose(total - 3 * np.asarray(alike), uncut,
+                                   atol=2e-4, err_msg=kind)
+        # and with the shared expert ungated the reference's is another
+        bare, _ = ref.layer(spec, kind, h64, full, lora64, jnp.float64,
+                            {"shared_gate": False})
+        assert float(jnp.max(jnp.abs(bare - uncut))) > 1e-2
+
+
+# (the reference's departure, the least it must move the logits by,
+# relative; read at 0.49, 0.71, 0.0026, 0.83, 0.81, 1.07, 1.00, 0.51, 0.75,
+# 0.20, 0.49)
+DEPARTURES = [
+    ("no_delta", {"delta": False}, 0.1),
+    ("beta_one", {"beta": 1.0}, 0.1),
+    ("decay_bfloat16", {"decay": "bfloat16", "chunk": 4}, 5e-4),
+    ("no_carry", {"carry": False, "chunk": 4}, 0.1),
+    ("no_l2norm", {"l2norm": False}, 0.1),
+    ("gate_before_norm", {"gate_first": True}, 0.1),
+    ("norm_not_zero_centred", {"zero_centred": False}, 0.1),
+    ("no_output_gate", {"output_gate": False}, 0.1),
+    ("no_shared_gate", {"shared_gate": False}, 0.1),
+    ("rotary_full", {"rotary": "full"}, 0.02),
+    ("no_renormalise", {"renormalise": False}, 0.1),
+]
+
+
+@pytest.mark.parametrize("name,variant,least",
+                         DEPARTURES, ids=[d[0] for d in DEPARTURES])
+def test_every_departure_of_the_reference_moves_the_logits(tiny, name,
+                                                           variant, least):
+    """The program sits on the reference (1e-5, relative) and every
+    control's departure far from both: the correction, beta, the carried
+    state, the two l2 norms, the norm's place, `1 + w`, the two gates, the
+    partial rotary and the renormalised top-k are in the program."""
+    model, frozen, w, x, _ = tiny
+    tokens = jnp.asarray(x[:2])
+    want = np.asarray(_ref64()[1](frozen, w, tokens)[0])
+    got = np.asarray(model.apply_flat(w, tokens, frozen), np.float64)
+    other = np.asarray(_ref64(variant)[1](frozen, w, tokens)[0])
+    scale = np.linalg.norm(want)
+    assert np.linalg.norm(got - want) / scale < 1e-5
+    assert np.linalg.norm(other - want) / scale > least, name
+
+
+def test_the_published_dtype_runs_close_to_the_reference():
+    """bfloat16 base and operands, float32 accumulation (the published
+    size's arithmetic, here at the tiny widths): within bfloat16's
+    resolution of the float64 reference on the same rounded weights
+    wherever no router flipped."""
+    cfg = dataclasses.replace(TINY, dtype="bfloat16")
+    model = qwen3_next.qwen3_next_model("qwen3_next_tiny_bf16", cfg, 16)
+    frozen = model.frozen(jax.random.PRNGKey(1))
+    assert frozen["layers"][0]["a_log"].dtype == jnp.bfloat16
+    w = model.flat_init(jax.random.PRNGKey(2))
+    tokens = jnp.asarray(ds.load_shard(DATASET, f"{DATASET}0")["x_train"][:2])
+    got = np.asarray(model.apply_flat(w, tokens, frozen), np.float64)
+    want = np.asarray(_ref64()[1](frozen, w, tokens)[0])
+    gap = np.linalg.norm(got - want, axis=-1) / np.linalg.norm(want, axis=-1)
+    assert 1e-4 < np.median(gap) < 3e-2, np.median(gap)
+
+
+def test_the_frozen_scalars_follow_their_laws():
+    layers = model_for_dataset(DATASET, NAME).frozen(
+        jax.random.PRNGKey(7))["layers"]
+    layer, attention = layers[0], layers[3]
+    a = np.exp(np.asarray(layer["a_log"], np.float64))
+    assert ((a > 0.0) & (a <= 16.0)).all()
+    step = np.log1p(np.exp(np.asarray(layer["dt_bias"], np.float64)))
+    assert ((step >= 0.99e-3) & (step <= 0.101)).all()  # Mamba-2's, not 1
+    assert abs(float(np.mean(layer["gate_norm"])) - 1.0) < 0.2   # w
+    assert abs(float(np.mean(layer["norm"]))) < 0.1              # 1 + w
+    assert layer["conv_w"].shape == (4, 2 * 2 * 8 + 4 * 8)
+    assert "conv_b" not in layer and layer["w_ba"].shape == (32, 8)
+    assert layer["w_qkvz"].shape == (32, 2 * 16 + 2 * 32)
+    assert attention["wq"].shape == (32, 2 * 4 * 8)  # a head [q | gate]
+    assert attention["q_norm"].shape == attention["k_norm"].shape == (8,)
+    assert layer["shared_gate"].shape == (32, 1)
