@@ -43,7 +43,9 @@ leaves are drawn from.
       sum; sum over those HELD HERE of p_e Expert_e(x)      (ops/moe.py)
       + sigmoid(x w_sg) Shared(x)
 
-The rule is ops/delta_rule.py's chunked form; the attention core is
+The rule is ops/delta_rule.py's: its fused kernel at the published widths
+(heads of 128 | 128, chunks of 64), its `jax.numpy` chunked form at the
+tiny preset's (`info["gdn_rule"]` says which); the attention core is
 ops/attention.py's (heads of 256 | 256, eight query heads a key/value
 head); the routed experts are ops/moe.py's, 128 groups of 2,048 x 512 at
 the published size. `attention_plan` says which side of the core's
@@ -197,8 +199,8 @@ def _delta_net(cfg, h, frozen, adapters):
     with scope("gdn_rule"):
         q = delta_rule.l2norm(q, 1e-6) * dk ** -0.5
         k = delta_rule.l2norm(k, 1e-6)
-        out = delta_rule.chunked(q.astype(dtype), k.astype(dtype),
-                                 v.astype(dtype), decay, beta, cfg.chunk)
+        out = delta_rule.rule(q.astype(dtype), k.astype(dtype),
+                              v.astype(dtype), decay, beta, cfg.chunk)
     with scope("gdn_gate"):
         out = lm.gated_norm(out, z, frozen["gate_norm"], cfg.eps,
                             gate_first=False)
@@ -299,9 +301,9 @@ def _layer_of(cfg, kind, h, frozen, adapters):
     models' does; the delta net runs the block's windows as ONE batch: its
     mixer keeps a dozen float32 arrays of a window's size for its
     backward, and the walk's stacking of them cost more than the batch (a
-    forced block of 3 on the v5e: 4,047 ms a round walked, 3,557 not; the
-    cell runs a block of 1, 2,995, and walks nothing: PERF.md section 6,
-    PR 38)."""
+    forced block of 3 on the v5e: 4,047 ms a round walked, 3,557 not: PR
+    38; since PR 39 the rule is a kernel whose grid walks the windows and
+    the cell runs a block of 3: PERF.md section 6)."""
     if kind == "gdn":
         mixed = _delta_net(cfg, h, frozen, adapters)
     else:
@@ -397,34 +399,37 @@ def qwen3_next_model(name: str, cfg: Qwen3NextConfig, length: int):
     def step_bytes(batch):
         """Bytes one peer's step adds to what a block holds live at its
         peak. Read off the compiled round's memory analysis at the
-        published size (v5e, ahead of time; PERF.md section 6, PR 38): its
-        temporaries are 2.34 GB at a peer block of 1 and 4.60 GB at 3, so
-        a peer adds 1.13 GB to 1.20 GB that every block pays. The terms
-        that come to it within a thirtieth (1.104 GB), all float32: the
-        logits over the held vocabulary, their log-softmax and their
-        cotangent (0.47 GB); every layer's input, kept for its
-        recomputation; six arrays of `in_proj_qkvz`'s width; a token's
-        `top_k` gathered expert rows, forward and backward; a layer's
-        chunk states and their cotangents, [chunks, value heads, 128,
-        128]. With 10.85 GB of base and 0.68 GB of deltas and noise
-        standing, three such peers are 0.618 of what the chip's 15.75 GiB
-        have left (0.634 by the compiled round's count), just over
-        `peer_step.BLOCK_SHARE`: the round walks one at a time, which is
-        also the fastest the chip ran (2,995 ms a round against 3,557 at
-        a forced 3: three windows' passes stream from HBM what one
-        window's keep in the chip's fast memory, PR 35's finding, and the
-        grouped calls' 252 streams of an 805 MB expert stack where 84 do
-        not make up for it)."""
+        published size (v5e, ahead of time; PERF.md section 6, PR 39,
+        recounted with the rule a kernel): its temporaries are 2.00 GB at
+        a peer block of 1 and 3.98 GB at 3 (2.34 and 4.60 while the rule
+        was `jax.numpy`, PR 38), so a peer adds 0.99 GB to 1.01 GB that
+        every block pays. The terms that come to it within a thirtieth
+        (0.970 GB), all float32: the logits over the held vocabulary,
+        their log-softmax and their cotangent (0.47 GB); every layer's
+        input, kept for its recomputation; four arrays of `in_proj_qkvz`'s
+        width (six before: the rule's head-major copies of q, k and v and
+        its `delta` are no arrays any more); a token's `top_k` gathered
+        expert rows, forward and backward; a layer's chunks' entry states,
+        [chunks, value heads, 128, 128], which the recomputed forward
+        hands the backward (their cotangents never leave the chip's own
+        memory). With 10.85 GB of base and 0.68 GB of deltas and noise
+        standing, three such peers are 0.542 of what the chip's 15.75 GiB
+        have left (0.554 by the compiled round's count), inside
+        `peer_step.BLOCK_SHARE`: the round walks three at a time (PR 38's
+        count read 0.618 and the round walked one)."""
         t = batch * length
         wide = 2 * cfg.key_heads * cfg.key_dim \
             + 2 * cfg.value_heads * cfg.value_dim
         states = chunks * cfg.value_heads * cfg.key_dim * cfg.value_dim
-        return 4 * (t * (3 * cfg.vocab + cfg.layers * cfg.hidden + 6 * wide
+        return 4 * (t * (3 * cfg.vocab + cfg.layers * cfg.hidden + 4 * wide
                          + 2 * cfg.top_k * cfg.hidden)
-                    + 2 * batch * states)
+                    + batch * states)
 
     return lm.lm_model(name, cfg, length,
                        (frozen_shapes, {"layers": trained_shapes}),
                        hidden_states, step_bytes,
                        {"attention": attention_plan(cfg, length),
-                        "gdn_chunks": chunks})
+                        "gdn_chunks": chunks,
+                        "gdn_rule": delta_rule.plan(
+                            cfg.key_heads, length, cfg.key_dim,
+                            cfg.value_dim, cfg.chunk, cfg.dtype)})

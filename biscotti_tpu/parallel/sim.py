@@ -527,6 +527,12 @@ class Simulator:
                         "(ops/delta_rule.py; static: the window over the "
                         "model's chunk size)").set(
                     self.model.info["gdn_chunks"])
+                m.gauge("biscotti_gdn_rule_kernel",
+                        "1 where the round's gated delta rule is "
+                        "ops/delta_rule.py's fused kernel (a chunk's "
+                        "system, its solve and the carried state in the "
+                        "chip's own memory), 0 the jax.numpy form").set(
+                    self.model.info["gdn_rule"]["kernel"])
         for it in range(num_rounds):
             t0 = time.perf_counter()
             w, stake, mask, err = self.round_step(w, stake, it)

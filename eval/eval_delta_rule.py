@@ -1,8 +1,10 @@
 #!/usr/bin/env python
 """One gated delta rule alone, at the shapes Qwen3-Next-80B-A3B's round
-sends it: ops/delta_rule.py's chunked form against the token-by-token
-recurrence (`delta_rule.sequential`), timed from the DEVICE trace
-(per-program durations).
+sends it: ops/delta_rule.py's fused kernel (`kernel_<chunk>`: what
+`delta_rule.rule` runs at these shapes since PR 39) beside its `jax.numpy`
+chunked form (`chunked_<chunk>`: the kernel's oracle, and what the round
+ran before) and the token-by-token recurrence (`delta_rule.sequential`),
+timed from the DEVICE trace (per-program durations).
 
 A peer block of `--windows` windows of 1,024 tokens, 16 key heads of 128
 serving 32 value heads of 128, chunks of `--chunks`, bfloat16 operands.
@@ -36,7 +38,9 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default="chiprun_out")
     ap.add_argument("--seed", type=int, default=38)
     ap.add_argument("--windows", default="1,3")
-    ap.add_argument("--chunks", default="64,32,128")
+    ap.add_argument("--chunks", default="64")
+    ap.add_argument("--forms", default="sequential,chunked,kernel",
+                    help="which of the three forms to time")
     args = ap.parse_args(argv)
 
     import jax
@@ -70,10 +74,17 @@ def main(argv=None) -> int:
         beta = jax.nn.sigmoid(jax.random.normal(
             keys[4], (windows, T, VALUE_HEADS), jnp.float32))
         cot = jax.random.normal(keys[5], v.shape, jnp.float32)
-        forms = {"sequential": delta_rule.sequential}
+        asked = args.forms.split(",")
+        forms = {"sequential": delta_rule.sequential} \
+            if "sequential" in asked else {}
         for chunk in (int(c) for c in args.chunks.split(",")):
-            forms[f"chunked_{chunk}"] = (
-                lambda *a, chunk=chunk: delta_rule.chunked(*a, chunk))
+            if "chunked" in asked:
+                forms[f"chunked_{chunk}"] = (
+                    lambda *a, chunk=chunk: delta_rule.chunked(*a, chunk))
+            if "kernel" in asked and delta_rule.fits(T, WIDTH, WIDTH, chunk,
+                                                     dtype):
+                forms[f"kernel_{chunk}"] = (
+                    lambda *a, chunk=chunk: delta_rule.fused(*a, chunk))
         programs, gaps, want = {}, {}, None
         for label, form in forms.items():
             def forward(q, k, v, g, beta, cot, form=form):

@@ -978,3 +978,45 @@ def test_experts_of_2048_by_512_take_the_kernel_under_half_a_tile(v5e):
             and "parameter(" not in line and "get-tuple-element(" not in line]
     assert not made, made[:5]
     assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
+def test_the_delta_rule_at_the_published_shapes_is_the_kernel(v5e):
+    """The same mixer's gradient, read for the rule (PR 39): under scope
+    `gdn_rule` there are ops/delta_rule.py's three `tpu_custom_call`s (the
+    forward pass's and the recomputed forward's, which both write the
+    chunks' entry states: under `jax.grad` the first is the same call, and
+    a custom call's unread result is still written; and the backward's,
+    which reads them) and each is booked under `gdn_rule`, the LAST scope
+    of its `op_name`, where a device trace's reader looks; nothing of a
+    chunk's system is an array any more: no float32 `[..., 64, 256]`
+    right side or solution, no `[..., 64, 64]` decay or system, and no
+    `while` (the `jax.numpy` form's 16 carried steps) anywhere."""
+    from biscotti_tpu.models import qwen3_next
+
+    cfg, one, frozen, adapters = _delta_hybrid_layer(v5e, 0)
+    model = qwen3_next.qwen3_next_model("lm", cfg, 1024)
+    assert model.info["gdn_rule"]["kernel"] == 1
+
+    def loss(adapters, h, frozen):
+        out = jax.checkpoint(lambda h, f, a: qwen3_next._delta_net(
+            cfg, h, f, a))(h, frozen, adapters)
+        return jnp.sum(out * out)
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        adapters, jax.ShapeDtypeStruct((1, 1, 1024, cfg.hidden), jnp.float32,
+                                       sharding=one), frozen
+    ).compile().as_text()
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 3, len(calls)
+    scopes = re.compile("|".join(qwen3_next.SCOPES))
+    for call in calls:
+        name = re.search(r'op_name="([^"]*)"', call).group(1)
+        assert scopes.findall(name)[-1] == "gdn_rule", name
+    assert all("f32[1,16,32,128,128]" in c for c in calls)  # entry states
+    assert any("bf16[1,1024,2048]" in c for c in calls)     # q as it comes
+    chunk = re.compile(r"f32\[[\d,]*,64,(?:64|256)\]")
+    made = [line.strip()[:160] for line in hlo.splitlines()
+            if chunk.search(line.split(" = ")[-1].split("(")[0])]
+    assert not made, made[:5]
+    assert " while(" not in hlo and "triangular" not in hlo

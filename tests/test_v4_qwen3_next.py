@@ -440,3 +440,186 @@ def test_the_frozen_scalars_follow_their_laws():
     assert attention["wq"].shape == (32, 2 * 4 * 8)  # a head [q | gate]
     assert attention["q_norm"].shape == attention["k_norm"].shape == (8,)
     assert layer["shared_gate"].shape == (32, 1)
+
+
+# ------------------------------ the rule's kernel, ops/delta_rule.py (PR 39)
+# (at the END of the file: the tests above run on the schedule they had, and
+# these, the heaviest, after the live clusters of other files are done)
+
+
+def _wide_inputs(windows=1, t=128, groups=1, dtype=jnp.float32, g=None,
+                 keys=None):
+    """Operands at lane-tile widths (D = E = 128, two value heads a key
+    head), as the model hands them over: q, k normalised, g <= 0 a softplus
+    (or the constant `g`), `keys` "one": every key of a window the same
+    unit vector."""
+    ks = jax.random.split(jax.random.PRNGKey(39), 6)
+    heads = 2 * groups
+    q = delta_rule.l2norm(jax.random.normal(
+        ks[0], (windows, t, groups, 128), jnp.float32)) * 128 ** -0.5
+    k = delta_rule.l2norm(jax.random.normal(
+        ks[1], (windows, 1 if keys == "one" else t, groups, 128),
+        jnp.float32))
+    k = jnp.broadcast_to(k, q.shape)
+    v = jax.random.normal(ks[2], (windows, t, heads, 128), jnp.float32)
+    decay = -jax.nn.softplus(jax.random.normal(
+        ks[3], (windows, t, heads), jnp.float32) - 3.0)
+    if g is not None:
+        decay = jnp.full_like(decay, g)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (windows, t, heads),
+                                            jnp.float32))
+    cot = jax.random.normal(ks[5], v.shape, jnp.float32)
+    return tuple(a.astype(dtype) for a in (q, k, v)) + (decay, beta), cot
+
+
+def _value_and_gradients(form, inputs, cot):
+    out, back = jax.vjp(form, *inputs)
+    return (out,) + back(cot)
+
+
+def _sequential32(q, k, v, g, beta):
+    return delta_rule.sequential(*(a.astype(jnp.float32) for a in (q, k, v)),
+                                 g, beta)
+
+
+def _gaps(got, want):
+    return [float(jnp.linalg.norm((a - r).astype(jnp.float32))
+                  / jnp.linalg.norm(r.astype(jnp.float32)))
+            for a, r in zip(got, want)]
+
+
+NAMES = "o dq dk dv dg dbeta".split()
+
+
+@pytest.mark.parametrize("windows,t,groups", [(1, 128, 1), (2, 128, 2),
+                                              (1, 256, 2), (2, 256, 1)])
+def test_the_kernel_is_the_rule_in_float32(windows, t, groups):
+    """The fused kernel (interpret mode here) against the `jax.numpy`
+    chunked form and the token-by-token recurrence at D = E = 128 and
+    chunks of 64: o and all five gradients, float32 operands, 1e-5 of the
+    largest entry."""
+    inputs, cot = _wide_inputs(windows, t, groups)
+    assert delta_rule.fits(t, 128, 128, 64, jnp.float32)
+    got = _value_and_gradients(lambda *a: delta_rule.rule(*a, 64), inputs,
+                               cot)
+    assert got[0].shape == (windows, t, 2 * groups, 128)
+    assert got[0].dtype == jnp.float32
+    for form in (lambda *a: delta_rule.chunked(*a, 64), _sequential32):
+        want = _value_and_gradients(form, inputs, cot)
+        for name, a, r in zip(NAMES, got, want):
+            assert a.shape == r.shape and a.dtype == r.dtype, name
+            scale = float(jnp.max(jnp.abs(r)))
+            assert scale > 0, name
+            np.testing.assert_allclose(a, r, atol=1e-5 * max(scale, 1.0),
+                                       err_msg=name)
+
+
+@pytest.mark.parametrize("windows,t,groups", [(1, 128, 2), (2, 256, 1)])
+def test_the_kernel_rounds_its_operands_as_the_chunked_form_does(
+        windows, t, groups):
+    """bfloat16 operands: the kernel as far from the float32 recurrence as
+    the `jax.numpy` form is on the chip (eval/eval_delta_rule.py's gaps,
+    PERF.md section 6: 0.0025 on o, 0.0037 on dq and dk; the cotangents
+    are rounded to the operands' type before their products, as the
+    chip's default precision rounds them)."""
+    inputs, cot = _wide_inputs(windows, t, groups, jnp.bfloat16)
+    got = _value_and_gradients(lambda *a: delta_rule.rule(*a, 64), inputs,
+                               cot)
+    assert [a.dtype for a in got] == [jnp.float32] + 3 * [jnp.bfloat16] \
+        + 2 * [jnp.float32]
+    want = _value_and_gradients(_sequential32, inputs, cot)
+    gaps = dict(zip(NAMES, _gaps(got, want)))
+    assert gaps["o"] < 0.003, gaps
+    assert max(gaps.values()) < 0.0045, gaps
+    same = _value_and_gradients(lambda *a: delta_rule.chunked(*a, 64),
+                                inputs, cot)
+    assert _gaps(got[:1], same[:1])[0] < 1e-4  # o: the same rounded sums
+
+
+@pytest.mark.parametrize("case", ["one_key", "strong_decay", "no_decay"])
+def test_the_kernels_solve_holds_where_a_chunk_is_hard(case):
+    """A chunk whose keys are ALL one vector (A = beta decay everywhere
+    below the diagonal: the case the product form of (I + A)^-1 loses, its
+    powers of A grow to 2^63), g near -20 (every decay underflows: a masked
+    decay must be exp(-inf), and exp(gamma_L - gamma) up to e^1280 must
+    never be formed) and g = 0 (no decay at all): values and gradients
+    finite and the recurrence's."""
+    inputs, cot = _wide_inputs(
+        1, 128, 1, keys="one" if case == "one_key" else None,
+        g={"one_key": None, "strong_decay": -20.0, "no_decay": 0.0}[case])
+    got = _value_and_gradients(lambda *a: delta_rule.rule(*a, 64), inputs,
+                               cot)
+    want = _value_and_gradients(_sequential32, inputs, cot)
+    for name, a, r in zip(NAMES, got, want):
+        assert np.isfinite(a).all(), name
+        scale = max(float(jnp.max(jnp.abs(r))), 1.0)
+        np.testing.assert_allclose(a, r, atol=2e-5 * scale, err_msg=name)
+
+
+def test_the_kernels_windows_never_meet():
+    """A batch of two windows is two batches of one, bit for bit, values
+    and gradients: the state starts from zero at a window's first chunk
+    and the cotangent of the state at its last."""
+    inputs, cot = _wide_inputs(2, 128, 1, jnp.bfloat16)
+    both = _value_and_gradients(lambda *a: delta_rule.rule(*a, 64), inputs,
+                                cot)
+    for at in range(2):
+        alone = _value_and_gradients(
+            lambda *a: delta_rule.rule(*a, 64),
+            tuple(a[at:at + 1] for a in inputs), cot[at:at + 1])
+        for name, a, r in zip(NAMES, both, alone):
+            np.testing.assert_array_equal(a[at:at + 1], r, err_msg=name)
+
+
+def test_the_kernel_takes_a_window_shorter_than_a_chunk():
+    """32 tokens in chunks of 64: one chunk of 32 (two blocks of `SUB`
+    rows in its solve)."""
+    inputs, cot = _wide_inputs(1, 32, 1)
+    assert delta_rule.fits(32, 128, 128, 64, jnp.float32)
+    got = _value_and_gradients(lambda *a: delta_rule.rule(*a, 64), inputs,
+                               cot)
+    want = _value_and_gradients(_sequential32, inputs, cot)
+    for name, a, r in zip(NAMES, got, want):
+        np.testing.assert_allclose(
+            a, r, atol=1e-5 * max(float(jnp.max(jnp.abs(r))), 1.0),
+            err_msg=name)
+
+
+@pytest.mark.parametrize("case", ["published", "tiny"])
+def test_a_model_says_which_side_of_the_rules_dispatch_it_runs(case):
+    """`model.info["gdn_rule"]`: the kernel at the published shapes (heads
+    of 128 | 128, chunks of 64, bfloat16), the `jax.numpy` form at the
+    tiny preset's (D = 8, chunks of 4)."""
+    if case == "published":
+        info = qwen3_next.qwen3_next_model(
+            "a", qwen3_next.PRESETS["qwen3_next_fedlora"],
+            1024).info["gdn_rule"]
+        assert info == {"kernel": 1, "states_saved": 1,
+                        "key_heads_a_step": delta_rule.key_heads_a_step(16)}
+        assert 16 % info["key_heads_a_step"] == 0
+    else:
+        info = qwen3_next.qwen3_next_model("a", TINY, 16).info["gdn_rule"]
+        assert info == {"kernel": 0, "states_saved": 0,
+                        "key_heads_a_step": 0}
+
+
+@pytest.mark.parametrize("case", ["float64", "narrow", "ragged_chunk"])
+def test_the_rule_keeps_to_jax_numpy_where_the_kernel_does_not_fit(
+        case, monkeypatch):
+    """float64 operands (the tests' own), a key width of half a lane tile,
+    a window of 24 tokens (no whole block of `SUB` rows): `rule` is
+    `chunked` there, bit for bit, and the kernel is never traced."""
+    def refuse(*a):
+        raise AssertionError("the kernel was called")
+
+    monkeypatch.setattr(delta_rule, "fused", refuse)
+    if case == "narrow":
+        inputs = _rule_inputs(1, 64, 1, 2, 64, 128, jnp.float32)
+    else:
+        inputs, _ = _wide_inputs(1, 24 if case == "ragged_chunk" else 64, 1)
+    if case == "float64":
+        inputs = tuple(a.astype(jnp.float64) for a in inputs)
+    assert not delta_rule.fits(inputs[0].shape[1], inputs[0].shape[-1], 128,
+                               64, inputs[0].dtype)
+    np.testing.assert_array_equal(delta_rule.rule(*inputs, 64),
+                                  delta_rule.chunked(*inputs, 64))

@@ -220,7 +220,8 @@ def test_the_round_trains_the_adapters_and_reports_its_chunks():
     for name in ("biscotti_sim_frozen_bytes", "biscotti_sim_peer_block",
                  "biscotti_lm_attention_fused 0",
                  "biscotti_lm_attention_shared_key 0",
-                 "biscotti_gdn_chunks 4", "biscotti_moe_tokens_dropped 0",
+                 "biscotti_gdn_chunks 4", "biscotti_gdn_rule_kernel 0",
+                 "biscotti_moe_tokens_dropped 0",
                  "biscotti_moe_tile_fill", "biscotti_moe_grouped_kernel 0"):
         assert name in page, name
     assert "biscotti_ssm_chunks" not in page
@@ -231,6 +232,7 @@ def test_the_round_trains_the_adapters_and_reports_its_chunks():
     Simulator(_cfg(model_name="granite_h_tiny", batch_size=2),
               metrics=other).run(num_rounds=1, stop_at_convergence=False)
     assert "biscotti_gdn_chunks" not in other.render()
+    assert "biscotti_gdn_rule_kernel" not in other.render()
 
 
 def test_the_walked_peer_axis_gives_the_same_deltas():
@@ -249,21 +251,22 @@ def test_the_walked_peer_axis_gives_the_same_deltas():
 
 
 def test_the_peer_block_is_what_the_step_bytes_leave_room_for():
-    """The published preset's `step_bytes` (1.104 GB: read off the compiled
-    round's memory analysis, PERF.md section 6, PR 38) against what the
-    chip's runtime states less the standing arrays: three peers are 0.618
-    of the free bytes, just over `BLOCK_SHARE`, so the cell walks ONE at a
-    time (the fastest the chip ran). An edge, and said so: a twentieth
-    more free memory and the rule would take 3."""
+    """The published preset's `step_bytes` (0.970 GB: read off the compiled
+    round's memory analysis, PERF.md section 6, PR 39: the rule a kernel,
+    a peer adds 0.99 GB where 1.13 while it was `jax.numpy`) against what
+    the chip's runtime states less the standing arrays: three peers are
+    0.542 of the free bytes, inside `BLOCK_SHARE`, so the cell walks
+    THREE at a time. A tenth less free memory and the rule would take 1;
+    seven are far out."""
     big = model_for_dataset("lm_tokens_qwen3next")
     step = big.step_bytes(1)
     free = DEVICE_BYTES - (2 * 5424460992 + 30 * 2 * 64 * 1024 * 4
                            + 4 * (3 * 21 + 2) * 2605056)
-    assert 1.09e9 < step < 1.12e9
-    assert BLOCK_SHARE < 3 * step / free < BLOCK_SHARE + 0.03
+    assert 0.96e9 < step < 1.0e9
+    assert BLOCK_SHARE - 0.07 < 3 * step / free < BLOCK_SHARE
     assert peer_block(21, step, free) == peer_block(21, step,
-                                                    int(0.9 * free)) == 1
-    assert peer_block(21, step, int(1.1 * free)) == 3
+                                                    int(2 * free)) == 3
+    assert peer_block(21, step, int(0.9 * free)) == 1
 
 
 def test_the_hive_stepper_steps_the_model_as_the_trainer_does():
