@@ -26,8 +26,9 @@ they are bit-identical across peer processes exactly like the synthetic ones.
 Token shards (`lm_tokens`, `lm_tokens_dsv2` over DeepSeek-V2's held
 25,600 rows, `lm_tokens_granite` over all 100,352 of Granite-4.0-H-Micro's,
 `lm_tokens_qwen3next` over Qwen3-Next-80B-A3B's held 37,984,
+`lm_tokens_mimo` over MiMo-V2.5's held 19,072 in windows of 2,048,
 and `lm_tokens_tiny` at the tests' size) feed the language models
-(models/laguna.py, models/deepseek_v2.py, models/granite_hybrid.py): a row
+(models/laguna.py and its siblings): a row
 is a WINDOW of `d_in` token ids
 (int32) and its label row is the same window one position on, so `y` holds
 a label a position. Ids are drawn from the slice of the vocabulary held
@@ -109,6 +110,9 @@ DATASETS: Dict[str, DatasetSpec] = {
     # and over the held quarter of Qwen3-Next-80B-A3B's
     "lm_tokens_qwen3next": DatasetSpec("lm_tokens_qwen3next", 1024, 37984,
                                        80, 2, tokens=True),
+    # windows of 2,048 over the held eighth of MiMo-V2.5's
+    "lm_tokens_mimo": DatasetSpec("lm_tokens_mimo", 2048, 19072, 80, 2,
+                                  tokens=True),
 }
 
 ZIPF_EXPONENT = 1.1  # the unigram law of token shards: p(rank) ∝ rank^-1.1

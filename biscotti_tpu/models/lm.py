@@ -1,7 +1,8 @@
 """What the language models of this package share (models/laguna.py,
-models/deepseek_v2.py, models/granite_hybrid.py, models/qwen3_next.py): a
-decoder whose frozen base is held once beside rank-r adapters `B [r, out]`,
-which are what the peers train, commit and aggregate (the FFA-LoRA form:
+models/deepseek_v2.py, models/granite_hybrid.py, models/qwen3_next.py,
+models/mimo_v2.py): a decoder whose frozen base is held once beside rank-r
+adapters `B [r, out]`, which are what the peers train, commit and
+aggregate (the FFA-LoRA form:
 `A` frozen and shared, so that the sum of the peers' updates IS the update
 of the sum).
 

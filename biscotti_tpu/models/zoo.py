@@ -33,6 +33,16 @@
              dataset `lm_tokens_qwen3next`
   qwen3_next_tiny  the same mechanism at the CPU tests' size
              (`lm_tokens_tiny`; two periods, four chunks a window)
+  mimo_v2_fedlora  MiMo-V2.5's window / full attention decoder
+             (models/mimo_v2.py): a frozen 5.8 B-parameter share at the
+             published widths (7 layers: the dense one and a whole period of
+             window x 4, full, window; 32 of 256 experts a layer, 19,072
+             rows of an untied vocabulary), rank-16 adapters on the fused
+             qkv and on o trained, d = 2,080,768; reads the dataset
+             `lm_tokens_mimo` (windows of 2,048 tokens)
+  mimo_v2_tiny  the same mechanism at the CPU tests' size
+             (`lm_tokens_tiny`; a window of 4 with its sink, head groups of
+             4 and 2)
 
 Inits are MXU-friendly (fan-in scaled normal) and every model is expressed in
 channels-last NHWC, the layout XLA prefers on TPU.
@@ -48,7 +58,7 @@ import jax.numpy as jnp
 
 from biscotti_tpu.data.datasets import base_name, spec as dspec
 from biscotti_tpu.models import (deepseek_v2, granite_hybrid, laguna,
-                                 qwen3_next)
+                                 mimo_v2, qwen3_next)
 from biscotti_tpu.models.base import Model, cross_entropy, make_model, multiclass_hinge
 
 
@@ -237,7 +247,8 @@ MODELS: Dict[str, callable] = {
                               (granite_hybrid.granite_hybrid_model,
                                granite_hybrid.PRESETS),
                               (qwen3_next.qwen3_next_model,
-                               qwen3_next.PRESETS))
+                               qwen3_next.PRESETS),
+                              (mimo_v2.mimo_v2_model, mimo_v2.PRESETS))
        for name in presets},
 }
 
@@ -246,7 +257,8 @@ DEFAULTS = {"creditcard": "logreg", "lm_tokens": "laguna_s_fedlora",
             "lm_tokens_tiny": "laguna_tiny",
             "lm_tokens_dsv2": "deepseek_v2_fedlora",
             "lm_tokens_granite": "granite_h_micro_fedlora",
-            "lm_tokens_qwen3next": "qwen3_next_fedlora"}
+            "lm_tokens_qwen3next": "qwen3_next_fedlora",
+            "lm_tokens_mimo": "mimo_v2_fedlora"}
 
 
 def _language_model(build, name: str, cfg, dataset: str) -> Model:

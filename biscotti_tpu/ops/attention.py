@@ -97,12 +97,14 @@ _NT = (((1,), (1,)), ((), ()))  # a @ b^T
 _TN = (((0,), (0,)), ((), ()))  # a^T @ b
 
 
-def plain(q, k, v, window: int, scale=None, shared=None):
+def plain(q, k, v, window: int, scale=None, shared=None, sink=None):
     """The `einsum` form: q [W, kv, G, T, d], k [W, kv, T, d], v [W, kv, T,
     e] in one type; float32[W, kv, G, T, e]. Makes the scores [W, kv, G, T,
     T]; `scale` multiplies them (None: 1 / sqrt(d)). With `shared` [W, 1,
     T, r], a key part every head has alike, k is [W, kv, T, d - r] and
-    q's last r dimensions are contracted with `shared`."""
+    q's last r dimensions are contracted with `shared`. With `sink` [kv,
+    G], a float a query head, the softmax has one more column, the sink's
+    (unscaled, seen by every query), and that column has no value."""
     t, d = q.shape[-2:]
     own = k.shape[-1]
     scores = jnp.einsum("wgqtd,wgsd->wgqts",
@@ -114,7 +116,15 @@ def plain(q, k, v, window: int, scale=None, shared=None):
     scores = scores / math.sqrt(d) if scale is None else scores * scale
     i, j = np.arange(t)[:, None], np.arange(t)[None, :]
     seen = (j <= i) if window >= t else ((j <= i) & (i - j < window))
-    probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
+    if sink is None:
+        probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
+    else:
+        column = jnp.broadcast_to(
+            sink.astype(jnp.float32)[None, :, :, None, None],
+            scores.shape[:-1] + (1,))
+        probs = jax.nn.softmax(jnp.concatenate(
+            [jnp.where(seen, scores, -jnp.inf), column], axis=-1),
+            axis=-1)[..., :-1]
     return jnp.einsum("wgqts,wgse->wgqte", probs.astype(q.dtype), v,
                       preferred_element_type=jnp.float32)
 
@@ -224,8 +234,11 @@ def _scores(rows, columns):
 
 
 def _forward(q_ref, k_ref, v_ref, *rest, bq: int, bk: int, window: int,
-             scale: float):
-    *shared_ref, out_ref, lse_ref = rest  # the shared key part, if any
+             scale: float, sink: bool = False):
+    # the shared key part, if any, then the sinks [kv, G] (SMEM), if any
+    *shared_ref, out_ref, lse_ref = rest
+    sink_ref = shared_ref.pop() if sink else None
+    at_head = pl.program_id(1) if sink else None  # read outside the loops
     keys = (k_ref, *shared_ref)
     iq = pl.program_id(2)
     first, last = key_blocks(iq, bq, bk, window, jnp.maximum)
@@ -249,11 +262,17 @@ def _forward(q_ref, k_ref, v_ref, *rest, bq: int, bk: int, window: int,
                                         preferred_element_type=jnp.float32)
             return m_new, l, acc
 
+        stop = last + 1
+        if sink:  # one more column, seen by every row, with no value:
+            # the running maximum and sum start from it
+            start = (jnp.full((bq, 1), sink_ref[at_head, g],
+                              jnp.float32),
+                     jnp.ones((bq, 1), jnp.float32))
+        else:
+            start = (jnp.full((bq, 1), -jnp.inf, jnp.float32),
+                     jnp.zeros((bq, 1), jnp.float32))
         m, l, acc = jax.lax.fori_loop(
-            first, last + 1, step,
-            (jnp.full((bq, 1), -jnp.inf, jnp.float32),
-             jnp.zeros((bq, 1), jnp.float32),
-             jnp.zeros((bq, e), jnp.float32)))
+            first, stop, step, start + (jnp.zeros((bq, e), jnp.float32),))
         out_ref[g] = (acc / l).astype(out_ref.dtype)
         lse_ref[g] = _as_row(m + jnp.log(l))
 
@@ -360,7 +379,9 @@ _SEMANTICS = ("parallel", "parallel", "arbitrary")
 _SEMANTICS_SHARED = ("parallel", "arbitrary", "arbitrary")
 
 
-def _call_forward(interpret, q, k, v, *shared, window, bq, bk, scale):
+def _call_forward(interpret, q, k, v, *rest, window, bq, bk, scale, sink):
+    # rest: the shared key part, if any, then the sinks, if any
+    shared, sinks = (rest[:-1], rest[-1:]) if sink else (rest, ())
     w, kv, g, t, d = q.shape
     e = v.shape[-1]  # the values' width, the scores' apart (== d: the same)
     heads, _, rows = _specs(g, t, d, bq)
@@ -370,9 +391,11 @@ def _call_forward(interpret, q, k, v, *shared, window, bq, bk, scale):
     # kernels are; every operand is 32 bits or narrower already
     with jax.enable_x64(False):
         return pl.pallas_call(
-            partial(_forward, bq=bq, bk=bk, window=window, scale=scale),
+            partial(_forward, bq=bq, bk=bk, window=window, scale=scale,
+                    sink=sink),
             grid=(w, kv, t // bq),
-            in_specs=[heads, whole, whole_e] + _shared_spec(shared),
+            in_specs=[heads, whole, whole_e] + _shared_spec(shared)
+            + [pl.BlockSpec(memory_space=pltpu.SMEM) for _ in sinks],
             out_specs=[heads_e, rows],
             out_shape=[jax.ShapeDtypeStruct(q.shape[:-1] + (e,), jnp.float32),
                        jax.ShapeDtypeStruct((w, kv, g, 1, t), jnp.float32)],
@@ -380,7 +403,7 @@ def _call_forward(interpret, q, k, v, *shared, window, bq, bk, scale):
                 dimension_semantics=_SEMANTICS),
             interpret=interpret,
             name="attention_forward",
-        )(q, k, v, *shared)
+        )(q, k, v, *shared, *sinks)
 
 
 def _call_backward(interpret, q, k, v, do, lse, di, *shared, window, bq, bk,
@@ -419,19 +442,20 @@ def _dispatched(call, *operands, **static):
         *operands, tpu=partial(call, False), default=partial(call, True))
 
 
-def _some(shared):
-    """() of None, (shared,) of a shared key part: a kernel's operand
-    list is read off what the call was given."""
-    return () if shared is None else (shared,)
+def _some(*optional):
+    """The operands a call was given, in order: a kernel's operand list
+    is read off them (None: that operand is not there)."""
+    return tuple(a for a in optional if a is not None)
 
 
 # jitted so that a program traces each shape of them once, however many
 # layers and passes call them (a round has two head counts x two masks x
 # three window counts, forward, recomputation and backward)
 @partial(jax.jit, static_argnames=("window", "bq", "bk", "scale"))
-def _run_forward(q, k, v, window, bq, bk, scale, shared=None):
-    return _dispatched(_call_forward, q, k, v, *_some(shared), window=window,
-                       bq=bq, bk=bk, scale=scale)
+def _run_forward(q, k, v, window, bq, bk, scale, shared=None, sink=None):
+    return _dispatched(_call_forward, q, k, v, *_some(shared, sink),
+                       window=window, bq=bq, bk=bk, scale=scale,
+                       sink=sink is not None)
 
 
 @partial(jax.jit, static_argnames=("window", "bq", "bk", "scale"))
@@ -442,11 +466,12 @@ def _run_backward(q, k, v, out, lse, do, window, bq, bk, scale, shared=None):
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def fused(q, k, v, window: int, block=None, scale=None, shared=None):
-    """float32[W, kv, G, T, e]: `plain(q, k, v, window, scale, shared)` by
-    the kernel, at `block` (query block, key block), or at `blocks(G, T, d,
-    q.dtype, e, r)`, which then must take the shape."""
-    return _fused_fwd(q, k, v, window, block, scale, shared)[0]
+def fused(q, k, v, window: int, block=None, scale=None, shared=None,
+          sink=None):
+    """float32[W, kv, G, T, e]: `plain(q, k, v, window, scale, shared,
+    sink)` by the kernel, at `block` (query block, key block), or at
+    `blocks(G, T, d, q.dtype, e, r)`, which then must take the shape."""
+    return _fused_fwd(q, k, v, window, block, scale, shared, sink)[0]
 
 
 def _static(q, k, v, window, block, scale):
@@ -458,35 +483,86 @@ def _static(q, k, v, window, block, scale):
             + (1.0 / math.sqrt(d) if scale is None else float(scale),))
 
 
-def _fused_fwd(q, k, v, window, block, scale, shared=None):
+def _fused_fwd(q, k, v, window, block, scale, shared=None, sink=None):
     out, lse = _run_forward(q, k, v, *_static(q, k, v, window, block, scale),
-                            shared=shared)
-    return out, (q, k, v, shared, out, lse)
+                            shared=shared, sink=sink)
+    return out, (q, k, v, shared, sink, out, lse)
 
 
 def _fused_bwd(window, block, scale, res, do):
-    q, k, v, shared, out, lse = res
+    q, k, v, shared, sink, out, lse = res
     dq, dk, dv, *dr = _run_backward(
         q, k, v, out, lse, do, *_static(q, k, v, window, block, scale),
         shared=shared)
-    return dq, dk, dv, (dr[0] if dr else None)
+    if sink is None:
+        return dq, dk, dv, (dr[0] if dr else None), None
+    # the sinks' own cotangent needs no kernel: a row's log-sum-exp holds
+    # its sink, whose probability exp(sink - lse) weighs no value, so d
+    # sink = - sum over the rows of that probability x sum(out * dout). A
+    # frozen sink asks for none and the compiler drops this
+    mass = jnp.exp(sink[None, :, :, None, None].astype(jnp.float32) - lse)
+    d_sink = -jnp.sum(mass[..., 0, :] * jnp.sum(out * do, axis=-1),
+                      axis=(0, 3))
+    return dq, dk, dv, (dr[0] if dr else None), d_sink.astype(sink.dtype)
 
 
 fused.defvjp(_fused_fwd, _fused_bwd)
 
 
-def attention(q, k, v, window: int, scale=None, shared=None):
+def group_split(g: int, t: int, d: int, dtype, e: int = None,
+                shared: int = 0):
+    """Into how many sub-groups a key/value head's `g` query heads go so
+    that the kernel takes the call: 1 where `blocks` takes the group whole,
+    None where it takes no sub-group either. A step of the kernel holds its
+    whole sub-group's query block beside the key/value head's whole k, v,
+    dk and dv: at windows of 2,048 tokens and scores of 192 those four and
+    their sums are 9.4 MB of the 12, and sixteen (or eight) heads' blocks
+    do not fit beside them. Of the sub-group sizes that fit, the one whose
+    block stands first in `BLOCKS` (fastest first), the larger sub-group
+    where two share a block: on the v5e the causal call at 64 heads of 192
+    | 128 on 2,048 tokens took 1.91 ms forward and 4.51 with its backward
+    a head at a time at 256 x 256, and 3.77 and 8.08 in sub-groups of four
+    at 128 x 128; under a window of 128 the two are within 5% of each other
+    (eval/eval_attention.py --layers mimo; PERF.md section 6, PR 40)."""
+    if blocks(g, t, d, dtype, e, shared) is not None:
+        return 1
+    found = {g // each: BLOCKS.index(block)
+             for each in range(g, 0, -1) if g % each == 0
+             for block in [blocks(each, t, d, dtype, e, shared)] if block}
+    return min(found, key=lambda s: (found[s], s)) if found else None
+
+
+def sub_groups(q, k, v, sink, split: int):
+    """(q, k, v, sink) of a call whose every key/value head's query heads
+    go in `split` sub-groups: each sub-group a key/value head of its own,
+    with its own copy of k and v."""
+    w, kv, g, t, d = q.shape
+    return (q.reshape(w, kv * split, g // split, t, d),
+            jnp.repeat(k, split, axis=1), jnp.repeat(v, split, axis=1),
+            None if sink is None else sink.reshape(kv * split, g // split))
+
+
+def attention(q, k, v, window: int, scale=None, shared=None, sink=None):
     """float32[W, kv, G, T, e] = softmax(scale q k^T + mask) v, the mask
     `j <= i and i - j < window`, `scale` 1 / sqrt(d) where None: q [W, kv,
     G, T, d], k [W, kv, T, d], v [W, kv, T, e], the scores' width d and
     the values' e each its own. With `shared` [W, 1, T, r], a key part
     every head has alike (DeepSeek-V2's one rotary key), k is [W, kv, T,
     d - r], a head's key is [k | shared] and no array holds it: the
-    scores are q[..., :d - r] k^T + q[..., d - r:] shared^T. The kernel
-    where `blocks` takes the shape, the `einsum` form elsewhere. One
-    algorithm, its parameters read off the shapes and its operands off
-    what it was given."""
-    r = q.shape[-1] - k.shape[-1]
-    if blocks(*q.shape[2:], q.dtype, v.shape[-1], r) is None:
-        return plain(q, k, v, window, scale, shared)
-    return fused(q, k, v, window, None, scale, shared)
+    scores are q[..., :d - r] k^T + q[..., d - r:] shared^T. With `sink`
+    [kv, G], a float a query head (MiMo-V2's learned sink), the softmax's
+    denominator holds exp(sink) too, and nothing of it reaches the result.
+    The kernel where `blocks` takes the shape, whole or in the sub-groups
+    of `group_split` (each then reads its own copy of its key/value head;
+    the copies' cotangents add up in the compiler's transpose of the
+    copy), the `einsum` form elsewhere. One algorithm, its parameters read
+    off the shapes and its operands off what it was given."""
+    w, kv, g, t, d = q.shape
+    split = group_split(g, t, d, q.dtype, v.shape[-1], d - k.shape[-1])
+    if split is None:
+        return plain(q, k, v, window, scale, shared, sink)
+    if split == 1:
+        return fused(q, k, v, window, None, scale, shared, sink)
+    q, k, v, sink = sub_groups(q, k, v, sink, split)
+    out = fused(q, k, v, window, None, scale, shared, sink)
+    return out.reshape(w, kv, g, t, out.shape[-1])

@@ -1,14 +1,15 @@
 """A sparse-expert layer that is told which experts it holds.
 
 The router keeps its published width (it scores ALL the model's experts),
-its experts a token and its rule (`route`: plain top-k, or group-limited
-greedy; the chosen probabilities renormalised or not); this device holds the contiguous slice
-`[first, first + E)` of them (the `E` leading rows of the stacked expert
-weights) and computes ITS experts' part of the layer's result for the
-tokens routed to them. What the absent experts would add is left out, as
-on one chip of an expert-parallel deployment before the exchange; no code
-here stands in for the other chips (tests/test_laguna.py: the shares add
-up to the uncut layer).
+its experts a token and its rule (`route`: plain top-k of a softmax, or
+group-limited greedy, or the top-k of sigmoid scores plus a choice bias;
+the chosen probabilities renormalised or not); this device holds the
+contiguous slice `[first, first + E)` of them (the `E` leading rows of the
+stacked expert weights) and computes ITS experts' part of the layer's
+result for the tokens routed to them. What the absent experts would add is
+left out, as on one chip of an expert-parallel deployment before the
+exchange; no code here stands in for the other chips (tests/test_laguna.py:
+the shares add up to the uncut layer).
 
 No token is dropped and there is no capacity factor: the token-expert
 assignments are sorted by expert (those of experts held elsewhere last) and
@@ -54,7 +55,8 @@ from biscotti_tpu.ops import grouped_matmul
 
 
 def route(x: jax.Array, router_w: jax.Array, top_k: int, scale: float,
-          groups: int = 1, groups_kept: int = 1, renormalise: bool = True):
+          groups: int = 1, groups_kept: int = 1, renormalise: bool = True,
+          bias: jax.Array = None):
     """softmax over ALL experts, the `top_k` largest, their probabilities
     scaled: (experts int32[N, k], coefficients float32[N, k], probabilities
     float32[N, E_all]).
@@ -65,9 +67,25 @@ def route(x: jax.Array, router_w: jax.Array, top_k: int, scale: float,
     the experts in those (the others' probabilities count as 0). One group
     is the plain top-k. `renormalise` divides the chosen probabilities by
     their sum before the scale (`norm_topk_prob`); without it the
-    coefficients are `scale * p`."""
+    coefficients are `scale * p`.
+
+    With `bias` float[E_all] (MiMo-V2's `scoring_func` sigmoid under
+    `topk_method` noaux_tc) an expert's score is s = sigmoid(logit), each
+    on its own, the `top_k` are the largest of s + bias and are weighed by
+    s alone; what comes back third is then s + bias, what the choice was
+    made by. One group only."""
     logits = jnp.dot(x.astype(router_w.dtype), router_w,
                      preferred_element_type=jnp.float32)
+    if bias is not None:
+        if groups > 1:
+            raise ValueError("a choice bias goes with one group of experts")
+        scores = jax.nn.sigmoid(logits)
+        chosen_by = scores + bias.astype(jnp.float32)
+        _, top_i = jax.lax.top_k(chosen_by, top_k)
+        top_p = jnp.take_along_axis(scores, top_i, axis=-1)
+        coef = scale * (top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+                        if renormalise else top_p)
+        return top_i.astype(jnp.int32), coef, chosen_by
     probs = jax.nn.softmax(logits, axis=-1)
     eligible = probs
     if groups > 1:

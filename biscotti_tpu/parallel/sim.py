@@ -516,6 +516,31 @@ class Simulator:
                         "(DeepSeek-V2's one rotary key), 0 where every "
                         "key is its head's own").set(
                     plan.get("shared_key", 0))
+                for kind, of in plan.get("kinds", {}).items():
+                    m.gauge("biscotti_attn_block_share",
+                            "(query block, key block) pairs of the scores "
+                            "the attention core of a KIND of layer visits "
+                            "over all pairs (models/mimo_v2.py: window | "
+                            "full; the einsum form: 1)").set(
+                        of["block_share"], kind=kind)
+                    m.gauge("biscotti_attn_seen_share",
+                            "scores the mask lets through over the scores "
+                            "of the pairs of blocks that kind's core "
+                            "visits").set(of["seen_share"], kind=kind)
+                    m.gauge("biscotti_attn_group",
+                            "query heads a call of that kind's core holds "
+                            "together: a key/value head's, or a sub-group "
+                            "of them (ops/attention.group_split)").set(
+                        of["group"], kind=kind)
+            if "sink_mass" in self.model.info:
+                m.gauge("biscotti_attn_sink_mass",
+                        "mean probability a query of the held-out windows "
+                        "gives its head's learned sink under the run's "
+                        "starting weights, window layers (what of a row's "
+                        "softmax reaches no value)").set(float(
+                            self.model.info["sink_mass"](
+                                self.model.unravel(w), self.x_val,
+                                self.frozen)))
             if "ssm_chunks" in self.model.info:
                 m.gauge("biscotti_ssm_chunks",
                         "chunks a window's state-space scan is walked in "

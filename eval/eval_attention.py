@@ -18,7 +18,16 @@ inside the timed program. Granite-4.0-H-Micro's
 attention layers (`--layers granite`): 32 query heads on 8 key/value heads
 of 64 | 64, causal, the scores times 1 / 64; the values as they are (half
 a lane tile) and, `values128_*`, zero-padded to 128 inside the timed
-program and cut again. Each forward alone and forward
+program and cut again. MiMo-V2.5's two kinds (`--layers mimo`: `mimo_swa`,
+64 query heads on 8 key/value heads of 192 | 128 under a window of 128
+with a learned sink a head, and `mimo_full`, on 4 under the causal mask),
+on ONE window of 2,048 tokens (the cell's peer block is 1): a key/value
+head's 8 or 16 query heads do not fit the kernel's buffers whole, so each
+form is a sub-group size (`_g4`, `_g2`, `_g1`, each reading its own copy
+of its key/value head, made inside the timed program) at the block
+`blocks` takes for it, and at 128 x 128; `the_programs_own` marks what
+`attention.group_split` picks (`_g1` at 256 x 256 since these readings).
+Each forward alone and forward
 + backward (what a `jax.checkpoint`ed layer runs in the backward pass).
 Beside each time: the products of the visited (query block, key block)
 pairs (forward 2 bq bk (d + e), backward 2 bq bk (3 d + 2 e) more; d the
@@ -31,6 +40,7 @@ error.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -40,13 +50,18 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 ITERS = 8
-T = 1024
+T = 1024  # a window's tokens (TOKENS: the kinds that have another)
 # kind: (query heads, window, key/value heads, scores' width, values', scale)
 LAYERS = {"full": (48, T, 8, 128, 128, None),
           "sliding": (72, 512, 8, 128, 128, None),
           "mla": (128, T, 128, 192, 128, 192 ** -0.5 * 1.2608 ** 2),
-          "granite": (32, T, 8, 64, 64, 0.015625)}
+          "granite": (32, T, 8, 64, 64, 0.015625),
+          "mimo_swa": (64, 128, 8, 192, 128, None),
+          "mimo_full": (64, 2048, 4, 192, 128, None)}
 SHARED = {"mla": 64}  # of the scores' width, a key part every head shares
+TOKENS = {"mimo_swa": 2048, "mimo_full": 2048}  # a window's (others: T)
+SINK = ("mimo_swa",)  # a learned float a query head in the denominator
+SPLIT = {"mimo_swa": (4, 2, 1), "mimo_full": (4, 2, 1)}  # sub-group sizes
 PAIRS = tuple((bq, bk) for bq in (128, 256, 512) for bk in (128, 256, 512))
 
 
@@ -81,10 +96,17 @@ def main(argv=None) -> int:
     rows = []
     pairs_run = tuple(tuple(int(x) for x in p.split("x"))
                       for p in args.pairs.split(",") if p) or PAIRS
-    for kind, W in ((kind, int(w)) for kind in args.layers.split(",")
-                    for w in args.windows.split(",")):
+    kinds = [kind for name in args.layers.split(",")
+             for kind in (("mimo_swa", "mimo_full") if name == "mimo"
+                          else (name,))]
+    for kind, W in ((kind, int(w)) for kind in kinds
+                    for w in (args.windows.split(",") if kind not in TOKENS
+                              else "1")):
         heads, window, kv, d, e, scale = LAYERS[kind]
         g, r = heads // kv, SHARED.get(kind, 0)
+        T = TOKENS.get(kind, 1024)
+        sink = jax.random.normal(keys[3], (kv, g), jnp.float32) + 4.85 \
+            if kind in SINK else None
         q = jax.random.normal(keys[0], (W, kv, g, T, d), jnp.float32)
         k = jax.random.normal(keys[1], (W, kv, T, d), jnp.float32).astype(dt)
         v = jax.random.normal(keys[2], (W, kv, T, e), jnp.float32).astype(dt)
@@ -98,14 +120,29 @@ def main(argv=None) -> int:
                 part, k.shape[:-1] + (r,))], -1)
         # every pair is timed where the chip's compiler takes it; `admitted`
         # are those whose buffers `blocks` counts inside the default VMEM
-        admitted = [pair for pair in PAIRS if at._buffers(
-            g, T, d, *pair, dt.dtype.itemsize, e, r) <= at._VMEM_BUFFERS]
+        def admitted(pair, each):
+            return at._buffers(each, T, d, *pair, dt.dtype.itemsize, e,
+                               r) <= at._VMEM_BUFFERS
+
         # label -> (form, its operands, its (dq, dk, dv[, dshared]) as the
         # whole key's (dq, dk, dv))
         whole = (q, k, v)
-        forms = {"einsum": (lambda q, k, v: at.plain(q, k, v, window, scale),
-                            whole)}
-        for pair in pairs_run:
+        forms = {"einsum": (lambda q, k, v: at.plain(q, k, v, window, scale,
+                                                     None, sink), whole)}
+        for each in SPLIT.get(kind, ()):  # sub-groups of `each` heads
+            taken = at.blocks(each, T, d, dt, e)
+            if at.group_split(g, T, d, dt, e) == g // each:
+                own = taken
+
+            def split(q, k, v, each=each, pair=None):
+                q, k, v, sinks = at.sub_groups(q, k, v, sink, g // each)
+                return at.fused(q, k, v, window, pair, scale, None,
+                                sinks).reshape(W, kv, g, T, e)
+
+            for pair in dict.fromkeys(filter(None, (taken, (128, 128)))):
+                forms["kernel_%dx%d_g%d" % (pair + (each,))] = (
+                    functools.partial(split, pair=pair), whole)
+        for pair in pairs_run if kind not in SPLIT else ():
             if r:
                 forms["shared_%dx%d" % pair] = (
                     lambda q, k, v, part, pair=pair: at.fused(
@@ -183,8 +220,12 @@ def main(argv=None) -> int:
                    "window": window,
                    "form": label, "the_programs_own": (
                        pair == own and label.startswith(
-                           "shared" if r else "kernel")),
-                   "admitted": pair in admitted,
+                           "shared" if r else "kernel")
+                       and (kind not in SPLIT or label.endswith("_g%d" % (
+                           g // at.group_split(g, T, d, dt, e))))),
+                   "admitted": bool(pair) and admitted(
+                       pair, int(label.rsplit("_g", 1)[1])
+                       if "_g" in label else g),
                    "block_share": (round(at.block_share(T, window, *pair), 4)
                                    if pair else 1.0),
                    "gap_to_einsum_out_dq_dk_dv": worst[label]}
@@ -205,12 +246,13 @@ def main(argv=None) -> int:
     payload = {"experiment": "attention", **jaxenv.device_info(),
                "timing": "median per-program device duration, "
                          f"{ITERS} calls, jax.profiler trace",
-               "shape": {"windows": args.windows, "tokens": T,
+               "shape": {"windows": args.windows, "tokens": 1024,
+                         "tokens_of": TOKENS,
                          "dtype": "bfloat16",
                          "layers": {kind: dict(zip(
                              ("heads", "window", "kv_heads", "score_width",
                               "value_width", "scale"), LAYERS[kind]))
-                             for kind in args.layers.split(",")}},
+                             for kind in kinds}},
                "rows": rows}
     with open(os.path.join(args.out, "attention.json"), "w") as fp:
         json.dump(payload, fp, indent=1)

@@ -340,6 +340,9 @@ PARENT_ROUNDS = {
     # read on fd5ebfc (PR 37) before the conv, the gated norm and the
     # step's law moved to models/lm.py for the second hybrid to share
     ("lm_tokens_tiny", "granite_h_tiny"): "ad3429641a9713fb",
+    # read on 19984dc (PR 39) before ops/attention.py learnt a sink and
+    # ops/moe.py's router a choice bias: the fifth cell's round
+    ("lm_tokens_tiny", "qwen3_next_tiny"): "d209b29b660e909d",
 }
 WALKED_IN_THREES = "83f7ac9576367014"  # the same, the peer axis in two blocks
 
@@ -646,7 +649,13 @@ PARENT_CORES = {
     "laguna_full": ((8, 6, 128, 1024, None), "9d053ecc4199a93b"),
     "laguna_sliding": ((8, 9, 128, 512, None), "74f9a694f33eac20"),
     "granite": ((8, 4, 64, 1024, 0.015625), "aab27e2608273730"),
+    # read on 19984dc (PR 39) before the kernel learnt a sink: Qwen3-Next's
+    # gated attention, eight query heads on a key/value head of 256 | 256
+    "qwen3_next": ((2, 8, 256, 1024, None), "cf976d71992edb8b"),
 }
+# the same of DeepSeek-V2's call WITH its shared key part (G = 1, k of 128
+# beside the one rotary key of 64, the scores times 0.1), read on 19984dc
+PARENT_SHARED_CORE = "7c7bddcd95a81ab8"
 
 
 def _lowered_core(kv, g, d, window, scale, t=1024):
@@ -681,6 +690,24 @@ def test_a_core_without_a_shared_key_lowers_as_the_parents(layer):
                                                               0.015625)
     shape, parent = PARENT_CORES[layer]
     assert _sha(_lowered_core(*shape)) == parent
+
+
+def test_a_core_with_a_shared_key_and_no_sink_lowers_as_the_parents():
+    """DeepSeek-V2's call passes a shared key part and no sink: the sink's
+    operand is not there, and the program is the parent's."""
+    from biscotti_tpu.ops import attention
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.bfloat16)
+
+    def loss(q, k, v, r):
+        out = attention.attention(q, k, v, 1024, 0.1, shared=r)
+        return jnp.sum(out * out)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        shape(1, 16, 1, 1024, 192), shape(1, 16, 1024, 128),
+        shape(1, 16, 1024, 128), shape(1, 1, 1024, 64)).as_text()
+    assert _sha(text) == PARENT_SHARED_CORE
 
 
 def test_experts_of_5120_by_1536_take_the_kernel_in_column_tiles(v5e):
@@ -1020,3 +1047,79 @@ def test_the_delta_rule_at_the_published_shapes_is_the_kernel(v5e):
             if chunk.search(line.split(" = ")[-1].split("(")[0])]
     assert not made, made[:5]
     assert " while(" not in hlo and "triangular" not in hlo
+
+
+# ------- MiMo-V2.5's share: a learned sink, 192 | 128 under grouped queries,
+# windows of 2,048 tokens (PR 40)
+
+
+@pytest.mark.parametrize("at,kind,kv", [(1, "window", 8), (5, "full", 4)])
+def test_the_sink_and_the_wide_groups_at_2048_tokens_take_the_kernel(
+        v5e, at, kind, kv):
+    """An attention block of the published MiMo-V2.5 share as a peer sends
+    it (1 window of 2,048 tokens, 64 query heads of 192 | 128 on 8 (window
+    of 128, a learned sink a head) or 4 (causal) key/value heads, bfloat16)
+    under `jax.checkpoint` and `jax.grad` compiles for the v5e under x64
+    with ops/attention.py's kernel as its core: a key/value head's 8 or 16
+    query heads do not fit the kernel's buffers beside 2,048 keys, so they
+    go a head at a time at blocks of 256 x 256 (`group_split`: of the
+    sub-groups that fit, the one whose block is fastest), each with its own
+    copy of its key/value head, the sinks reach the forward
+    kernel through SMEM, and NO float32 array of the scores' size is made
+    (all 64 heads' would be 1.07 GB). The full kind's block is
+    differentiated in its adapters alone: ALONE, with its input's cotangent
+    asked for too, the compiler fuses the transpose of the 13,568-column
+    product with the norm's backward into one fusion that wants 19.7 MB of
+    its 16 MB of scoped VMEM and gives up ("please file a bug against
+    XLA"); inside the whole round it fuses otherwise and compiles
+    (tests/test_v5_mimo_v2_lowering.py; PERF.md section 7)."""
+    from biscotti_tpu.models import mimo_v2
+    from biscotti_tpu.ops import attention
+
+    cfg = mimo_v2.PRESETS["mimo_v2_fedlora"]
+    assert cfg.kind(at)[0] == kind and cfg.kv_heads[cfg.pattern[at]] == kv
+    g = cfg.heads // kv
+    assert attention.blocks(g, 2048, 192, jnp.bfloat16, 128) is None
+    assert attention.group_split(g, 2048, 192, jnp.bfloat16, 128) == g
+    assert attention.blocks(1, 2048, 192, jnp.bfloat16, 128) == (256, 256)
+    one = SingleDeviceSharding(v5e[0])
+    model = mimo_v2.mimo_v2_model("lm", cfg, 2048)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one), tree)
+
+    frozen = on_chip(jax.eval_shape(
+        model.init_frozen, jax.random.PRNGKey(0))["layers"][at])
+    adapters = on_chip(jax.eval_shape(
+        lambda key: jax.tree.map(lambda b: b[None],
+                                 model.init(key)["layers"][at]),
+        jax.random.PRNGKey(0)))
+    assert ("sink" in frozen) == (kind == "window")
+
+    def loss(adapters, h, frozen):
+        out = jax.checkpoint(lambda h, f, a: mimo_v2._attention(
+            cfg, kind, h, f, a))(h, frozen, adapters)
+        return jnp.sum(out * out)
+
+    compiled = jax.jit(jax.grad(
+        loss, argnums=(0, 1) if kind == "window" else (0,))).lower(
+        adapters, jax.ShapeDtypeStruct((1, 1, 2048, cfg.hidden), jnp.float32,
+                                       sharding=one), frozen).compile()
+    hlo = compiled.as_text()
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert 2 <= len(calls) <= 3, len(calls)
+    assert any("f32[1,64,1,2048,128]" in c for c in calls)    # the result
+    assert any("bf16[1,64,1,2048,192]" in c for c in calls)   # q, dq
+    scope = "attn_core_swa" if kind == "window" else "attn_core_full"
+    assert all(scope in c for c in calls)
+    square = re.compile(r"f32\[([\d,]*2048,2048)\]")
+    made = [line.strip()[:160] for line in hlo.splitlines()
+            for dims in square.findall(line)
+            if math.prod(int(v) for v in dims.split(",")) > 2048 * 2048]
+    assert not made, made[:5]
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
+    assert not [line.strip()[:160] for line in hlo.splitlines()
+                if "f64[" in line or ("s64[" in line
+                                      and "parameter(" not in line)]
