@@ -192,7 +192,7 @@ class Simulator:
 
         # set-up and per-round host phases, also `biscotti:<name>` spans in
         # a profiler trace (sim.shards / sim.stack / sim.to_device /
-        # sim.build; sim.round.args / sim.round.dispatch)
+        # sim.build; sim.round.args / sim.round.dispatch / sim.round.stage)
         self.phases = PhaseClock()
 
         with self.phases.phase("sim.shards"):
@@ -244,16 +244,44 @@ class Simulator:
             self.y_attack = jnp.asarray(attack["y_test"])
             self.x = put_stack(x_host)
             self.y = put_stack(y_host)
+            # the seed stands on the device from here on: one array for
+            # every round and for run_scan (a Simulator is built for one
+            # seed; the seed stays an ARGUMENT of the program)
+            self.seed = self._place(np.int32(cfg.seed))
+
+        # what the round's host side knows from the last call, so that it
+        # builds, copies and checks nothing between a sync's return and the
+        # next dispatch: the arrays it returned (they are where the stack
+        # is: `_at_home`) and the round counter it staged behind them
+        self._returned = (None, None)
+        self._staged_it, self._staged = None, None
+        self._host = {"placed": 0, "rounds": 0, "staged": 0}
 
         def round_step(w, stake, it):
             with self.phases.phase("sim.round.args"):
-                seed = jnp.asarray(self.cfg.seed, jnp.int32)
-                w, stake = self._at_home(w, stake)
+                it = int(it)
+                if w is not self._returned[0] \
+                        or stake is not self._returned[1]:
+                    w, stake = self._at_home(w, stake)  # from outside
+                staged = it == self._staged_it
+                # ONE form of `it` for every call, a typed scalar where the
+                # seed is: a Python int on one call and an array on the
+                # next are two programs. Built here only for a first call,
+                # a replayed round or a jump
+                at = self._staged if staged else self._place(np.int32(it))
             with self.phases.phase("sim.round.dispatch"):
                 *out, self.last_counts = self._round_step_jit(
-                    w, stake, it, seed, self.x, self.y, self.x_val,
+                    w, stake, at, self.seed, self.x, self.y, self.x_val,
                     self.y_val, self.frozen)
-                return tuple(out)
+            with self.phases.phase("sim.round.stage"):
+                # the next round's counter, handed over behind the running
+                # round: a host copy (no program), while the device works
+                self._returned = (out[0], out[1])
+                self._staged_it = it + 1
+                self._staged = self._place(np.int32(it + 1))
+                self._host["rounds"] += 1
+                self._host["staged"] += staged
+            return tuple(out)
 
         self.round_step = round_step
 
@@ -393,8 +421,8 @@ class Simulator:
         data arguments with their own formats (`jit` compiles for the layout
         an argument has, so a lowering from bare shapes would be another
         program, with other instruction names), with
-        `it` as the weakly typed Python int that run() passes, and compiled
-        OUTSIDE the persistent compile cache: its key ignores scope
+        `it` as the int32 scalar the `round_step` closure hands over, and
+        compiled OUTSIDE the persistent compile cache: its key ignores scope
         metadata (`jax_compilation_cache_include_metadata_in_key` is off),
         so a cache filled by an older tree would hand back that tree's
         executable, and its text that tree's names. For the same reason it
@@ -410,20 +438,25 @@ class Simulator:
         def round_step(*args):  # the name the program and its scopes carry
             return self._round_step_raw(*args)
 
+        lowered = jax.jit(round_step, donate_argnums=(0, 1)).lower(
+            *self.round_arg_shapes())
+        with outside_compile_cache():
+            self._round_hlo_text = lowered.compile().as_text()
+        return self._round_hlo_text
+
+    def round_arg_shapes(self):
+        """The arguments of the round program as `round_step` hands them
+        over, as shapes: what `round_hlo()` lowers. `it` and the seed are
+        int32 scalars, never weakly typed; the data arguments carry their
+        own formats."""
         w = jax.ShapeDtypeStruct((self.num_params,), jnp.float32)
         stake = jax.ShapeDtypeStruct((self.cfg.num_nodes,), jnp.int32)
-        it = jax.ShapeDtypeStruct((), jax.dtypes.canonicalize_dtype(int),
-                                  weak_type=True)
-        seed = jax.ShapeDtypeStruct((), jnp.int32)
+        it = seed = jax.ShapeDtypeStruct((), jnp.int32)
         data = jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
                                            sharding=a.format),
             (self.x, self.y, self.x_val, self.y_val, self.frozen))
-        lowered = jax.jit(round_step, donate_argnums=(0, 1)).lower(
-            w, stake, it, seed, *data)
-        with outside_compile_cache():
-            self._round_hlo_text = lowered.compile().as_text()
-        return self._round_hlo_text
+        return (w, stake, it, seed, *data)
 
     # ------------------------------------------------- the gather's witness
 
@@ -461,11 +494,36 @@ class Simulator:
         every mix of committed and free arguments: fresh weights into a
         round whose stake came out of the last one would compile the round
         a second time, and the first round on its own results a third. An
-        array that is already there is handed back as it is."""
+        array that is already there is handed back as it is. For what
+        enters from outside (fresh weights, `init_state`, a caller's own
+        arrays): `round_step` does not ask again about the arrays it
+        returned itself."""
         if not self.x.committed:
             return arrays  # nothing is committed: nothing to match
-        return tuple(a if getattr(a, "committed", False)
-                     else jax.device_put(a, self.x.sharding) for a in arrays)
+        home = tuple(a if getattr(a, "committed", False) else self._place(a)
+                     for a in arrays)
+        self._host["placed"] += sum(a is not b for a, b in zip(arrays, home))
+        return home
+
+    def _place(self, value):
+        """The host `value` on the device, committed where the stack is: a
+        copy, never a program (a program would queue behind the stack's
+        relayout: `init_state`)."""
+        if self.x.committed:
+            return jax.device_put(value, self.x.sharding)
+        return jax.device_put(value)
+
+    def round_host_stats(self) -> dict:
+        """What the round's host side did since construction:
+        `args_placed_total`, arrays `_at_home` had to move (2 after
+        `init_state` where the stack is committed, then flat through a
+        closed loop); `round_counter_staged_share`, rounds that took the
+        `it` staged behind the round before over rounds run (towards 1 in
+        a closed loop, 0 for a caller that replays one round)."""
+        host = self._host
+        return {"args_placed_total": host["placed"],
+                "round_counter_staged_share":
+                    host["staged"] / max(host["rounds"], 1)}
 
     def init_state(self):
         # host values, handed over as copies: a program (`jnp.zeros`) would
@@ -568,6 +626,17 @@ class Simulator:
                     time.perf_counter() - t0)
                 m.gauge("biscotti_sim_round_height",
                         "simulator rounds completed").set(it + 1)
+                host = self.round_host_stats()
+                m.gauge("biscotti_sim_args_placed_total",
+                        "arrays the round's host side had to move to the "
+                        "stack's device since construction (2 after "
+                        "init_state, then flat through a closed loop)").set(
+                    host["args_placed_total"])
+                m.gauge("biscotti_sim_round_counter_staged_share",
+                        "rounds that took the round counter staged on the "
+                        "device behind the round before, over rounds run "
+                        "(towards 1 in a closed loop)").set(
+                    host["round_counter_staged_share"])
                 moe = self.dispatch_stats()
                 if moe:
                     m.gauge("biscotti_moe_assignments_held",
@@ -652,10 +721,10 @@ class Simulator:
             self._scan_cache = getattr(self, "_scan_cache", {})
             self._scan_cache[num_rounds] = full
 
-        s = self.cfg.seed if seed is None else seed
+        seed = self.seed if seed is None else self._place(np.int32(seed))
         (w, stake), (errs, accepted) = full(
-            w, stake, jnp.asarray(s, jnp.int32), self.x, self.y,
-            self.x_val, self.y_val, self.frozen)
+            w, stake, seed, self.x, self.y, self.x_val, self.y_val,
+            self.frozen)
         return w, stake, np.asarray(errs), np.asarray(accepted)
 
     # ------------------------------------------------------------------ metrics
@@ -664,8 +733,7 @@ class Simulator:
         """The [S, d] noised deltas round `it` hands the verifier
         committee at weights `w` — the defence's actual input, for
         checking a scoring kernel against an oracle on it."""
-        return self._noised_jit(*self._at_home(w), it,
-                                jnp.asarray(self.cfg.seed, jnp.int32),
+        return self._noised_jit(*self._at_home(w), it, self.seed,
                                 self.x, self.y, self.frozen)[2]
 
     def dispatch_stats(self, counts=None) -> dict:
