@@ -364,7 +364,9 @@ def deepseek_v2_model(name: str, cfg: DeepSeekV2Config, length: int):
         per_head = 2 * (cfg.nope + cfg.rope) + cfg.nope + cfg.v_dim
         return 2 * 4 * t * (cfg.heads * per_head + cfg.vocab)
 
+    plan = attention_plan(cfg, length)
     return lm.lm_model(name, cfg, length,
                        (frozen_shapes, {"layers": trained_shapes}),
                        hidden_states, step_bytes,
-                       {"attention": attention_plan(cfg, length)})
+                       {"attention": plan,
+                        "gauges": lm.attention_gauges(plan)})

@@ -302,8 +302,14 @@ def granite_hybrid_model(name: str, cfg: GraniteHybridConfig, length: int):
                         + 4 * cfg.ssm_heads * min(cfg.chunk, length)
                         + 6 * wide)
 
+    plan = attention_plan(cfg, length)
     return lm.lm_model(name, cfg, length,
                        (frozen_shapes, {"layers": trained_shapes}),
                        hidden_states, step_bytes,
-                       {"attention": attention_plan(cfg, length),
-                        "ssm_chunks": chunks})
+                       {"attention": plan,
+                        "ssm_chunks": chunks,
+                        "gauges": lm.attention_gauges(plan) + [
+                            ("biscotti_ssm_chunks",
+                             "chunks a window's state-space scan is walked "
+                             "in (ops/ssm.py; static: the window over the "
+                             "model's chunk size)", chunks, {})]})

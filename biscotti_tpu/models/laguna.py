@@ -323,7 +323,9 @@ def laguna_model(name: str, cfg: LagunaConfig, length: int):
                 + t * cfg.top_k * cfg.hidden * (4 + dtype.itemsize)
                 + 2 * 4 * t * cfg.vocab + 12 * 4 * t * cfg.hidden)
 
+    plan = attention_plan(cfg, length)
     return lm.lm_model(name, cfg, length,
                        (frozen_shapes, {"layers": trained_shapes}),
                        hidden_states, step_bytes,
-                       {"attention": attention_plan(cfg, length)})
+                       {"attention": plan,
+                        "gauges": lm.attention_gauges(plan)})

@@ -311,3 +311,45 @@ def frozen_count(model) -> int:
     """Parameters in the model's frozen tree, from shapes alone."""
     tree = jax.eval_shape(model.init_frozen, jax.random.PRNGKey(0))
     return sum(math.prod(leaf.shape) for leaf in jax.tree.leaves(tree))
+
+
+def attention_gauges(plan: dict):
+    """The rows of a model's `attention_plan` among its static gauges: what
+    every plan states, `shared_key` (0 where it states none) and, where it
+    tells its `kinds` of core apart, a row a kind.
+
+    A model declares its static gauges as `info["gauges"]`, rows (name,
+    help, value, labels): these beside its own, written where they are
+    computed (the model's builder). A value is a number, or a function of
+    (the run's starting adapters, the held-out windows, the frozen tree). A
+    simulator with a registry publishes the rows before its first round
+    and reads them no other way."""
+    rows = [
+        ("biscotti_lm_attention_fused",
+         "1 where the round's attention cores are ops/attention.py's "
+         "kernel, 0 the einsum form that writes the scores to HBM",
+         plan["fused"], {}),
+        ("biscotti_lm_attention_block_share",
+         "(query block, key block) pairs of the scores the attention "
+         "visits over all pairs, all layers (the einsum form: 1)",
+         plan["block_share"], {}),
+        ("biscotti_lm_attention_shared_key",
+         "1 where the attention core receives a key part once for all "
+         "heads beside each head's own (DeepSeek-V2's one rotary key), 0 "
+         "where every key is its head's own", plan.get("shared_key", 0), {})]
+    for kind, of in plan.get("kinds", {}).items():
+        rows += [
+            ("biscotti_attn_block_share",
+             "(query block, key block) pairs of the scores the attention "
+             "core of a KIND of layer visits over all pairs "
+             "(models/mimo_v2.py: window | full; the einsum form: 1)",
+             of["block_share"], {"kind": kind}),
+            ("biscotti_attn_seen_share",
+             "scores the mask lets through over the scores of the pairs of "
+             "blocks that kind's core visits", of["seen_share"],
+             {"kind": kind}),
+            ("biscotti_attn_group",
+             "query heads a call of that kind's core holds together: a "
+             "key/value head's, or a sub-group of them "
+             "(ops/attention.group_split)", of["group"], {"kind": kind})]
+    return rows

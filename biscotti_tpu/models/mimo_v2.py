@@ -390,9 +390,18 @@ def mimo_v2_model(name: str, cfg: MiMoV2Config, length: int):
                 + t * 2 * wide * dtype.itemsize
                 + t * cfg.top_k * cfg.hidden * (4 + dtype.itemsize))
 
-    return lm.lm_model(name, cfg, length,
+    mimo = lm.lm_model(name, cfg, length,
                        (frozen_shapes, {"layers": trained_shapes}),
                        hidden_states, step_bytes,
                        {"attention": attention_plan(cfg, length),
                         "sink_mass": jax.jit(functools.partial(sink_mass,
                                                                cfg))})
+    # the sink's row takes the jitted function `info` holds as its value,
+    # so the rows are set once that stands
+    mimo.info["gauges"] = lm.attention_gauges(mimo.info["attention"]) + [
+        ("biscotti_attn_sink_mass",
+         "mean probability a query of the held-out windows gives its head's "
+         "learned sink under the run's starting weights, window layers "
+         "(what of a row's softmax reaches no value)",
+         mimo.info["sink_mass"], {})]
+    return mimo

@@ -425,11 +425,23 @@ def qwen3_next_model(name: str, cfg: Qwen3NextConfig, length: int):
                          + 2 * cfg.top_k * cfg.hidden)
                     + batch * states)
 
+    plan = attention_plan(cfg, length)
+    rule = delta_rule.plan(cfg.key_heads, length, cfg.key_dim,
+                           cfg.value_dim, cfg.chunk, cfg.dtype)
     return lm.lm_model(name, cfg, length,
                        (frozen_shapes, {"layers": trained_shapes}),
                        hidden_states, step_bytes,
-                       {"attention": attention_plan(cfg, length),
+                       {"attention": plan,
                         "gdn_chunks": chunks,
-                        "gdn_rule": delta_rule.plan(
-                            cfg.key_heads, length, cfg.key_dim,
-                            cfg.value_dim, cfg.chunk, cfg.dtype)})
+                        "gdn_rule": rule,
+                        "gauges": lm.attention_gauges(plan) + [
+                            ("biscotti_gdn_chunks",
+                             "chunks a window's gated delta rule is walked "
+                             "in (ops/delta_rule.py; static: the window "
+                             "over the model's chunk size)", chunks, {}),
+                            ("biscotti_gdn_rule_kernel",
+                             "1 where the round's gated delta rule is "
+                             "ops/delta_rule.py's fused kernel (a chunk's "
+                             "system, its solve and the carried state in "
+                             "the chip's own memory), 0 the jax.numpy form",
+                             rule["kernel"], {})]})

@@ -281,3 +281,90 @@ def held_experts(x: jax.Array, experts: jax.Array, coef: jax.Array,
               "buffer_rows": jnp.asarray(cut, jnp.int32),
               "uncut": (rows > cut).astype(jnp.int32)}
     return out, counts
+
+
+# {key of `dispatch_stats`: (the gauge a simulator with a registry sets to
+# it after a round, its help)}: a new count is published from here
+GAUGES = {
+    "assignments_held": (
+        "biscotti_moe_assignments_held",
+        "token-expert assignments of the last round that landed on experts "
+        "held here"),
+    "load_max_over_mean": (
+        "biscotti_moe_load_max_over_mean",
+        "fullest held expert's assignments over the held experts' mean, "
+        "worst sparse layer"),
+    "tokens_dropped": (
+        "biscotti_moe_tokens_dropped",
+        "held assignments of the last round that reached no expert (must "
+        "read 0)"),
+    "tile_fill": (
+        "biscotti_moe_tile_fill",
+        "held rows of the last round's grouped products over the rows of "
+        "the (group, row tile) pairs they visited"),
+    "grouped_kernel": (
+        "biscotti_moe_grouped_kernel",
+        "1 where the round's grouped products are ops/grouped_matmul.py's "
+        "kernel, 0 the compiler's ragged_dot"),
+    "uncut_calls": (
+        "biscotti_moe_uncut_calls",
+        "calls of the last round's expert layers that ran on the uncut "
+        "sorted buffer; 0 unless a block's held rows pass CAPACITY x the "
+        "uniform router's"),
+    "buffer_rows": (
+        "biscotti_moe_buffer_rows",
+        "rows of the sorted buffer a call of an expert layer runs on (the "
+        "cut one: CAPACITY x the uniform router's, from the shapes)"),
+    "groups_kept": (
+        "biscotti_moe_groups_kept",
+        "groups of experts a token's chosen experts lie in, mean over the "
+        "last round's tokens and sparse layers (a group-limited router "
+        "keeps at most its topk_group)"),
+}
+
+
+def dispatch_stats(counts: dict, blocks: float) -> dict:
+    """What a round's expert dispatch counted, `{}` where it counted
+    nothing: `assignments_held`, token-expert assignments that landed on
+    experts held here, all sparse layers; `load_max_over_mean`, the fullest
+    held expert's over the held experts' mean, worst sparse layer;
+    `tokens_dropped`, held assignments that reached no expert (must read
+    0); `tile_fill`, held rows over the rows of the (group, row tile) pairs
+    the grouped products visited, all calls; `grouped_kernel`, 1.0 where
+    those products are ops/grouped_matmul.py's; `uncut_calls`, the (block,
+    sparse layer) calls that ran on the uncut sorted buffer (0 unless a
+    block's held rows pass `CAPACITY` x the uniform router's);
+    `buffer_rows`, the cut buffer's rows a call; and, where the router
+    limits a token to some groups of experts, `groups_kept`, the groups a
+    token's chosen experts lie in, mean over tokens and sparse layers.
+
+    `counts`: `held_experts`' of every sparse layer, `load` int32[layers,
+    held experts] and the rest a number a layer (and `groups_spanned`,
+    `tokens` where the model's router counts them), summed over the
+    `blocks` peer blocks a round walked. Reads them back to the host."""
+    # not at the top: the lines of what a round traces stay where they are,
+    # a Pallas call's cache key holds them (ROADMAP C10)
+    import numpy as np
+
+    if "load" not in counts:
+        return {}
+    load = np.asarray(counts["load"], np.float64)
+    grouped = {} if "groups_spanned" not in counts else {
+        "groups_kept": float(
+            np.asarray(counts["groups_spanned"], np.float64).sum()
+            / max(np.asarray(counts["tokens"], np.float64).sum(), 1.0))}
+    return {
+        **grouped,
+        "assignments_held": float(load.sum()),
+        "load_max_over_mean": float(np.max(load.max(axis=1)
+                                           / load.mean(axis=1))),
+        "tokens_dropped": float(np.asarray(counts["dropped"]).sum()),
+        "tile_fill": float(load.sum() / max(
+            np.asarray(counts["tile_rows"], np.float64).sum(), 1.0)),
+        "grouped_kernel": float(np.asarray(
+            counts["grouped_kernel"]).any()),
+        "uncut_calls": float(np.asarray(counts["uncut"]).sum()),
+        "buffer_rows": float(
+            np.asarray(counts["buffer_rows"], np.float64).sum()
+            / (load.shape[0] * blocks)),
+    }

@@ -539,6 +539,11 @@ class Simulator:
         """Python round loop over the jitted step; returns (w, stake, logs).
         Log rows mirror the reference's parsed node-0 output so eval tooling
         is directly comparable (BASELINE.md)."""
+        # imported here and in `dispatch_stats`, not at the top: what
+        # stands above `run` is held byte for byte, a Pallas call's cache
+        # key holds the line numbers of what traces it (ROADMAP C10)
+        from biscotti_tpu.ops import moe
+
         if num_rounds is None:
             num_rounds = self.cfg.max_iterations
         w, stake = self.init_state()
@@ -558,64 +563,14 @@ class Simulator:
                     "sampled peers whose local steps the round computes "
                     "together (num_samples: all of them at once)").set(
                 self.peer_block)
-            plan = self.model.info.get("attention")
-            if plan:
-                m.gauge("biscotti_lm_attention_fused",
-                        "1 where the round's attention cores are "
-                        "ops/attention.py's kernel, 0 the einsum form "
-                        "that writes the scores to HBM").set(plan["fused"])
-                m.gauge("biscotti_lm_attention_block_share",
-                        "(query block, key block) pairs of the scores the "
-                        "attention visits over all pairs, all layers "
-                        "(the einsum form: 1)").set(plan["block_share"])
-                m.gauge("biscotti_lm_attention_shared_key",
-                        "1 where the attention core receives a key part "
-                        "once for all heads beside each head's own "
-                        "(DeepSeek-V2's one rotary key), 0 where every "
-                        "key is its head's own").set(
-                    plan.get("shared_key", 0))
-                for kind, of in plan.get("kinds", {}).items():
-                    m.gauge("biscotti_attn_block_share",
-                            "(query block, key block) pairs of the scores "
-                            "the attention core of a KIND of layer visits "
-                            "over all pairs (models/mimo_v2.py: window | "
-                            "full; the einsum form: 1)").set(
-                        of["block_share"], kind=kind)
-                    m.gauge("biscotti_attn_seen_share",
-                            "scores the mask lets through over the scores "
-                            "of the pairs of blocks that kind's core "
-                            "visits").set(of["seen_share"], kind=kind)
-                    m.gauge("biscotti_attn_group",
-                            "query heads a call of that kind's core holds "
-                            "together: a key/value head's, or a sub-group "
-                            "of them (ops/attention.group_split)").set(
-                        of["group"], kind=kind)
-            if "sink_mass" in self.model.info:
-                m.gauge("biscotti_attn_sink_mass",
-                        "mean probability a query of the held-out windows "
-                        "gives its head's learned sink under the run's "
-                        "starting weights, window layers (what of a row's "
-                        "softmax reaches no value)").set(float(
-                            self.model.info["sink_mass"](
-                                self.model.unravel(w), self.x_val,
-                                self.frozen)))
-            if "ssm_chunks" in self.model.info:
-                m.gauge("biscotti_ssm_chunks",
-                        "chunks a window's state-space scan is walked in "
-                        "(ops/ssm.py; static: the window over the model's "
-                        "chunk size)").set(self.model.info["ssm_chunks"])
-            if "gdn_chunks" in self.model.info:
-                m.gauge("biscotti_gdn_chunks",
-                        "chunks a window's gated delta rule is walked in "
-                        "(ops/delta_rule.py; static: the window over the "
-                        "model's chunk size)").set(
-                    self.model.info["gdn_chunks"])
-                m.gauge("biscotti_gdn_rule_kernel",
-                        "1 where the round's gated delta rule is "
-                        "ops/delta_rule.py's fused kernel (a chunk's "
-                        "system, its solve and the carried state in the "
-                        "chip's own memory), 0 the jax.numpy form").set(
-                    self.model.info["gdn_rule"]["kernel"])
+            # what the model declares (models/lm.py); a value is a number,
+            # or a function of the run's start that is called once, here
+            for name, text, value, labels in self.model.info.get("gauges",
+                                                                 ()):
+                if callable(value):
+                    value = float(value(self.model.unravel(w), self.x_val,
+                                        self.frozen))
+                m.gauge(name, text).set(value, **labels)
         for it in range(num_rounds):
             t0 = time.perf_counter()
             w, stake, mask, err = self.round_step(w, stake, it)
@@ -637,47 +592,8 @@ class Simulator:
                         "device behind the round before, over rounds run "
                         "(towards 1 in a closed loop)").set(
                     host["round_counter_staged_share"])
-                moe = self.dispatch_stats()
-                if moe:
-                    m.gauge("biscotti_moe_assignments_held",
-                            "token-expert assignments of the last round "
-                            "that landed on experts held here").set(
-                        moe["assignments_held"])
-                    m.gauge("biscotti_moe_load_max_over_mean",
-                            "fullest held expert's assignments over the "
-                            "held experts' mean, worst sparse layer").set(
-                        moe["load_max_over_mean"])
-                    m.gauge("biscotti_moe_tokens_dropped",
-                            "held assignments of the last round that "
-                            "reached no expert (must read 0)").set(
-                        moe["tokens_dropped"])
-                    m.gauge("biscotti_moe_tile_fill",
-                            "held rows of the last round's grouped "
-                            "products over the rows of the (group, row "
-                            "tile) pairs they visited").set(
-                        moe["tile_fill"])
-                    m.gauge("biscotti_moe_grouped_kernel",
-                            "1 where the round's grouped products are "
-                            "ops/grouped_matmul.py's kernel, 0 the "
-                            "compiler's ragged_dot").set(
-                        moe["grouped_kernel"])
-                    m.gauge("biscotti_moe_uncut_calls",
-                            "calls of the last round's expert layers that "
-                            "ran on the uncut sorted buffer; 0 unless a "
-                            "block's held rows pass CAPACITY x the uniform "
-                            "router's").set(moe["uncut_calls"])
-                    m.gauge("biscotti_moe_buffer_rows",
-                            "rows of the sorted buffer a call of an expert "
-                            "layer runs on (the cut one: CAPACITY x the "
-                            "uniform router's, from the shapes)").set(
-                        moe["buffer_rows"])
-                    if "groups_kept" in moe:
-                        m.gauge("biscotti_moe_groups_kept",
-                                "groups of experts a token's chosen "
-                                "experts lie in, mean over the last "
-                                "round's tokens and sparse layers (a "
-                                "group-limited router keeps at most its "
-                                "topk_group)").set(moe["groups_kept"])
+                for key, value in self.dispatch_stats().items():
+                    m.gauge(*moe.GAUGES[key]).set(value)
             if it % log_every == 0 or it == num_rounds - 1:
                 e = float(err)
                 logs.append(RoundLog(it, e, time.time(), int(mask.sum())))
@@ -737,48 +653,15 @@ class Simulator:
                                 self.x, self.y, self.frozen)[2]
 
     def dispatch_stats(self, counts=None) -> dict:
-        """What a round's expert dispatch counted (`counts`: the last
-        round's), `{}` for a model that has none: `assignments_held`,
-        token-expert assignments that landed on experts held here, all
-        sparse layers; `load_max_over_mean`, the fullest held expert's over
-        the held experts' mean, worst sparse layer; `tokens_dropped`, held
-        assignments that reached no expert (must read 0); `tile_fill`, held
-        rows over the rows of the (group, row tile) pairs the grouped
-        products visited, all calls; `grouped_kernel`, 1.0 where those
-        products are ops/grouped_matmul.py's; `uncut_calls`, the (block,
-        sparse layer) calls that ran on the uncut sorted buffer (0 unless a
-        block's held rows pass `moe.CAPACITY` x the uniform router's);
-        `buffer_rows`, the cut buffer's rows a call; and, where the router
-        limits a token to some groups of experts (models/deepseek_v2.py),
-        `groups_kept`, the groups a token's chosen experts lie in, mean over
-        tokens and sparse layers. From `load` int32[layers, held experts],
-        `dropped`, `tile_rows`, `grouped_kernel`, `uncut` and `buffer_rows`
-        (and `groups_spanned`, `tokens`), which the round returns summed
-        over its peer blocks; reads them back: call it outside a timed
-        round."""
-        counts = self.last_counts if counts is None else counts
-        if "load" not in counts:
-            return {}
-        load = np.asarray(counts["load"], np.float64)
-        grouped = {} if "groups_spanned" not in counts else {
-            "groups_kept": float(
-                np.asarray(counts["groups_spanned"], np.float64).sum()
-                / max(np.asarray(counts["tokens"], np.float64).sum(), 1.0))}
-        return {
-            **grouped,
-            "assignments_held": float(load.sum()),
-            "load_max_over_mean": float(np.max(load.max(axis=1)
-                                               / load.mean(axis=1))),
-            "tokens_dropped": float(np.asarray(counts["dropped"]).sum()),
-            "tile_fill": float(load.sum() / max(
-                np.asarray(counts["tile_rows"], np.float64).sum(), 1.0)),
-            "grouped_kernel": float(np.asarray(
-                counts["grouped_kernel"]).any()),
-            "uncut_calls": float(np.asarray(counts["uncut"]).sum()),
-            "buffer_rows": float(
-                np.asarray(counts["buffer_rows"], np.float64).sum()
-                / (load.shape[0] * self.cfg.num_samples / self.peer_block)),
-        }
+        """`ops/moe.dispatch_stats` of what a round's expert dispatch
+        counted (`counts`: the last round's, which the round returns summed
+        over its peer blocks), `{}` for a model that has none; reads them
+        back: call it outside a timed round."""
+        from biscotti_tpu.ops import moe
+
+        return moe.dispatch_stats(
+            self.last_counts if counts is None else counts,
+            self.cfg.num_samples / self.peer_block)
 
     def test_error(self, w) -> float:
         return float(self.model.error_flat(jnp.asarray(w), self.x_val,

@@ -12,16 +12,18 @@ import numpy as np
 import pytest
 
 from benchmark.reference import laguna as ref
-from biscotti_tpu.config import BiscottiConfig, Defense
 from biscotti_tpu.data import datasets as ds
 from biscotti_tpu.models import laguna
-from biscotti_tpu.models.trainer import (Trainer, block_step_fn,
-                                         local_step_fn)
+from biscotti_tpu.models.trainer import block_step_fn, local_step_fn
 from biscotti_tpu.models.zoo import MODELS, model_for_dataset
 from biscotti_tpu.ops import moe
 from biscotti_tpu.parallel.sim import Simulator
 
-DATASET = "lm_tokens_tiny"
+from lm_family import (  # noqa: F401  (collected, run and counted here)
+    DATASET, Family, a_block_of_peers_is_each_peer_alone, cfg_of, family,
+    the_round_trains_the_adapters_and_reports, tiny,
+    test_trainer_step_is_the_simulators_for_the_same_batch)
+
 TINY = laguna.PRESETS["laguna_tiny"]
 
 
@@ -43,13 +45,15 @@ def published(cfg):
         "lora_rank": cfg.rank, "lora_alpha": cfg.alpha}
 
 
-@pytest.fixture(scope="module")
-def tiny():
-    model = model_for_dataset(DATASET)
-    frozen = model.frozen(jax.random.PRNGKey(1))
-    w = model.flat_init(jax.random.PRNGKey(2))
-    shard = ds.load_shard(DATASET, f"{DATASET}0")
-    return model, frozen, w, shard["x_train"], shard["y_train"]
+# the family's round cases (tests/lm_family.py) over this model's record
+FAMILY = Family(
+    module=laguna, ref=ref, name="laguna_tiny", published=published,
+    num_params=608, load=(2, 4),
+    clip=0.05,  # the clip bound under a gradient of norm ~0.5
+    gauges=("biscotti_lm_attention_shared_key 0",  # each head's own
+            "biscotti_moe_assignments_held",
+            "biscotti_moe_load_max_over_mean",
+            "biscotti_moe_tokens_dropped 0"))
 
 
 def _ref64(cfg):
@@ -124,24 +128,9 @@ def _wide_heads(t=128):
 
 @pytest.mark.parametrize("side", ["einsum", "kernel"])
 def test_a_block_of_peers_is_each_peer_alone(tiny, side):
-    """One dispatch over the block's tokens, the per-peer part confined to
-    the adapters: every row of the block's deltas is that peer's own step
-    (on the kernel's side too: its grid walks the windows)."""
-    model, frozen, w, x, y = tiny if side == "einsum" else _wide_heads()[1:]
-    block = jax.jit(block_step_fn(model, "clipped_sgd", 0.05, 0.1))
-    one = local_step_fn(model, "clipped_sgd", 0.05, 0.1)
-    xb = jnp.asarray(x[:6]).reshape(3, 2, -1)
-    yb = jnp.asarray(y[:6]).reshape(3, 2, -1)
-    deltas, counts = block(w, xb, yb, frozen)
-    assert deltas.shape == (3, model.num_params)
-    for peer in range(3):
-        np.testing.assert_allclose(deltas[peer],
-                                   one(w, xb[peer], yb[peer], frozen),
-                                   atol=1e-7)
-    # the clip bound: C = 0.05 under a gradient of norm ~0.5
-    np.testing.assert_allclose(jnp.linalg.norm(deltas, axis=1), 0.1 * 0.05,
-                               rtol=1e-4)
-    assert counts["load"].shape == (2, 4) and int(counts["dropped"].sum()) == 0
+    """On the kernel's side too: its grid walks the windows."""
+    a_block_of_peers_is_each_peer_alone(
+        FAMILY, tiny if side == "einsum" else _wide_heads()[1:])
 
 
 @pytest.mark.parametrize("name", ["laguna_tiny", "deepseek_v2_tiny"])
@@ -468,14 +457,6 @@ def test_int32_float32_and_the_base_dtype_throughout_with_x64_on(dtype):
 # ------------------------------------------------- the system's own path
 
 
-def _cfg(**kw):
-    base = dict(dataset=DATASET, num_nodes=6, batch_size=8, epsilon=1.0,
-                noising=True, verification=True, defense=Defense.KRUM,
-                sample_percent=1.0, num_verifiers=1, num_miners=1,
-                num_noisers=1, learning_rate=0.1, grad_clip=0.05, seed=9)
-    return BiscottiConfig(**{**base, **kw})
-
-
 def test_the_step_rule_is_declared_and_the_zoo_registers_the_model():
     assert set(laguna.PRESETS) <= set(MODELS)
     model = model_for_dataset(DATASET)
@@ -487,51 +468,13 @@ def test_the_step_rule_is_declared_and_the_zoo_registers_the_model():
         model_for_dataset(DATASET, "laguna_s_fedlora")
 
 
-def test_trainer_step_is_the_simulators_for_the_same_batch():
-    """A batch of all 8 windows of a shard: whatever order each side draws
-    them in, the mean loss is the same, so peer 3's delta from its own
-    Trainer is the row the round computes for it."""
-    cfg = _cfg()
-    sim = Simulator(cfg)
-    assert sim.mode == "clipped_sgd" and sim.rows == 8
-    w = sim.model.flat_init(jax.random.PRNGKey(4))
-    cidx, deltas, noised = sim._noised_jit(
-        w, 0, jnp.asarray(cfg.seed, jnp.int32), sim.x, sim.y, sim.frozen)
-    trainer = Trainer(DATASET, f"{DATASET}3", cfg=cfg)
-    assert trainer.mode == "clipped_sgd"
-    mine = trainer.private_fun(np.asarray(w), 0)
-    row = int(np.nonzero(np.asarray(cidx) == 3)[0][0])
-    np.testing.assert_allclose(mine, deltas[row], atol=1e-7)
-    # the noise is scaled by the same eta as the step
-    spread = float(jnp.std(noised - deltas))
-    sigma = np.sqrt(2 * np.log(1.25 / cfg.delta)) / cfg.epsilon
-    np.testing.assert_allclose(spread, 0.1 * sigma / np.sqrt(8), rtol=0.1)
-    np.testing.assert_allclose(np.std(trainer.get_noise(0)), spread, rtol=0.2)
-    assert trainer.test_error(np.asarray(w)) == pytest.approx(
-        sim.test_error(w))
-
-
 def test_the_round_trains_the_adapters_and_reports_its_routing():
-    from biscotti_tpu.telemetry import MetricsRegistry
-
-    registry = MetricsRegistry()
-    sim = Simulator(_cfg(batch_size=2), metrics=registry)
-    w, stake, logs = sim.run(num_rounds=2, stop_at_convergence=False)
-    assert w.shape == (608,) and np.isfinite(w).all() and np.asarray(w).any()
-    assert logs[-1].accepted == 4 - 4 // 2
-    page = registry.render()
-    for name in ("biscotti_sim_frozen_bytes", "biscotti_sim_peer_block",
-                 "biscotti_lm_attention_shared_key 0",  # each head's own
-                 "biscotti_moe_assignments_held",
-                 "biscotti_moe_load_max_over_mean",
-                 "biscotti_moe_tokens_dropped 0"):
-        assert name in page, name
-    stats = sim.dispatch_stats()
-    assert stats["load_max_over_mean"] >= 1.0
+    sim = the_round_trains_the_adapters_and_reports(FAMILY)
     # 4 peers x 2 windows x 16 tokens x 3 a token x 2 sparse layers, of
     # which about a quarter lands on the 4 of 16 experts held
-    assert 0 < stats["assignments_held"] < 768
-    assert Simulator(_cfg(dataset="mnist", num_nodes=4)).dispatch_stats() == {}
+    assert sim.dispatch_stats()["assignments_held"] < 768
+    assert Simulator(cfg_of("", dataset="mnist",
+                            num_nodes=4)).dispatch_stats() == {}
 
 
 @pytest.mark.parametrize("name", [f"{DATASET}2", f"{DATASET}_bad2",

@@ -10,7 +10,13 @@ string constants resolved across the package — the `WIRE_BYTES_METRIC`
 pattern); label keys come from the keyword arguments of the
 `.inc/.set/.observe(...)` call sites reached from each family, both
 chained (`reg.counter(N).inc(k=v)`) and through a local variable
-(`g = reg.gauge(N); g.set(v, k=v)`)."""
+(`g = reg.gauge(N); g.set(v, k=v)`). A family may also be DECLARED where
+its value is computed and set by a loop that names none (PR 46): a tuple
+literal that opens with the family's name, `(name, help)` in
+ops/moe.py's `GAUGES` and `(name, help, value, {label: ...})` in the rows
+a language model declares as `info["gauges"]` (models/lm.py:
+`attention_gauges`, and each model's builder); the label keys are the dict
+literal's."""
 
 import ast
 import pathlib
@@ -120,6 +126,15 @@ def emitted_families():
             if name is None:
                 continue
             families.setdefault(name, set()).update(labels_of(node))
+        # declared rows: `(name, help[, value, {label: ...}])`
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Tuple) and len(node.elts) >= 2:
+                name = _resolve_name(node.elts[0], {})
+                if name:
+                    labels = node.elts[3] if len(node.elts) > 3 else None
+                    families.setdefault(name, set()).update(
+                        key.value for key in getattr(labels, "keys", [])
+                        if isinstance(key, ast.Constant))
         # families created but updated elsewhere (or passed around)
         # still count as emitted by name
         for node in ast.walk(tree):

@@ -343,6 +343,9 @@ PARENT_ROUNDS = {
     # read on 19984dc (PR 39) before ops/attention.py learnt a sink and
     # ops/moe.py's router a choice bias: the fifth cell's round
     ("lm_tokens_tiny", "qwen3_next_tiny"): "d209b29b660e909d",
+    # read on 63bc454 (PR 44's tree) before the models' builders declared
+    # their gauges (PR 46)
+    ("lm_tokens_tiny", "mimo_v2_tiny"): "14452e92480a457c",
 }
 WALKED_IN_THREES = "83f7ac9576367014"  # the same, the peer axis in two blocks
 
@@ -501,6 +504,46 @@ def test_the_expert_layer_at_the_published_shapes_takes_the_kernel(v5e):
     assert not made, made[:5]
 
 
+def _described_layer(v5e, build, cfg, at, length=1024, peers=1):
+    """(sharding, model, frozen leaves, adapters with a peer axis of
+    `peers`) of layer `at` of a language model `build(name, cfg, length)`,
+    as shapes on the described chip."""
+    one = SingleDeviceSharding(v5e[0])
+    model = build("lm", cfg, length)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one), tree)
+
+    frozen = on_chip(jax.eval_shape(
+        model.init_frozen, jax.random.PRNGKey(0))["layers"][at])
+    adapters = on_chip(jax.eval_shape(
+        lambda key: jax.tree.map(lambda b: jnp.stack([b] * peers),
+                                 model.init(key)["layers"][at]),
+        jax.random.PRNGKey(0)))
+    return one, model, frozen, adapters
+
+
+def _block_gradient(block, described, argnums=(0, 1)):
+    """`block(h, frozen, adapters)` of a `_described_layer` under
+    `jax.checkpoint` and `jax.grad` (in the adapters and the input, or as
+    `argnums` says), compiled for the described chip under x64; a block
+    that gives a layer's triple is read by its hidden states."""
+    one, model, frozen, adapters = described
+    peers = jax.tree.leaves(adapters)[0].shape[0]
+
+    def loss(adapters, h, frozen):
+        out = jax.checkpoint(block)(h, frozen, adapters)
+        out = out[0] if isinstance(out, tuple) else out
+        return jnp.sum(out * out)
+
+    h = jax.ShapeDtypeStruct(
+        (peers, 1, model.d_in, model.info["config"].hidden), jnp.float32,
+        sharding=one)
+    return jax.jit(jax.grad(loss, argnums=argnums)).lower(
+        adapters, h, frozen).compile()
+
+
 @pytest.mark.parametrize("at,heads,kind", [(0, 48, "full"),
                                            (1, 72, "sliding")])
 def test_the_attention_at_the_published_shapes_takes_the_kernel(v5e, at,
@@ -514,29 +557,10 @@ def test_the_attention_at_the_published_shapes_takes_the_kernel(v5e, at,
 
     cfg = laguna.PRESETS["laguna_s_fedlora"]
     assert (cfg.heads[at], cfg.layer_types[at]) == (heads, kind)
-    one = SingleDeviceSharding(v5e[0])
-    model = laguna.laguna_model("lm", cfg, 1024)
-
-    def on_chip(tree):
-        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=one), tree)
-
-    frozen = on_chip(jax.eval_shape(
-        model.init_frozen, jax.random.PRNGKey(0))["layers"][at])
-    adapters = on_chip(jax.eval_shape(
-        lambda key: jax.tree.map(lambda b: jnp.stack([b] * 3),
-                                 model.init(key)["layers"][at]),
-        jax.random.PRNGKey(0)))
-
-    def loss(adapters, h, frozen):
-        out = jax.checkpoint(lambda h, f, a: laguna._attention(
-            cfg, at, h, f, a))(h, frozen, adapters)
-        return jnp.sum(out * out)
-
-    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-        adapters, jax.ShapeDtypeStruct((3, 1, 1024, cfg.hidden), jnp.float32,
-                                       sharding=one), frozen
-    ).compile().as_text()
+    hlo = _block_gradient(
+        lambda h, f, a: laguna._attention(cfg, at, h, f, a),
+        _described_layer(v5e, laguna.laguna_model, cfg, at,
+                         peers=3)).as_text()
     calls = [line for line in hlo.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     # forward (the primal's is dead code under the gradient: the
@@ -569,31 +593,13 @@ def test_the_latent_attention_at_the_published_shapes_takes_the_kernel(v5e):
     from biscotti_tpu.models import deepseek_v2
 
     cfg = deepseek_v2.PRESETS["deepseek_v2_fedlora"]
-    one = SingleDeviceSharding(v5e[0])
-    model = deepseek_v2.deepseek_v2_model("lm", cfg, 1024)
-    assert model.info["attention"] == {"fused": 1, "block_share": 0.75,
-                                       "shared_key": 1}
-
-    def on_chip(tree):
-        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=one), tree)
-
-    frozen = on_chip(jax.eval_shape(
-        model.init_frozen, jax.random.PRNGKey(0))["layers"][1])
-    adapters = on_chip(jax.eval_shape(
-        lambda key: jax.tree.map(lambda b: jnp.stack([b] * 3),
-                                 model.init(key)["layers"][1]),
-        jax.random.PRNGKey(0)))
-
-    def loss(adapters, h, frozen):
-        out = jax.checkpoint(lambda h, f, a: deepseek_v2._attention(
-            cfg, h, f, a))(h, frozen, adapters)
-        return jnp.sum(out * out)
-
-    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-        adapters, jax.ShapeDtypeStruct((3, 1, 1024, cfg.hidden), jnp.float32,
-                                       sharding=one), frozen
-    ).compile().as_text()
+    described = _described_layer(v5e, deepseek_v2.deepseek_v2_model, cfg, 1,
+                                 peers=3)
+    assert described[1].info["attention"] == {
+        "fused": 1, "block_share": 0.75, "shared_key": 1}
+    hlo = _block_gradient(
+        lambda h, f, a: deepseek_v2._attention(cfg, h, f, a),
+        described).as_text()
     kernels = [line for line in hlo.splitlines()
                if 'custom_call_target="tpu_custom_call"' in line]
     calls = [c for c in kernels if "attention_" in c.split(" = ")[0]]
@@ -776,350 +782,3 @@ def test_a_sparse_layers_gradient_holds_no_copy_of_an_expert_stack(v5e,
     temporaries = compiled.memory_analysis().temp_size_in_bytes
     assert temporaries < EXPERTS_TEMPORARIES, temporaries
     assert EXPERTS_TEMPORARIES + 2 * e * h * f < SPLIT_COND_TEMPORARIES[model]
-
-
-# ------------------------- Granite-4.0-H-Micro, whole, on one chip (PR 33)
-
-
-def test_the_hybrids_sizes_from_shapes_alone():
-    """d = 6,410,240 and 3,195,459,328 frozen parameters (6.39 GB in
-    bfloat16, 39.9% of the chip), the tied embedding counted once, with no
-    parameter drawn; the sibling models' plans are the parent's."""
-    from biscotti_tpu.models import lm
-    from biscotti_tpu.models.zoo import model_for_dataset
-
-    model = model_for_dataset("lm_tokens_granite")
-    assert model.num_params == 6410240
-    assert lm.frozen_count(model) == 3195459328
-    tree = jax.eval_shape(model.init_frozen, jax.random.PRNGKey(0))
-    assert {leaf.dtype for leaf in jax.tree.leaves(tree)} == {
-        jnp.dtype(jnp.bfloat16)}
-    assert model.info["attention"] == {"fused": 1, "block_share": 0.75}
-    assert model_for_dataset("lm_tokens").info["attention"] == {
-        "fused": 1, "block_share": 0.75}
-    assert model_for_dataset("lm_tokens_dsv2").info["attention"] == {
-        "fused": 1, "block_share": 0.75, "shared_key": 1}
-    # a peer's step holds 2.05 GB by the model's count: a 16 GB chip with
-    # 6.39 GB of base and 1.67 GB of deltas standing steps ONE at a time
-    from biscotti_tpu.models.peer_step import DEVICE_BYTES, peer_block
-
-    step = model.step_bytes(1)
-    assert 2.0e9 < step < 2.1e9
-    free = DEVICE_BYTES - 2 * 3195459328 - 4 * (3 * 21 + 2) * 6410240
-    assert peer_block(21, step, free) == 1
-
-
-def _hybrid_layer(v5e, at):
-    """(config, sharding, frozen leaves, adapters with a peer axis of 1) of
-    layer `at` of the published hybrid, as shapes on the described chip."""
-    from biscotti_tpu.models import granite_hybrid
-
-    cfg = granite_hybrid.PRESETS["granite_h_micro_fedlora"]
-    one = SingleDeviceSharding(v5e[0])
-    model = granite_hybrid.granite_hybrid_model("lm", cfg, 1024)
-
-    def on_chip(tree):
-        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=one), tree)
-
-    frozen = on_chip(jax.eval_shape(
-        model.init_frozen, jax.random.PRNGKey(0))["layers"][at])
-    adapters = on_chip(jax.eval_shape(
-        lambda key: jax.tree.map(lambda b: b[None],
-                                 model.init(key)["layers"][at]),
-        jax.random.PRNGKey(0)))
-    return cfg, one, frozen, adapters
-
-
-def test_the_state_space_layer_at_the_published_shapes_compiles(v5e):
-    """One Mamba-2 layer of the published size as a peer sends it (1
-    window of 1,024 tokens: 4 chunks of 256, 64 heads of 64, state 128,
-    bfloat16) under `jax.checkpoint` and `jax.grad` compiles for the v5e
-    under x64, and its scan makes no float32 array of the decays' size
-    [chunks, heads, 256, 256] more than a handful of times."""
-    from biscotti_tpu.models import granite_hybrid
-
-    cfg, one, frozen, adapters = _hybrid_layer(v5e, 0)
-
-    def loss(adapters, h, frozen):
-        out, _, _ = jax.checkpoint(lambda h, f, a: granite_hybrid._layer(
-            cfg, 0, h, f, a))(h, frozen, adapters)
-        return jnp.sum(out * out)
-
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-        adapters, jax.ShapeDtypeStruct((1, 1, 1024, cfg.hidden), jnp.float32,
-                                       sharding=one), frozen).compile()
-    hlo = compiled.as_text()
-    assert "ssm_scan" in hlo and "ssm_conv" in hlo and "ssm_gate" in hlo
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
-    assert not [line.strip()[:160] for line in hlo.splitlines()
-                if "f64[" in line or ("s64[" in line
-                                      and "parameter(" not in line)]
-
-
-def test_the_hybrids_attention_at_the_published_shapes_takes_the_kernel(v5e):
-    """An attention layer of the published size (32 query heads on 8
-    key/value heads of 64 | 64, no rotary, the scores times 1 / 64) under
-    `jax.checkpoint` and `jax.grad`: ops/attention.py's kernel with the
-    values' 64 as they are, and no float32 array of the scores' size."""
-    from biscotti_tpu.models import granite_hybrid
-
-    cfg, one, frozen, adapters = _hybrid_layer(v5e, 5)
-
-    def loss(adapters, h, frozen):
-        out = jax.checkpoint(lambda h, f, a: granite_hybrid._attention(
-            cfg, h, f, a))(h, frozen, adapters)
-        return jnp.sum(out * out)
-
-    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-        adapters, jax.ShapeDtypeStruct((1, 1, 1024, cfg.hidden), jnp.float32,
-                                       sharding=one), frozen
-    ).compile().as_text()
-    calls = [line for line in hlo.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
-    assert 2 <= len(calls) <= 3, len(calls)
-    assert any("f32[1,8,4,1024,64]" in c for c in calls)     # the result
-    assert any("bf16[1,8,4,1024,64]" in c for c in calls)    # q, dq
-    square = re.compile(r"f32\[([\d,]*1024,1024)\]")
-    made = [line.strip()[:160] for line in hlo.splitlines()
-            for dims in square.findall(line)
-            if math.prod(int(v) for v in dims.split(",")) > 1024 * 1024]
-    assert not made, made[:5]
-
-
-# ------------- Qwen3-Next-80B-A3B's share: the delta rule, heads of 256 (PR 38)
-
-
-def _delta_hybrid_layer(v5e, at):
-    """(config, sharding, frozen leaves, adapters with a peer axis of 1) of
-    layer `at` of the published delta-net hybrid, as shapes on the
-    described chip."""
-    from biscotti_tpu.models import qwen3_next
-
-    cfg = qwen3_next.PRESETS["qwen3_next_fedlora"]
-    one = SingleDeviceSharding(v5e[0])
-    model = qwen3_next.qwen3_next_model("lm", cfg, 1024)
-
-    def on_chip(tree):
-        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=one), tree)
-
-    frozen = on_chip(jax.eval_shape(
-        model.init_frozen, jax.random.PRNGKey(0))["layers"][at])
-    adapters = on_chip(jax.eval_shape(
-        lambda key: jax.tree.map(lambda b: b[None],
-                                 model.init(key)["layers"][at]),
-        jax.random.PRNGKey(0)))
-    return cfg, one, frozen, adapters
-
-
-def test_the_delta_net_mixer_at_the_published_shapes_compiles(v5e):
-    """One gated delta-net mixer of the published size as a peer sends it
-    (1 window of 1,024 tokens: 16 chunks of 64, 16 key heads serving 32
-    value heads of 128, bfloat16) under `jax.checkpoint` and `jax.grad`
-    compiles for the v5e under x64: the unit-lower-triangular solve and
-    its transpose lower, and nothing of it is 64 bits wide."""
-    from biscotti_tpu.models import qwen3_next
-
-    cfg, one, frozen, adapters = _delta_hybrid_layer(v5e, 0)
-
-    def loss(adapters, h, frozen):
-        out = jax.checkpoint(lambda h, f, a: qwen3_next._delta_net(
-            cfg, h, f, a))(h, frozen, adapters)
-        return jnp.sum(out * out)
-
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-        adapters, jax.ShapeDtypeStruct((1, 1, 1024, cfg.hidden), jnp.float32,
-                                       sharding=one), frozen).compile()
-    hlo = compiled.as_text()
-    for scope in ("gdn_proj", "gdn_conv", "gdn_rule", "gdn_gate"):
-        assert scope in hlo, scope
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
-    assert not [line.strip()[:160] for line in hlo.splitlines()
-                if "f64[" in line or ("s64[" in line
-                                      and "parameter(" not in line)]
-
-
-def test_the_gated_attention_at_heads_of_256_takes_the_kernel(v5e):
-    """A gated attention layer of the published size (16 query heads on 2
-    key/value heads of 256 | 256: eight query heads a key/value head, the
-    widest head and the largest group so far) under `jax.checkpoint` and
-    `jax.grad`: `blocks` finds a block inside the kernels' VMEM rule, so
-    the core is ops/attention.py's kernel and no float32 array of the
-    scores' size [16, 1024, 1024] is made."""
-    from biscotti_tpu.models import qwen3_next
-    from biscotti_tpu.ops import attention
-
-    cfg, one, frozen, adapters = _delta_hybrid_layer(v5e, 3)
-    assert attention.blocks(8, 1024, 256, jnp.bfloat16) == (128, 128)
-    assert qwen3_next.attention_plan(cfg, 1024) == {"fused": 1,
-                                                    "block_share": 0.5625}
-
-    def loss(adapters, h, frozen):
-        out = jax.checkpoint(lambda h, f, a: qwen3_next._attention(
-            cfg, h, f, a))(h, frozen, adapters)
-        return jnp.sum(out * out)
-
-    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-        adapters, jax.ShapeDtypeStruct((1, 1, 1024, cfg.hidden), jnp.float32,
-                                       sharding=one), frozen
-    ).compile().as_text()
-    calls = [line for line in hlo.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
-    assert 2 <= len(calls) <= 3, len(calls)
-    assert any("f32[1,2,8,1024,256]" in c for c in calls)     # the result
-    assert any("bf16[1,2,8,1024,256]" in c for c in calls)    # q, dq
-    square = re.compile(r"f32\[([\d,]*1024,1024)\]")
-    made = [line.strip()[:160] for line in hlo.splitlines()
-            for dims in square.findall(line)
-            if math.prod(int(v) for v in dims.split(",")) > 1024 * 1024]
-    assert not made, made[:5]
-
-
-def test_experts_of_2048_by_512_take_the_kernel_under_half_a_tile(v5e):
-    """`held_experts` at Qwen3-Next's published shapes (a peer block of 3:
-    3,072 tokens, ten a token, 128 of 512 experts held, H = 2,048, F = 512,
-    bfloat16): a group is sent 60 rows, under half of the smallest row
-    tile there is, and every grouped product is still
-    ops/grouped_matmul.py's, whole weights a column tile."""
-    from biscotti_tpu.ops import grouped_matmul
-
-    n, (k, e, total, h, f) = 3072, EXPERTS["qwen3_next"]
-    assert n * k / total == 60.0
-    tile = grouped_matmul.row_tile(n * k / total)
-    assert tile == grouped_matmul.ROW_TILES[0] == 128
-    for rows in (n * k // 2, n * k):  # the cut buffer and the uncut one
-        assert grouped_matmul.column_tile(rows, h, f, jnp.bfloat16,
-                                          tile) == 512
-        assert grouped_matmul.column_tile(rows, f, h, jnp.bfloat16,
-                                          tile) == 1024
-    compiled = _experts_gradient(v5e[0], "qwen3_next", remat=True)
-    hlo = compiled.as_text()
-    assert "ragged-dot" not in hlo
-    lines = [line.strip() for line in hlo.splitlines()]
-    calls = [line for line in lines
-             if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(calls) == 18  # 9 a side: primal 3, recomputed 3, transposed 3
-    stack = r" = bf16\[128,(2048,512|512,2048)\]"
-    made = [line[:160] for line in lines if re.search(stack, line)
-            and "parameter(" not in line and "get-tuple-element(" not in line]
-    assert not made, made[:5]
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
-
-
-def test_the_delta_rule_at_the_published_shapes_is_the_kernel(v5e):
-    """The same mixer's gradient, read for the rule (PR 39): under scope
-    `gdn_rule` there are ops/delta_rule.py's three `tpu_custom_call`s (the
-    forward pass's and the recomputed forward's, which both write the
-    chunks' entry states: under `jax.grad` the first is the same call, and
-    a custom call's unread result is still written; and the backward's,
-    which reads them) and each is booked under `gdn_rule`, the LAST scope
-    of its `op_name`, where a device trace's reader looks; nothing of a
-    chunk's system is an array any more: no float32 `[..., 64, 256]`
-    right side or solution, no `[..., 64, 64]` decay or system, and no
-    `while` (the `jax.numpy` form's 16 carried steps) anywhere."""
-    from biscotti_tpu.models import qwen3_next
-
-    cfg, one, frozen, adapters = _delta_hybrid_layer(v5e, 0)
-    model = qwen3_next.qwen3_next_model("lm", cfg, 1024)
-    assert model.info["gdn_rule"]["kernel"] == 1
-
-    def loss(adapters, h, frozen):
-        out = jax.checkpoint(lambda h, f, a: qwen3_next._delta_net(
-            cfg, h, f, a))(h, frozen, adapters)
-        return jnp.sum(out * out)
-
-    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-        adapters, jax.ShapeDtypeStruct((1, 1, 1024, cfg.hidden), jnp.float32,
-                                       sharding=one), frozen
-    ).compile().as_text()
-    calls = [line for line in hlo.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(calls) == 3, len(calls)
-    scopes = re.compile("|".join(qwen3_next.SCOPES))
-    for call in calls:
-        name = re.search(r'op_name="([^"]*)"', call).group(1)
-        assert scopes.findall(name)[-1] == "gdn_rule", name
-    assert all("f32[1,16,32,128,128]" in c for c in calls)  # entry states
-    assert any("bf16[1,1024,2048]" in c for c in calls)     # q as it comes
-    chunk = re.compile(r"f32\[[\d,]*,64,(?:64|256)\]")
-    made = [line.strip()[:160] for line in hlo.splitlines()
-            if chunk.search(line.split(" = ")[-1].split("(")[0])]
-    assert not made, made[:5]
-    assert " while(" not in hlo and "triangular" not in hlo
-
-
-# ------- MiMo-V2.5's share: a learned sink, 192 | 128 under grouped queries,
-# windows of 2,048 tokens (PR 40)
-
-
-@pytest.mark.parametrize("at,kind,kv", [(1, "window", 8), (5, "full", 4)])
-def test_the_sink_and_the_wide_groups_at_2048_tokens_take_the_kernel(
-        v5e, at, kind, kv):
-    """An attention block of the published MiMo-V2.5 share as a peer sends
-    it (1 window of 2,048 tokens, 64 query heads of 192 | 128 on 8 (window
-    of 128, a learned sink a head) or 4 (causal) key/value heads, bfloat16)
-    under `jax.checkpoint` and `jax.grad` compiles for the v5e under x64
-    with ops/attention.py's kernel as its core: a key/value head's 8 or 16
-    query heads do not fit the kernel's buffers beside 2,048 keys, so they
-    go a head at a time at blocks of 256 x 256 (`group_split`: of the
-    sub-groups that fit, the one whose block is fastest), each with its own
-    copy of its key/value head, the sinks reach the forward
-    kernel through SMEM, and NO float32 array of the scores' size is made
-    (all 64 heads' would be 1.07 GB). The full kind's block is
-    differentiated in its adapters alone: ALONE, with its input's cotangent
-    asked for too, the compiler fuses the transpose of the 13,568-column
-    product with the norm's backward into one fusion that wants 19.7 MB of
-    its 16 MB of scoped VMEM and gives up ("please file a bug against
-    XLA"); inside the whole round it fuses otherwise and compiles
-    (tests/test_v5_mimo_v2_lowering.py; PERF.md section 7)."""
-    from biscotti_tpu.models import mimo_v2
-    from biscotti_tpu.ops import attention
-
-    cfg = mimo_v2.PRESETS["mimo_v2_fedlora"]
-    assert cfg.kind(at)[0] == kind and cfg.kv_heads[cfg.pattern[at]] == kv
-    g = cfg.heads // kv
-    assert attention.blocks(g, 2048, 192, jnp.bfloat16, 128) is None
-    assert attention.group_split(g, 2048, 192, jnp.bfloat16, 128) == g
-    assert attention.blocks(1, 2048, 192, jnp.bfloat16, 128) == (256, 256)
-    one = SingleDeviceSharding(v5e[0])
-    model = mimo_v2.mimo_v2_model("lm", cfg, 2048)
-
-    def on_chip(tree):
-        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=one), tree)
-
-    frozen = on_chip(jax.eval_shape(
-        model.init_frozen, jax.random.PRNGKey(0))["layers"][at])
-    adapters = on_chip(jax.eval_shape(
-        lambda key: jax.tree.map(lambda b: b[None],
-                                 model.init(key)["layers"][at]),
-        jax.random.PRNGKey(0)))
-    assert ("sink" in frozen) == (kind == "window")
-
-    def loss(adapters, h, frozen):
-        out = jax.checkpoint(lambda h, f, a: mimo_v2._attention(
-            cfg, kind, h, f, a))(h, frozen, adapters)
-        return jnp.sum(out * out)
-
-    compiled = jax.jit(jax.grad(
-        loss, argnums=(0, 1) if kind == "window" else (0,))).lower(
-        adapters, jax.ShapeDtypeStruct((1, 1, 2048, cfg.hidden), jnp.float32,
-                                       sharding=one), frozen).compile()
-    hlo = compiled.as_text()
-    calls = [line for line in hlo.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
-    assert 2 <= len(calls) <= 3, len(calls)
-    assert any("f32[1,64,1,2048,128]" in c for c in calls)    # the result
-    assert any("bf16[1,64,1,2048,192]" in c for c in calls)   # q, dq
-    scope = "attn_core_swa" if kind == "window" else "attn_core_full"
-    assert all(scope in c for c in calls)
-    square = re.compile(r"f32\[([\d,]*2048,2048)\]")
-    made = [line.strip()[:160] for line in hlo.splitlines()
-            for dims in square.findall(line)
-            if math.prod(int(v) for v in dims.split(",")) > 2048 * 2048]
-    assert not made, made[:5]
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
-    assert not [line.strip()[:160] for line in hlo.splitlines()
-                if "f64[" in line or ("s64[" in line
-                                      and "parameter(" not in line)]
