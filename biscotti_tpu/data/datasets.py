@@ -27,6 +27,7 @@ Token shards (`lm_tokens`, `lm_tokens_dsv2` over DeepSeek-V2's held
 25,600 rows, `lm_tokens_granite` over all 100,352 of Granite-4.0-H-Micro's,
 `lm_tokens_qwen3next` over Qwen3-Next-80B-A3B's held 37,984,
 `lm_tokens_mimo` over MiMo-V2.5's held 19,072 in windows of 2,048,
+`lm_tokens_olmo` over all 100,352 of Olmo-Hybrid-7B's,
 and `lm_tokens_tiny` at the tests' size) feed the language models
 (models/laguna.py and its siblings): a row
 is a WINDOW of `d_in` token ids
@@ -112,6 +113,9 @@ DATASETS: Dict[str, DatasetSpec] = {
                                        80, 2, tokens=True),
     # windows of 2,048 over the held eighth of MiMo-V2.5's
     "lm_tokens_mimo": DatasetSpec("lm_tokens_mimo", 2048, 19072, 80, 2,
+                                  tokens=True),
+    # windows of 1,024 over Olmo-Hybrid-7B's whole vocabulary
+    "lm_tokens_olmo": DatasetSpec("lm_tokens_olmo", 1024, 100352, 80, 2,
                                   tokens=True),
 }
 

@@ -43,6 +43,16 @@
   mimo_v2_tiny  the same mechanism at the CPU tests' size
              (`lm_tokens_tiny`; a window of 4 with its sink, head groups of
              4 and 2)
+  olmo_hybrid_fedlora  Olmo-Hybrid-7B's gated delta-net / plain full
+             attention hybrid (models/olmo_hybrid.py): the first of two
+             pipeline stages, 4.1 B frozen parameters at the published
+             widths (16 layers, 12 of them the delta rule at heads of 96 |
+             192 with beta in (0, 2), a dense SwiGLU on every layer, the
+             whole untied vocabulary of 100,352 rows), rank-16 adapters on
+             in_proj_qkvz / out_proj and q, k, v, o trained, d = 5,038,080;
+             reads the dataset `lm_tokens_olmo`
+  olmo_hybrid_tiny  the same mechanism at the CPU tests' size
+             (`lm_tokens_tiny`; one period, four chunks a window)
 
 Inits are MXU-friendly (fan-in scaled normal) and every model is expressed in
 channels-last NHWC, the layout XLA prefers on TPU.
@@ -58,7 +68,7 @@ import jax.numpy as jnp
 
 from biscotti_tpu.data.datasets import base_name, spec as dspec
 from biscotti_tpu.models import (deepseek_v2, granite_hybrid, laguna,
-                                 mimo_v2, qwen3_next)
+                                 mimo_v2, olmo_hybrid, qwen3_next)
 from biscotti_tpu.models.base import Model, cross_entropy, make_model, multiclass_hinge
 
 
@@ -248,7 +258,9 @@ MODELS: Dict[str, callable] = {
                                granite_hybrid.PRESETS),
                               (qwen3_next.qwen3_next_model,
                                qwen3_next.PRESETS),
-                              (mimo_v2.mimo_v2_model, mimo_v2.PRESETS))
+                              (mimo_v2.mimo_v2_model, mimo_v2.PRESETS),
+                              (olmo_hybrid.olmo_hybrid_model,
+                               olmo_hybrid.PRESETS))
        for name in presets},
 }
 
@@ -258,7 +270,8 @@ DEFAULTS = {"creditcard": "logreg", "lm_tokens": "laguna_s_fedlora",
             "lm_tokens_dsv2": "deepseek_v2_fedlora",
             "lm_tokens_granite": "granite_h_micro_fedlora",
             "lm_tokens_qwen3next": "qwen3_next_fedlora",
-            "lm_tokens_mimo": "mimo_v2_fedlora"}
+            "lm_tokens_mimo": "mimo_v2_fedlora",
+            "lm_tokens_olmo": "olmo_hybrid_fedlora"}
 
 
 def _language_model(build, name: str, cfg, dataset: str) -> Model:

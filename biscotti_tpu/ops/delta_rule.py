@@ -5,15 +5,16 @@ whose update is not a decayed outer product: every token also takes out of
 the state what the state already answers for its key. `rule` is what a
 model calls; it runs ONE algorithm in one of two forms, read off the
 shapes: a fused Pallas TPU kernel pair under one `jax.custom_vjp`
-(`fused`; PR 39) where the widths are whole lane tiles, and plain
-`jax.numpy` under `jax.grad` (`chunked`) elsewhere: the tiny preset's
+(`fused`; PR 39) where the widths are whole lane tiles, or `laid` makes
+them so with zero columns (heads of 96 | 192; PR 48), and plain
+`jax.numpy` under `jax.grad` (`chunked`) elsewhere: the tiny presets'
 heads of 8 and chunks of 4, the float64 tests, and the kernel's oracle.
 
 A value head h of a window holds a state S in R^{D x E} (D the key's
 width, E the value's) that starts from ZERO at the window's first token:
 
     S   = exp(g_t) S_{t-1}                       g_t <= 0, a head and token
-    d_t = beta_t (v_t - S^T k_t)                 beta_t in (0, 1)
+    d_t = beta_t (v_t - S^T k_t)                 beta_t in (0, 2)
     S_t = S + k_t d_t^T                          (so S_t = exp(g_t) S_{t-1}
     o_t = S_t^T q_t                               (I - beta_t k_t k_t^T) + beta_t k_t v_t^T)
 
@@ -49,21 +50,23 @@ no k k^T term.
     where the mask lets it through; where it does not, the difference is
     set to -inf BEFORE the exp.
   the solve is forward substitution in blocks of `SUB` rows (`_solve`):
-    stable whatever the keys (the product form of (I + A)^-1 is not, its
-    powers of A grow where a chunk's keys align), and all small products,
-    at the highest precision. The compiler's own `triangular_solve` took
-    2.5 ms a window and layer on the v5e, 1.3 s of a 4.9 s round (PERF.md
-    section 6, PR 38). No inverse of the whole system is formed.
+    stable whatever the keys, at every beta in (0, 2) (a model that lets
+    the eigenvalue along k go negative doubles its sigmoid,
+    arXiv:2411.12537, and hands `rule` the beta it means). Entry (i, j)
+    of (I + A)^-1 is -beta_i k_i^T P k_j times a decay, P the product of
+    the steps between, I - beta k k^T, each of norm <= 1 while |k| <= 1
+    and beta <= 2: no entry passes 2, and substitution forms those
+    entries, block by block, and nothing larger (the product form's
+    powers of A grow where a chunk's keys align, as 2^n at beta = 2). All
+    small products, at the highest precision; no whole inverse is formed.
   windows never meet: W is a batch axis of every product, and a chunk never
     spans two windows (T is a whole number of chunks, or one chunk).
 
 What is `jax.numpy`'s (`chunked`, `_solve`): every chunk's decays, system,
-right side, solution and deltas are ARRAYS, float32 [W, N, G, R, 64, 64]
-and [..., 64, 256], each written to HBM and read back (300 MB a forward
-call at the published size), five head-major transposes in and one out,
-and the carried part a `lax.scan` of T / L steps of small launches: 1.44 ms
-a window and layer forward, 3.64 with its backward on the v5e, 3.6% of the
-rule's roofline (PERF.md section 6, PR 38).
+right side, solution and deltas are ARRAYS in HBM, float32 [W, N, G, R, 64,
+64] and [..., 64, 256] (300 MB a forward call at the published size), six
+head-major transposes, and the carried part a `lax.scan` of small launches:
+1.44 | 3.64 ms a window and layer, 3.6% of the rule's roofline (PR 38).
 
 What is the kernel's (`fused`: `_forward`, `_backward`; the same
 mathematics at the same precision, statement for statement):
@@ -76,19 +79,16 @@ mathematics at the same precision, statement for statement):
     product rounds them), the decays, k k^T and q k^T once a key head,
     the strictly lower system, its solve for [U | W], delta = U - W S, the
     state's update and o. Nothing of [64, 64] or [64, 256] goes to HBM.
-  operands as the conv writes them: q, k [W, T, G D] and v [W, T, H E]
-    token-major, a head a 128-lane column range of a (64, heads x 128)
-    block; o is written the same way. No transpose but g's and beta's,
-    [W, T, H] float32 (128 KB), laid a column a head outside the kernel.
+  operands as the conv writes them: q, k [W, T, G D] and v, o [W, T, H E]
+    token-major, a head a lane-tile column range of a (64, heads x width)
+    block. No transpose but g's and beta's ([W, T, H]), laid a column a head.
   the solve is the same blocked forward substitution: the four diagonal
     blocks of `SUB` rows inverted by substitution on the identity (15
     rank-1 steps on the vector unit), then a block row at a time on the
-    matrix unit at the highest precision. A step's four value heads
-    solve IN STEP with each other (`_inverses`, `_lower`, `_upper` take
-    lists): the substitution is a chain of seven dependent small products
-    a head, and a head at a time the chip waits out each product's
-    latency: 0.69 | 1.97 ms a call a head at a time, 0.39 | 1.11 in step
-    (PERF.md section 6, PR 39).
+    matrix unit at the highest precision. A step's value heads (four or
+    more: `heads_a_step`) solve IN STEP with each other (`_inverses`,
+    `_lower`, `_upper` take lists): a head at a time the chip waits out
+    seven dependent small products (0.69 | 1.97 ms a call; 0.39 | 1.11).
   backward: a kernel too, the chunks in reverse, dS carried in VMEM, the
     chunk's system, solve and delta made again on chip from q, k, v, g,
     beta and the chunk's ENTRY STATE, which the forward under `jax.grad`
@@ -601,7 +601,7 @@ def _operands(q, k, v, g, beta, chunk):
     w, t, groups, d = q.shape
     heads, e = v.shape[2:]
     n = chunks(t, chunk)
-    held = key_heads_a_step(groups)
+    held = heads_a_step(groups, heads)
     across = held * _each(groups, heads)
     return ((q.reshape(w, t, -1), k.reshape(w, t, -1), v.reshape(w, t, -1),
              _columns(g, n, across), _columns(beta, n, across)),
@@ -656,16 +656,16 @@ def fits(t: int, d: int, e: int, chunk: int, dtype) -> bool:
             and jnp.dtype(dtype) in (jnp.bfloat16, jnp.float32))
 
 
-def plan(groups: int, t: int, d: int, e: int, chunk: int, dtype) -> dict:
+def plan(groups: int, t: int, d: int, e: int, chunk: int, dtype,
+         heads: int = 0) -> dict:
     """Which side of `rule`'s dispatch a model is built with, from the
-    shapes alone: `kernel` 1 the fused kernel (0: the `jax.numpy` form),
-    `key_heads_a_step` what a step of its grid holds, `states_saved` 1:
-    the forward under `jax.grad` keeps each chunk's entry state for the
-    backward (it does not walk the chunks twice)."""
-    kernel = fits(t, d, e, chunk, dtype)
-    return {"kernel": int(kernel),
-            "key_heads_a_step": key_heads_a_step(groups) if kernel else 0,
-            "states_saved": int(kernel)}
+    shapes alone, `heads` its value heads (two a key head where a model
+    states none). The keys are `layout`'s, below `rule`: what PR 48
+    brought stands there, so that no line a published round traces moved
+    (a kernel's compiled payload holds its call stack, line by line)."""
+    if not heads:
+        heads = 2 * groups
+    return layout(groups, heads, t, d, e, chunk, dtype)
 
 
 def rule(q, k, v, g, beta, chunk: int):
@@ -676,4 +676,79 @@ def rule(q, k, v, g, beta, chunk: int):
     t, d, e = q.shape[1], q.shape[-1], v.shape[-1]
     if q.dtype == v.dtype == k.dtype and fits(t, d, e, chunk, q.dtype):
         return fused(q, k, v, g, beta, chunk)
-    return chunked(q, k, v, g, beta, chunk)
+    return laid(q, k, v, g, beta, chunk)
+
+
+# ---------------------------------------- heads that are no whole lane tiles
+# (PR 48. All of it stands BELOW `rule`: a Mosaic payload embeds its call
+# stack, and a line added above would re-key every program a published
+# round compiles from this file; PERF.md section 6, PR 46. ROADMAP Queue C
+# has the merge.)
+
+IN_STEP = 4  # value heads whose solves run in step with each other (PR 39)
+
+
+def heads_a_step(groups: int, heads: int) -> int:
+    """Key heads a step of the kernel's grid holds, each with its R value
+    heads: the fewest that divide G and bring `IN_STEP` value heads
+    together (2 of 16 at two value heads a key head, 5 of 30 at one); all
+    of G where no divisor does."""
+    each = _each(groups, heads)
+    return next(held for held in range(1, groups + 1)
+                if groups % held == 0
+                and (held * each >= IN_STEP or held == groups))
+
+
+def _tiles(width: int) -> int:
+    """`width` rounded up to whole lane tiles."""
+    return -(-width // _LANES) * _LANES
+
+
+def padded_share(d: int, e: int) -> float:
+    """Of the kernel's state products (D x E a token and value head), the
+    share that multiplies the zero columns `laid` puts in: 0 at whole lane
+    tiles, 0.4375 at 96 | 192."""
+    return 1.0 - d * e / (_tiles(d) * _tiles(e))
+
+
+def _lays(t: int, d: int, e: int, chunk: int, dtype) -> bool:
+    """Whether `laid` takes widths d | e to the kernel: the tiles above
+    them fit it and under half of its state products would multiply
+    zeros (a head of 64 or less on a tile of 128 stays `chunked`'s)."""
+    return 0.0 < padded_share(d, e) < 0.5 \
+        and fits(t, _tiles(d), _tiles(e), chunk, dtype)
+
+
+def laid(q, k, v, g, beta, chunk: int):
+    """`rule` where the widths are no whole lane tiles: the kernel on
+    heads laid in whole tiles with ZERO columns where `_lays` says so,
+    `chunked` elsewhere. Exact: a zero key column adds nothing to k . k,
+    q . k or S^T k and its row of the state stays zero; a zero value column
+    gives a zero column of d, of the state and of o, which is dropped.
+    The cotangents of the zero columns are dropped by the pad's own
+    transpose."""
+    t, d, e = q.shape[1], q.shape[-1], v.shape[-1]
+    if not (q.dtype == v.dtype == k.dtype
+            and _lays(t, d, e, chunk, q.dtype)):
+        return chunked(q, k, v, g, beta, chunk)
+
+    def wide(a, width):
+        return jnp.pad(a, ((0, 0),) * 3 + ((0, width - a.shape[-1]),))
+
+    return fused(wide(q, _tiles(d)), wide(k, _tiles(d)),
+                 wide(v, _tiles(e)), g, beta, chunk)[..., :e]
+
+
+def layout(groups: int, heads: int, t: int, d: int, e: int, chunk: int,
+           dtype) -> dict:
+    """What `plan` states: `kernel` 1 the fused kernel (0: the `jax.numpy`
+    form), `key_heads_a_step` and `value_heads_a_step` what a step of its
+    grid holds, `padded_share` as above (0 off the kernel), `states_saved`
+    1: the forward under `jax.grad` keeps each chunk's entry state for the
+    backward (it does not walk the chunks twice)."""
+    kernel = fits(t, d, e, chunk, dtype) or _lays(t, d, e, chunk, dtype)
+    held = heads_a_step(groups, heads) if kernel else 0
+    return {"kernel": int(kernel), "key_heads_a_step": held,
+            "value_heads_a_step": held * heads // groups,
+            "padded_share": padded_share(d, e) if kernel else 0.0,
+            "states_saved": int(kernel)}

@@ -782,3 +782,54 @@ def test_a_sparse_layers_gradient_holds_no_copy_of_an_expert_stack(v5e,
     temporaries = compiled.memory_analysis().temp_size_in_bytes
     assert temporaries < EXPERTS_TEMPORARIES, temporaries
     assert EXPERTS_TEMPORARIES + 2 * e * h * f < SPLIT_COND_TEMPORARIES[model]
+
+
+# ------------------- what a published round lowers to, by hash (PR 48)
+# (appended: the tests above run on the schedule they had)
+
+_MOSAIC_BODY = re.compile(r'\\22body\\22: \\22[A-Za-z0-9+/=]*\\22')
+
+
+def _lowered_sha(lowered):
+    """sha256[:16] of a lowered program's text less its Mosaic bodies: a
+    kernel's payload holds its call stack, file by file and line by line,
+    so it differs from one checkout's path to the next; everything else of
+    the text is the program's alone."""
+    return _sha(_MOSAIC_BODY.sub("", lowered.as_text()))
+
+
+# read on 27a3ed4 (PR 46's tree) before ops/delta_rule.py learnt heads that
+# are no whole lane tiles: the published rounds that share the rule's
+# module or the hybrids' mixer parts (tests/test_v4_qwen3_next_lowering.py,
+# tests/test_v3_granite_lowering.py hold their whole rounds to these)
+PARENT_PUBLISHED_ROUNDS = {"lm_tokens_qwen3next": "ed891139b7db5505",
+                           "lm_tokens_granite": "62d702f5222ff058"}
+# and the rule's kernel pair itself at Qwen3-Next's shapes, as a jaxpr
+# (the text of both kernels' bodies, no path in it)
+PARENT_RULE_KERNELS = "9936be577aabdcf3"
+
+
+def test_the_rules_kernels_at_whole_tiles_trace_as_the_parents():
+    """16 key heads of 128 serving 32 value heads of 128, one window of
+    1,024 tokens, bfloat16, forward and backward: the jaxpr of `rule`
+    under `jax.vjp` (both `pallas_call`s with their bodies, two key heads
+    and four value heads a step) is the parent's, statement for statement:
+    `heads_a_step`, `laid` and `layout` changed nothing of what Qwen3-Next
+    runs."""
+    from biscotti_tpu.ops import delta_rule
+
+    shape = jax.ShapeDtypeStruct
+    args = (shape((1, 1024, 16, 128), jnp.bfloat16),
+            shape((1, 1024, 16, 128), jnp.bfloat16),
+            shape((1, 1024, 32, 128), jnp.bfloat16),
+            shape((1, 1024, 32), jnp.float32),
+            shape((1, 1024, 32), jnp.float32),
+            shape((1, 1024, 32, 128), jnp.float32))
+
+    def both(q, k, v, g, beta, cot):
+        out, back = jax.vjp(lambda *a: delta_rule.rule(*a, 64), q, k, v, g,
+                            beta)
+        return out, back(cot)
+
+    assert delta_rule.heads_a_step(16, 32) == 2
+    assert _sha(str(jax.make_jaxpr(both)(*args))) == PARENT_RULE_KERNELS

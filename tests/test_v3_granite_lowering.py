@@ -17,7 +17,8 @@ from jax.sharding import SingleDeviceSharding
 
 from biscotti_tpu.parallel.sim import Simulator
 from test_tpu_lowering import (  # noqa: F401  (v5e: the fixture)
-    _abstract, _block_gradient, _cfg, _described_layer, v5e)
+    PARENT_PUBLISHED_ROUNDS, _abstract, _block_gradient, _cfg,
+    _described_layer, _lowered_sha, v5e)
 
 HYBRID = dict(dataset="lm_tokens_granite", num_nodes=30, batch_size=1,
               sample_percent=0.7, num_verifiers=3, num_miners=3,
@@ -46,7 +47,10 @@ def test_the_published_hybrid_round_compiles_for_v5e(v5e, monkeypatch):
             + _abstract([sim.x, sim.y], one, stack=True)
             + _abstract([sim.x_val, sim.y_val], one)
             + [jax.tree.map(lambda a: _abstract([a], one)[0], sim.frozen)])
-    compiled = jax.jit(sim._round_step_raw).lower(*args).compile()
+    lowered = jax.jit(sim._round_step_raw).lower(*args)
+    # the text it had before the seventh model (PR 48), Mosaic bodies aside
+    assert _lowered_sha(lowered) == PARENT_PUBLISHED_ROUNDS["lm_tokens_granite"]
+    compiled = lowered.compile()
     memory = compiled.memory_analysis()
     assert 6.4e9 < memory.argument_size_in_bytes < 6.5e9
     assert memory.temp_size_in_bytes < 3.6e9

@@ -139,26 +139,27 @@ def test_l2norm_divides_by_the_length():
 
 
 def _wide_inputs(windows=1, t=128, groups=1, dtype=jnp.float32, g=None,
-                 keys=None):
-    """Operands at lane-tile widths (D = E = 128, two value heads a key
-    head), as the model hands them over: q, k normalised, g <= 0 a softplus
-    (or the constant `g`), `keys` "one": every key of a window the same
+                 keys=None, d=128, e=128, each=2, top=1.0):
+    """Operands as the model hands them over, at lane-tile widths (D = E =
+    128, two value heads a key head) unless `d`, `e`, `each` say
+    otherwise: q, k normalised, g <= 0 a softplus (or the constant `g`),
+    beta = `top` sigmoid(.), `keys` "one": every key of a window the same
     unit vector."""
     ks = jax.random.split(jax.random.PRNGKey(39), 6)
-    heads = 2 * groups
+    heads = each * groups
     q = delta_rule.l2norm(jax.random.normal(
-        ks[0], (windows, t, groups, 128), jnp.float32)) * 128 ** -0.5
+        ks[0], (windows, t, groups, d), jnp.float32)) * d ** -0.5
     k = delta_rule.l2norm(jax.random.normal(
-        ks[1], (windows, 1 if keys == "one" else t, groups, 128),
+        ks[1], (windows, 1 if keys == "one" else t, groups, d),
         jnp.float32))
     k = jnp.broadcast_to(k, q.shape)
-    v = jax.random.normal(ks[2], (windows, t, heads, 128), jnp.float32)
+    v = jax.random.normal(ks[2], (windows, t, heads, e), jnp.float32)
     decay = -jax.nn.softplus(jax.random.normal(
         ks[3], (windows, t, heads), jnp.float32) - 3.0)
     if g is not None:
         decay = jnp.full_like(decay, g)
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (windows, t, heads),
-                                            jnp.float32))
+    beta = top * jax.nn.sigmoid(jax.random.normal(
+        ks[4], (windows, t, heads), jnp.float32))
     cot = jax.random.normal(ks[5], v.shape, jnp.float32)
     return tuple(a.astype(dtype) for a in (q, k, v)) + (decay, beta), cot
 
@@ -296,3 +297,96 @@ def test_the_rule_keeps_to_jax_numpy_where_the_kernel_does_not_fit(
                                64, inputs[0].dtype)
     np.testing.assert_array_equal(delta_rule.rule(*inputs, 64),
                                   delta_rule.chunked(*inputs, 64))
+
+
+# ------------- heads that are no whole lane tiles, beta up to 2 (PR 48)
+# (appended: the cases above run on the schedule they had)
+
+
+def _laid_inputs(windows, t, groups, dtype=jnp.float32, keys=None):
+    """`_wide_inputs` as Olmo-Hybrid-7B's mixer hands them over: ONE value
+    head a key head, widths 96 | 192, beta = 2 sigmoid(.)."""
+    return _wide_inputs(windows, t, groups, dtype, keys=keys, d=96, e=192,
+                        each=1, top=2.0)
+
+
+@pytest.mark.parametrize("groups,windows,t,keys", [
+    (30, 2, 64, None), (5, 1, 128, "one"), (6, 1, 128, None)],
+    ids=["published_heads", "keys_align", "six_heads"])
+def test_the_kernel_takes_heads_of_96_by_192_and_beta_up_to_2(
+        groups, windows, t, keys):
+    """`rule` at D = 96, E = 192, one value head a key head: the fused
+    kernel pair (interpret mode here) on heads laid in 128 | 256 with zero
+    columns, five (of 30, of 5) or four (of 6: `heads_a_step`) value
+    heads a step, against `sequential` in float64: o and all five
+    gradients, with beta drawn up to 2, at the published 30 heads x 2
+    windows and where a chunk's keys all align (every entry of its system
+    beta times a decay, up to 2: the case the product form loses as 2^63).
+    1e-5 of the largest entry, as the kernel at whole tiles."""
+    inputs, cot = _laid_inputs(windows, t, groups, keys=keys)
+    assert float(inputs[-1].max()) > 1.8
+    assert not delta_rule.fits(t, 96, 192, 64, jnp.float32)
+    plan = delta_rule.plan(groups, t, 96, 192, 64, jnp.float32, heads=groups)
+    assert plan == {"kernel": 1, "states_saved": 1, "padded_share": 0.4375,
+                    "key_heads_a_step": 5 if groups % 5 == 0 else 6,
+                    "value_heads_a_step": 5 if groups % 5 == 0 else 6}
+    got = _value_and_gradients(lambda *a: delta_rule.rule(*a, 64), inputs,
+                               cot)
+    assert got[0].shape == (windows, t, groups, 192)
+    assert got[1].shape == (windows, t, groups, 96)
+    want = _value_and_gradients(
+        delta_rule.sequential,
+        tuple(a.astype(jnp.float64) for a in inputs),
+        cot.astype(jnp.float64))
+    for name, a, r in zip(NAMES, got, want):
+        assert a.shape == r.shape and np.isfinite(a).all(), name
+        scale = float(jnp.max(jnp.abs(r)))
+        assert scale > 0, name
+        np.testing.assert_allclose(a, r, atol=1e-5 * max(scale, 1.0),
+                                   err_msg=name)
+
+
+def test_the_laid_heads_are_the_chunked_rule_in_bfloat16():
+    """bfloat16 operands at 96 | 192: the kernel on the laid heads rounds
+    as the `jax.numpy` form does at the widths as they are (a zero column
+    adds nothing to any rounded sum)."""
+    inputs, cot = _laid_inputs(1, 128, 5, jnp.bfloat16)
+    got = _value_and_gradients(lambda *a: delta_rule.rule(*a, 64), inputs,
+                               cot)
+    same = _value_and_gradients(lambda *a: delta_rule.chunked(*a, 64),
+                                inputs, cot)
+    assert [a.dtype for a in got] == [jnp.float32] + 3 * [jnp.bfloat16] \
+        + 2 * [jnp.float32]
+    assert _gaps(got[:1], same[:1])[0] < 1e-4
+    want = _value_and_gradients(_sequential32, inputs, cot)
+    assert max(_gaps(got, want)) < 0.006, _gaps(got, want)
+
+
+@pytest.mark.parametrize("groups,heads,held", [
+    (16, 32, 2), (30, 30, 5), (6, 6, 6), (2, 4, 2), (1, 2, 1), (3, 3, 3),
+    (8, 8, 4)])
+def test_a_step_holds_the_fewest_key_heads_that_solve_four_in_step(
+        groups, heads, held):
+    """`heads_a_step`: Qwen3-Next's 2 of 16 (four value heads, the kernel
+    text it had: `key_heads_a_step` says the same), 5 of 30 at one value
+    head a key head; all of G where no divisor reaches `IN_STEP`."""
+    assert delta_rule.IN_STEP == 4
+    assert delta_rule.heads_a_step(groups, heads) == held
+    assert groups % held == 0
+    if heads == 2 * groups:
+        assert held == delta_rule.key_heads_a_step(groups)
+
+
+@pytest.mark.parametrize("d,e,share,kernel", [
+    (128, 128, 0.0, 1), (96, 192, 0.4375, 1), (128, 192, 0.25, 1),
+    (64, 128, 0.5, 0), (8, 8, 0.99609375, 0)])
+def test_the_zero_columns_are_laid_only_where_they_are_the_lesser_part(
+        d, e, share, kernel):
+    """`padded_share` of the kernel's state products, and `plan`: the
+    kernel at whole tiles and where under half of its products would
+    multiply zeros; a head of half a tile or less stays `chunked`'s."""
+    assert delta_rule.padded_share(d, e) == share
+    plan = delta_rule.plan(4, 128, d, e, 64, jnp.bfloat16, heads=4)
+    assert plan["kernel"] == kernel
+    assert plan["padded_share"] == (share if kernel else 0.0)
+    assert plan["value_heads_a_step"] == (4 if kernel else 0)

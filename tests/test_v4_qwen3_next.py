@@ -344,9 +344,11 @@ def test_a_model_says_which_side_of_the_rules_dispatch_it_runs(case):
             "a", qwen3_next.PRESETS["qwen3_next_fedlora"],
             1024).info["gdn_rule"]
         assert info == {"kernel": 1, "states_saved": 1,
-                        "key_heads_a_step": delta_rule.key_heads_a_step(16)}
+                        "key_heads_a_step": delta_rule.key_heads_a_step(16),
+                        "value_heads_a_step": 4, "padded_share": 0.0}
         assert 16 % info["key_heads_a_step"] == 0
     else:
         info = qwen3_next.qwen3_next_model("a", TINY, 16).info["gdn_rule"]
         assert info == {"kernel": 0, "states_saved": 0,
-                        "key_heads_a_step": 0}
+                        "key_heads_a_step": 0, "value_heads_a_step": 0,
+                        "padded_share": 0.0}

@@ -13,8 +13,8 @@ from jax.sharding import SingleDeviceSharding
 
 from biscotti_tpu.parallel.sim import Simulator
 from test_tpu_lowering import (  # noqa: F401  (v5e: the fixture)
-    EXPERTS, _abstract, _block_gradient, _cfg, _described_layer,
-    _experts_gradient, v5e)
+    EXPERTS, PARENT_PUBLISHED_ROUNDS, _abstract, _block_gradient, _cfg,
+    _described_layer, _experts_gradient, _lowered_sha, v5e)
 
 HYBRID = dict(dataset="lm_tokens_qwen3next", num_nodes=30, batch_size=1,
               sample_percent=0.7, num_verifiers=3, num_miners=3,
@@ -47,7 +47,10 @@ def test_the_published_delta_net_round_compiles_for_v5e(v5e, monkeypatch):
             + _abstract([sim.x, sim.y], one, stack=True)
             + _abstract([sim.x_val, sim.y_val], one)
             + [jax.tree.map(lambda a: _abstract([a], one)[0], sim.frozen)])
-    compiled = jax.jit(sim._round_step_raw).lower(*args).compile()
+    lowered = jax.jit(sim._round_step_raw).lower(*args)
+    # the text it had before the seventh model (PR 48), Mosaic bodies aside
+    assert _lowered_sha(lowered) == PARENT_PUBLISHED_ROUNDS["lm_tokens_qwen3next"]
+    compiled = lowered.compile()
     memory = compiled.memory_analysis()
     assert 10.8e9 < memory.argument_size_in_bytes < 10.9e9
     assert memory.temp_size_in_bytes < 4.2e9
