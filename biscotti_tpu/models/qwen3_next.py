@@ -54,8 +54,8 @@ dispatch, from the shapes alone.
 The trainable tree is {"layers": [{"out", "qkvz"} or {"k", "o", "q", "v"}:
 B [r, out]]}; the frozen tree holds everything else in `dtype`. A block of
 peers meets the expert dispatch ONCE a layer (models/laguna.py's module
-doc); the attention runs a peer at a time inside it (`lm.peer_at_a_time`),
-the delta net the block's windows as one batch (`_layer_of` says why), and
+doc); both mixers, the attention and the delta net, run a peer at a time
+inside it (`_layer_of` says how, and what it was measured against), and
 only the adapters' `B` carry the peer axis.
 """
 
@@ -296,20 +296,40 @@ def _mlp(cfg, h, frozen):
 @functools.partial(jax.jit, static_argnums=(0, 1))
 def _layer_of(cfg, kind, h, frozen, adapters):
     """A layer of `kind`. Jitted, so that a round traces the two kinds of
-    layer and not the twelve layers (models/laguna.py:_layer_as). Inside a
-    block of peers the attention runs a peer at a time, as the sibling
-    models' does; the delta net runs the block's windows as ONE batch: its
-    mixer keeps a dozen float32 arrays of a window's size for its
-    backward, and the walk's stacking of them cost more than the batch (a
-    forced block of 3 on the v5e: 4,047 ms a round walked, 3,557 not: PR
-    38; since PR 39 the rule is a kernel whose grid walks the windows and
-    the cell runs a block of 3: PERF.md section 6)."""
-    if kind == "gdn":
-        mixed = _delta_net(cfg, h, frozen, adapters)
+    layer and not the twelve layers (models/laguna.py:_layer_as). The
+    block of peers is there for the routed experts; inside it BOTH mixers
+    run a peer at a time, ONE walk a layer whose body is the whole mixer
+    (each of its parts opens its scope inside, so a trace reads the walk
+    itself as `peer_walk` and the parts as their own). The delta net's
+    dozen float32 passes as wide as `in_proj_qkvz` (conv, silu, split,
+    gated norm, the casts around the rule) stay in the chip's fast memory
+    on one window and stream from HBM on three: on the v5e `gdn_proj` +
+    `gdn_conv` + `gdn_gate` read 839.6 ms a round on the block of 3 and
+    407.6 at a block of 1 (PR 39's two sides; PRs 42 and 43 walked them
+    and were refused on other grounds: PERF.md section 6, PR 49).
+    The attention's walk is `lm.peer_at_a_time`, a `lax.map` whose
+    backward pass reads what the forward loop STACKED over the peers (27.6
+    ms of `peer_walk` a round); of the delta net's mixer that is 0.29 GB a
+    peer and layer, written and read back through HBM, which gave back
+    373 of the 597 ms the passes had gained (`peer_walk` and `mixed`: the
+    same entry). So the delta net's walk is unrolled: a peer's mixer
+    after the other on slices of the block, their results joined once,
+    and nothing stacked.
+    While the rule was `jax.numpy` a walk LOST (4,047 ms a round walked,
+    3,557 not: PR 38); since PR 39 the rule is a kernel. A block of ONE
+    peer walks nothing and lowers as before the walk was there, so a
+    model whose cell runs a block of 1 (models/olmo_hybrid.py) gains
+    nothing from it."""
+    if kind == "gdn" and h.shape[0] > 1:
+        with jax.named_scope("peer_walk"):
+            mixed = jnp.concatenate([
+                _delta_net(cfg, h[peer:peer + 1], frozen,
+                           jax.tree.map(lambda b: b[peer:peer + 1], adapters))
+                for peer in range(h.shape[0])])
     else:
+        mixer = _delta_net if kind == "gdn" else _attention
         mixed = lm.peer_at_a_time(
-            lambda h, adapters: _attention(cfg, h, frozen, adapters), h,
-            adapters)
+            lambda h, adapters: mixer(cfg, h, frozen, adapters), h, adapters)
     # the residual is the mixer's too
     with jax.named_scope("gdn_proj" if kind == "gdn" else "lm_attention"):
         h = h + mixed
@@ -416,7 +436,12 @@ def qwen3_next_model(name: str, cfg: Qwen3NextConfig, length: int):
         standing, three such peers are 0.542 of what the chip's 15.75 GiB
         have left (0.554 by the compiled round's count), inside
         `peer_step.BLOCK_SHARE`: the round walks three at a time (PR 38's
-        count read 0.618 and the round walked one)."""
+        count read 0.618 and the round walked one). Since PR 49 a block's
+        delta net runs a peer at a time and the compiled round's
+        temporaries at 3 are 3.66 GB (2.00 at 1, which walks nothing, as
+        before): a peer adds 0.83 GB, so this count stands a sixth over
+        what the compiler holds; it is kept, the next block the 21 peers
+        divide into is 7, and 7 fit by neither count."""
         t = batch * length
         wide = 2 * cfg.key_heads * cfg.key_dim \
             + 2 * cfg.value_heads * cfg.value_dim
@@ -444,4 +469,10 @@ def qwen3_next_model(name: str, cfg: Qwen3NextConfig, length: int):
                              "ops/delta_rule.py's fused kernel (a chunk's "
                              "system, its solve and the carried state in "
                              "the chip's own memory), 0 the jax.numpy form",
-                             rule["kernel"], {})]})
+                             rule["kernel"], {}),
+                            ("biscotti_gdn_walked_layers",
+                             "delta-net layers whose mixer a block of more "
+                             "than one peer runs a peer at a time (static; "
+                             "beside biscotti_sim_peer_block: above 1 "
+                             "there, the walk ran)",
+                             cfg.layer_types.count("gdn"), {})]})

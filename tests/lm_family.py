@@ -134,6 +134,13 @@ def test_a_block_of_peers_is_each_peer_alone(family, tiny):
     a_block_of_peers_is_each_peer_alone(family, tiny)
 
 
+def walked_names(hlo):
+    """The `op_name`s of a compiled program's text that lie under
+    `lm.peer_at_a_time`'s loop."""
+    return [name for name in re.findall(r'op_name="([^"]*)"', hlo)
+            if "peer_walk" in name]
+
+
 def test_the_attention_is_walked_and_every_scope_is_in_the_round(family):
     """A block's attention layers run their mixer under
     `lm.peer_at_a_time`, what the model keeps out of the walk is out of
@@ -146,8 +153,7 @@ def test_the_attention_is_walked_and_every_scope_is_in_the_round(family):
     for part in family.module.SUBSCOPES:
         assert f"lm_attention/{part}" in hlo, part
     assert sim.peer_block > 1
-    walked = [name for name in re.findall(r'op_name="([^"]*)"', hlo)
-              if "peer_walk" in name]
+    walked = walked_names(hlo)
     for scope in family.walked:
         assert any(scope in name for name in walked), scope
     for scope in family.not_walked:

@@ -7,7 +7,8 @@ simulator's own was, and each tiny preset's page is, family by family, the
 text the parent commit rendered (tests/lm_gauges_63bc454.txt: `# HELP`, `#
 TYPE` and the samples of every `biscotti_{lm,attn,ssm,gdn,moe,sim}_*`
 family after two rounds, read on 63bc454 before the gauges moved out of
-parallel/sim.py; the one clock's samples left out). A gauge renamed,
+parallel/sim.py; the one clock's samples left out; Qwen3-Next's page with
+the one row PR 49 declared, `biscotti_gdn_walked_layers`). A gauge renamed,
 dropped or re-worded fails here."""
 
 import dataclasses

@@ -340,9 +340,10 @@ PARENT_ROUNDS = {
     # read on fd5ebfc (PR 37) before the conv, the gated norm and the
     # step's law moved to models/lm.py for the second hybrid to share
     ("lm_tokens_tiny", "granite_h_tiny"): "ad3429641a9713fb",
-    # read on 19984dc (PR 39) before ops/attention.py learnt a sink and
-    # ops/moe.py's router a choice bias: the fifth cell's round
-    ("lm_tokens_tiny", "qwen3_next_tiny"): "d209b29b660e909d",
+    # PR 49's: a block's delta net runs a peer at a time too, its walk
+    # unrolled (d209b29b660e909d from 19984dc, PR 39, until then:
+    # ops/attention.py's sink and ops/moe.py's choice bias did not move it)
+    ("lm_tokens_tiny", "qwen3_next_tiny"): "fb114805bebced98",
     # read on 63bc454 (PR 44's tree) before the models' builders declared
     # their gauges (PR 46)
     ("lm_tokens_tiny", "mimo_v2_tiny"): "14452e92480a457c",
@@ -801,8 +802,10 @@ def _lowered_sha(lowered):
 # read on 27a3ed4 (PR 46's tree) before ops/delta_rule.py learnt heads that
 # are no whole lane tiles: the published rounds that share the rule's
 # module or the hybrids' mixer parts (tests/test_v4_qwen3_next_lowering.py,
-# tests/test_v3_granite_lowering.py hold their whole rounds to these)
-PARENT_PUBLISHED_ROUNDS = {"lm_tokens_qwen3next": "ed891139b7db5505",
+# tests/test_v3_granite_lowering.py hold their whole rounds to these).
+# Qwen3-Next's is PR 49's, whose block of 3 runs its delta net a peer at a
+# time (ed891139b7db5505 until then); Granite's, a block of 1, is as read
+PARENT_PUBLISHED_ROUNDS = {"lm_tokens_qwen3next": "10d4c12b795b4e77",
                            "lm_tokens_granite": "62d702f5222ff058"}
 # and the rule's kernel pair itself at Qwen3-Next's shapes, as a jaxpr
 # (the text of both kernels' bodies, no path in it)

@@ -24,6 +24,7 @@ from biscotti_tpu.models import granite_hybrid, lm, qwen3_next
 from biscotti_tpu.models.zoo import model_for_dataset
 from biscotti_tpu.ops import delta_rule
 
+from lm_family import walked_names
 from test_v4_delta_rule import _rule_inputs
 
 DATASET = "lm_tokens_tiny"
@@ -352,3 +353,86 @@ def test_a_model_says_which_side_of_the_rules_dispatch_it_runs(case):
         assert info == {"kernel": 0, "states_saved": 0,
                         "key_heads_a_step": 0, "value_heads_a_step": 0,
                         "padded_share": 0.0}
+
+
+# --------------- a block's mixers, a peer at a time (PR 49; appended: the
+# tests above run on the schedule they had)
+
+
+def _a_block_through_a_layer(tiny, kind, peers=3):
+    """(`_layer_of`'s h' and its sum against a cotangent, as a function of
+    (adapters with a peer axis, h [P, 2, 16, 32], the cotangent); those
+    three) at the tiny preset, float32."""
+    frozen = tiny[1]["layers"][TINY.layer_types.index(kind)]
+    keys = jax.random.split(jax.random.PRNGKey(49), 3)
+    h = jax.random.normal(keys[0], (peers, 2, 16, TINY.hidden), jnp.float32)
+    adapters = {
+        name: 0.1 * jax.random.normal(jax.random.fold_in(keys[1], i),
+                                      (peers, TINY.rank, out), jnp.float32)
+        for i, (name, (_, out)) in enumerate(
+            sorted(qwen3_next._widths(TINY, kind).items()))}
+    cot = jax.random.normal(keys[2], h.shape, jnp.float32)
+
+    def through(adapters, h, cot):
+        out = qwen3_next._layer_of(TINY, kind, h, frozen, adapters)[0]
+        return jnp.sum(out * cot), out
+
+    return through, adapters, h, cot
+
+
+@pytest.mark.parametrize("kind", ["gdn", "attention"])
+def test_a_block_of_3_through_a_layer_is_three_blocks_of_1(tiny, kind):
+    """`_layer_of` on a block of three peers (its mixer a peer at a time,
+    its experts on the block's tokens as one batch)
+    gives, peer for peer, the values and the adapters' gradients of three
+    blocks of one peer, which walk nothing (float32, to 1e-6 of the
+    largest entry)."""
+    through, *block = _a_block_through_a_layer(tiny, kind)
+    both = jax.value_and_grad(through, has_aux=True)
+    (_, out), grads = both(*block)
+    assert out.shape == block[1].shape and set(grads) == set(block[0])
+    for peer in range(3):
+        (_, alone), own = both(*jax.tree.map(lambda a: a[peer:peer + 1],
+                                             block))
+        np.testing.assert_allclose(out[peer], alone[0], atol=1e-6
+                                   * float(jnp.max(jnp.abs(alone))))
+        for name, mine in own.items():
+            largest = float(jnp.max(jnp.abs(mine)))
+            assert largest > 1e-3, name
+            np.testing.assert_allclose(grads[name][peer], mine[0],
+                                       atol=1e-6 * largest)
+
+
+@pytest.mark.parametrize("kind", ["gdn", "attention"])
+def test_a_block_of_1_walks_nothing_and_a_block_of_3_walks_the_mixer(tiny,
+                                                                     kind):
+    """The walk follows from the shape of `h` alone: a block of one peer
+    lowers with no `peer_walk` in its text (as before the walk was there),
+    a block of three compiles with the mixer's scopes under it and the
+    router's and the experts' out of it."""
+    mixer = ("gdn_rule", "gdn_conv", "gdn_gate", "gdn_proj") \
+        if kind == "gdn" else ("attn_core", "attn_in", "attn_out")
+
+    def lowered(peers):
+        through, *block = _a_block_through_a_layer(tiny, kind, peers)
+        return jax.jit(jax.grad(lambda *a: through(*a)[0])).lower(*block)
+
+    assert "peer_walk" not in lowered(1).as_text(debug_info=True)
+    walked = walked_names(lowered(3).compile().as_text())
+    for scope in mixer:
+        assert any(scope in name for name in walked), scope
+    for scope in ("lm_experts", "lm_router", "lm_dense"):
+        assert not any(scope in name for name in walked), scope
+
+
+@pytest.mark.parametrize("preset,length,layers", [
+    ("qwen3_next_fedlora", 1024, 9), ("qwen3_next_tiny", 16, 6)])
+def test_a_model_counts_the_delta_net_layers_a_block_walks(preset, length,
+                                                           layers):
+    """`biscotti_gdn_walked_layers`: every delta-net layer of the preset
+    (three of four), declared beside the rule's two gauges."""
+    model = qwen3_next.qwen3_next_model("a", qwen3_next.PRESETS[preset],
+                                        length)
+    rows = {name: value for name, _, value, _ in model.info["gauges"]}
+    assert rows["biscotti_gdn_walked_layers"] == layers
+    assert {"biscotti_gdn_chunks", "biscotti_gdn_rule_kernel"} <= set(rows)

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from biscotti_tpu.parallel.sim import Simulator
+from lm_family import walked_names
 from test_tpu_lowering import (  # noqa: F401  (v5e: the fixture)
     EXPERTS, PARENT_PUBLISHED_ROUNDS, _abstract, _block_gradient, _cfg,
     _described_layer, _experts_gradient, _lowered_sha, v5e)
@@ -29,8 +30,10 @@ def test_the_published_delta_net_round_compiles_for_v5e(v5e, monkeypatch):
     place: the compile sees shapes), walks its peers three at a time
     (one while the delta rule was `jax.numpy`: PR 39's recount of
     `step_bytes`) and fits: 10.85 GB of base and the stacks as arguments,
-    3.98 GB of temporaries; every grouped product, attention core and
-    delta rule a kernel, and no `triangular_solve`."""
+    3.66 GB of temporaries (3.98 until PR 49 ran a block's delta net a
+    peer at a time); every grouped product, attention core and delta rule
+    a kernel, and no `triangular_solve`; inside a block both mixers under
+    `peer_walk`, the router and the experts outside it."""
     from biscotti_tpu.models import lm
 
     monkeypatch.setattr(lm, "_draw", lambda key, shape, fan_in, dtype:
@@ -48,7 +51,8 @@ def test_the_published_delta_net_round_compiles_for_v5e(v5e, monkeypatch):
             + _abstract([sim.x_val, sim.y_val], one)
             + [jax.tree.map(lambda a: _abstract([a], one)[0], sim.frozen)])
     lowered = jax.jit(sim._round_step_raw).lower(*args)
-    # the text it had before the seventh model (PR 48), Mosaic bodies aside
+    # the text PR 49 gave it (the delta net under the walk), Mosaic bodies
+    # aside
     assert _lowered_sha(lowered) == PARENT_PUBLISHED_ROUNDS["lm_tokens_qwen3next"]
     compiled = lowered.compile()
     memory = compiled.memory_analysis()
@@ -62,7 +66,19 @@ def test_the_published_delta_net_round_compiles_for_v5e(v5e, monkeypatch):
                   "lm_attention", "attn_core", "lm_router", "lm_experts",
                   "lm_dense", "lm_head_loss"):
         assert scope in hlo, scope
-    assert "peer_walk" in hlo  # a block's attention, a peer at a time
+    # a block's mixers, a peer at a time: the rule's kernels, the conv and
+    # the gated norm carry the walk in their `op_name`; what the block is
+    # there for does not
+    walked = walked_names(hlo)
+    for scope in ("gdn_rule", "gdn_conv", "gdn_gate", "attn_core"):
+        assert any(scope in name for name in walked), scope
+    for scope in ("lm_experts", "lm_router"):
+        assert not any(scope in name for name in walked), scope
+    kernels = [line for line in hlo.splitlines() if "/round_grad/" in line
+               and "delta_rule_" in line
+               and 'custom_call_target="tpu_custom_call"' in line]
+    assert kernels and all("peer_walk" in line and "f32[1,16,32,128,128]"
+                           in line for line in kernels)  # ONE window a call
     assert "delta_rule_forward" in hlo and "delta_rule_backward" in hlo
 
 

@@ -31,9 +31,10 @@ FAMILY = Family(
     port=13960,
     big=("lm_tokens_qwen3next", "qwen3_next_fedlora", 37984, 2605056,
          5424460992),
-    # the attention under the walk, the delta net the block's windows as
-    # one batch
-    walked=("attn_core",), not_walked=("gdn_",),
+    # both mixers under the walk, a peer at a time; the router and the
+    # experts on the block's tokens as one batch
+    walked=("attn_core", "gdn_rule", "gdn_conv", "gdn_gate"),
+    not_walked=("lm_experts", "lm_router"),
     # 0.970 GB a peer (read off the compiled round's memory analysis with
     # the rule a kernel): three peers are 0.542 of the free bytes, inside
     # `BLOCK_SHARE`, so the cell walks THREE at a time. A tenth less free
@@ -42,12 +43,14 @@ FAMILY = Family(
                 {1.0: 3, 2.0: 3, 0.9: 1}),
     gauges=("biscotti_lm_attention_fused 0",
             "biscotti_lm_attention_shared_key 0", "biscotti_gdn_chunks 4",
-            "biscotti_gdn_rule_kernel 0", "biscotti_moe_tokens_dropped 0",
+            "biscotti_gdn_rule_kernel 0", "biscotti_gdn_walked_layers 6",
+            "biscotti_moe_tokens_dropped 0",
             "biscotti_moe_tile_fill", "biscotti_moe_grouped_kernel 0"),
     no_gauges=("biscotti_ssm_chunks",),
     # the other hybrid states no chunks of the rule
     sibling=("granite_h_tiny", ("biscotti_gdn_chunks",
-                                "biscotti_gdn_rule_kernel")))
+                                "biscotti_gdn_rule_kernel",
+                                "biscotti_gdn_walked_layers")))
 
 
 def test_the_scopes_are_the_models_own_and_the_others_stay_theirs():
